@@ -24,7 +24,7 @@ class ExpiredError(SimulatorError):
 
 class DeviceUnavailableError(SimulatorError):
     """The accelerator backend failed or stopped answering — an XLA
-    runtime error, a wedged chip tunnel, or a dispatch that outlived its
+    runtime error, a hung backend, or a dispatch that outlived its
     watchdog.  Consumers must DEGRADE (host path, circuit breaker)
     rather than crash: the condition is environmental, not a bug."""
 

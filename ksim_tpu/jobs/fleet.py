@@ -984,9 +984,10 @@ def main(argv: "list[str] | None" = None) -> int:
     from ksim_tpu.util import enable_compilation_cache
 
     # A worker is a product entrypoint: arm the persistent XLA compile
-    # cache (KSIM_COMPILE_CACHE) like the simulator/scheduler CLIs do,
-    # so a fleet pointed at one cache dir compiles each rung once
-    # fleet-wide instead of once per process.
+    # cache like the simulator/scheduler CLIs do, so a fleet sharing
+    # one cache dir (JAX_COMPILATION_CACHE_DIR, else the checkout's
+    # .jax_cache) compiles each rung once fleet-wide instead of once
+    # per process.
     enable_compilation_cache()
     jm = JobManager(
         workers=args.workers,

@@ -74,6 +74,8 @@ __all__ = [
     "register_provider",
     "provider_snapshots",
     "process_identity",
+    "note_device",
+    "device_identity",
     "publish_snapshot",
     "read_fleet_snapshots",
     "read_fleet_traces",
@@ -988,18 +990,47 @@ def next_publish_seq() -> int:
         return _publish_seq
 
 
+_NO_DEVICE = {"platform": None, "device_kind": None, "device_count": None}
+_device = _NO_DEVICE  # replaced whole by note_device, never mutated
+
+
+def note_device(platform: str, device_kind: str, device_count: int) -> bool:
+    """Record the JAX backend this process computes on.  This module is
+    stdlib-only, so whoever just completed a dispatch passes the values
+    in (``ksim_tpu.util.note_backend``) — reading them earlier would
+    force a backend init on the caller's thread.  Returns True the
+    first time (the caller logs it once)."""
+    global _device
+    first = _device is _NO_DEVICE
+    _device = {
+        "platform": platform,
+        "device_kind": device_kind,
+        "device_count": device_count,
+    }
+    return first
+
+
+def device_identity() -> dict:
+    """``platform`` / ``device_kind`` / ``device_count`` of the backend
+    that did this process's work; all ``None`` until a dispatch ran."""
+    return dict(_device)
+
+
 def process_identity(
     *, role: "str | None" = None, worker_id: "str | None" = None
 ) -> dict:
     """The process-identity block every metrics document carries (solo
     ``/api/v1/metrics`` and published fleet snapshots alike): who
-    produced this evidence, from which process, alive since when."""
+    produced this evidence, from which process, alive since when, and
+    on which backend (a server that came up on JAX's CPU fallback must
+    be distinguishable from one that holds the chip)."""
     return {
         "role": role or "solo",
         "worker_id": worker_id or f"w{os.getpid()}",
         "pid": os.getpid(),
         "started_at": round(_STARTED_AT, 3),
         "uptime_s": round(time.time() - _STARTED_AT, 3),
+        **_device,
     }
 
 

@@ -54,7 +54,7 @@ from ksim_tpu.obs import TRACE
 from ksim_tpu.state.cluster import ClusterStore, WatchEvent
 from ksim_tpu.state.featurizer import FeaturizedSnapshot, Featurizer
 from ksim_tpu.state.resources import JSON, name_of, namespace_of
-from ksim_tpu.util import Metrics
+from ksim_tpu.util import Metrics, note_backend
 
 logger = logging.getLogger(__name__)
 
@@ -647,6 +647,10 @@ class SchedulerService:
                 for rv in sorted(self._own_rvs, key=int)[:-limit]:
                     self._own_rvs.discard(rv)
         self._record_attempts(placements)
+        if placements:
+            # An engine ran, so the backend exists: publish it (once)
+            # into the metrics identity block.
+            note_backend()
         if TRACE.active:
             TRACE.event(
                 "service.pass",

@@ -394,7 +394,7 @@ class _Handler(BaseHTTPRequestHandler):
             }
         )
         # The process-identity block (role, worker_id, pid, started_at,
-        # uptime_s) — unconditional: the fleet aggregator attributes
+        # uptime_s, platform/device_kind/device_count) — unconditional: the fleet aggregator attributes
         # every snapshot to its producer through it.  Set LAST so no
         # provider can shadow it.
         doc["process"] = process_identity(

@@ -2,54 +2,77 @@
 
 from __future__ import annotations
 
+import logging
 import os
 import threading
 import time
 from typing import Callable, TypeVar
 
-from ksim_tpu.obs import LatencyHistogram
+from ksim_tpu.obs import LatencyHistogram, device_identity, note_device
+
+logger = logging.getLogger(__name__)
 
 T = TypeVar("T")
 
+_REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-def enable_compilation_cache(cache_dir: str | None = None) -> None:
+
+def enable_compilation_cache() -> None:
     """Turn on JAX's persistent (on-disk) compilation cache.
 
     XLA compiles of the scheduling scan at large shapes cost seconds to
     tens of seconds each; the disk cache makes them one-time per machine
-    instead of per process (measured: a 5k-event churn replay drops
-    46s -> 18s on its second cold-process run).  Called by the product
-    entrypoints (simulator/scheduler CLIs, bench) — NOT on library
+    instead of per process.  Called by the product entrypoints
+    (simulator/scheduler CLIs, fleet workers, bench) — NOT on library
     import, so embedding applications keep control of jax.config.
 
-    ``KSIM_COMPILE_CACHE`` overrides the location; set it to ``off`` to
-    disable.
+    Placement has one knob, JAX's own: with ``JAX_COMPILATION_CACHE_DIR``
+    set, JAX reads it itself and this function sets no directory.
+    Otherwise the cache lives at a FIXED path inside the checkout,
+    ``<repo>/.jax_cache/<host fingerprint>`` (the path is part of the
+    cache key, so a directory that moves never hits).
 
-    The default location is fingerprinted by the HOST CPU's feature set:
-    XLA:CPU caches AOT-compiled code, and an artifact produced on a
-    machine with different vector extensions can SIGILL when loaded on
-    this one (cpu_aot_loader warns exactly that; images here migrate
-    across heterogeneous hosts between rounds, and a round-4 suite run
-    crashed on a stale cross-host artifact).  One subdirectory per
-    feature set makes the cache per-machine-model instead of
-    per-filesystem."""
-    env = os.environ.get("KSIM_COMPILE_CACHE")
-    if env == "off":
-        return
-    cache_dir = env or cache_dir
-    if cache_dir is None:
-        cache_dir = os.path.join(
-            os.path.expanduser("~/.cache/ksim_tpu/jax"), _host_fingerprint()
-        )
+    The leaf is fingerprinted by the HOST CPU's feature set: XLA:CPU
+    caches AOT-compiled code, and an artifact produced on a machine with
+    different vector extensions can SIGILL when loaded on this one
+    (cpu_aot_loader warns exactly that).  It is a function of the
+    machine, never of the run."""
     import jax
 
-    try:
-        os.makedirs(cache_dir, exist_ok=True)
-    except OSError:
-        # Read-only HOME (containers): run without the persistent cache.
-        return
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        cache_dir = os.path.join(_REPO_ROOT, ".jax_cache", _host_fingerprint())
+        try:
+            os.makedirs(cache_dir, exist_ok=True)
+        except OSError as e:
+            # Read-only checkout (containers): run without the cache,
+            # but say so — every start then pays every compile.
+            logger.warning(
+                "persistent compile cache disabled: cannot create %s (%s); "
+                "set JAX_COMPILATION_CACHE_DIR to a writable directory",
+                cache_dir, e,
+            )
+            return
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+
+
+def note_backend() -> None:
+    """Publish the JAX backend this process computes on into the
+    process identity (``obs.process_identity``, ``ReplayDriver.stats``)
+    and log it once.  Call only right after a dispatch completed: the
+    backend exists by then, so ``jax.devices()`` is a lookup — never a
+    backend init on the calling thread."""
+    if device_identity()["platform"] is not None:
+        return
+    import jax
+
+    devices = jax.devices()
+    d0 = devices[0]
+    if note_device(d0.platform, d0.device_kind, len(devices)):
+        logger.info(
+            "compute backend: platform=%s device_kind=%s device_count=%d",
+            d0.platform, d0.device_kind, len(devices),
+        )
 
 
 def raise_map_count_limit(target: int = 1_000_000) -> None:

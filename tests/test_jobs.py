@@ -424,6 +424,10 @@ def test_two_same_rung_jobs_compile_once():
             state, result, err = j.result_view()
             assert state == "succeeded", (j.id, state, err)
             assert result["replay"]["device_round_trips"] >= 1, result["replay"]
+            # The result names the backend that ran the segments.
+            assert result["replay"]["platform"] == "cpu", result["replay"]
+            assert result["replay"]["device_kind"]
+            assert result["replay"]["device_count"] >= 1
         s1 = j1.result_view()[1]["result"]
         s2 = j2.result_view()[1]["result"]
         assert (s1["podsScheduled"], s1["unschedulableAttempts"]) == (

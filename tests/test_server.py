@@ -269,6 +269,15 @@ def test_metrics_identity_block_and_prometheus_exposition(server):
     assert set(ident) >= {"role", "worker_id", "pid", "started_at", "uptime_s"}
     assert ident["role"] == "solo" and ident["pid"] == os.getpid()
     assert ident["uptime_s"] >= 0
+    # The backend fields are always present (null until a dispatch ran)
+    # and name the process's backend after one scheduling pass.
+    assert set(ident) >= {"platform", "device_kind", "device_count"}
+    server.di.store.create("nodes", make_node("n0"))
+    server.di.store.create("pods", make_pod("p0"))
+    server.di.scheduler_service.schedule_pending()
+    ident = _req(server, "GET", "/api/v1/metrics")[1]["process"]
+    assert ident["platform"] == "cpu" and ident["device_kind"]
+    assert ident["device_count"] >= 1
     for path in ("/metrics", "/metrics?scope=fleet"):
         status, text = _raw(server, "GET", path)
         assert status == 200, path

@@ -32,7 +32,7 @@ from dataclasses import dataclass, replace
 
 #: What ``make lint`` (and the no-argument CLI) analyzes.  tests/ is
 #: deliberately out of scope: fixtures there contain SEEDED violations.
-DEFAULT_TARGETS: tuple[str, ...] = ("ksim_tpu", "bench.py", "tools")
+DEFAULT_TARGETS: tuple[str, ...] = ("ksim_tpu", "bench.py", "chip_smoke.py", "tools")
 
 _DISABLE_RE = re.compile(r"ksimlint:\s*disable=([\w,-]+)")
 

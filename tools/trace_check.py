@@ -25,7 +25,7 @@ any hardware condition, like ``make faults``), then validates:
   submit→claim→run flow-event triple (``s``/``t``/``f``) per job.
 
 The parent process is stdlib-only (the bench.py crash-containment
-pattern: jax backend init can wedge on a dead chip, so anything that
+pattern: jax backend init can hang on a dead backend, so anything that
 must complete runs jax only in subprocesses)."""
 
 from __future__ import annotations
@@ -128,17 +128,20 @@ def _child_fleet_obs(out_path: str) -> None:
 
     from ksim_tpu import obs
     from ksim_tpu.jobs import JobManager
-    from tests.helpers import make_node, make_pod
+    from tests.helpers import make_node, make_pod, sanitized_cpu_env
 
     jobs_dir = tf.mkdtemp(prefix="ksim_fleet_obs_")
     workers: dict = {}
+    # Explicitly CPU, whatever this child inherited: two worker
+    # processes cannot share one chip (docs/jobs.md "Multi-worker fleet").
+    worker_env = sanitized_cpu_env()
     for wid in ("w1", "w2"):
         proc = subprocess.Popen(
             [
                 sys.executable, "-m", "ksim_tpu.jobs",
                 "--dir", jobs_dir, "--worker-id", wid, "--workers", "1",
             ],
-            cwd=_REPO, stdout=subprocess.PIPE, text=True,
+            cwd=_REPO, env=worker_env, stdout=subprocess.PIPE, text=True,
         )
         line = proc.stdout.readline()
         if line.strip() != f"READY {wid}":
