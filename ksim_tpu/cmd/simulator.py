@@ -28,7 +28,8 @@ def start_simulator(argv: list[str] | None = None) -> int:
         "--profile-dir",
         default=None,
         help="write a jax.profiler trace (TensorBoard format) of the "
-        "scheduling passes to this directory",
+        "server's life to this directory: device ops with the program's "
+        "own spans beside them (Python tracer off)",
     )
     args = ap.parse_args(argv)
 

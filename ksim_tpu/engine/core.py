@@ -48,6 +48,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ksim_tpu.engine.kernelreg import device_kernel
+from ksim_tpu.obs import TRACE
 from ksim_tpu.plugins.base import (
     FilterOutput,
     NodeStateView,
@@ -828,12 +829,18 @@ class Engine:
         *,
         record: str = "full",  # full | final | selection
         sampling_k: int | None = None,
+        metrics=None,
     ) -> None:
         """``sampling_k`` enables percentageOfNodesToScore emulation on
         the ``schedule`` path: each pod's cycle visits nodes from a
         rotating start index and stops after finding K feasible — only
         visited nodes are scored/recorded, exactly upstream's adaptive
-        sampling (scan-only; batch evaluation has no visit order)."""
+        sampling (scan-only; batch evaluation has no visit order).
+
+        ``metrics`` (a ``util.Metrics``; the scheduler service passes
+        its own) receives the pass's device phases as timers
+        ``engine_pack`` / ``engine_exec`` / ``engine_pull``, from the
+        same clock readings as the ``engine.*`` spans."""
         if record not in ("full", "final", "selection"):
             raise ValueError(f"unknown record mode {record!r}")
         # Validate against the REAL node count, not the padded axis: a K
@@ -868,10 +875,12 @@ class Engine:
             has_requests=p.has_requests,
             index=p.index,
         )
-        aux_host, self._aux_axes = _aux_host(feats.aux)
-        node_dev, pod_dev, self._aux = _pack_tree_to_device(
-            (node_host, pod_host, aux_host)
-        )
+        self._metrics = metrics
+        with TRACE.phase("engine.pack", metrics, "engine_pack"):
+            aux_host, self._aux_axes = _aux_host(feats.aux)
+            node_dev, pod_dev, self._aux = _pack_tree_to_device(
+                (node_host, pod_host, aux_host)
+            )
         self._node_state = NodeStateView(**node_dev)
         self._pods = PodBatch(**pod_dev)
         self._sharded = False
@@ -1165,15 +1174,20 @@ class Engine:
             pods_c = jax.tree_util.tree_map(
                 lambda x: x[s : s + chunk], self._pods
             )
-            if sampled:
-                state, carries, start, out = self._prog._schedule_sampled_fn(
-                    state, pods_c, self._aux, carries, start, n_real
-                )
-            else:
-                state, carries, out = self._prog._schedule_fn(
-                    state, pods_c, self._aux, carries
-                )
-            outs.append(_pull_tree_to_host(out))
+            with TRACE.phase("engine.exec", self._metrics, "engine_exec"):
+                if sampled:
+                    state, carries, start, out = self._prog._schedule_sampled_fn(
+                        state, pods_c, self._aux, carries, start, n_real
+                    )
+                else:
+                    state, carries, out = self._prog._schedule_fn(
+                        state, pods_c, self._aux, carries
+                    )
+                # The pull below blocks here anyway: waiting inside the
+                # phase splits device wait from the transfer.
+                jax.block_until_ready(out)
+            with TRACE.phase("engine.pull", self._metrics, "engine_pull"):
+                outs.append(_pull_tree_to_host(out))
         merged = jax.tree_util.tree_map(
             lambda *xs: np.concatenate(xs, axis=0), *outs
         )

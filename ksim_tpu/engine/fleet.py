@@ -404,7 +404,8 @@ class FleetDriver:
                 lane_pulled = jax.tree_util.tree_map(lambda a, j=j: a[j], pulled)
             else:
                 lane_state, lane_pulled = pulled_state, pulled
-            res = ln.driver._decode_outputs(plan, lane_state, lane_pulled)
+            with TRACE.span("replay.decode", lane=ln.idx):
+                res = ln.driver._decode_outputs(plan, lane_state, lane_pulled)
             if isinstance(res, str):
                 # Post-dispatch validation discard — deterministic over
                 # identical inputs, so every lane lands here together

@@ -1407,6 +1407,9 @@ class ReplayDriver:
         self.device_steps = 0  # guarded-by: main-thread
         self.fallback_steps = 0  # guarded-by: main-thread
         self.device_round_trips = 0  # guarded-by: main-thread
+        # Summed replay.exec walls (launch until outputs ready) of the
+        # healthy dispatches: an upper bound of this run's device time.
+        self.device_wait_s = 0.0  # guarded-by: main-thread
         # Streaming ingest overlap (round 22, traces/stream.py): a
         # runner-provided NONBLOCKING drain of the trace-ingest queue,
         # called on the main thread while the dispatch worker owns the
@@ -1525,6 +1528,7 @@ class ReplayDriver:
             "device_steps": self.device_steps,
             "fallback_steps": self.fallback_steps,
             "device_round_trips": self.device_round_trips,
+            "device_wait_s": round(self.device_wait_s, 6),
             "ingest_prefetches": self.ingest_prefetches,
             "device_errors": self.device_errors,
             "watchdog_timeouts": self.watchdog_timeouts,
@@ -1819,9 +1823,11 @@ class ReplayDriver:
         try:
             with TRACE.span(
                 "replay.prelower", segment=self._segment_seq, steps=len(nxt)
-            ):
+            ) as sp:
                 FAULTS.check("replay.prelower")
+                sp.lap("replay.lower.parse")
                 spec = self._parse_window(nxt)
+                sp.lap("replay.lower.warm")
                 self._warm_spec(spec)
         except Exception as e:
             # Catch EVERYTHING, not just SimulatorError: this runs while
@@ -1991,6 +1997,7 @@ class ReplayDriver:
                 if check_lane_faults and self._lane_faults is not None:
                     self._lane_faults.check("replay.lower")
                 if spec is None:
+                    sp.lap("replay.lower.parse")
                     spec = self._parse_window(batches[:wlen])
                 m = min(spec.n, wlen)
                 if m == 0:
@@ -1999,7 +2006,7 @@ class ReplayDriver:
                 # actually-lowered count so lower spans line up with
                 # dispatch spans on short (vocabulary-miss) segments.
                 sp.set(steps=m)
-                plan = self._lower(list(batches[:m]), spec)
+                plan = self._lower(list(batches[:m]), spec, sp)
         except ReplayFallback as e:
             self._reject(str(e))
             return None
@@ -2073,6 +2080,7 @@ class ReplayDriver:
             # driver to the device path.
             self._breaker_close()
         self.device_round_trips += 1
+        self.device_wait_s += plan.exec_s
         note_backend()
         if self._dev_cache_on is None:
             # Safe to probe now: the dispatch initialized the backend on
@@ -2309,12 +2317,18 @@ class ReplayDriver:
             svc._featurizers[name] = feat
         return feat
 
-    def _lower(self, batches: list[list[Any]], spec: _WindowSpec):
+    def _lower(self, batches: list[list[Any]], spec: _WindowSpec, span):
+        """Lower one validated window into a dispatch-ready plan.  ``span``
+        is the enclosing ``replay.lower`` span: the three phases below
+        are its sequential children (``span.lap``), so the timeline says
+        whether a slow lowering is universe assembly, the featurizer or
+        the tensor build."""
         from ksim_tpu.engine.core import _Program
         from ksim_tpu.scheduler.service import queue_sort_key
         from ksim_tpu.state.featurizer import bucket_size
         from ksim_tpu.state.priorities import build_priority_resolver
 
+        span.lap("replay.lower.universe")
         svc = self.service
         store = self.store
         for kind in ("persistentvolumes", "persistentvolumeclaims", "storageclasses"):
@@ -2504,6 +2518,7 @@ class ReplayDriver:
         # the identity-stable cached universe, fresh row builds are
         # O(window creates) — tracked in pod_rows_built and logged per
         # segment in lower_log for the counter-based O(delta) guard).
+        span.lap("replay.lower.featurize")
         if self._featurizer is None:
             if svc._plugins_factory is not None:
                 from ksim_tpu.state.featurizer import Featurizer
@@ -2567,6 +2582,7 @@ class ReplayDriver:
             ):
                 raise _Unsupported("preemption_filter_set")
 
+        span.lap("replay.lower.tensors")
         N = feats.nodes.padded
         P = feats.pods.requests.shape[0]
         K = k_pad
@@ -3062,7 +3078,8 @@ class ReplayDriver:
             # schedule.
             self._lane_faults.check("replay.dispatch")
         pulled_state, pulled = self._device_exec(plan)
-        return self._decode_outputs(plan, pulled_state, pulled)
+        with TRACE.span("replay.decode", segment=self._segment_seq):
+            return self._decode_outputs(plan, pulled_state, pulled)
 
     def _device_exec(self, plan: "_SegmentPlan"):  # ksimlint: worker-thread
         """The device half of a dispatch: pack constants (id-keyed
@@ -3073,54 +3090,52 @@ class ReplayDriver:
         compile-once gate (engine/compilecache.py): the first caller of
         a shape rung compiles, concurrent same-rung callers — other
         tenant jobs on the same bucketed shapes — wait and reuse."""
-        from ksim_tpu.engine.core import _pull_tree_to_host
-
         FAULTS.check("replay.dispatch")
-        if plan.statics.tp > 1:
-            # Round 17: committed NamedShardings on every input leaf —
-            # GSPMD lays the node axis over the tp mesh and inserts the
-            # per-step collectives; the scan carry stays sharded on
-            # device end to end.  An explicit service mesh rides on the
-            # plan; the env-knob mesh is built lazily HERE (this is the
-            # watchdogged worker — jax.devices() may initialize the
-            # backend, which must never happen on the main thread).
-            mesh = plan.mesh if plan.mesh is not None else _tp_mesh(plan.statics.tp)
-            const_dev, (ev_dev, state_dev) = _shard_plan_buffers(
-                plan, (plan.ev, plan.state0), mesh
-            )
-        else:
-            mesh = None
-            const_dev, (ev_dev, state_dev) = _pack_plan_buffers(
-                plan, (plan.ev, plan.state0)
-            )
+        with TRACE.span("replay.pack", segment=self._segment_seq):
+            if plan.statics.tp > 1:
+                # Round 17: committed NamedShardings on every input leaf
+                # — GSPMD lays the node axis over the tp mesh and inserts
+                # the per-step collectives; the scan carry stays sharded
+                # on device end to end.  An explicit service mesh rides
+                # on the plan; the env-knob mesh is built lazily HERE
+                # (this is the watchdogged worker — jax.devices() may
+                # initialize the backend, which must never happen on the
+                # main thread).
+                mesh = (
+                    plan.mesh if plan.mesh is not None else _tp_mesh(plan.statics.tp)
+                )
+                const_dev, (ev_dev, state_dev) = _shard_plan_buffers(
+                    plan, (plan.ev, plan.state0), mesh
+                )
+            else:
+                mesh = None
+                const_dev, (ev_dev, state_dev) = _pack_plan_buffers(
+                    plan, (plan.ev, plan.state0)
+                )
         # Mesh dispatches take the non-donating twin — donated
         # multi-device carries race on the virtual-device CPU backend
         # (the _DONATE_ARGNUMS note); the cache key's mesh component
         # keeps the two executables distinct.
         seg_fn = _segment_fn if mesh is None else _segment_fn_nodonate
-        final_state, outs = COMPILE_CACHE.run(
-            _compile_cache_key("solo", plan, (const_dev, ev_dev, state_dev), mesh=mesh),
-            lambda: seg_fn(
-                plan.statics, plan.prog, const_dev, ev_dev, state_dev
+        return _exec_and_pull(
+            plan,
+            lambda: COMPILE_CACHE.run(
+                _compile_cache_key(
+                    "solo", plan, (const_dev, ev_dev, state_dev), mesh=mesh
+                ),
+                lambda: seg_fn(
+                    plan.statics, plan.prog, const_dev, ev_dev, state_dev
+                ),
+                owner=TRACE.scope_tags().get("job"),
+                wait_s=self.watchdog_s if self.watchdog_s > 0 else 300.0,
+                # The persistent layer (round 15): a warm restart loads
+                # the serialized executable instead of re-compiling; None
+                # when KSIM_AOT_CACHE is off/unset (and no KSIM_JOBS_DIR)
+                # or the plan's identity is process-local.
+                disk=_aot_disk_spec("solo", plan, (const_dev, ev_dev, state_dev)),
             ),
-            owner=TRACE.scope_tags().get("job"),
-            wait_s=self.watchdog_s if self.watchdog_s > 0 else 300.0,
-            # The persistent layer (round 15): a warm restart loads the
-            # serialized executable instead of re-compiling; None when
-            # KSIM_AOT_CACHE is off/unset (and no KSIM_JOBS_DIR) or the
-            # plan's identity is process-local.
-            disk=_aot_disk_spec("solo", plan, (const_dev, ev_dev, state_dev)),
+            segment=self._segment_seq,
         )
-        pulled_state, pulled = _pull_tree_to_host(
-            (
-                {
-                    k: final_state[k]
-                    for k in ("alive", "bound", "attempts", "retry_at", "pass_count")
-                },
-                outs,
-            )
-        )
-        return pulled_state, pulled
 
     def _decode_outputs(  # ksimlint: worker-thread
         self, plan: "_SegmentPlan", pulled_state, pulled
@@ -3953,38 +3968,65 @@ def _fleet_exec(plan: "_SegmentPlan", lanes_state0, mesh=None):
     lane's slice through ``ReplayDriver._decode_outputs``.  Module
     function, side-effect-free on every driver (packing evidence rides
     on the plan, applied by the fleet on the main thread)."""
-    from ksim_tpu.engine.core import _pull_tree_to_host
-
     FAULTS.check("replay.dispatch")
-    st_s = jax.tree_util.tree_map(lambda *xs: np.stack(xs), *lanes_state0)
-    if mesh is not None:
-        const_dev, (ev_dev, state_dev) = _shard_plan_buffers(
-            plan,
-            (plan.ev, st_s),
-            mesh,
-            specs=_fleet_shard_specs(plan, (plan.ev, st_s), mesh),
-        )
-    else:
-        const_dev, (ev_dev, state_dev) = _pack_plan_buffers(plan, (plan.ev, st_s))
+    with TRACE.span("replay.pack", lanes=len(lanes_state0)):
+        st_s = jax.tree_util.tree_map(lambda *xs: np.stack(xs), *lanes_state0)
+        if mesh is not None:
+            const_dev, (ev_dev, state_dev) = _shard_plan_buffers(
+                plan,
+                (plan.ev, st_s),
+                mesh,
+                specs=_fleet_shard_specs(plan, (plan.ev, st_s), mesh),
+            )
+        else:
+            const_dev, (ev_dev, state_dev) = _pack_plan_buffers(
+                plan, (plan.ev, st_s)
+            )
     # Mesh cohorts take the non-donating twin (_DONATE_ARGNUMS note:
     # donated multi-device carries race on virtual CPU devices).
     fleet_fn = _fleet_segment_fn if mesh is None else _fleet_segment_fn_nodonate
-    final_state, outs = COMPILE_CACHE.run(
-        _compile_cache_key("fleet", plan, (const_dev, ev_dev, state_dev), mesh=mesh),
-        lambda: fleet_fn(
-            plan.statics, plan.prog, const_dev, ev_dev, state_dev
+    return _exec_and_pull(
+        plan,
+        lambda: COMPILE_CACHE.run(
+            _compile_cache_key(
+                "fleet", plan, (const_dev, ev_dev, state_dev), mesh=mesh
+            ),
+            lambda: fleet_fn(
+                plan.statics, plan.prog, const_dev, ev_dev, state_dev
+            ),
+            owner=TRACE.scope_tags().get("job"),
         ),
-        owner=TRACE.scope_tags().get("job"),
+        lanes=len(lanes_state0),
     )
-    return _pull_tree_to_host(
-        (
-            {
-                k: final_state[k]
-                for k in ("alive", "bound", "attempts", "retry_at", "pass_count")
-            },
-            outs,
+
+
+def _exec_and_pull(plan: "_SegmentPlan", launch, **tags):
+    """The device half of a dispatch after packing, shared by the solo
+    and fleet executors (worker thread): ``launch()`` starts the
+    compiled program (compile-or-load inside ``COMPILE_CACHE.run``) and
+    returns ``(final_state, outs)``; ``replay.exec`` ends when those are
+    READY — the pull would block there anyway, so waiting inside the
+    span costs nothing and makes its wall an upper bound of the
+    dispatch's device time (``plan.exec_s``, summed into the job
+    result's ``replay.device_wait_s`` with tracing on or off).
+    ``replay.pull`` is then the device->host transfer alone."""
+    from ksim_tpu.engine.core import _pull_tree_to_host
+
+    t0 = time.perf_counter()
+    with TRACE.span("replay.exec", **tags):
+        final_state, outs = launch()
+        jax.block_until_ready((final_state, outs))
+    plan.exec_s = time.perf_counter() - t0
+    with TRACE.span("replay.pull", **tags):
+        return _pull_tree_to_host(
+            (
+                {
+                    k: final_state[k]
+                    for k in ("alive", "bound", "attempts", "retry_at", "pass_count")
+                },
+                outs,
+            )
         )
-    )
 
 
 @dataclass
@@ -4023,6 +4065,7 @@ class _SegmentPlan:
     dev_map_out: "dict | None" = None
     dev_hits: int = 0
     dev_misses: int = 0
+    exec_s: float = 0.0  # wall of this dispatch's replay.exec (worker)
     # Round 19: device-buffer LAYOUT tokens — ``dev_reuse_layout`` is
     # the token the attached reuse map's buffers were committed under
     # (("pack",) for the single-device packed transfer, ("mesh", dp, tp)

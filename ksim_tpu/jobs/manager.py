@@ -106,7 +106,7 @@ from ksim_tpu.errors import RunCancelled
 from ksim_tpu.faults import FAULTS, FaultPlane
 from ksim_tpu.jobs.journal import JOURNAL_NAME, JobJournal
 from ksim_tpu.jobs.queue import JobQueue, JobQueueFull
-from ksim_tpu.obs import TRACE, TracePlane
+from ksim_tpu.obs import TRACE, TracePlane, runtime_growth, runtime_totals
 
 logger = logging.getLogger(__name__)
 
@@ -358,7 +358,14 @@ class Job:
         # tagged with the job id; the sink feeds the SSE event log.
         self.trace = TracePlane(tags={"job": job_id})
         self.trace.configure_from_env(
-            {"KSIM_TRACE_RING": str(ring_cap), "KSIM_TRACE": "1"}
+            {
+                "KSIM_TRACE_RING": str(ring_cap),
+                "KSIM_TRACE": "1",
+                # One clock: under KSIM_TRACE_JAX=1 the job's spans (main
+                # and dispatch-worker thread) are profiler annotations
+                # too, beside the device lines, like the global plane's.
+                "KSIM_TRACE_JAX": "1" if TRACE.jax_bridge else "",
+            }
         )
         self.trace.set_sink(self._on_record)
         self._max_events = max_events
@@ -1436,6 +1443,7 @@ class JobManager:
             job.finish("failed", error="journal append failed (running)")
             return
         try:
+            runtime0 = runtime_totals()
             with TRACE.scoped(job.trace):
                 with TRACE.span("jobs.run", steps=job.steps_total):
                     FAULTS.check("jobs.run")
@@ -1443,6 +1451,9 @@ class JobManager:
                         job.faults.check("jobs.run")
                     res, runner = self._execute(job)
             result = self._result_doc(job, res, runner)
+            # Full collections and XLA compiles / cache loads the PROCESS
+            # saw while this job ran (other jobs' included).
+            result["runtime"] = runtime_growth(runtime0)
             # WAL: result + terminal record become durable BEFORE the
             # in-memory success — a success the journal cannot vouch
             # for must not be reported (it would vanish on restart).

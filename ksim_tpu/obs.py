@@ -44,7 +44,19 @@ Environment:
 - ``KSIM_TRACE_RING=N``    ring capacity (default 65536 records).
 - ``KSIM_TRACE_JAX=1``     also wrap spans in
   ``jax.profiler.TraceAnnotation`` (guarded; no-op if jax is absent or
-  no profiler session is active).
+  no profiler session is active).  A job's private plane inherits the
+  flag (jobs/manager.py), so job spans sit on the profiler's own clock
+  beside the device lines.
+
+Two process-level evidence streams ride beside the planes, always on:
+full (generation-2) garbage collections, timed by ONE ``gc.callbacks``
+hook this module installs at import, and XLA compiles / persistent-cache
+loads, fed by ``jax.monitoring`` listeners that ``ksim_tpu.util``
+registers once jax is in the process (this module stays stdlib-only).
+Both land in ``runtime_snapshot()`` (``/api/v1/metrics`` ``counters`` /
+``timings``) and, as growth across a job, in the job result's
+``runtime`` block; with a plane active they also show on the timeline
+(``service.gc`` spans, ``engine.compile`` instants).
 
 The span/event name taxonomy lives in ``SPAN_NAMES`` / ``EVENT_NAMES``
 below; tests/test_obs.py's registry-sync test asserts every
@@ -56,6 +68,7 @@ from __future__ import annotations
 
 import atexit
 import bisect
+import gc
 import json
 import os
 import threading
@@ -76,6 +89,11 @@ __all__ = [
     "process_identity",
     "note_device",
     "device_identity",
+    "note_xla_compile",
+    "note_xla_cache_load",
+    "runtime_snapshot",
+    "runtime_totals",
+    "runtime_growth",
     "publish_snapshot",
     "read_fleet_snapshots",
     "read_fleet_traces",
@@ -100,10 +118,51 @@ SPAN_NAMES: tuple[str, ...] = (
     #                     (runs on the main thread INSIDE the dispatch
     #                     span's wall-clock window — the two are
     #                     concurrent by design, not additive)
+    "replay.lower.parse",  # child of replay.lower / replay.prelower:
+    #                        the window's store-independent parse
+    #                        (_parse_window) — under replay.lower only
+    #                        when no speculative spec could be consumed
+    "replay.lower.warm",  # child of replay.prelower: per-object parse
+    #                       memos warmed for the window's created objects
+    "replay.lower.universe",  # child of replay.lower: store-dependent
+    #                           window validation + the cached universe
+    #                           merge, up to the featurizer call
+    "replay.lower.featurize",  # child of replay.lower: the
+    #                            Featurizer.featurize call, the slot map
+    #                            and the plugin/program build
+    "replay.lower.tensors",  # child of replay.lower: initial state,
+    #                          inter-pod locals, event index tensors,
+    #                          slot simulation, preemption ranks, consts
     "replay.dispatch",  # device dispatch incl. watchdog wait
+    "replay.pack",  # dispatch worker: host->device transfer of the
+    #                 plan's constants + transient trees (H2D)
+    "replay.exec",  # dispatch worker: launch (compile-or-load inside)
+    #                 until the program's outputs are ready — an upper
+    #                 bound of the device time of one dispatch
+    "replay.pull",  # dispatch worker: device->host transfer (D2H)
+    "replay.decode",  # dispatch worker: host decode of the pulled
+    #                   tensors into the SegmentOutcome
     "replay.reconcile",  # staged store reconcile (the segment txn)
     "runner.step",  # one per-pass host step (ops + flush + schedule)
     "service.schedule",  # one scheduling pass (scheduler/service.py)
+    "service.featurize",  # pass phase: featurize (Metrics timer
+    #                       ``featurize``, same clock reading)
+    "engine.pack",  # pass phase: Engine construction = host->device
+    #                 pack of the snapshot (timer ``engine_pack``)
+    "engine.exec",  # pass phase: one scan dispatch until its outputs
+    #                 are ready (timer ``engine_exec``)
+    "engine.pull",  # pass phase: device->host pull of one dispatch's
+    #                 results (timer ``engine_pull``)
+    "service.bind",  # pass phase: decode + annotation render + store
+    #                  writes of every pod of the pass (timer ``bind``;
+    #                  args render_s / store_s = the per-pod sums)
+    "service.import",  # POST /api/v1/import: body parse + snapshot load
+    #                    (server/http.py; timer ``import_load``)
+    "service.export",  # GET /api/v1/export: snapshot + encode + write
+    #                    (timers ``export_snap`` / ``export_encode``)
+    "service.gc",  # one full (generation-2) garbage collection, on the
+    #                collecting thread's plane (args generation,
+    #                collected) — recorded by this module's gc hook
     "writeback.push",  # live-cluster write-back push
     "kubeapi.request",  # any kube-apiserver HTTP request
     "jobs.run",  # one tenant job end-to-end on a job-plane worker
@@ -210,6 +269,9 @@ EVENT_NAMES: tuple[str, ...] = (
     #                        (args: worker / stale_s — the dead worker
     #                        is FLAGGED in the merged doc, never
     #                        silently dropped)
+    "engine.compile",  # XLA compiled a program (not a cache load) on
+    #                    this thread (args.seconds) — the timeline shows
+    #                    inside which span a step recompiled
     "traces.ingest_fallback",  # the streaming producer degraded to the
     #                            materialized ingest path (args.reason —
     #                            an armed fault or unexpected error
@@ -462,17 +524,46 @@ class _NoopSpan:
     def set(self, **args) -> None:
         pass
 
+    def lap(self, name: str, **args) -> None:
+        pass
+
 
 _NOOP = _NoopSpan()
+
+
+def _jax_annotation(name: str):
+    """Enter a ``jax.profiler.TraceAnnotation`` (the ``KSIM_TRACE_JAX``
+    bridge); None when jax is absent or the annotation fails."""
+    try:
+        import jax
+
+        ctx = jax.profiler.TraceAnnotation(name)
+        ctx.__enter__()
+        return ctx
+    except Exception:
+        return None
+
+
+def _jax_annotation_exit(ctx) -> None:
+    try:
+        ctx.__exit__(None, None, None)
+    except Exception:
+        pass
 
 
 class _Span:
     """One live span.  Records at EXIT: a span that never exits (a
     wedged dispatch abandoned with its watchdog worker) simply leaves
     no record — the caller-side watchdog timeout event is the evidence
-    for that case."""
+    for that case.
 
-    __slots__ = ("_plane", "name", "args", "_t0", "_jax_ctx")
+    ``_observe`` / ``_timer`` (``TracePlane.phase``) feed a
+    ``Metrics``-style timer from the SAME pair of clock reads, so the
+    timer and the span histogram can never disagree."""
+
+    __slots__ = (
+        "_plane", "name", "args", "_t0", "_jax_ctx", "_lap", "_observe", "_timer",
+    )
 
     def __init__(self, plane: "TracePlane", name: str, args: dict) -> None:
         self._plane = plane
@@ -480,6 +571,9 @@ class _Span:
         self.args = args
         self._t0 = 0
         self._jax_ctx = None
+        self._lap = None  # (name, t0, args, jax_ctx) of the open lap
+        self._observe = None
+        self._timer = None
 
     def __enter__(self):
         plane = self._plane
@@ -488,13 +582,7 @@ class _Span:
         if plane._jax_bridge:
             # Guarded device-timeline bridge: annotations show up in a
             # captured jax profile next to the XLA ops they enclose.
-            try:
-                import jax
-
-                self._jax_ctx = jax.profiler.TraceAnnotation(self.name)
-                self._jax_ctx.__enter__()
-            except Exception:
-                self._jax_ctx = None
+            self._jax_ctx = _jax_annotation(self.name)
         self._t0 = time.perf_counter_ns()
         return self
 
@@ -504,13 +592,43 @@ class _Span:
         lowered step count of a window that hit a vocabulary miss."""
         self.args.update(args)
 
+    def lap(self, name: str, **args) -> None:
+        """Open the next SEQUENTIAL child phase of this span, closing
+        the one before it at the same clock reading; the span's own exit
+        closes the last.  For a long straight-line body (the segment
+        lowering) whose phases follow one another: one call per
+        boundary instead of re-indenting the body under ``with``
+        blocks, and an exception anywhere still closes the open lap.
+        ``name`` must be a string literal (registry-literals lint).
+        Same thread as the span, like every span."""
+        now = time.perf_counter_ns()
+        self._end_lap(now)
+        plane = self._plane
+        tl = plane._tls
+        tl.depth = getattr(tl, "depth", 0) + 1
+        ctx = _jax_annotation(name) if plane._jax_bridge else None
+        self._lap = (name, now, args, ctx)
+
+    def _end_lap(self, now: int) -> None:
+        lap = self._lap
+        if lap is None:
+            return
+        self._lap = None
+        name, t0, args, ctx = lap
+        if ctx is not None:
+            _jax_annotation_exit(ctx)
+        tl = self._plane._tls
+        depth = getattr(tl, "depth", 1)
+        tl.depth = depth - 1
+        self._plane._record_span(name, t0, now, depth - 1, args)
+
     def __exit__(self, exc_type, exc, tb):
         t1 = time.perf_counter_ns()
+        self._end_lap(t1)
         if self._jax_ctx is not None:
-            try:
-                self._jax_ctx.__exit__(exc_type, exc, tb)
-            except Exception:
-                pass
+            _jax_annotation_exit(self._jax_ctx)
+        if self._observe is not None:
+            self._observe(self._timer, (t1 - self._t0) / 1e9)
         plane = self._plane
         tl = plane._tls
         depth = getattr(tl, "depth", 1)
@@ -603,6 +721,11 @@ class TracePlane:
         # guarded-by: _lock (ring pressure evidence: dropped = appended - len)
         self._appended = 0
         self._thread_names: dict[int, str] = {}  # guarded-by: _lock
+        # Records produced where the lock must not be taken: the gc hook
+        # runs at whatever allocation tripped the collection — possibly
+        # one made by THIS thread while it holds ``_lock``.  It appends
+        # here (atomic, lock-free) and every locked section drains first.
+        self._deferred: deque = deque()
 
     # -- configuration ---------------------------------------------------
 
@@ -633,6 +756,7 @@ class TracePlane:
             self._ring.clear()
             self._appended = 0
             self._thread_names.clear()
+            self._deferred.clear()
             self._epoch_ns = time.perf_counter_ns()
 
     def configure_from_env(self, environ=os.environ) -> None:
@@ -666,6 +790,19 @@ class TracePlane:
     @property
     def active(self) -> bool:
         return self._active
+
+    @property
+    def jax_bridge(self) -> bool:
+        """Whether spans also enter ``jax.profiler.TraceAnnotation``
+        (``KSIM_TRACE_JAX=1``) — a job's private plane copies it."""
+        return self._jax_bridge
+
+    def set_jax_bridge(self, on: bool) -> bool:
+        """Switch the ``TraceAnnotation`` bridge; returns the previous
+        setting.  ``start_profiling`` turns it on for the capture so an
+        operator's profile shows the program's spans, not only XLA's."""
+        prev, self._jax_bridge = self._jax_bridge, bool(on)
+        return prev
 
     def set_sink(self, sink: "Callable[[dict], None] | None") -> None:
         """Install (or clear) the record callback.  Set before the plane
@@ -724,6 +861,26 @@ class TracePlane:
             return _NOOP
         return _Span(self, name, args)
 
+    def phase(self, name: str, metrics=None, timer: "str | None" = None, **args):
+        """A span that ALSO feeds ``metrics.observe(timer, seconds)``
+        from the same pair of clock reads — the scheduling pass's phase
+        timers (``Metrics`` timers ``featurize`` / ``engine_*`` /
+        ``bind``, served at /api/v1/metrics with tracing off) and the
+        timeline are one measurement, not two systems.  Plane off: the
+        timer alone runs; plane off and no ``metrics``: the no-op
+        singleton.  ``name`` must be a string literal (registry-literals
+        lint, like ``span``)."""
+        ov = getattr(self._tls, "scope", None)
+        if ov is not None:
+            return ov.phase(name, metrics, timer, **args)
+        if not self._active:
+            return _NOOP if metrics is None else metrics.timer(timer)
+        sp = _Span(self, name, args)
+        if metrics is not None:
+            sp._observe = metrics.observe
+            sp._timer = timer
+        return sp
+
     def event(self, name: str, **args) -> None:
         """Record one instant event (counted always; stored when the
         ring is on)."""
@@ -740,6 +897,7 @@ class TracePlane:
         sink = self._sink
         rec = None
         with self._lock:
+            self._drain_deferred()
             self._counters[name] = self._counters.get(name, 0) + 1
             if self._ring_on or sink is not None:
                 rec = {"ph": "i", "name": name, "t": now, "tid": tid, "args": args}
@@ -762,34 +920,62 @@ class TracePlane:
         sink = self._sink
         rec = None
         with self._lock:
-            hist = self._hist.get(name)
-            if hist is None:
-                hist = self._hist[name] = LatencyHistogram()
-            hist.observe((t1 - t0) / 1e9)
-            if self._ring_on or sink is not None:
-                rec = {
-                    "ph": "X",
-                    "name": name,
-                    "t": t0,
-                    "d": t1 - t0,
-                    "tid": tid,
-                    "depth": depth,
-                    "args": args,
-                }
-                if self._ring_on:
-                    self._note_thread(tid)
-                    self._appended += 1
-                    self._ring.append(rec)
+            self._drain_deferred()
+            rec = self._store_span(name, t0, t1, tid, depth, args, sink is not None)
         if rec is not None and sink is not None:
             try:
                 sink(rec)
             except Exception:  # a broken sink must not break the plane
                 pass
 
-    def _note_thread(self, tid: int) -> None:  # ksimlint: lock-held(_lock)
+    def _store_span(  # ksimlint: lock-held(_lock)
+        self, name: str, t0: int, t1: int, tid: int, depth: int, args: dict,
+        want_rec: bool, tname: "str | None" = None,
+    ) -> "dict | None":
+        hist = self._hist.get(name)
+        if hist is None:
+            hist = self._hist[name] = LatencyHistogram()
+        hist.observe((t1 - t0) / 1e9)
+        if not (self._ring_on or want_rec):
+            return None
+        rec = {
+            "ph": "X",
+            "name": name,
+            "t": t0,
+            "d": t1 - t0,
+            "tid": tid,
+            "depth": depth,
+            "args": args,
+        }
+        if self._ring_on:
+            self._note_thread(tid, tname)
+            self._appended += 1
+            self._ring.append(rec)
+        return rec
+
+    def _note_thread(  # ksimlint: lock-held(_lock)
+        self, tid: int, tname: "str | None" = None
+    ) -> None:
         if tid not in self._thread_names:
-            t = threading.current_thread()
-            self._thread_names[tid] = t.name
+            self._thread_names[tid] = tname or threading.current_thread().name
+
+    def _drain_deferred(self) -> None:  # ksimlint: lock-held(_lock)
+        """Store the spans the gc hook left (``_defer_span``).  They
+        bypass the sink: the SSE feed is a job's progress log, not its
+        pause log."""
+        while self._deferred:  # only lock holders pop
+            name, t0, t1, tid, tname, depth, args = self._deferred.popleft()
+            if self._tags:
+                args = {**self._tags, **args}
+            self._store_span(name, t0, t1, tid, depth, args, False, tname)
+
+    def _defer_span(self, name: str, t0: int, t1: int, args: dict) -> None:
+        """Record a span WITHOUT taking the lock (see ``_deferred``);
+        it reaches histograms and ring at the next locked section."""
+        t = threading.current_thread()
+        self._deferred.append(
+            (name, t0, t1, t.ident, t.name, getattr(self._tls, "depth", 0), args)
+        )
 
     # -- evidence --------------------------------------------------------
 
@@ -802,12 +988,14 @@ class TracePlane:
         if ov is not None:
             return ov.phase_totals()
         with self._lock:
+            self._drain_deferred()
             return {n: (h.total, h.count) for n, h in self._hist.items()}
 
     def snapshot(self) -> dict:
         """Histograms + event counters + ring pressure, JSON-ready (the
         ``trace`` section of /api/v1/metrics)."""
         with self._lock:
+            self._drain_deferred()
             return {
                 "enabled": self._active,
                 "ring": {
@@ -823,12 +1011,14 @@ class TracePlane:
     def ring_records(self) -> list[dict]:
         """A consistent copy of the ring (tests; the exporter)."""
         with self._lock:
+            self._drain_deferred()
             return list(self._ring)
 
     # -- export ----------------------------------------------------------
 
     def _chrome_events(self) -> Iterator[dict]:
         with self._lock:
+            self._drain_deferred()
             ring = list(self._ring)
             names = dict(self._thread_names)
             epoch = self._epoch_ns
@@ -875,6 +1065,7 @@ class TracePlane:
         now_wall = time.time()
         now_ns = time.perf_counter_ns()
         with self._lock:
+            self._drain_deferred()
             phase = {
                 n: [round(h.total, 6), h.count]
                 for n, h in sorted(self._hist.items())
@@ -955,6 +1146,117 @@ def provider_snapshots() -> dict[str, dict]:
 #: bench parent never has to import this module.
 TRACE = TracePlane()
 TRACE.configure_from_env()
+
+
+# ---------------------------------------------------------------------------
+# Process-level runtime evidence: full garbage collections, XLA compiles
+# ---------------------------------------------------------------------------
+
+#: ``/api/v1/metrics`` names (merged into ``counters`` / ``timings``
+#: beside the scheduler's own; ``runtime_totals`` flattens them for the
+#: job result's ``runtime`` block).
+_runtime_lock = threading.Lock()
+_runtime_counters = {"xla_compiles": 0, "xla_cache_loads": 0}  # guarded-by: _runtime_lock
+_runtime_hists = {  # guarded-by: _runtime_lock
+    "gc_gen2": LatencyHistogram(),
+    "xla_compile": LatencyHistogram(),
+}
+#: Finished full collections not yet folded into ``gc_gen2``: the gc
+#: hook appends seconds here lock-free (it may run on a thread that
+#: holds ``_runtime_lock``), readers fold under the lock.
+_gc_done: deque = deque()
+_gc_open: "tuple | None" = None  # (t0_ns, plane, jax_ctx) of the running full collection
+
+
+def _gc_callback(phase: str, info: dict) -> None:
+    if info["generation"] < 2:
+        return
+    # Collections never nest and run under the GIL, so one module slot
+    # holds the open one.  No lock may be taken here and nothing may
+    # raise: this runs inside whatever allocation tripped the collector.
+    global _gc_open
+    if phase == "start":
+        ov = getattr(TRACE._tls, "scope", None)
+        plane = ov if ov is not None else TRACE
+        ctx = None
+        if not plane._active:
+            plane = None
+        elif plane._jax_bridge:
+            ctx = _jax_annotation("service.gc")
+        _gc_open = (time.perf_counter_ns(), plane, ctx)
+        return
+    opened, _gc_open = _gc_open, None
+    if opened is None:
+        return
+    t1 = time.perf_counter_ns()
+    t0, plane, ctx = opened
+    if ctx is not None:
+        _jax_annotation_exit(ctx)
+    _gc_done.append((t1 - t0) / 1e9)
+    if plane is not None:
+        plane._defer_span(
+            "service.gc", t0, t1,
+            {"generation": info["generation"], "collected": info.get("collected", 0)},
+        )
+
+
+gc.callbacks.append(_gc_callback)
+
+
+def _fold_gc() -> None:  # ksimlint: lock-held(_runtime_lock)
+    hist = _runtime_hists["gc_gen2"]
+    while _gc_done:  # only lock holders pop
+        hist.observe(_gc_done.popleft())
+
+
+def note_xla_compile(seconds: float) -> None:
+    """XLA compiled a program on this thread (``ksim_tpu.util``'s
+    ``jax.monitoring`` listener; a persistent-cache load is
+    ``note_xla_cache_load`` instead)."""
+    with _runtime_lock:
+        _runtime_counters["xla_compiles"] += 1
+        _runtime_hists["xla_compile"].observe(seconds)
+    TRACE.event("engine.compile", seconds=round(seconds, 6))
+
+
+def note_xla_cache_load() -> None:
+    """An executable came from JAX's persistent compilation cache."""
+    with _runtime_lock:
+        _runtime_counters["xla_cache_loads"] += 1
+
+
+def runtime_snapshot() -> dict:
+    """``{"counters", "timings"}`` of the process-level evidence, in the
+    shape of ``Metrics.snapshot()`` — /api/v1/metrics merges the two."""
+    with _runtime_lock:
+        _fold_gc()
+        return {
+            "counters": dict(_runtime_counters),
+            "timings": {n: h.snapshot() for n, h in _runtime_hists.items()},
+        }
+
+
+def runtime_totals() -> dict:
+    """The same evidence as flat cumulative numbers — two readings
+    around a job give its ``runtime`` block (``runtime_growth``)."""
+    with _runtime_lock:
+        _fold_gc()
+        gc2, xla = _runtime_hists["gc_gen2"], _runtime_hists["xla_compile"]
+        return {
+            "gc_gen2_collections": gc2.count,
+            "gc_gen2_pause_s": gc2.total,
+            "xla_compiles": _runtime_counters["xla_compiles"],
+            "xla_compile_s": xla.total,
+            "xla_cache_loads": _runtime_counters["xla_cache_loads"],
+        }
+
+
+def runtime_growth(before: dict) -> dict:
+    """``runtime_totals()`` now minus ``before``.  Process-wide: with
+    several jobs in flight each one's block also holds the others'
+    collections and compiles."""
+    now = runtime_totals()
+    return {k: round(v - before.get(k, 0), 6) for k, v in now.items()}
 
 
 # ---------------------------------------------------------------------------

@@ -28,6 +28,7 @@ from __future__ import annotations
 import logging
 import os
 import threading
+import time
 from typing import Callable, Sequence
 
 import copy
@@ -458,20 +459,31 @@ class SchedulerService:
     # -- one scheduling pass ------------------------------------------------
 
     def start_profiling(self, log_dir: str) -> None:
-        """Start a jax.profiler trace (TensorBoard/XPlane format) with a
-        StepTraceAnnotation per scheduling pass — kernel-level device
-        timing, the TPU-native layer on top of the metrics counters (the
-        reference's observability is the upstream scheduler's Prometheus
-        metrics + klog, SURVEY.md section 5)."""
+        """Start a jax.profiler trace (TensorBoard/XPlane format) of the
+        device work with the program's own spans beside it: the Python
+        tracer is OFF (the server is host-bound Python; a trace of every
+        call would be the workload, and hundreds of MB) and the trace
+        plane's ``TraceAnnotation`` bridge is ON for the capture, so the
+        profile shows ``service.schedule`` / ``replay.*`` / ``engine.*``
+        / ``service.gc`` on the profiler's clock above the XLA ops they
+        enclose — the same view the benchmark's traced runs reduce
+        (``benchmark/server_child.py``).  One StepTraceAnnotation per
+        scheduling pass still marks the steps.  An operator's
+        ``KSIM_TRACE=off`` keeps the spans (not the device lines) out."""
         import jax
 
-        jax.profiler.start_trace(log_dir)
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(log_dir, profiler_options=opts)
+        TRACE.ensure_timing()
+        self._profiling_prev_bridge = TRACE.set_jax_bridge(True)
         self._profiling = True
 
     def stop_profiling(self) -> None:
         if getattr(self, "_profiling", False):
             import jax
 
+            TRACE.set_jax_bridge(self._profiling_prev_bridge)
             jax.profiler.stop_trace()
             self._profiling = False
 
@@ -500,6 +512,7 @@ class SchedulerService:
     # ksimlint: lock-order(SchedulerService._pass_lock<FaultPlane._lock)
     # ksimlint: lock-order(SchedulerService._pass_lock<Metrics._lock)
     # ksimlint: lock-order(SchedulerService._pass_lock<TracePlane._lock)
+    # ksimlint: lock-order(SchedulerService._pass_lock<util._xla_watch_lock)
     def _schedule_pending_inner(self) -> dict[str, str | None]:
         with self._pass_lock:
             # The span covers the pass body only (not the lock wait):
@@ -612,7 +625,7 @@ class SchedulerService:
                     prof=prof,
                 )
                 continue
-            with self.metrics.timer("featurize"):
+            with TRACE.phase("service.featurize", self.metrics, "featurize"):
                 feats = featurizer.featurize(
                     nodes,
                     (),
@@ -625,7 +638,11 @@ class SchedulerService:
             sampling_k = self._sampling_k_for(prof, len(nodes))
             with self.metrics.timer("engine"):
                 eng = Engine(
-                    feats, plugins, record=self._record, sampling_k=sampling_k
+                    feats,
+                    plugins,
+                    record=self._record,
+                    sampling_k=sampling_k,
+                    metrics=self.metrics,
                 )
                 if self._shard_mesh is not None:
                     eng.shard(self._shard_mesh)
@@ -635,8 +652,15 @@ class SchedulerService:
                 )
             if sampling_k is not None and res.sampling_next_start is not None:
                 self._pnts_start[sched_name] = res.sampling_next_start
-            with self.metrics.timer("bind"):
-                self._bind_results(queue, feats, plugins, res, placements, prof=prof)
+            with TRACE.phase("service.bind", self.metrics, "bind") as ph:
+                render_s, store_s = self._bind_results(
+                    queue, feats, plugins, res, placements, prof=prof
+                )
+                ph.set(render_s=round(render_s, 6), store_s=round(store_s, 6))
+            # Per-pod work is summed with two clock reads a pod and
+            # recorded once per pass: never a span per pod.
+            self.metrics.observe("render", render_s)
+            self.metrics.observe("bind_store", store_s)
         # Bound _own_rvs growth for library use (schedule_pending without
         # the watch loop draining events).  The limit scales with the pass
         # size so one large pass never trims its own still-queued events
@@ -940,7 +964,15 @@ class SchedulerService:
                 except Exception:
                     logger.exception("eviction listener failed")
 
-    def _bind_results(self, queue, feats, plugins, res, placements, prof=None) -> None:
+    def _bind_results(
+        self, queue, feats, plugins, res, placements, prof=None
+    ) -> tuple[float, float]:
+        """Decode, render and write back every pod of the pass; returns
+        the seconds summed over the pods inside ``render_pod_results``
+        and inside ``store.rewrap`` (the rest of the ``bind`` timer is
+        the host hook chains and the loop itself)."""
+        clock = time.perf_counter
+        render_s = store_s = 0.0
         render_ctx = RenderCtx(feats, plugins) if self._record == "full" else None
         for j, pod in enumerate(queue):
             sel = int(res.selected[j])
@@ -991,6 +1023,7 @@ class SchedulerService:
                     )
                 if not bind_ok:
                     self._run_unreserve(plugins, pod, node_name)
+            t0 = clock()
             anno = (
                 render_pod_results(
                     feats,
@@ -1009,6 +1042,7 @@ class SchedulerService:
                 if self._record == "full"
                 else {}
             )
+            render_s += clock() - t0
             node_name_settle = None if reserve_failed else node_name
             node_name, parked = self._settle_permit(
                 pod, node_name_settle, permit_verdict, wait_deadlines, anno,
@@ -1047,11 +1081,13 @@ class SchedulerService:
                 new["status"] = status
                 return new
 
+            t0 = clock()
             try:
                 updated = self._store.rewrap(
                     "pods", name_of(pod), namespace_of(pod), rebuild
                 )
             except NotFoundError:
+                store_s += clock() - t0
                 # The pod was deleted while this pass ran (a reset or an
                 # external delete during a long compile): upstream's Bind
                 # fails just THAT pod; the rest of the batch still binds.
@@ -1060,6 +1096,7 @@ class SchedulerService:
                     namespace_of(pod), name_of(pod),
                 )
                 continue
+            store_s += clock() - t0
             with self._own_rvs_lock:
                 self._own_rvs.add(updated["metadata"]["resourceVersion"])
             if node_name is not None:
@@ -1070,6 +1107,7 @@ class SchedulerService:
             for v in victims:
                 self._evict_victim(v)
             placements[f"{namespace_of(pod)}/{name_of(pod)}"] = node_name
+        return render_s, store_s
 
     # -- host extension points (PreEnqueue/PostFilter/PreBind/Bind/PostBind) -
 

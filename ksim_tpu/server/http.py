@@ -83,6 +83,7 @@ from ksim_tpu.obs import (
     read_fleet_snapshots,
     read_fleet_traces,
     render_prometheus,
+    runtime_snapshot,
 )
 from ksim_tpu.server.di import DIContainer
 
@@ -230,7 +231,7 @@ class _Handler(BaseHTTPRequestHandler):
                 parse_qs(url.query),
             )
         elif url.path == "/api/v1/export":
-            self._json(200, self.server.di.snapshot_service.snap())
+            self._export()
         elif url.path == "/api/v1/metrics":
             # ?scope=fleet folds every published worker snapshot (plus
             # this process's live document) into one fleet document —
@@ -284,8 +285,10 @@ class _Handler(BaseHTTPRequestHandler):
         elif url.path == "/api/v1/jobs":
             self._job_submit()
         elif url.path == "/api/v1/import":
+            metrics = self.server.di.scheduler_service.metrics
             try:
-                self.server.di.snapshot_service.load(self._body())
+                with TRACE.phase("service.import", metrics, "import_load"):
+                    self.server.di.snapshot_service.load(self._body())
             except Exception:
                 logger.exception("failed to load snapshot")
                 self._json(400, {"message": "Bad Request"})
@@ -355,6 +358,22 @@ class _Handler(BaseHTTPRequestHandler):
 
     # -- handlers -----------------------------------------------------------
 
+    def _export(self) -> None:
+        """``GET /api/v1/export``: the snapshot, then its encoding and
+        the socket write — a record="full" export is hundreds of MB, a
+        third of an import's time to result, and until these timers
+        only the client's clock saw it."""
+        metrics = self.server.di.scheduler_service.metrics
+        with TRACE.span("service.export") as sp:
+            t0 = time.perf_counter()
+            doc = self.server.di.snapshot_service.snap()
+            t1 = time.perf_counter()
+            self._json(200, doc)
+            t2 = time.perf_counter()
+            sp.set(snap_s=round(t1 - t0, 6), encode_s=round(t2 - t1, 6))
+        metrics.observe("export_snap", t1 - t0)
+        metrics.observe("export_encode", t2 - t1)
+
     def _merged_metrics(self) -> dict:
         """One GET = the whole degradation-evidence surface: the
         scheduler's counters + latency histograms, the trace plane's
@@ -367,6 +386,11 @@ class _Handler(BaseHTTPRequestHandler):
         ``Metrics.snapshot()`` was served and the rest was visible
         only in bench JSON."""
         doc = self.server.di.scheduler_service.metrics.snapshot()
+        # Process-level evidence (full collections, XLA compiles and
+        # cache loads) reads like the scheduler's own counters/timers.
+        runtime = runtime_snapshot()
+        doc["counters"].update(runtime["counters"])
+        doc["timings"].update(runtime["timings"])
         doc["trace"] = TRACE.snapshot()
         doc["faults"] = FAULTS.snapshot()
         doc.update(provider_snapshots())

@@ -8,7 +8,13 @@ import threading
 import time
 from typing import Callable, TypeVar
 
-from ksim_tpu.obs import LatencyHistogram, device_identity, note_device
+from ksim_tpu.obs import (
+    LatencyHistogram,
+    device_identity,
+    note_device,
+    note_xla_cache_load,
+    note_xla_compile,
+)
 
 logger = logging.getLogger(__name__)
 
@@ -54,6 +60,45 @@ def enable_compilation_cache() -> None:
             return
         jax.config.update("jax_compilation_cache_dir", cache_dir)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+    watch_xla_compiles()
+
+
+_xla_watch_lock = threading.Lock()
+_xla_watched = False  # guarded-by: _xla_watch_lock
+_xla_tls = threading.local()
+
+
+def watch_xla_compiles() -> None:
+    """Register (once) the ``jax.monitoring`` listeners behind
+    ``obs.note_xla_compile`` / ``note_xla_cache_load``.  Called where jax
+    is already in the process — the product entry points' cache set-up
+    above and ``note_backend`` — never from ``obs``, which stays
+    stdlib-only.  Listeners run on the compiling thread: JAX reports a
+    persistent-cache hit as an event and THEN the (short) backend-compile
+    duration of the same request, so the hit marks the thread and the
+    duration that follows counts as a load, every other as a compile."""
+    global _xla_watched
+    with _xla_watch_lock:
+        if _xla_watched:
+            return
+        _xla_watched = True
+    from jax import monitoring
+
+    def on_event(event: str, **kw) -> None:
+        if event == "/jax/compilation_cache/cache_hits":
+            _xla_tls.cache_hit = True
+
+    def on_duration(event: str, seconds: float, **kw) -> None:
+        if event != "/jax/core/compile/backend_compile_duration":
+            return
+        if getattr(_xla_tls, "cache_hit", False):
+            _xla_tls.cache_hit = False
+            note_xla_cache_load()
+        else:
+            note_xla_compile(seconds)
+
+    monitoring.register_event_listener(on_event)
+    monitoring.register_event_duration_secs_listener(on_duration)
 
 
 def note_backend() -> None:
@@ -65,6 +110,8 @@ def note_backend() -> None:
     if device_identity()["platform"] is not None:
         return
     import jax
+
+    watch_xla_compiles()
 
     devices = jax.devices()
     d0 = devices[0]
@@ -172,6 +219,10 @@ class Metrics:
         def __exit__(self, *exc):
             self._m.observe(self._name, time.perf_counter() - self._t0)
             return False
+
+        def set(self, **args) -> None:
+            """Span attributes: dropped — this is ``TRACE.phase`` with
+            the plane off, where only the timer runs."""
 
     def timer(self, name: str) -> "_Timer":
         return self._Timer(self, name)

@@ -13,6 +13,12 @@ class _Stub:
     def event(self, name, **kw):
         pass
 
+    def phase(self, name, metrics=None, timer=None, **kw):
+        pass
+
+    def lap(self, name, **kw):
+        pass
+
 
 FAULTS = _Stub()
 TRACE = _Stub()
@@ -31,3 +37,7 @@ def run(name):
     TRACE.event("rogue.event")  # finding: not in EVENT_NAMES
     TRACE.event(name)  # finding: non-literal name
     _expo_family(name, "counter", "x")  # finding: non-literal family
+    TRACE.phase("rogue.phase", None, "t")  # finding: not in SPAN_NAMES
+    sp = TRACE.span("wired.site")
+    sp.lap("rogue.lap")  # finding: not in SPAN_NAMES
+    sp.lap(name)  # finding: non-literal name

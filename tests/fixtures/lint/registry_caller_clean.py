@@ -12,6 +12,12 @@ class _Stub:
     def event(self, name, **kw):
         pass
 
+    def phase(self, name, metrics=None, timer=None, **kw):
+        pass
+
+    def lap(self, name, **kw):
+        pass
+
 
 FAULTS = _Stub()
 TRACE = _Stub()
@@ -19,6 +25,8 @@ TRACE = _Stub()
 
 def run():
     with_span = TRACE.span("wired.site")
+    with_span.lap("wired.site")
+    TRACE.phase("wired.site", None, "wired")
     FAULTS.check("wired.site")
     TRACE.event("fault.fired", site="wired.site")
     return with_span

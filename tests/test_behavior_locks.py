@@ -83,6 +83,12 @@ def test_churn_lock_6k_holds_with_tracing_enabled(tmp_path):
         names = {e["name"] for e in doc["traceEvents"]}
         # The per-pass path's phase spans + pass-outcome events.
         assert {"runner.step", "service.schedule", "service.pass"} <= names
+        # ... and the pass's phases beneath service.schedule (PR 25): the
+        # lock holds with every new site recording.
+        assert {
+            "service.featurize", "engine.pack", "engine.exec", "engine.pull",
+            "service.bind",
+        } <= names
         n_sched_spans = sum(
             1
             for e in doc["traceEvents"]

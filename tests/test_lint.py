@@ -176,6 +176,10 @@ def test_registry_literals_fires_on_seeded_violations():
     assert "'rogue.site' is not declared in SITES" in joined
     assert "SITES entry 'wired.site' has no FAULTS.check call site" in joined
     assert "'rogue.span' is not in obs.SPAN_NAMES" in joined
+    # The helpers that open spans by another spelling are scanned too.
+    assert "'rogue.phase' is not in obs.SPAN_NAMES" in joined
+    assert "'rogue.lap' is not in obs.SPAN_NAMES" in joined
+    assert joined.count("TRACE.span with a non-literal name") == 1
     assert "'rogue.event' is not in obs.EVENT_NAMES" in joined
     assert "non-literal name" in joined
     assert "'rogue_metric' is not in obs.METRIC_NAMES" in joined

@@ -13,7 +13,10 @@ scans every call site in the tree and checks BOTH directions:
 - every declared site has a same-named span (a fault event always has
   an enclosing phase on the timeline);
 - every ``TRACE.span("...")`` / ``TRACE.event("...")`` name is in the
-  span/event taxonomy;
+  span/event taxonomy — and so is every name given to the two helpers
+  that open spans by another spelling: ``TRACE.phase("...", ...)`` (a
+  span that also feeds a Metrics timer) and ``<span>.lap("...")`` (the
+  next sequential child of an open span, on any receiver);
 - every ``_expo_family("...")`` Prometheus exposition family declared
   in obs.py resolves into ``obs.METRIC_NAMES`` (and every registry
   entry is declared somewhere — a family in the registry with no
@@ -52,6 +55,8 @@ class RegistryConfig:
     replay_module: str = "ksim_tpu/engine/replay.py"
     faults_object: str = "FAULTS"  # <obj>.check(site)
     trace_object: str = "TRACE"  # <obj>.span(name) / <obj>.event(name)
+    span_methods: tuple = ("span", "phase")  # <obj>.<method>(name, ...)
+    lap_method: str = "lap"  # <any span>.lap(name): a child span
     metric_helper: str = "_expo_family"  # <helper>(family, kind, help)
 
 
@@ -118,9 +123,12 @@ def load_registries(project: Project, cfg: RegistryConfig = DEFAULT_CONFIG) -> R
     )
 
 
-def _method_calls(project: Project, obj: str, method: str, skip: frozenset[str]):
+def _method_calls(
+    project: Project, obj: "str | None", method: str, skip: frozenset[str]
+):
     """Every ``<obj>.<method>(...)`` call in the tree (minus ``skip``
-    files): yields (rel, call node)."""
+    files): yields (rel, call node).  ``obj=None`` matches any
+    receiver."""
     for rel, sf in project.files.items():
         if rel in skip:
             continue
@@ -129,8 +137,13 @@ def _method_calls(project: Project, obj: str, method: str, skip: frozenset[str])
                 isinstance(node, ast.Call)
                 and isinstance(node.func, ast.Attribute)
                 and node.func.attr == method
-                and isinstance(node.func.value, ast.Name)
-                and node.func.value.id == obj
+                and (
+                    obj is None
+                    or (
+                        isinstance(node.func.value, ast.Name)
+                        and node.func.value.id == obj
+                    )
+                )
             ):
                 yield rel, node
 
@@ -198,7 +211,14 @@ def scan_trace_literals(
 ) -> tuple[LiteralScan, LiteralScan]:
     """(span call sites, event call sites) for the trace plane."""
     spans, events = LiteralScan(), LiteralScan()
-    for rel, node in _method_calls(project, cfg.trace_object, "span", frozenset()):
+    for method in cfg.span_methods:
+        for rel, node in _method_calls(project, cfg.trace_object, method, frozenset()):
+            spans.add(rel, node)
+    # The lap helper is called on the span object, whatever it is named;
+    # the defining module spells the method, not a site.
+    for rel, node in _method_calls(
+        project, None, cfg.lap_method, frozenset({cfg.obs_module})
+    ):
         spans.add(rel, node)
     for rel, node in _method_calls(project, cfg.trace_object, "event", frozenset()):
         events.add(rel, node)
