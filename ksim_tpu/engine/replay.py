@@ -1550,6 +1550,7 @@ class ReplayDriver:
             "lower_cache": self._cache.stats(),
             "featurize_calls": feat.pod_rows_built if feat is not None else 0,
             "featurize_reused": feat.pod_rows_reused if feat is not None else 0,
+            "featurize_rebuilt": feat.pod_rows_rebuilt if feat is not None else 0,
             "featurize_passes": feat.featurize_passes if feat is not None else 0,
             "prelower": {
                 "windows": self.prelower_windows,
@@ -2526,7 +2527,8 @@ class ReplayDriver:
                 self._featurizer = Featurizer()
             else:
                 self._featurizer = svc._profiles[self._sched_name].featurizer()
-        rows_built0 = self._featurizer.pod_rows_built
+        feat = self._featurizer
+        rows0 = (feat.pod_rows_built, feat.pod_rows_reused, feat.pod_rows_rebuilt)
         universe_nodes = list(cur_nodes) + created_nodes
         bound_pods = store.pods_with_node()
         feats = self._featurizer.featurize(
@@ -2930,7 +2932,9 @@ class ReplayDriver:
                 "events": sum(len(b) for b in batches),
                 "steps": m_steps,
                 "universe": U,
-                "rows_built": self._featurizer.pod_rows_built - rows_built0,
+                "rows_built": feat.pod_rows_built - rows0[0],
+                "rows_reused": feat.pod_rows_reused - rows0[1],
+                "rows_rebuilt": feat.pod_rows_rebuilt - rows0[2],
                 "cache_hit": use_cache,
                 "tp": tp,
                 "full_bytes_per_shard": int(full_bytes_shard),

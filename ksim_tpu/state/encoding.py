@@ -28,25 +28,35 @@ from typing import Any, Sequence
 
 import numpy as np
 
+from ksim_tpu.state import objcache
+from ksim_tpu.state.boundagg import sync_family
+from ksim_tpu.state.featurizer import vocab_pad as _vpad
+from ksim_tpu.state.podtable import (
+    LIST,
+    ROW,
+    Column,
+    PodTable,
+    first_seen,
+    rank_lut,
+    scatter_add,
+)
 from ksim_tpu.state.resources import (
     JSON,
     labels_of,
     name_of,
+    namespace_of,
     pod_tolerations,
     toleration_tolerates,
 )
-from ksim_tpu.state.selectors import match_node_selector_requirement
+from ksim_tpu.state.selectors import (
+    match_label_selector,
+    match_node_selector_requirement,
+)
 
 FORBIDDING_EFFECTS = ("NoSchedule", "NoExecute")
 
 
 # -- node-affinity / node-selector encoding ---------------------------------
-
-
-def _vpad(n: int, minimum: int = 8) -> int:
-    from ksim_tpu.state.featurizer import vocab_pad
-
-    return vocab_pad(n, minimum)
 
 
 def _canon(obj: Any) -> str:
@@ -139,8 +149,8 @@ def _term_reqs_from_selector_term(term: JSON) -> list[JSON] | None:
 
 def _parsed_node_affinity(pod: JSON) -> dict:
     """Vocab-independent nodeSelector/nodeAffinity parse with canonical
-    requirement keys, memoized per pod object.  Pairs are (req, canon)."""
-    from ksim_tpu.state import objcache
+    requirement keys, memoized per pod object (the replay's prelower
+    warms it off the critical path).  Pairs are (req, canon)."""
 
     def build() -> dict:
         spec = pod.get("spec", {})
@@ -172,24 +182,48 @@ def _parsed_node_affinity(pod: JSON) -> dict:
     return objcache.cached("affpod", pod, build)
 
 
+_AFFINITY_COLUMNS = (
+    Column("sel", np.int32, -1),
+    Column("has_req", bool, False),
+    Column("req", np.int32, -1, LIST),
+    Column("pref", np.int32, -1, LIST),
+    Column("pref_w", np.int32, 0, LIST),
+)
+
+
 def encode_affinity(
     nodes: Sequence[JSON],
-    pods: Sequence[JSON],
+    table: PodTable,
     n_padded: int,
     p_padded: int,
     added_affinity: JSON | None = None,
 ) -> AffinityTensors:
-    from ksim_tpu.state import objcache
+    # Table rows name a pod's terms by PERSISTENT id: a term as written
+    # (its canonical requirement keys, in the pod's order).  The
+    # call-local vocabulary below numbers requirements and terms by
+    # first appearance in queue order, as a walk over the pods would.
+    terms = table.interner("affinity_terms")
+    terms.valve()
+
+    def term_of(pairs: list[tuple[JSON, str]]) -> int:
+        return terms.intern(tuple(k for _r, k in pairs), pairs)
+
+    def row(pod: JSON) -> tuple:
+        parsed = _parsed_node_affinity(pod)
+        # Match-nothing terms (None) contribute nothing to the OR.
+        return (
+            -1 if parsed["sel"] is None else term_of(parsed["sel"]),
+            parsed["req"] is not None,
+            [term_of(pairs) for pairs in parsed["req"] or () if pairs is not None],
+            [term_of(pairs) for pairs, _w in parsed["pref"] if pairs is not None],
+            [w for pairs, w in parsed["pref"] if pairs is not None],
+        )
+
+    fam = table.family("affinity", _AFFINITY_COLUMNS)
+    table.sync(fam, terms.gen, row)
+    P = table.idx.shape[0]
 
     vocab = _TermVocab()
-
-    def term_of_pairs(pairs: list[tuple[JSON, str]]) -> int:
-        return vocab.term_id_by_keys(pairs)
-
-    sel_term = np.full(p_padded, -1, dtype=np.int32)
-    has_req = np.zeros(p_padded, dtype=bool)
-    req_terms: list[list[int]] = [[] for _ in range(p_padded)]
-    pref: list[dict[int, int]] = [{} for _ in range(p_padded)]
 
     # Profile-level addedAffinity terms register in the same vocabulary
     # (upstream NodeAffinityArgs.addedAffinity, node_affinity.go New).
@@ -210,20 +244,17 @@ def encode_affinity(
                 tid = vocab.term_id(reqs)
                 added_pref_ids[tid] = added_pref_ids.get(tid, 0) + int(pt.get("weight", 0))
 
-    for j, pod in enumerate(pods):
-        parsed = _parsed_node_affinity(pod)
-        if parsed["sel"] is not None:
-            sel_term[j] = term_of_pairs(parsed["sel"])
-        if parsed["req"] is not None:
-            has_req[j] = True
-            for pairs in parsed["req"]:
-                # Match-nothing terms contribute nothing to the OR.
-                if pairs is not None:
-                    req_terms[j].append(term_of_pairs(pairs))
-        for pairs, w in parsed["pref"]:
-            if pairs is not None:
-                tid = term_of_pairs(pairs)
-                pref[j][tid] = pref[j].get(tid, 0) + w
+    # A pod's terms in the order the walk met them: selector, required,
+    # preferred.
+    g_sel, g_req, g_pref = fam.take("sel"), fam.take("req"), fam.take("pref")
+    local = np.full(len(terms.items) + 1, -1, dtype=np.int32)
+    for pid in first_seen(np.concatenate([g_sel[:, None], g_req, g_pref], axis=1)).tolist():
+        local[pid] = vocab.term_id_by_keys(terms.items[pid])
+
+    sel_term = np.full(p_padded, -1, dtype=np.int32)
+    sel_term[:P] = local[g_sel]
+    has_req = np.zeros(p_padded, dtype=bool)
+    has_req[:P] = fam.take("has_req")
 
     Q = _vpad(len(vocab.req_list))
     T = _vpad(len(vocab.term_list))
@@ -269,11 +300,9 @@ def encode_affinity(
 
     required_terms = np.zeros((p_padded, max(T, 1)), dtype=bool)
     preferred_weights = np.zeros((p_padded, max(T, 1)), dtype=np.int32)
-    for j in range(p_padded):
-        for tid in req_terms[j]:
-            required_terms[j, tid] = True
-        for tid, w in pref[j].items():
-            preferred_weights[j, tid] = w
+    rr, cc = np.nonzero(g_req >= 0)
+    required_terms[rr, local[g_req[rr, cc]]] = True
+    scatter_add(preferred_weights, g_pref, local, fam.take("pref_w"))
 
     added_terms = np.zeros(max(T, 1), dtype=bool)
     for tid in added_req_ids:
@@ -323,11 +352,15 @@ class TaintTensors:
         return len(self.taints)
 
 
-def encode_taints(
-    nodes: Sequence[JSON], pods: Sequence[JSON], n_padded: int, p_padded: int
-) -> TaintTensors:
-    from ksim_tpu.state import objcache
+_TAINT_COLUMNS = (
+    Column("tol", bool, False, ROW),
+    Column("tol_prefer", bool, False, ROW),
+)
 
+
+def encode_taints(
+    nodes: Sequence[JSON], table: PodTable, n_padded: int, p_padded: int
+) -> TaintTensors:
     def build_node_side():
         """The taint vocabulary and every node-derived array — a pure
         function of the node list (+ n_padded), cached as a family on
@@ -380,35 +413,45 @@ def encode_taints(
         "enc_taints_nodes", nodes, build_node_side, n_padded
     )
     W0 = len(taints)
-    taints_tok = objcache.intern_token(taints_token)
+
+    # Table rows span every taint this featurizer has met (persistent
+    # columns); the call's vocabulary — the node list's, in node order —
+    # picks its columns out of them.  A taint never met before widens
+    # the rows, which rebuilds them.
+    known = table.interner("taints")
+    known.valve()
+    cols = [known.intern(k, t) for k, t in zip(taints_token, taints)]
+    every = list(known.items)
 
     def tol_rows(pod: JSON) -> tuple[np.ndarray, np.ndarray]:
-        """(tolerated, tolerated_prefer) rows over the taint vocab,
-        memoized per (pod object, vocab)."""
-        key = ("taintrow", objcache.ref_id(pod), taints_tok)
-        hit = objcache.get(key)
-        if hit is not objcache.MISS:
-            return hit
+        """(tolerated, tolerated_prefer) over the persistent taints."""
         tols = pod_tolerations(pod)
         prefer_tols = [t for t in tols if (t.get("effect") or "") in ("", "PreferNoSchedule")]
-        row = np.fromiter(
-            (any(toleration_tolerates(tl, t) for tl in tols) for t in taints),
-            dtype=bool,
-            count=W0,
+        return (
+            np.fromiter(
+                (any(toleration_tolerates(tl, t) for tl in tols) for t in every),
+                dtype=bool,
+                count=len(every),
+            ),
+            np.fromiter(
+                (any(toleration_tolerates(tl, t) for tl in prefer_tols) for t in every),
+                dtype=bool,
+                count=len(every),
+            ),
         )
-        prow = np.fromiter(
-            (any(toleration_tolerates(tl, t) for tl in prefer_tols) for t in taints),
-            dtype=bool,
-            count=W0,
-        )
-        return objcache.put(key, (row, prow))
 
+    fam = table.family("taints", _TAINT_COLUMNS)
+    table.sync(
+        fam,
+        (known.gen, len(every)),
+        tol_rows,
+        {"tol": len(every), "tol_prefer": len(every)},
+    )
+    P = table.idx.shape[0]
     tolerated = np.zeros((p_padded, W), dtype=bool)
     tolerated_prefer = np.zeros((p_padded, W), dtype=bool)
-    for j, pod in enumerate(pods):
-        row, prow = tol_rows(pod)
-        tolerated[j, :W0] = row
-        tolerated_prefer[j, :W0] = prow
+    tolerated[:P, :W0] = fam.take("tol")[:, cols]
+    tolerated_prefer[:P, :W0] = fam.take("tol_prefer")[:, cols]
 
     return TaintTensors(
         taints=taints,
@@ -505,8 +548,6 @@ def default_spread_selector(
     defaultConstraints/System defaulting are inert: the same blind spot,
     by construction.  The parameters exist so the behavior stays
     upstream-shaped if the snapshot model ever grows these kinds."""
-    from ksim_tpu.state.resources import namespace_of
-
     ns = namespace_of(pod) or "default"
     pod_labels = dict(labels_of(pod))
     merged: dict[str, str] = {}
@@ -568,34 +609,72 @@ def _effective_selector(con: JSON, pod: JSON) -> JSON:
     return sel
 
 
+_SPREAD_CON_COLUMNS = (
+    Column("valid", bool, False, LIST),
+    Column("mode", np.int32, 0, LIST),
+    Column("sel", np.int32, 0, LIST),
+    Column("tk", np.int32, -1, LIST),
+    Column("max_skew", np.int32, 1, LIST),
+    Column("min_domains", np.int32, 0, LIST),
+    Column("self", bool, False, LIST),
+    Column("honor_aff", bool, True, LIST),
+    Column("honor_taints", bool, False, LIST),
+)
+_SPREAD_MATCH_COLUMNS = (Column("match", bool, False, ROW),)
+
+
+def _spread_constraints(pod: JSON, default_constraints: tuple | None) -> list[dict]:
+    """Vocabulary-independent constraint parse (the effective selector
+    and its canonical key are the expensive parts).  Pods without their
+    own constraints fall back to the profile's defaultConstraints
+    (PodTopologySpreadArgs; upstream pod_topology_spread/common.go
+    buildDefaultConstraints) — whose selector comes from
+    default_spread_selector and is empty in the snapshot model, so the
+    fallback yields no constraints (documented there)."""
+    ns = namespace_of(pod) or "default"
+    out = []
+    own = pod.get("spec", {}).get("topologySpreadConstraints") or []
+    cons_src = own
+    if not own and default_constraints:
+        sel = default_spread_selector(pod)
+        if sel is not None:
+            cons_src = [dict(c, labelSelector=sel) for c in default_constraints]
+    for con in cons_src:
+        sel = _effective_selector(con, pod)
+        out.append(
+            {
+                "tk_str": con.get("topologyKey", ""),
+                "ns": ns,
+                "sel_obj": sel,
+                "sel_key": _canon({"ns": ns, "sel": sel}),
+                "mode": 0 if con.get("whenUnsatisfiable", "DoNotSchedule") == "DoNotSchedule" else 1,
+                "max_skew": int(con.get("maxSkew", 1)),
+                "min_domains": int(con.get("minDomains") or 0),
+                "self": match_label_selector(sel, labels_of(pod)),
+                "honor_aff": (con.get("nodeAffinityPolicy") or "Honor") == "Honor",
+                "honor_taints": (con.get("nodeTaintsPolicy") or "Ignore") == "Honor",
+            }
+        )
+    return out
+
+
 def encode_topology_spread(
     nodes: Sequence[JSON],
-    pods: Sequence[JSON],
-    bound_pods: Sequence[JSON],
+    table: PodTable,
     n_padded: int,
     p_padded: int,
     *,
-    agg: dict | None = None,
-    bound_map: "dict[int, JSON] | None" = None,
-    changed_slots: "set[int] | None" = None,
-    slot_of: "dict[str, int] | None" = None,
+    agg: dict,
+    bound_map: "dict[int, JSON]",
+    changed_slots: "set[int]",
+    slot_of: "dict[str, int]",
     default_constraints: tuple | None = None,
 ) -> SpreadTensors:
-    """``agg``/``bound_map``/``changed_slots``/``slot_of`` come from a
-    persistent Featurizer (state/boundagg.py): the selector vocabulary
-    then persists append-only across calls and the per-node
-    selector-match counts over BOUND pods update by delta.  Without
-    ``agg`` every call is a one-shot rebuild (same code path, throwaway
-    state)."""
-    from ksim_tpu.state.resources import namespace_of
-    from ksim_tpu.state.selectors import match_label_selector
-
-    agg = agg if agg is not None else {}
-    if bound_map is None:
-        bound_map = {id(p): p for p in bound_pods}
-    changed_slots = changed_slots if changed_slots is not None else set()
-
-    tk_vocab: dict[str, int] = {}
+    """``agg``/``bound_map``/``changed_slots``/``slot_of`` are the
+    Featurizer's persistent state (state/boundagg.py): the selector
+    vocabulary persists append-only across calls and the per-node
+    selector-match counts over BOUND pods update by delta.  A one-shot
+    Featurizer is the same code with empty state."""
     dom_vocab: dict[tuple[int, str], int] = {}
     sels = agg.setdefault("spread_sels", {"vocab": {}, "list": []})
     if len(sels["list"]) > 4096:
@@ -605,13 +684,15 @@ def encode_topology_spread(
         agg.pop("spread_sels", None)
         agg.pop("spread_init", None)
         sels = agg.setdefault("spread_sels", {"vocab": {}, "list": []})
+        # New lineage: table rows and aggregates storing the old ids die.
+        agg["spread_sels_gen"] = agg.get("spread_sels_gen", 0) + 1
+    sels_gen = agg.get("spread_sels_gen", 0)
     sel_vocab: dict[str, int] = sels["vocab"]
     sel_list: list[tuple[str, JSON]] = sels["list"]  # (namespace, selector)
-
-    def tk_id(k: str) -> int:
-        if k not in tk_vocab:
-            tk_vocab[k] = len(tk_vocab)
-        return tk_vocab[k]
+    # Topology keys by persistent id in the rows; the call's own
+    # numbering (first appearance in queue order) is recovered below.
+    tks = table.interner("spread_tks")
+    tks.valve()
 
     def sel_id_by_key(key: str, ns: str, sel: JSON) -> int:
         if key not in sel_vocab:
@@ -619,61 +700,35 @@ def encode_topology_spread(
             sel_list.append((ns, sel))
         return sel_vocab[key]
 
-    from ksim_tpu.state import objcache
+    # Pass 1: constraint tables.  Building a pod's row registers its
+    # selectors, so every selector of the call is in the vocabulary
+    # before S0 is read (a surviving row's selectors already are: the
+    # vocabulary only grows within a lineage).
+    def con_rows(pod: JSON) -> tuple:
+        cons = _spread_constraints(pod, default_constraints)
+        return (
+            [True] * len(cons),
+            [c["mode"] for c in cons],
+            [sel_id_by_key(c["sel_key"], c["ns"], c["sel_obj"]) for c in cons],
+            [tks.intern(c["tk_str"]) for c in cons],
+            [c["max_skew"] for c in cons],
+            [c["min_domains"] for c in cons],
+            [c["self"] for c in cons],
+            [c["honor_aff"] for c in cons],
+            [c["honor_taints"] for c in cons],
+        )
 
     defaults_token = _canon(list(default_constraints)) if default_constraints else ""
+    confam = table.family("spread_cons", _SPREAD_CON_COLUMNS)
+    table.sync(confam, (sels_gen, tks.gen, defaults_token), con_rows)
+    P = table.idx.shape[0]
 
-    def parsed_cons(pod: JSON) -> list[dict]:
-        """Vocab-independent constraint parse, memoized per pod object
-        (the effective selector and its canonical key are the expensive
-        parts; vocab ids are assigned per call).  Pods without their own
-        constraints fall back to the profile's defaultConstraints
-        (PodTopologySpreadArgs; upstream pod_topology_spread/common.go
-        buildDefaultConstraints) — whose selector comes from
-        default_spread_selector and is empty in the snapshot model, so
-        the fallback yields no constraints (documented there)."""
-
-        def build() -> list[dict]:
-            ns = namespace_of(pod) or "default"
-            out = []
-            own = pod.get("spec", {}).get("topologySpreadConstraints") or []
-            cons_src = own
-            if not own and default_constraints:
-                sel = default_spread_selector(pod)
-                if sel is not None:
-                    cons_src = [
-                        dict(c, labelSelector=sel) for c in default_constraints
-                    ]
-            for con in cons_src:
-                sel = _effective_selector(con, pod)
-                out.append(
-                    {
-                        "tk_str": con.get("topologyKey", ""),
-                        "ns": ns,
-                        "sel_obj": sel,
-                        "sel_key": _canon({"ns": ns, "sel": sel}),
-                        "mode": 0 if con.get("whenUnsatisfiable", "DoNotSchedule") == "DoNotSchedule" else 1,
-                        "max_skew": int(con.get("maxSkew", 1)),
-                        "min_domains": int(con.get("minDomains") or 0),
-                        "self": match_label_selector(sel, labels_of(pod)),
-                        "honor_aff": (con.get("nodeAffinityPolicy") or "Honor") == "Honor",
-                        "honor_taints": (con.get("nodeTaintsPolicy") or "Ignore") == "Honor",
-                    }
-                )
-            return out
-
-        return objcache.cached("spreadcons", pod, build, defaults_token)
-
-    # Pass 1: constraint tables.
-    per_pod_cons: list[list[dict]] = []
-    for pod in pods:
-        cons = []
-        for c in parsed_cons(pod):
-            cons.append(
-                dict(c, tk=tk_id(c["tk_str"]), sel=sel_id_by_key(c["sel_key"], c["ns"], c["sel_obj"]))
-            )
-        per_pod_cons.append(cons)
-
+    g_tk = confam.take("tk")
+    tk_present = first_seen(g_tk)
+    tk_local = rank_lut(tk_present, len(tks.items))
+    tk_vocab: dict[str, int] = {
+        tks.items[pid]: i for i, pid in enumerate(tk_present.tolist())
+    }
     TK = max(len(tk_vocab), 1)
 
     def build_node_domains():
@@ -709,31 +764,18 @@ def encode_topology_spread(
 
     S = _vpad(len(sel_list))
     S0 = len(sel_list)
-    # Per-pod selector-match rows, memoized on (pod object, selector
-    # vocab) — the vocab stabilizes under churn, so unchanged pods cost
-    # one lookup per pass.
-    sels_token = tuple(sel_vocab)
-    sels_tok = objcache.intern_token(sels_token)
 
     def sel_row(pod: JSON) -> np.ndarray:
-        key = ("spreadrow", objcache.ref_id(pod), sels_tok)
-        hit = objcache.get(key)
-        if hit is not objcache.MISS:
-            return hit
+        """The pod's match row over the selector vocabulary (S0 wide)."""
         pod_ns = namespace_of(pod) or "default"
         pod_labels = labels_of(pod)
-        row = np.fromiter(
+        return np.fromiter(
             (pod_ns == ns and match_label_selector(sel, pod_labels) for ns, sel in sel_list),
             dtype=bool,
             count=S0,
         )
-        return objcache.put(key, row)
 
-    from ksim_tpu.state.boundagg import sync_family
-
-    node_index = slot_of if slot_of is not None else {
-        name_of(n): i for i, n in enumerate(nodes)
-    }
+    node_index = slot_of
     N0 = len(nodes)
 
     def _init_record(bp: JSON):
@@ -752,7 +794,7 @@ def encode_topology_spread(
     init_counts = sync_family(
         agg,
         "spread_init",
-        (sels_tok, S, S0, n_padded),
+        (sels_gen, S, S0, n_padded),
         bound_map,
         changed_slots,
         make_arrays=lambda: np.zeros((n_padded, S), dtype=np.int32),
@@ -760,36 +802,34 @@ def encode_topology_spread(
         apply=_init_apply,
     ).copy()
 
+    # Match rows are valid for the vocabulary they span: a new selector
+    # rebuilds them (it can match pods that did not themselves change).
+    matchfam = table.family("spread_match", _SPREAD_MATCH_COLUMNS)
+    table.sync(matchfam, (sels_gen, S0), lambda pod: (sel_row(pod),), {"match": S0})
     pod_sel_match = np.zeros((p_padded, S), dtype=bool)
-    for j, pod in enumerate(pods):
-        pod_sel_match[j, :S0] = sel_row(pod)
+    pod_sel_match[:P, :S0] = matchfam.take("match")
 
-    MC = max((len(c) for c in per_pod_cons), default=0)
-    MC = _vpad(MC, minimum=2)
+    g_valid = confam.take("valid")
+    MC = _vpad(int(g_valid.sum(axis=1).max(initial=0)), minimum=2)
     shape = (p_padded, MC)
-    con_valid = np.zeros(shape, dtype=bool)
-    con_mode = np.zeros(shape, dtype=np.int32)
-    con_sel = np.zeros(shape, dtype=np.int32)
+    w = min(MC, g_valid.shape[1])
+
+    def con(col: str, dtype, fill=0) -> np.ndarray:
+        out = np.full(shape, fill, dtype=dtype)
+        out[:P, :w] = confam.take(col)[:, :w]
+        return out
+
+    con_valid = con("valid", bool)
+    con_mode = con("mode", np.int32)
+    con_sel = con("sel", np.int32)
     con_tk = np.zeros(shape, dtype=np.int32)
-    con_max_skew = np.ones(shape, dtype=np.int32)
-    con_min_domains = np.zeros(shape, dtype=np.int32)
-    con_self = np.zeros(shape, dtype=bool)
-    con_honor_aff = np.ones(shape, dtype=bool)
-    con_honor_taints = np.zeros(shape, dtype=bool)
-    has_score = np.zeros(p_padded, dtype=bool)
-    for j, cons in enumerate(per_pod_cons):
-        for ci, c in enumerate(cons):
-            con_valid[j, ci] = True
-            con_mode[j, ci] = c["mode"]
-            con_sel[j, ci] = c["sel"]
-            con_tk[j, ci] = c["tk"]
-            con_max_skew[j, ci] = c["max_skew"]
-            con_min_domains[j, ci] = c["min_domains"]
-            con_self[j, ci] = c["self"]
-            con_honor_aff[j, ci] = c["honor_aff"]
-            con_honor_taints[j, ci] = c["honor_taints"]
-            if c["mode"] == 1:
-                has_score[j] = True
+    con_tk[:P, :w] = np.maximum(tk_local[g_tk[:, :w]], 0)
+    con_max_skew = con("max_skew", np.int32, 1)
+    con_min_domains = con("min_domains", np.int32)
+    con_self = con("self", bool)
+    con_honor_aff = con("honor_aff", bool, True)
+    con_honor_taints = con("honor_taints", bool)
+    has_score = (con_valid & (con_mode == 1)).any(axis=1)
 
     return SpreadTensors(
         n_domains=n_domains,

@@ -16,6 +16,16 @@ from typing import Sequence
 
 import numpy as np
 
+from ksim_tpu.state import objcache
+from ksim_tpu.state.featurizer import vocab_pad
+from ksim_tpu.state.podtable import (
+    LIST,
+    Column,
+    PodTable,
+    first_seen,
+    rank_lut,
+    scatter_add,
+)
 from ksim_tpu.state.resources import JSON, name_of
 
 # Upstream nodeports: empty hostIP means "bind all".
@@ -33,15 +43,32 @@ class NodeNameTensors:
     pod_req_node: np.ndarray  # i32 [P]
 
 
+_NODE_NAME_COLUMNS = (Column("want", np.int32, -1),)
+
+
 def encode_node_name(
-    nodes: Sequence[JSON], pods: Sequence[JSON], p_padded: int
+    nodes: Sequence[JSON], table: PodTable, p_padded: int
 ) -> NodeNameTensors:
-    index = {name_of(n): i for i, n in enumerate(nodes)}
-    out = np.full(p_padded, -1, dtype=np.int32)
-    for j, p in enumerate(pods):
+    # Rows hold the requested name's persistent id; only the distinct
+    # names a call asks for are looked up in its node list.
+    names = table.interner("node_names")
+    names.valve()
+
+    def row(p: JSON) -> tuple:
         want = p.get("spec", {}).get("nodeName") or ""
-        if want:
-            out[j] = index.get(want, -2)
+        return (names.intern(want) if want else -1,)
+
+    fam = table.family("nodename", _NODE_NAME_COLUMNS)
+    table.sync(fam, names.gen, row)
+    g_want = fam.take("want")
+    out = np.full(p_padded, -1, dtype=np.int32)
+    asked = first_seen(g_want)
+    if asked.size:
+        index = {name_of(n): i for i, n in enumerate(nodes)}
+        node_of = np.full(len(names.items) + 1, -1, dtype=np.int32)
+        for pid in asked.tolist():
+            node_of[pid] = index.get(names.items[pid], -2)
+        out[: g_want.shape[0]] = node_of[g_want]
     return NodeNameTensors(pod_req_node=out)
 
 
@@ -49,7 +76,6 @@ def _host_ports(pod: JSON) -> list[tuple[str, str, int]]:
     """The pod's (hostIP, protocol, hostPort) triples, upstream
     getContainerPorts (hostPort == 0 entries are ignored).  Memoized per
     pod object."""
-    from ksim_tpu.state import objcache
 
     def build() -> list[tuple[str, str, int]]:
         out = []
@@ -101,22 +127,26 @@ class NodePortTensors:
 _NO_PORTS: dict = {}
 
 
+_PORT_COLUMNS = (Column("ports", np.int32, -1, LIST),)
+
+
 def encode_node_ports(
     nodes: Sequence[JSON],
-    pods: Sequence[JSON],
+    table: PodTable,
     bound_pods: Sequence[JSON],
     n_padded: int,
     p_padded: int,
 ) -> NodePortTensors:
-    vocab: dict[tuple[str, str, int], int] = {}
-    pod_ports = [_host_ports(p) for p in pods]
-    for ports in pod_ports:
-        for t in ports:
-            vocab.setdefault(t, len(vocab))
-    from ksim_tpu.state.featurizer import vocab_pad
-
-    v = vocab_pad(len(vocab))
-    if not vocab:
+    # Rows list a pod's triples by persistent id; the call's vocabulary
+    # numbers them by first appearance in queue order.
+    triples = table.interner("host_ports")
+    triples.valve()
+    fam = table.family("nodeports", _PORT_COLUMNS)
+    table.sync(fam, triples.gen, lambda p: ([triples.intern(t) for t in _host_ports(p)],))
+    g_ports = fam.take("ports")
+    present = first_seen(g_ports)
+    v = vocab_pad(present.size)
+    if not present.size:
         # No queue pod wants a host port: every tensor is zero whatever
         # the bound pods hold — skip the bound walk (churn steady state).
         hit = _NO_PORTS.get((n_padded, p_padded))
@@ -130,7 +160,8 @@ def encode_node_ports(
                 _NO_PORTS.clear()
             _NO_PORTS[(n_padded, p_padded)] = hit
         return hit
-    entries = list(vocab)
+    entries = [triples.items[pid] for pid in present.tolist()]
+    local = rank_lut(present, len(triples.items))
 
     conflict_counts = np.zeros((n_padded, v), dtype=np.int32)
     node_index = {name_of(n): i for i, n in enumerate(nodes)}
@@ -143,16 +174,16 @@ def encode_node_ports(
                 if ports_conflict(t, entry):
                     conflict_counts[ni, vi] += 1
 
-    pod_wants = np.zeros((p_padded, v), dtype=bool)
-    pod_adds = np.zeros((p_padded, v), dtype=np.int32)
-    for j, ports in enumerate(pod_ports):
-        for t in ports:
-            pod_wants[j, vocab[t]] = True
-            for vi, entry in enumerate(entries):
-                if ports_conflict(t, entry):
-                    pod_adds[j, vi] += 1
+    # own[j, a]: how many of pod j's triples are vocab entry a; a pod
+    # adds, to each entry b, one per own triple conflicting with it.
+    own = np.zeros((p_padded, v), dtype=np.int32)
+    scatter_add(own, g_ports, local)
+    conflicts = np.zeros((v, v), dtype=np.int32)
+    for a, ta in enumerate(entries):
+        for b, tb in enumerate(entries):
+            conflicts[a, b] = ports_conflict(ta, tb)
     return NodePortTensors(
-        conflict_counts=conflict_counts, pod_wants=pod_wants, pod_adds=pod_adds
+        conflict_counts=conflict_counts, pod_wants=own > 0, pod_adds=own @ conflicts
     )
 
 
@@ -189,35 +220,45 @@ class ImageTensors:
     pod_num_containers: np.ndarray  # i32 [P]
 
 
+_IMAGE_COLUMNS = (
+    Column("containers", np.int32, 0),
+    Column("images", np.int32, -1, LIST),
+)
+
+
 def encode_image_locality(
     nodes: Sequence[JSON],
-    pods: Sequence[JSON],
+    table: PodTable,
     n_padded: int,
     p_padded: int,
 ) -> ImageTensors:
-    from ksim_tpu.state import objcache
+    # Rows list a pod's normalized images by persistent id; the call's
+    # vocabulary numbers them by first appearance in queue order.
+    images = table.interner("images")
+    images.valve()
 
-    def pod_images(p: JSON) -> tuple[int, list[str]]:
-        """(container count, normalized image names), memoized per pod."""
+    def row(p: JSON) -> tuple:
+        containers = p.get("spec", {}).get("containers") or []
+        return (
+            len(containers),
+            [
+                images.intern(normalized_image_name(c["image"]))
+                for c in containers
+                if c.get("image")
+            ],
+        )
 
-        def build() -> tuple[int, list[str]]:
-            containers = p.get("spec", {}).get("containers") or []
-            return (
-                len(containers),
-                [normalized_image_name(c["image"]) for c in containers if c.get("image")],
-            )
-
-        return objcache.cached("podimgs", p, build)
-
-    vocab: dict[str, int] = {}
-    pod_imgs: list[list[int]] = []
+    fam = table.family("imagelocality", _IMAGE_COLUMNS)
+    table.sync(fam, images.gen, row)
+    P = table.idx.shape[0]
+    g_images = fam.take("images")
+    present = first_seen(g_images)
+    local = rank_lut(present, len(images.items))
+    vocab: dict[str, int] = {
+        images.items[pid]: vi for vi, pid in enumerate(present.tolist())
+    }
     n_containers = np.zeros(p_padded, dtype=np.int32)
-    for j, p in enumerate(pods):
-        nc, names = pod_images(p)
-        n_containers[j] = nc
-        pod_imgs.append([vocab.setdefault(nm, len(vocab)) for nm in names])
-
-    from ksim_tpu.state.featurizer import vocab_pad
+    n_containers[:P] = fam.take("containers")
 
     i = vocab_pad(len(vocab))
 
@@ -244,9 +285,7 @@ def encode_image_locality(
     )
 
     pod_image_count = np.zeros((p_padded, i), dtype=np.int32)
-    for j, imgs in enumerate(pod_imgs):
-        for vi in imgs:
-            pod_image_count[j, vi] += 1
+    scatter_add(pod_image_count, g_images, local)
     return ImageTensors(
         total_nodes=max(len(nodes), 1),
         total_nodes_f=np.asarray(float(max(len(nodes), 1))),

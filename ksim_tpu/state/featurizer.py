@@ -28,10 +28,13 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass, field
-from typing import Any, Sequence
+from typing import Any, Iterable, Sequence
 
 import numpy as np
 
+from ksim_tpu.state import objcache
+from ksim_tpu.state.boundagg import NodeSlots, sync_family
+from ksim_tpu.state.podtable import ROW, Column, PodTable
 from ksim_tpu.state.resources import (
     BASE_RESOURCES,
     UNSCHEDULABLE_TAINT,
@@ -142,7 +145,36 @@ class FeaturizedSnapshot:
         return self.resources.index(r)
 
 
-def _gcd_unit(values: Sequence[int]) -> int:
+_BASE_SET = frozenset(BASE_RESOURCES)
+
+# Table families of the base pod rows (state/podtable.py).
+_STATIC_COLUMNS = (
+    Column("key", object, None),
+    Column("vals", object, None),  # ((resource, raw value), ...)
+    Column("tol", bool, False),
+    Column("has", bool, False),
+)
+_REQUEST_COLUMNS = (
+    Column("req", np.int64, 0, ROW),
+    Column("nz", np.int64, 0, ROW),
+)
+
+
+def _vals_apply(counters: "dict[str, dict[int, int]]", pairs, sign: int) -> None:
+    """Add (+1) or remove (-1) ``(resource, raw value)`` pairs in a
+    per-resource multiset of values."""
+    for r, v in pairs:
+        c = counters.setdefault(r, {})
+        nv = c.get(v, 0) + sign
+        if nv:
+            c[v] = nv
+        else:
+            del c[v]
+            if not c:
+                del counters[r]
+
+
+def _gcd_unit(values: "Iterable[int]") -> int:
     g = 0
     for v in values:
         g = math.gcd(g, v)
@@ -188,8 +220,6 @@ class Featurizer:
         # update by delta instead of re-walking every bound pod.  A fresh
         # instance behaves exactly like the one-shot path (slot order =
         # first-seen order = the caller's order).
-        from ksim_tpu.state.boundagg import NodeSlots
-
         self._slots = NodeSlots()
         # Slot churn applied through advance_slots() between featurize
         # calls (the device-resident replay rolls node history forward
@@ -206,17 +236,32 @@ class Featurizer:
         # re-scanning 15k+ bound pods per pass was the single largest
         # steady-state featurize cost.
         self._bound_vol_count = 0
-        # O(delta) evidence counters: per-pod base-row computations that
-        # actually RAN vs. ones served from the identity memo.  A caller
-        # with an identity-stable queue (the replay lower-cache keeps
-        # surviving universe pods' objects alive across segments) should
-        # see ``pod_rows_built`` grow with its per-window object churn,
-        # not with the universe size — the counter the bench /
+        # The persistent per-pod row table (state/podtable.py): every
+        # pod-axis output is a gather from it, so a call's per-pod Python
+        # runs only for pods it has not seen — and the raw request values
+        # of the table's pods, kept as a multiset per resource the way
+        # the ``resvals`` family keeps the bound pods', decide the
+        # resource axis and the gcd units without a walk over the queue.
+        self._table = PodTable()
+        self._queue_vals: dict[str, dict[int, int]] = {}
+        # O(delta) evidence counters.  ``pod_rows_built``: pods lowered
+        # for the first time; ``pod_rows_reused``: pods whose rows the
+        # table served by gather; ``pod_rows_rebuilt``: family rows
+        # recomputed for a pod the table held because the family's token
+        # moved (vocabulary growth, a new resource, namespace labels).
+        # A caller with an identity-stable queue (the replay lower-cache
+        # keeps surviving universe pods' objects alive across segments)
+        # sees ``pod_rows_built`` grow with its per-window object churn,
+        # not with the universe size — the counters the bench /
         # ``make lock-check`` O(delta) guard reads (docs/churn_floor.md
         # "Incremental lowering + pipelined executor").
         self.pod_rows_built = 0
         self.pod_rows_reused = 0
         self.featurize_passes = 0
+
+    @property
+    def pod_rows_rebuilt(self) -> int:
+        return self._table.rows_rebuilt
 
     def slot_names(self) -> list[str]:
         """The current node-slot order, lowest slot first — the carry a
@@ -265,14 +310,15 @@ class Featurizer:
         ``store.pods_with_node()`` to skip the O(all pods) split —
         phase filtering still happens here);
         ``namespaces`` feed namespaceSelector matching (InterPodAffinity);
-        ``pvs``/``pvcs``/``storage_classes`` feed the volume plugins."""
-        from ksim_tpu.state import objcache
+        ``pvs``/``pvcs``/``storage_classes`` feed the volume plugins.
 
+        Queue pods are recognised by object identity across calls (the
+        row table, state/podtable.py): a pod object handed in again is
+        served its stored rows, so — as for every objcache memo — an
+        object must not be edited in place after it was featurized."""
         # Safe point for memo-table size enforcement: no memo key is in
         # flight here (see objcache.maybe_flush).
         objcache.maybe_flush()
-
-        from ksim_tpu.state.boundagg import sync_family
 
         sched_pods = list(queue_pods) if queue_pods else [
             p for p in pods if not pod_is_scheduled(p)
@@ -313,9 +359,45 @@ class Featurizer:
             self._bound_vol_count -= _pod_has_volumes(prev[pid])
         self._prev_bound = bound_map
 
-        node_alloc = [node_allocatable(n) for n in nodes]
-        pod_reqs = [pod_requests(p) for p in sched_pods]
-        pod_nz_reqs = [pod_requests(p, non_zero=True) for p in sched_pods]
+        # The pod axis: one identity lookup per pod, shared by every
+        # family (state/podtable.py); all per-pod Python below runs
+        # inside a family's row builder, for new pods only.
+        table = self._table
+        queue_vals = self._queue_vals
+        static = table.family("static", _STATIC_COLUMNS)
+        P = len(sched_pods)
+
+        def forget_vals(rows: np.ndarray) -> None:
+            for pairs in static.cols["vals"][rows]:
+                if pairs is not None:
+                    _vals_apply(queue_vals, pairs, -1)
+
+        n_new = table.index(sched_pods, forget_vals)
+        self.pod_rows_built += n_new
+        self.pod_rows_reused += P - n_new
+        self.featurize_passes += 1
+
+        def static_row(p: JSON) -> tuple:
+            """Everything about a pod's base row that no token can move."""
+            reqs = pod_requests(p)
+            nz = pod_requests(p, non_zero=True)
+            # Every raw value that enters math, plus the zero-valued keys
+            # (a requested resource joins the axis whatever its value).
+            pairs = tuple(reqs.items()) + tuple((r, v) for r, v in nz.items() if v)
+            _vals_apply(queue_vals, pairs, +1)
+            return (
+                namespaced_key(p),
+                pairs,
+                tolerations_tolerate_taint(pod_tolerations(p), UNSCHEDULABLE_TAINT),
+                # Upstream fitsRequest early-exit predicate: base requests
+                # all zero AND no scalar-resource key present (a zero-
+                # valued extended-resource key still defeats the early
+                # return).
+                any(reqs.get(r, 0) for r in BASE_RESOURCES)
+                or any(k not in _BASE_SET and k != PODS for k in reqs),
+            )
+
+        table.sync(static, None, static_row)
 
         # Bound pods' raw request values as an incrementally-maintained
         # multiset per resource: the resource axis and exact gcd units
@@ -328,17 +410,6 @@ class Featurizer:
                         pairs.append((r, v))
             return (-1, tuple(pairs))
 
-        def _resvals_apply(counters: dict, rec, sign: int) -> None:
-            for r, v in rec[1]:
-                c = counters.setdefault(r, {})
-                nv = c.get(v, 0) + sign
-                if nv:
-                    c[v] = nv
-                else:
-                    del c[v]
-                    if not c:
-                        del counters[r]
-
         bound_vals: dict[str, dict[int, int]] = sync_family(
             self._agg,
             "resvals",
@@ -347,16 +418,22 @@ class Featurizer:
             set(),  # node-independent
             make_arrays=dict,
             record_of=_resvals_record,
-            apply=_resvals_apply,
+            apply=lambda counters, rec, sign: _vals_apply(counters, rec[1], sign),
         )
 
+        def build_node_vals():
+            vals: dict[str, set[int]] = {}
+            for n in nodes:
+                for r, v in node_allocatable(n).items():
+                    vals.setdefault(r, set()).add(v)
+            return vals
+
+        node_vals = objcache.cached_seq("feat_node_vals", nodes, build_node_vals)
+
         # Resource axis: base prefix + extended resources seen anywhere.
-        seen: set[str] = set()
-        for d in (*node_alloc, *pod_reqs):
-            seen.update(d.keys())
-        seen.update(bound_vals.keys())
+        seen = set(node_vals) | set(queue_vals) | set(bound_vals)
         seen.discard(PODS)
-        extended = sorted(seen - set(BASE_RESOURCES))
+        extended = sorted(seen - _BASE_SET)
         resources = BASE_RESOURCES + tuple(extended)
         ridx = {r: i for i, r in enumerate(resources)}
         R = len(resources)
@@ -369,9 +446,12 @@ class Featurizer:
         # Exact gcd units per resource across every value that enters math.
         units: dict[str, int] = {}
         for r in resources:
-            vals = [d.get(r, 0) for d in (*node_alloc, *pod_reqs, *pod_nz_reqs)]
-            vals = [v for v in vals if v]
-            vals.extend(bound_vals.get(r, ()))
+            vals = {
+                *node_vals.get(r, ()),
+                *queue_vals.get(r, ()),
+                *bound_vals.get(r, ()),
+            }
+            vals.discard(0)
             unit = _gcd_unit(vals)
             max_scaled = max((v // unit for v in vals), default=0)
             if max_scaled > MAX_EXACT_SCALED:
@@ -398,7 +478,7 @@ class Featurizer:
                     row[i] = v // u if v % u == 0 else -(-v // u)
             return objcache.put(key, row)
 
-        N, P = len(nodes), len(sched_pods)
+        N = len(nodes)
         NP, PP = bucket_size(N, self._node_bucket_min), bucket_size(P, self._pod_bucket_min)
 
         def build_node_arrays():
@@ -408,8 +488,9 @@ class Featurizer:
             nvalid = np.zeros(NP, dtype=bool)
             node_names = [name_of(n) for n in nodes]
             for i, n in enumerate(nodes):
-                alloc[i] = lower(node_alloc[i])
-                allowed_pods[i] = node_alloc[i].get(PODS, 0)
+                node_alloc = node_allocatable(n)
+                alloc[i] = lower(node_alloc)
+                allowed_pods[i] = node_alloc.get(PODS, 0)
                 unsched[i] = node_unschedulable(n)
                 nvalid[i] = True
             return alloc, allowed_pods, unsched, nvalid, node_names
@@ -471,41 +552,36 @@ class Featurizer:
         requested = requested.astype(np.int32)
         nz_requested = nz_requested.astype(np.int32)
 
+        # Request rows are kept RAW over the resource axis and scaled at
+        # the gather, so a unit that moves (a new value changing a gcd)
+        # costs one vectorised division, not a rebuild; only a new
+        # extended resource re-lowers the rows.
+        def raw_rows(p: JSON) -> tuple:
+            out = []
+            for d in (pod_requests(p), pod_requests(p, non_zero=True)):
+                row = np.zeros(R, dtype=np.int64)
+                for r, v in d.items():
+                    i = ridx.get(r)
+                    if i is not None:
+                        row[i] = v
+                out.append(row)
+            return tuple(out)
+
+        reqfam = table.family("requests", _REQUEST_COLUMNS)
+        table.sync(reqfam, resources, raw_rows, {"req": R, "nz": R})
+        unit_row = np.array([units[r] for r in resources], dtype=np.int64)
         preq = np.zeros((PP, R), dtype=np.int32)
         pnz = np.zeros((PP, R), dtype=np.int32)
         pvalid = np.zeros(PP, dtype=bool)
         ptol = np.zeros(PP, dtype=bool)
         phas = np.zeros(PP, dtype=bool)
-        base_set = set(BASE_RESOURCES)
-
-        self.featurize_passes += 1
-
-        def pod_base(p: JSON, j: int):
-            """One memo entry bundling the pod's base-row pieces — a
-            saturated churn pass re-featurizes ~1k unchanged pods, and
-            one lookup per pod beats four."""
-            key = ("podbase", objcache.ref_id(p), units_token)
-            hit = objcache.get(key)
-            if hit is not objcache.MISS:
-                self.pod_rows_reused += 1
-                return hit
-            self.pod_rows_built += 1
-            reqs = pod_reqs[j]
-            # Upstream fitsRequest early-exit predicate: base requests all
-            # zero AND no scalar-resource key present (a zero-valued
-            # extended-resource key still defeats the early return).
-            bundle = (
-                lower(reqs),
-                lower(pod_nz_reqs[j]),
-                tolerations_tolerate_taint(pod_tolerations(p), UNSCHEDULABLE_TAINT),
-                any(reqs.get(r, 0) for r in BASE_RESOURCES)
-                or any(k not in base_set and k != PODS for k in reqs),
-            )
-            return objcache.put(key, bundle)
-
-        for j, p in enumerate(sched_pods):
-            preq[j], pnz[j], ptol[j], phas[j] = pod_base(p, j)
-            pvalid[j] = True
+        # Ceiling division: exact for gcd units, rounds up under the
+        # lossy fallback.
+        preq[:P] = -(-reqfam.take("req") // unit_row)
+        pnz[:P] = -(-reqfam.take("nz") // unit_row)
+        ptol[:P] = static.take("tol")
+        phas[:P] = static.take("has")
+        pvalid[:P] = True
 
         from ksim_tpu.state.encoding import (
             encode_affinity,
@@ -522,26 +598,26 @@ class Featurizer:
 
         aux = {
             "affinity": encode_affinity(
-                nodes, sched_pods, NP, PP, added_affinity=self._added_affinity
+                nodes, table, NP, PP, added_affinity=self._added_affinity
             ),
-            "taints": encode_taints(nodes, sched_pods, NP, PP),
+            "taints": encode_taints(nodes, table, NP, PP),
             "spread": encode_topology_spread(
-                nodes, sched_pods, bound_pods, NP, PP,
+                nodes, table, NP, PP,
                 agg=self._agg, bound_map=bound_map,
                 changed_slots=changed_slots, slot_of=node_index,
                 default_constraints=self._spread_defaults,
             ),
             "interpod": encode_inter_pod(
-                nodes, sched_pods, bound_pods, namespaces, NP, PP,
+                nodes, table, namespaces, NP, PP,
                 hard_weight=self._interpod_hard_weight,
                 agg=self._agg, bound_map=bound_map,
                 changed_slots=changed_slots, slot_of=node_index,
             ),
-            "nodename": encode_node_name(nodes, sched_pods, PP),
-            "nodeports": encode_node_ports(nodes, sched_pods, bound_pods, NP, PP),
-            "imagelocality": encode_image_locality(nodes, sched_pods, NP, PP),
+            "nodename": encode_node_name(nodes, table, PP),
+            "nodeports": encode_node_ports(nodes, table, bound_pods, NP, PP),
+            "imagelocality": encode_image_locality(nodes, table, NP, PP),
             "volumes": encode_volumes(
-                nodes, sched_pods, bound_pods, pvs, pvcs, storage_classes, NP, PP,
+                nodes, table, bound_pods, pvs, pvcs, storage_classes, NP, PP,
                 bound_volume_free=self._bound_vol_count == 0,
             ),
         }
@@ -564,7 +640,7 @@ class Featurizer:
                 valid=nvalid,
             ),
             pods=PodTensors(
-                keys=[namespaced_key(p) for p in sched_pods],
+                keys=static.take("key").tolist(),
                 requests=preq,
                 nonzero_requests=pnz,
                 valid=pvalid,

@@ -42,6 +42,10 @@ from typing import Any, Sequence
 
 import numpy as np
 
+from ksim_tpu.state import objcache
+from ksim_tpu.state.boundagg import sync_family
+from ksim_tpu.state.featurizer import vocab_pad
+from ksim_tpu.state.podtable import LIST, ROW, Column, PodTable
 from ksim_tpu.state.resources import JSON, labels_of, name_of, namespace_of
 from ksim_tpu.state.selectors import match_label_selector
 
@@ -143,8 +147,6 @@ def term_context(term: JSON, owner_ns: str) -> dict:
     labelSelector matches NOTHING (metav1.LabelSelectorAsSelector(nil))
     while an empty one matches everything.  Memoized per term object so
     the returned dict is identity-stable across featurizations."""
-    from ksim_tpu.state import objcache
-
     return objcache.cached("ipctx", term, lambda: _term_context(term, owner_ns), owner_ns)
 
 
@@ -176,8 +178,6 @@ def context_matches(ctx: dict, pod: JSON, ns_labels: dict[str, dict]) -> bool:
 
 def _pod_terms(pod: JSON) -> dict[str, list]:
     """Extract the four term families from a pod spec (memoized)."""
-    from ksim_tpu.state import objcache
-
     def build() -> dict[str, list]:
         aff = (pod.get("spec", {}).get("affinity") or {})
         pa = aff.get("podAffinity") or {}
@@ -197,8 +197,6 @@ def parsed_terms(pod: JSON) -> dict[str, list[tuple[dict, str, str, int]]]:
     about a pod's affinity terms that is independent of the per-call
     vocab, memoized per pod object so replay passes skip the JSON walk
     AND the canonical-key dumps."""
-    from ksim_tpu.state import objcache
-
     def build() -> dict[str, list[tuple[dict, str, str, int]]]:
         owner_ns = namespace_of(pod) or "default"
         fams = _pod_terms(pod)
@@ -231,21 +229,32 @@ def has_any_affinity(pod: JSON) -> bool:
     return any(t.values())
 
 
+# A pod's own terms, one entry per term in family order: the term id,
+# its context id, its weight and its family (index into _TERM_FAMILIES).
+_TERM_FAMILIES = ("req_aff", "req_anti", "pref_aff", "pref_anti")
+_TERM_COLUMNS = (
+    Column("t", np.int32, -1, LIST),
+    Column("u", np.int32, 0, LIST),
+    Column("w", np.int32, 0, LIST),
+    Column("fam", np.int8, -1, LIST),
+)
+_MATCH_COLUMNS = (Column("match", bool, False, ROW),)
+
+
 def encode_inter_pod(
     nodes: Sequence[JSON],
-    pods: Sequence[JSON],
-    bound_pods: Sequence[JSON],
+    table: PodTable,
     namespaces: Sequence[JSON],
     n_padded: int,
     p_padded: int,
     *,
     hard_weight: int = DEFAULT_HARD_POD_AFFINITY_WEIGHT,
-    agg: dict | None = None,
-    bound_map: "dict[int, JSON] | None" = None,
-    changed_slots: "set[int] | None" = None,
-    slot_of: "dict[str, int] | None" = None,
+    agg: dict,
+    bound_map: "dict[int, JSON]",
+    changed_slots: "set[int]",
+    slot_of: "dict[str, int]",
 ) -> InterPodTensors:
-    """With ``agg`` (a persistent Featurizer's state, state/boundagg.py)
+    """``agg`` is the Featurizer's persistent state (state/boundagg.py):
     the context/term/domain vocabularies persist append-only across
     calls — ids stay stable — and the existing-pod domain aggregates
     (match counts, required-anti counts, signed score weights) update by
@@ -253,16 +262,7 @@ def encode_inter_pod(
     the context vocabulary or namespace labels change (a new context can
     match pods that did not themselves change); the term aggregates only
     depend on each pod's own terms, so they survive vocabulary growth.
-    Without ``agg``, one-shot rebuild with throwaway state (identical
-    results)."""
-    from ksim_tpu.state.boundagg import sync_family
-    from ksim_tpu.state.featurizer import vocab_pad
-
-    agg = agg if agg is not None else {}
-    if bound_map is None:
-        bound_map = {id(p): p for p in bound_pods}
-    changed_slots = changed_slots if changed_slots is not None else set()
-
+    A one-shot Featurizer is the same code with empty state."""
     # Persistent vocabularies, with a reset valve: adversarial streams
     # could grow them without bound (every reset is just one full
     # rebuild).
@@ -294,17 +294,34 @@ def encode_inter_pod(
 
     # Registration pre-pass: every CURRENT pod's contexts/terms must be
     # in the vocab before any vocab-derived token or array is built.
-    # Queue pods register every call (cheap, the queue is bounded);
-    # bound pods register once (persistent ``ip_seen``).
-    queue_terms = [terms_of(p) for p in pods]
-    seen: set[int] = agg.setdefault("ip_seen", set())
-    # In-place: ``seen &= dict.keys()`` would REBIND the local to a new
-    # set and orphan the persisted one.
-    seen.intersection_update(bound_map.keys())
-    for pid, p in bound_map.items():
-        if pid not in seen:
-            terms_of(p)
-            seen.add(pid)
+    # Building a queue pod's term row registers them (a surviving row's
+    # already are: ids only grow within a vocabulary lineage); bound
+    # pods register when they arrive.
+    def term_rows(pod: JSON) -> tuple:
+        terms = terms_of(pod)
+        flat = [
+            (t, u, w, fi)
+            for fi, fam in enumerate(_TERM_FAMILIES)
+            for t, u, w in terms[fam]
+        ]
+        return tuple([e[c] for e in flat] for c in range(4))
+
+    lineage = agg.get("ip_doms_gen", 0)
+    termfam = table.family("interpod_terms", _TERM_COLUMNS)
+    table.sync(termfam, lineage, term_rows)
+    P = table.idx.shape[0]
+    # ``ip_seen`` is the bound-set generation registered so far: on the
+    # pass right after it only the arrivals of the featurizer's shared
+    # diff are new; any gap (first call, a vocabulary reset) registers
+    # the whole bound set.
+    diff = agg["__diff__"]
+    if agg.get("ip_seen") == diff["gen"] - 1:
+        arrivals = [bound_map[pid] for pid in diff["added"]]
+    else:
+        arrivals = bound_map.values()
+    for p in arrivals:
+        terms_of(p)
+    agg["ip_seen"] = diff["gen"]
 
     # Padded terms are inert: term_u/term_tk 0 with all-zero pod columns.
     U = vocab_pad(len(vocab.ctxs))
@@ -319,8 +336,6 @@ def encode_inter_pod(
 
     # Topology domains from node labels (domain ids persist append-only,
     # so bound-pod contribution records stay valid across passes).
-    from ksim_tpu.state import objcache
-
     def build_node_domains():
         node_dom = np.full((n_padded, TK), -1, dtype=np.int32)
         for ni, node in enumerate(nodes):
@@ -353,29 +368,20 @@ def encode_inter_pod(
         n_padded,
     )
 
-    node_index = slot_of if slot_of is not None else {
-        name_of(n): i for i, n in enumerate(nodes)
-    }
+    node_index = slot_of
     N0 = len(nodes)
 
-    # Per-pod context-match rows, memoized on (pod object, final ctx
-    # vocab, namespace labels): with a persistent vocab the token is
-    # stable, so steady state is one dict lookup per pod.
+    # Per-pod context-match rows span the final ctx vocab and depend on
+    # the namespace labels: both are the table family's token below.
     U0 = len(vocab.ctxs)
-    vocab_token = objcache.intern_token(tuple(vocab.ctx_ids))
-    ns_token = objcache.intern_token(_canon(ns_labels))
+    ns_token = _canon(ns_labels)
 
     def match_row(pod: JSON) -> np.ndarray:
-        key = ("iprow", objcache.ref_id(pod), vocab_token, ns_token)
-        hit = objcache.get(key)
-        if hit is not objcache.MISS:
-            return hit
-        row = np.fromiter(
+        return np.fromiter(
             (context_matches(ctx, pod, ns_labels) for ctx in vocab.ctxs),
             dtype=bool,
             count=U0,
         )
-        return objcache.put(key, row)
 
     # Existing-pod state (the carry init), accumulated in domain space: a
     # bound pod on node ni contributes to ni's domain for EVERY topology
@@ -456,32 +462,38 @@ def encode_inter_pod(
         apply=_terms_apply,
     )
 
-    # Queue-pod tables.
+    # Queue-pod tables, scattered from the gathered term entries.
+    matchfam = table.family("interpod_match", _MATCH_COLUMNS)
+    table.sync(
+        matchfam, (lineage, U0, ns_token), lambda pod: (match_row(pod),), {"match": U0}
+    )
     pod_ctx_match = np.zeros((p_padded, U), dtype=bool)
+    pod_ctx_match[:P, :U0] = matchfam.take("match")
     req_aff = np.zeros((p_padded, T), dtype=bool)
     req_anti = np.zeros((p_padded, T), dtype=bool)
     self_aff = np.zeros(p_padded, dtype=bool)
     pref_w = np.zeros((p_padded, T), dtype=np.int32)
     pod_vw = np.zeros((p_padded, T), dtype=np.int32)
     pod_eat = np.zeros((p_padded, T), dtype=np.int32)
-    for j, (pod, terms) in enumerate(zip(pods, queue_terms)):
-        row = match_row(pod)
-        pod_ctx_match[j, :U0] = row
-        self_ok = True
-        for t, u, _w in terms["req_aff"]:
-            req_aff[j, t] = True
-            pod_vw[j, t] += hard_weight
-            self_ok = self_ok and bool(row[u])
-        self_aff[j] = self_ok and bool(terms["req_aff"])
-        for t, _u, _w in terms["req_anti"]:
-            req_anti[j, t] = True
-            pod_eat[j, t] += 1
-        for t, _u, w in terms["pref_aff"]:
-            pref_w[j, t] += w
-            pod_vw[j, t] += w
-        for t, _u, w in terms["pref_anti"]:
-            pref_w[j, t] -= w
-            pod_vw[j, t] -= w
+    g_t = termfam.take("t")
+    rr, cc = np.nonzero(g_t >= 0)
+    if rr.size:
+        tt = g_t[rr, cc]
+        ww = termfam.take("w")[rr, cc]
+        ff = termfam.take("fam")[rr, cc]
+        ra, rn, pa, pn = (ff == fi for fi in range(4))
+        req_aff[rr[ra], tt[ra]] = True
+        np.add.at(pod_vw, (rr[ra], tt[ra]), hard_weight)
+        # A pod matches ALL its own required affinity terms' contexts.
+        misses = ~pod_ctx_match[rr[ra], termfam.take("u")[rr, cc][ra]]
+        self_aff[rr[ra]] = True
+        self_aff[rr[ra][misses]] = False
+        req_anti[rr[rn], tt[rn]] = True
+        np.add.at(pod_eat, (rr[rn], tt[rn]), 1)
+        np.add.at(pref_w, (rr[pa], tt[pa]), ww[pa])
+        np.add.at(pod_vw, (rr[pa], tt[pa]), ww[pa])
+        np.subtract.at(pref_w, (rr[pn], tt[pn]), ww[pn])
+        np.subtract.at(pod_vw, (rr[pn], tt[pn]), ww[pn])
 
     # Node-space carry initialization: pre-apply the domain aggregation so
     # the device never has to (see module docstring).

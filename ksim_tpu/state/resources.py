@@ -19,6 +19,7 @@ from __future__ import annotations
 
 from typing import Any, Iterable
 
+from ksim_tpu.state import objcache
 from ksim_tpu.state.quantity import parse_quantity
 
 JSON = dict[str, Any]
@@ -102,8 +103,6 @@ def pod_requests(pod: JSON, *, non_zero: bool = False) -> dict[str, int]:
     containers missing cpu/memory requests (NonMissingContainerRequests in
     upstream noderesources/resource_allocation.go calculatePodResourceRequest).
     """
-    from ksim_tpu.state import objcache
-
     key = ("preq", objcache.ref_id(pod), non_zero)
     hit = objcache.get(key)
     if hit is not objcache.MISS:
@@ -145,8 +144,6 @@ def node_allocatable(node: JSON) -> dict[str, int]:
     """Node allocatable in scheduler units; falls back to capacity.
     Memoized per node object (returned dict is frozen) so the
     featurizer's lower() rows can memoize on the dict's identity."""
-    from ksim_tpu.state import objcache
-
     def build() -> dict[str, int]:
         status = node.get("status", {})
         alloc = status.get("allocatable") or status.get("capacity") or {}

@@ -45,9 +45,12 @@ from typing import Sequence
 
 import numpy as np
 
+from ksim_tpu.state import objcache
+from ksim_tpu.state.featurizer import vocab_pad
+from ksim_tpu.state.podtable import Column, PodTable
+from ksim_tpu.state.quantity import parse_quantity
 from ksim_tpu.state.resources import JSON, labels_of, name_of, namespace_of
 from ksim_tpu.state.selectors import match_node_selector_terms
-from ksim_tpu.state.featurizer import vocab_pad
 
 # Zone/region label keys upstream volume_zone.go consults.
 ZONE_KEYS = (
@@ -147,8 +150,6 @@ def _pod_volumes(pod: JSON) -> list[JSON]:
 def _pod_has_volumes(pod: JSON) -> bool:
     """Memoized per pod object: churn replay re-checks every bound pod
     each pass, and the common case is volume-free pods."""
-    from ksim_tpu.state import objcache
-
     return objcache.cached(
         "has_vols", pod, lambda: bool(_pod_volumes(pod))
     )
@@ -157,7 +158,6 @@ def _pod_has_volumes(pod: JSON) -> bool:
 def _node_has_attach_pools(node: JSON) -> bool:
     """Memoized per node object: does the node expose any
     attachable-volumes-* allocatable key?"""
-    from ksim_tpu.state import objcache
 
     def build() -> bool:
         alloc = node.get("status", {}).get("allocatable") or {}
@@ -170,8 +170,6 @@ def _any_node_has_attach_pools(nodes) -> bool:
     """Family-memoized over the exact node list: the volumes fast path
     asks this every pass, and walking 2k per-node memos was a measurable
     slice of churn featurize time."""
-    from ksim_tpu.state import objcache
-
     return objcache.cached_seq(
         "any_attach_pools",
         nodes,
@@ -188,8 +186,6 @@ def _trivial_volume_tensors(n_padded: int, p_padded: int) -> "VolumeTensors":
     hit = _TRIVIAL.get((n_padded, p_padded))
     if hit is not None:
         return hit
-    from ksim_tpu.state.featurizer import vocab_pad
-
     NPV = C = V = R = D = vocab_pad(0)
     K = 1
     out = VolumeTensors(
@@ -269,8 +265,6 @@ def _pv_matches_claim(pv: JSON, pvc: JSON) -> bool:
     want_modes = set(pvc_spec.get("accessModes") or [])
     if want_modes and not want_modes.issubset(set(spec.get("accessModes") or [])):
         return False
-    from ksim_tpu.state.quantity import parse_quantity
-
     want = (pvc_spec.get("resources") or {}).get("requests", {}).get("storage")
     have = (spec.get("capacity") or {}).get("storage")
     if want is not None:
@@ -281,9 +275,12 @@ def _pv_matches_claim(pv: JSON, pvc: JSON) -> bool:
     return True
 
 
+_VOLUME_COLUMNS = (Column("has", bool, False),)
+
+
 def encode_volumes(
     nodes: Sequence[JSON],
-    pods: Sequence[JSON],
+    table: PodTable,
     bound_pods: Sequence[JSON],
     pvs: Sequence[JSON],
     pvcs: Sequence[JSON],
@@ -291,23 +288,22 @@ def encode_volumes(
     n_padded: int,
     p_padded: int,
     *,
-    bound_volume_free: "bool | None" = None,
+    bound_volume_free: bool,
 ) -> VolumeTensors:
     # Fast path — the common churn case: no volume API objects, no pod
-    # declares volumes, no node exposes attach pools.  The bound-pod scan
-    # is the expensive precondition at churn scale; a persistent
-    # Featurizer passes ``bound_volume_free`` from its incrementally
-    # maintained count instead.
+    # declares volumes, no node exposes attach pools.  A bound-pod scan
+    # would be the expensive precondition at churn scale: the Featurizer
+    # passes ``bound_volume_free`` from its incrementally maintained
+    # count, and the table knows which queue pods carry volumes.
+    fam = table.family("volumes", _VOLUME_COLUMNS)
+    table.sync(fam, None, lambda p: (_pod_has_volumes(p),))
+    with_volumes = np.nonzero(fam.take("has"))[0]
     if (
         not pvs
         and not pvcs
         and not storage_classes
-        and not any(_pod_has_volumes(p) for p in pods)
-        and (
-            bound_volume_free
-            if bound_volume_free is not None
-            else not any(_pod_has_volumes(p) for p in bound_pods)
-        )
+        and not with_volumes.size
+        and bound_volume_free
         and not _any_node_has_attach_pools(nodes)
     ):
         return _trivial_volume_tensors(n_padded, p_padded)
@@ -338,7 +334,7 @@ def encode_volumes(
     disk_vocab: dict[tuple[str, str], int] = {}  # (source, id) -> row
 
     pod_fail = np.zeros(p_padded, dtype=np.int32)
-    pod_rows: list[dict] = []
+    pod_rows: list[tuple[int, dict]] = []  # volume-carrying pods only
 
     def classify_pod(pod: JSON, register: bool):
         """Walk a pod's volumes; returns per-pod row dict (queue pods).
@@ -427,9 +423,11 @@ def encode_volumes(
         row["fail"] = fail
         return row
 
-    for j, pod in enumerate(pods):
-        row = classify_pod(pod, register=True)
-        pod_rows.append(row)
+    # A pod without volumes has the empty row everywhere; the walk
+    # visits the others, in queue order.
+    for j in with_volumes.tolist():
+        row = classify_pod(table.pod(j), register=True)
+        pod_rows.append((j, row))
         pod_fail[j] = row["fail"]
 
     # Bound pods register too: their attached volumes / disk uses / RWOP
@@ -481,7 +479,7 @@ def encode_volumes(
     pod_rwop = np.zeros((p_padded, R), dtype=bool)
     pod_disk_any = np.zeros((p_padded, D), dtype=bool)
     pod_disk_rw = np.zeros((p_padded, D), dtype=bool)
-    for j, row in enumerate(pod_rows):
+    for j, row in pod_rows:
         for vi in row["pv"]:
             pod_pv[j, vi] = True
         for ci in row["wffc"]:

@@ -130,6 +130,72 @@ def test_cache_and_pipeline_match_per_pass_small():
             assert entry["rows_built"] <= entry["events"] + 32
 
 
+def test_gather_guard_survivors_cost_no_per_pod_python(monkeypatch):
+    """The guard on what DRIVES the lowering's time, not only on the
+    builds: per cache-hit segment every surviving universe pod is served
+    by gather from the featurizer's row table (``rows_reused == U -
+    rows_built``), a family's rows are recomputed only when its token
+    moved (``rows_rebuilt``, 0 while no vocabulary or unit moves), and —
+    by a counting wrapper around every per-pod row builder — no per-pod
+    Python ran for a pod the table already held."""
+    from ksim_tpu.state.featurizer import Featurizer
+    from ksim_tpu.state.podtable import PodTable
+
+    held: dict[int, set[int]] = {}  # table -> pod ids it held before this call
+    survivor_builds: dict[int, int] = {}
+    orig_index, orig_sync = PodTable.index, PodTable.sync
+
+    def index(self, pods, on_release=None):
+        held[id(self)] = set(self._row_of)
+        return orig_index(self, pods, on_release)
+
+    def sync(self, fam, token, build, widths=None):
+        before = held[id(self)]
+
+        def counted(pod):
+            if id(pod) in before:
+                survivor_builds[id(self)] = survivor_builds.get(id(self), 0) + 1
+            return build(pod)
+
+        return orig_sync(self, fam, token, counted, widths)
+
+    # What a family token is made of, read after each driver lowering.
+    vocab_sizes: list[tuple] = []
+    orig_featurize = Featurizer.featurize
+
+    def featurize(self, nodes, pods, **kw):
+        out = orig_featurize(self, nodes, pods, **kw)
+        sels = self._agg.get("spread_sels", {"list": ()})["list"]
+        ip = self._agg.get("ip_vocab")
+        vocab_sizes.append((
+            id(self), out.resources, len(sels), len(ip.ctxs) if ip else 0,
+            tuple((k, v.gen, len(v.items)) for k, v in sorted(self._table._vocabs.items())),
+        ))
+        return out
+
+    monkeypatch.setattr(PodTable, "index", index)
+    monkeypatch.setattr(PodTable, "sync", sync)
+    monkeypatch.setattr(Featurizer, "featurize", featurize)
+    dev, _sig = _run(_small_ops(), device=True, k=4)
+    d = dev.replay_driver
+    table = d._featurizer._table
+    sizes = [v[1:] for v in vocab_sizes if v[0] == id(d._featurizer)]
+    assert len(sizes) == len(d.lower_log) >= 3
+    steady = 0
+    for i, entry in enumerate(d.lower_log):
+        if not entry["cache_hit"]:
+            continue
+        assert entry["rows_built"] <= entry["events"] + 32
+        assert entry["rows_reused"] == entry["universe"] - entry["rows_built"], entry
+        if sizes[i] == sizes[i - 1]:
+            assert entry["rows_rebuilt"] == 0, entry
+            steady += 1
+    assert steady >= 2
+    # Every builder run on a surviving pod is a counted rebuild.
+    assert survivor_builds.get(id(table), 0) == d.stats()["featurize_rebuilt"]
+    assert d.stats()["featurize_reused"] == sum(e["rows_reused"] for e in d.lower_log)
+
+
 def test_mid_stream_fallback_discards_prefix_and_invalidates():
     """An op outside the tensor vocabulary (a patch) forces a per-pass
     fallback mid-stream: the speculative prefix for the shifted window
