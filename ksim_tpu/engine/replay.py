@@ -1410,6 +1410,14 @@ class ReplayDriver:
         # Summed replay.exec walls (launch until outputs ready) of the
         # healthy dispatches: an upper bound of this run's device time.
         self.device_wait_s = 0.0  # guarded-by: main-thread
+        # Shape evidence of the healthy dispatches (PR 27): the widest
+        # compiled queue bucket, the tail-padded step slots (compiled K
+        # less the real steps, summed), and — counted at commit — the
+        # pod x node pairs the committed passes evaluated (attempts
+        # times live nodes: the north star's unit).
+        self.queue_width_max = 0  # guarded-by: main-thread
+        self.steps_padded = 0  # guarded-by: main-thread
+        self.pairs_evaluated = 0  # guarded-by: main-thread
         # Streaming ingest overlap (round 22, traces/stream.py): a
         # runner-provided NONBLOCKING drain of the trace-ingest queue,
         # called on the main thread while the dispatch worker owns the
@@ -1529,6 +1537,9 @@ class ReplayDriver:
             "fallback_steps": self.fallback_steps,
             "device_round_trips": self.device_round_trips,
             "device_wait_s": round(self.device_wait_s, 6),
+            "queue_width_max": self.queue_width_max,
+            "steps_padded": self.steps_padded,
+            "pairs_evaluated": self.pairs_evaluated,
             "ingest_prefetches": self.ingest_prefetches,
             "device_errors": self.device_errors,
             "watchdog_timeouts": self.watchdog_timeouts,
@@ -2082,6 +2093,8 @@ class ReplayDriver:
             self._breaker_close()
         self.device_round_trips += 1
         self.device_wait_s += plan.exec_s
+        self.queue_width_max = max(self.queue_width_max, plan.statics.q)
+        self.steps_padded += plan.statics.k - plan.n_steps
         note_backend()
         if self._dev_cache_on is None:
             # Safe to probe now: the dispatch initialized the backend on
@@ -2927,19 +2940,21 @@ class ReplayDriver:
         # actually built vs the window's event count (the lock-check
         # guard asserts steady-state proportionality; counters, not
         # timings, so it is CI-stable).
-        self.lower_log.append(
-            {
-                "events": sum(len(b) for b in batches),
-                "steps": m_steps,
-                "universe": U,
-                "rows_built": feat.pod_rows_built - rows0[0],
-                "rows_reused": feat.pod_rows_reused - rows0[1],
-                "rows_rebuilt": feat.pod_rows_rebuilt - rows0[2],
-                "cache_hit": use_cache,
-                "tp": tp,
-                "full_bytes_per_shard": int(full_bytes_shard),
-            }
-        )
+        log_entry = {
+            "events": sum(len(b) for b in batches),
+            "steps": m_steps,
+            "universe": U,
+            "rows_built": feat.pod_rows_built - rows0[0],
+            "rows_reused": feat.pod_rows_reused - rows0[1],
+            "rows_rebuilt": feat.pod_rows_rebuilt - rows0[2],
+            "cache_hit": use_cache,
+            "tp": tp,
+            "full_bytes_per_shard": int(full_bytes_shard),
+            "queue_width": q,
+            "steps_padded": K - m_steps,
+            "pairs_evaluated": 0,  # filled in when the segment commits
+        }
+        self.lower_log.append(log_entry)
         return _SegmentPlan(
             statics=statics,
             prog=prog,
@@ -2964,6 +2979,7 @@ class ReplayDriver:
             sched_names=sched_names,
             dev_collect=bool(self._dev_cache_on),
             mesh=self._shard_mesh_obj,
+            log_entry=log_entry,
         )
 
     @staticmethod
@@ -3313,6 +3329,25 @@ class ReplayDriver:
         for nodes in step_nodes:
             if nodes is not None:
                 feat.advance_slots(nodes)
+
+    def note_segment_committed(
+        self, seg: SegmentOutcome, step_nodes: "Sequence[Any]"
+    ) -> None:
+        """Post-commit accounting of one device segment: its steps, and
+        the pod x node pairs its passes evaluated — every attempt of a
+        pass runs Filter and Score over the nodes live at that step
+        (``step_nodes`` as for ``advance_service_slots``: ``None`` where
+        the pass never ran, and then nothing was attempted)."""
+        self.device_steps += len(seg.steps)
+        pairs = sum(
+            (o.scheduled + o.unschedulable) * len(nodes)
+            for o, nodes in zip(seg.steps, step_nodes)
+            if nodes is not None
+        )
+        self.pairs_evaluated += pairs
+        plan = self._last_plan  # None on a fleet follower: it lowered nothing
+        if plan is not None and plan.log_entry is not None:
+            plan.log_entry["pairs_evaluated"] = pairs
 
     def verify_segment(self, seg: SegmentOutcome) -> None:
         """Verify the staged store converged to the device's view of the
@@ -4070,6 +4105,7 @@ class _SegmentPlan:
     dev_hits: int = 0
     dev_misses: int = 0
     exec_s: float = 0.0  # wall of this dispatch's replay.exec (worker)
+    log_entry: "dict | None" = None  # this lowering's lower_log entry
     # Round 19: device-buffer LAYOUT tokens — ``dev_reuse_layout`` is
     # the token the attached reuse map's buffers were committed under
     # (("pack",) for the single-device packed transfer, ("mesh", dp, tp)

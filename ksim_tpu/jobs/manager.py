@@ -1767,6 +1767,9 @@ class JobManager:
         drv = getattr(runner, "replay_driver", None)
         if drv is not None:
             doc["replay"] = drv.stats()  # includes the shared compile_cache
+            # Where every pod of the job's store stands at its end: the
+            # counts above do not show a pod that landed elsewhere.
+            doc["replay"]["placements_digest"] = runner.store.placements_digest()
         return doc
 
     # -- lookups & lifecycle --------------------------------------------

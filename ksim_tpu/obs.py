@@ -168,6 +168,11 @@ SPAN_NAMES: tuple[str, ...] = (
     "jobs.run",  # one tenant job end-to-end on a job-plane worker
     #              (ksim_tpu/jobs/manager.py; recorded on the JOB's
     #              private plane via the worker's scoped override)
+    "jobs.submit",  # POST /api/v1/jobs on the HTTP handler thread:
+    #                 body read + parse, spec validation (15,000
+    #                 operations are 15,000 Operation objects) and the
+    #                 enqueue (server/http.py; global plane — the job's
+    #                 private ring does not exist yet)
     "scenario.ingest",  # one trace ingestion: parse + resample +
     #                     compile of a real cluster trace into the
     #                     operation stream (ksim_tpu/traces/compile.py;

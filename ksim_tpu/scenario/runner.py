@@ -604,7 +604,7 @@ class ScenarioRunner:
         self.service._notify_evictions(evictions)
         driver.advance_service_slots(step_nodes)
         driver.sync_service(seg)
-        driver.device_steps += len(seg.steps)
+        driver.note_segment_committed(seg, step_nodes)
         for step, batch, outcome in zip(seg_keys, batches, seg.steps):
             self._record_device_step(step, batch, outcome, result)
         return True

@@ -283,7 +283,8 @@ class _Handler(BaseHTTPRequestHandler):
         if url.path == "/api/v1/schedulerconfiguration":
             self._apply_scheduler_config()
         elif url.path == "/api/v1/jobs":
-            self._job_submit()
+            with TRACE.span("jobs.submit"):
+                self._job_submit()
         elif url.path == "/api/v1/import":
             metrics = self.server.di.scheduler_service.metrics
             try:
@@ -492,7 +493,11 @@ class _Handler(BaseHTTPRequestHandler):
         the bounded queue refuses or the submitting tenant
         (``X-Ksim-Tenant`` header, else ``spec.tenant``) is over its
         quota/rate — the throttle response carries a ``Retry-After``
-        header with the token bucket's computed wait."""
+        header with the token bucket's computed wait.  ``do_POST`` wraps
+        the whole request — body read, parse, validation, enqueue,
+        response — in one ``jobs.submit`` span on the global plane: a
+        15,000-operation body spends 0.2-0.6 s here before the job's own
+        ring exists."""
         from ksim_tpu.jobs import JobLimitExceeded, JobQueueFull, JobThrottled
         from ksim_tpu.scenario.spec import ScenarioSpecError
 

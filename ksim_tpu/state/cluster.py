@@ -16,6 +16,7 @@ import bisect
 import collections
 import contextlib
 import copy
+import hashlib
 import itertools
 import os
 import queue
@@ -287,6 +288,20 @@ class ClusterStore:
         ``list(copy_objs=False)``."""
         with self._lock:
             return list(self._with_node.values())
+
+    def placements_digest(self) -> str:
+        """sha256 over one line ``<namespace>/<name> <nodeName>\\n`` per
+        pod in the store, lines sorted (an unbound pod's node is empty):
+        where every pod stands, in 64 characters.  A job's result
+        document carries it (``replay.placements_digest``), so that a
+        client holding its own replay of the submitted operations can
+        tell ANY moved placement without fetching the pods — equal
+        counts do not show a pod that landed elsewhere."""
+        with self._lock:
+            lines = sorted(
+                f"{key} {self._node_of.get(key, '')}\n" for key in self._objects["pods"]
+            )
+        return hashlib.sha256("".join(lines).encode()).hexdigest()
 
     def pods_on_nodes(self, node_names) -> list[JSON]:
         """Live dicts of pods bound to any of ``node_names`` (ANY

@@ -107,6 +107,47 @@ def test_churn_lock_6k_holds_with_tracing_enabled(tmp_path):
         TRACE._active, TRACE._ring_on, TRACE._user_disabled = prev_state
 
 
+# The third lock (PR 27): the burst-5k deployment — every node created
+# in step 0, the whole backlog in step 1, ONE pass with no
+# max_pods_per_pass.  At the benchmark cell's own size (seed 0, 5000
+# nodes, 10000 pods) the counts are 9390/610 — benchmark/replay.py and
+# the chip runs hold them; here the rehearsal size (200 nodes, 400 pods)
+# is pinned.  Both taken from the plain replay under benchmark/ and the
+# oracle (tests/test_burst_onestep.py), not from the program.
+BURST_LOCK_SCHEDULED = 384
+BURST_LOCK_UNSCHEDULABLE = 16
+
+
+@pytest.mark.parametrize("device", [False, True], ids=["per-pass", "device"])
+@pytest.mark.parametrize("x64", [False, True], ids=["f32-fast", "exact-x64"])
+def test_burst_lock_onestep_rehearsal_size(x64, device):
+    """As the job plane runs the benchmark's burst-5k configuration
+    (deviceReplay, preemption, podBucketMin 128, no pass cap), on both
+    paths and in both modes."""
+    prev = jax.config.jax_enable_x64
+    jax.config.update("jax_enable_x64", x64)
+    try:
+        runner = ScenarioRunner(
+            preemption=True, pod_bucket_min=128, device_replay=device
+        )
+        res = runner.run(
+            churn_scenario(
+                0, n_nodes=200, n_events=600, ops_per_step=400,
+                pod_create_frac=1.0, pod_delete_frac=0.0,
+            )
+        )
+    finally:
+        jax.config.update("jax_enable_x64", prev)
+    assert res.events_applied == 600
+    assert (res.pods_scheduled, res.unschedulable_attempts) == (
+        BURST_LOCK_SCHEDULED, BURST_LOCK_UNSCHEDULABLE,
+    )
+    if device:
+        stats = runner.replay_driver.stats()
+        assert stats["device_steps"] == 2 and stats["fallback_steps"] == 0
+        assert stats["unsupported"] == {}
+
+
 @pytest.mark.slow
 def test_churn_lock_6k_holds_under_dispatch_faults_with_recovery(monkeypatch):
     """The chaos leg (`make lock-check`, round 15): the locked 6k counts

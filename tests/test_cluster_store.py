@@ -231,6 +231,59 @@ def test_pod_node_name_partition_tracks_every_write_path():
     assert "s" in names(store.pods_with_node())
 
 
+def _digest_of(placements: dict) -> str:
+    """The documented definition, spelled out: sha256 over the sorted
+    lines ``<namespace>/<name> <node>\\n``, an unbound pod's node empty."""
+    import hashlib
+
+    lines = sorted(f"{key} {node or ''}\n" for key, node in placements.items())
+    return hashlib.sha256("".join(lines).encode()).hexdigest()
+
+
+def test_placements_digest_is_the_documented_hash():
+    store = ClusterStore()
+    assert store.placements_digest() == _digest_of({})
+    store.create("pods", make_pod("b", node_name="n1"))
+    store.create("pods", make_pod("a"))
+    store.create("pods", make_pod("a", namespace="other", node_name="n2"))
+    want = {"default/a": None, "default/b": "n1", "other/a": "n2"}
+    assert store.placements_digest() == _digest_of(want)
+
+
+@pytest.mark.parametrize("change", ["bind", "move", "unbind", "delete", "create"])
+def test_placements_digest_moves_with_any_one_placement(change):
+    """One pod bound, bound elsewhere, unbound, gone or new: another
+    digest — and the same digest again whatever the order of the writes."""
+    def build(order):
+        store = ClusterStore()
+        for nm in order:
+            store.create("pods", make_pod(nm, node_name="n1" if nm != "a" else ""))
+        return store
+
+    store, other = build(["a", "b", "c"]), build(["c", "a", "b"])
+    before = store.placements_digest()
+    assert before == other.placements_digest()
+
+    def set_node(name, node):
+        def mutate(o):
+            o["spec"].pop("nodeName", None)
+            if node:
+                o["spec"]["nodeName"] = node
+        store.patch("pods", name, "default", mutate)
+
+    if change == "bind":
+        set_node("a", "n1")
+    elif change == "move":
+        set_node("b", "n2")
+    elif change == "unbind":
+        set_node("c", "")
+    elif change == "delete":
+        store.delete("pods", "c", "default")
+    else:
+        store.create("pods", make_pod("d"))
+    assert store.placements_digest() != before
+
+
 def test_pods_without_node_is_name_sorted():
     """The without-node side is the scheduling queue's stable pre-order:
     it must come back (name, key)-sorted like list("pods")."""
