@@ -517,6 +517,11 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
     from ksim_tpu.plugins.base import NodeStateView, PodBatch
     from ksim_tpu.engine.core import SCAN_UNROLL
 
+    # Slots per trip of the pod loops (``run_slots`` in ``_run_step``):
+    # the widest block <= SCAN_UNROLL that divides the queue, so a block
+    # never reaches past slot q - 1 (every bucket_size rung is a
+    # multiple of 4, the default).
+    blk = math.gcd(SCAN_UNROLL, st.q)
     max_backoff, flush_cap = _backoff_constants()
     # _record_attempts' delay is min(2^(attempts_new-1), MAX) — computed
     # as a shift with the exponent clamped where the cap saturates.
@@ -534,6 +539,9 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
     n_filters = sum(1 for sp in prog.plugins if sp.filter_enabled)
     n_scores = sum(1 for sp in prog.plugins if sp.score_enabled)
     bits_dtype, final_dtype = prog._result_dtypes()
+    # Raw scores keep the width the mode computes them in (core.py
+    # records them unconverted): int64 in exact (x64) mode, else int32.
+    raw_dtype = jax.dtypes.canonicalize_dtype(int)
     # Effective search bounds: the configured statics are PER-SHARD
     # budgets (round 17) — multiplied by the mesh width, then clamped to
     # the padded axes (top_k needs k <= axis; small universes can't
@@ -799,6 +807,7 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
                 "scheduled": jnp.zeros((), jnp.int32),
                 "unschedulable": jnp.zeros((), jnp.int32),
                 "eligible": jnp.zeros((), jnp.int32),
+                "slots": jnp.zeros((), jnp.int32),
                 "pass_count": s["pass_count"],
                 "pending_after": jnp.zeros((), jnp.int32),
             }
@@ -808,7 +817,7 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
                 z["overflow"] = jnp.zeros((), bool)
             if st.record == "full":
                 z["bits"] = jnp.zeros((st.q, n_filters, N), bits_dtype)
-                z["raw"] = jnp.zeros((st.q, n_scores, N), jnp.int32)
+                z["raw"] = jnp.zeros((st.q, n_scores, N), raw_dtype)
                 z["final"] = jnp.zeros((st.q, n_scores, N), final_dtype)
             return s, z
 
@@ -852,6 +861,57 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
             .at[jnp.where(att, pos, st.q)]
             .set(jnp.arange(P, dtype=jnp.int32), mode="drop")
         )
+        # The attempted pods fill slots 0 .. n_att - 1 and every later
+        # slot is invalid: an invalid slot binds nothing (best = -1, so
+        # every commit and scatter below drops) and no decoder reads
+        # its outputs (idx == P marks it).  So the pod loops run the
+        # first n_blocks * blk slots only — the step's attempts rounded
+        # up to a whole block — and the compiled queue width costs
+        # nothing beyond them.  In the fleet program the bound is
+        # pmax-reduced over the lane axis, like `go` below: a batched
+        # bound would make vmap batch the `while` predicate and turn
+        # every carry update into a select; a lane with fewer attempts
+        # runs a few invalid slots instead.
+        n_att = jnp.sum(att.astype(jnp.int32)).astype(jnp.int32)
+        n_blocks = (n_att + (blk - 1)) // blk
+        if st.lane_axis is not None:
+            n_blocks = jax.lax.pmax(n_blocks, st.lane_axis)
+
+        def all_invalid(invalid):
+            """Stacked outputs of a queue of invalid slots."""
+            return jax.tree.map(
+                lambda v: jnp.broadcast_to(v, (st.q,) + v.shape), invalid
+            )
+
+        def run_slots(slot_body, carry0, xs, invalid):
+            """``lax.scan(slot_body, carry0, xs)`` over slots
+            0 .. n_blocks * blk - 1; the later rows of the stacked
+            outputs hold ``invalid``, what ``slot_body`` yields for an
+            invalid slot (per-slot shapes and dtypes)."""
+
+            def block(c):
+                b, carry, ys = c
+                x_blk = jax.tree.map(
+                    lambda a: jax.lax.dynamic_slice_in_dim(a, b * blk, blk), xs
+                )
+                # Traced once, unrolled whole: no inner loop.
+                carry, y_blk = jax.lax.scan(slot_body, carry, x_blk, unroll=True)
+                ys = jax.tree.map(
+                    lambda buf, y: jax.lax.dynamic_update_slice_in_dim(
+                        buf, y, b * blk, 0
+                    ),
+                    ys,
+                    y_blk,
+                )
+                return b + 1, carry, ys
+
+            _, carry, ys = jax.lax.while_loop(
+                lambda c: c[0] < n_blocks,
+                block,
+                (jnp.int32(0), carry0, all_invalid(invalid)),
+            )
+            return carry, ys
+
         clamped = jnp.minimum(idx_q, P - 1)
         pods_q = PodBatch(
             requests=prow["requests"][clamped],
@@ -957,7 +1017,7 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
                 ).astype(bits_dtype)
                 out_pod["raw"] = (
                     jnp.stack(_raw) if _raw else jnp.zeros((0, N), jnp.int32)
-                )
+                ).astype(raw_dtype)
                 out_pod["final"] = (
                     jnp.stack(_final) if _final else jnp.zeros((0, N), jnp.int32)
                 ).astype(final_dtype)
@@ -988,8 +1048,15 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
                 )
             return (nstate, pcarries, live), out_pod
 
-        (node_state, carries, live), pod_outs = jax.lax.scan(
-            pod_body, (node_state, carries, live0), pods_q, unroll=SCAN_UNROLL
+        invalid_pod = {"best": jnp.int32(-1)}
+        if st.record == "full":
+            invalid_pod["bits"] = jnp.zeros((n_filters, N), bits_dtype)
+            invalid_pod["raw"] = jnp.zeros((n_scores, N), raw_dtype)
+            invalid_pod["final"] = jnp.zeros((n_scores, N), final_dtype)
+        if st.preempt:
+            invalid_pod["pred_hat"] = jnp.zeros((), bool)
+        (node_state, carries, live), pod_outs = run_slots(
+            pod_body, (node_state, carries, live0), pods_q, invalid_pod
         )
         sel = pod_outs["best"]
         bound_mask = (idx_q < P) & (sel >= 0)
@@ -1016,6 +1083,12 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
             search_xs = (pods_q, sel) + (
                 (pod_outs["bits"],) if with_bits else ()
             )
+
+            invalid_search = {
+                "nom": jnp.int32(-1),
+                "vic": jnp.full(v_eff, -1, jnp.int32),
+                "over": jnp.zeros((), bool),
+            }
 
             def search_pods(_):
                 # Exact replay: rescan the queue from the pre-pass live
@@ -1068,9 +1141,9 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
                         lv, _lw = op
                         return (
                             lv,
-                            jnp.int32(-1),
-                            jnp.full(v_eff, -1, jnp.int32),
-                            jnp.zeros((), bool),
+                            invalid_search["nom"],
+                            invalid_search["vic"],
+                            invalid_search["over"],
                         )
 
                     live, nom, vicr, over = jax.lax.cond(
@@ -1078,16 +1151,13 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
                     )
                     return live, {"nom": nom, "vic": vicr, "over": over}
 
-                return jax.lax.scan(
-                    search_body, dict(live0), search_xs, unroll=SCAN_UNROLL
+                # The same slots as the bind loop: it replays that queue.
+                return run_slots(
+                    search_body, dict(live0), search_xs, invalid_search
                 )
 
             def skip_search(_):
-                return dict(live), {
-                    "nom": jnp.full(st.q, -1, jnp.int32),
-                    "vic": jnp.full((st.q, v_eff), -1, jnp.int32),
-                    "over": jnp.zeros(st.q, bool),
-                }
+                return dict(live), all_invalid(invalid_search)
 
             live, souts = jax.lax.cond(go, search_pods, skip_search, 0)
         if st.preempt:
@@ -1155,6 +1225,8 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
             "eligible": jnp.where(
                 any_valid, jnp.sum(elig.astype(jnp.int32)), 0
             ).astype(jnp.int32),
+            # Queue slots the pod loops ran (replay.queue_slots_run).
+            "slots": n_blocks * blk,
             "pass_count": pc,
             "pending_after": jnp.sum(
                 (s["alive"] & (s["bound"] < 0)).astype(jnp.int32)
@@ -1316,6 +1388,7 @@ class StepOutcome:
     unschedulable: int
     pending_after: int
     eligible: int  # queue size before the cap (0 = the pass never featurized)
+    slots_run: int  # queue slots the device's pod loops ran for this step
     # (namespace, name, node_name) in queue (commit) order.
     binds: list[tuple[str, str, str]] = field(default_factory=list)
     # Per-attempt detail (preemption / full-record segments); None means
@@ -1418,6 +1491,7 @@ class ReplayDriver:
         self.queue_width_max = 0  # guarded-by: main-thread
         self.steps_padded = 0  # guarded-by: main-thread
         self.pairs_evaluated = 0  # guarded-by: main-thread
+        self.queue_slots_run = 0  # guarded-by: main-thread
         # Streaming ingest overlap (round 22, traces/stream.py): a
         # runner-provided NONBLOCKING drain of the trace-ingest queue,
         # called on the main thread while the dispatch worker owns the
@@ -1540,6 +1614,7 @@ class ReplayDriver:
             "queue_width_max": self.queue_width_max,
             "steps_padded": self.steps_padded,
             "pairs_evaluated": self.pairs_evaluated,
+            "queue_slots_run": self.queue_slots_run,
             "ingest_prefetches": self.ingest_prefetches,
             "device_errors": self.device_errors,
             "watchdog_timeouts": self.watchdog_timeouts,
@@ -2952,7 +3027,9 @@ class ReplayDriver:
             "full_bytes_per_shard": int(full_bytes_shard),
             "queue_width": q,
             "steps_padded": K - m_steps,
-            "pairs_evaluated": 0,  # filled in when the segment commits
+            # Filled in when the segment commits:
+            "pairs_evaluated": 0,
+            "slots_run": 0,
         }
         self.lower_log.append(log_entry)
         return _SegmentPlan(
@@ -3261,6 +3338,7 @@ class ReplayDriver:
                     unschedulable=int(pulled["unschedulable"][k]),
                     pending_after=int(pulled["pending_after"][k]),
                     eligible=int(eligible[k]),
+                    slots_run=int(pulled["slots"][k]),
                     binds=binds,
                     attempts=attempts,
                 )
@@ -3337,7 +3415,10 @@ class ReplayDriver:
         the pod x node pairs its passes evaluated — every attempt of a
         pass runs Filter and Score over the nodes live at that step
         (``step_nodes`` as for ``advance_service_slots``: ``None`` where
-        the pass never ran, and then nothing was attempted)."""
+        the pass never ran, and then nothing was attempted) — and the
+        queue slots the device's pod loops ran for them
+        (``queue_slots_run``: the attempts, each step's rounded up to a
+        whole block of the loop)."""
         self.device_steps += len(seg.steps)
         pairs = sum(
             (o.scheduled + o.unschedulable) * len(nodes)
@@ -3345,9 +3426,12 @@ class ReplayDriver:
             if nodes is not None
         )
         self.pairs_evaluated += pairs
+        slots = sum(o.slots_run for o in seg.steps)
+        self.queue_slots_run += slots
         plan = self._last_plan  # None on a fleet follower: it lowered nothing
         if plan is not None and plan.log_entry is not None:
             plan.log_entry["pairs_evaluated"] = pairs
+            plan.log_entry["slots_run"] = slots
 
     def verify_segment(self, seg: SegmentOutcome) -> None:
         """Verify the staged store converged to the device's view of the

@@ -206,6 +206,19 @@ def test_shape_counters(case, x64):
     assert stats["pairs_evaluated"] == N_PODS * N_NODES == entry["pairs_evaluated"]
 
 
+@pytest.mark.parametrize("x64", MODES, ids=MODE_IDS)
+@pytest.mark.parametrize("case", CASES)
+def test_pod_loops_run_the_backlog_not_the_bucket(case, x64):
+    """``queue_slots_run``: the node-only step 0 runs no slot, and step
+    1 runs its 400 attempts of the 512-slot bucket (400 is a whole
+    number of the loop's blocks)."""
+    got = run(case, x64, device=True)
+    stats, (entry,) = got["stats"], got["lower_log"]
+    assert sum(a + b for a, b in got["steps"]) == N_PODS
+    assert stats["queue_slots_run"] == N_PODS == entry["slots_run"]
+    assert stats["queue_slots_run"] < stats["queue_width_max"]
+
+
 def test_the_served_burst_job_reports_the_counters_and_a_submit_span():
     """Through ``POST /api/v1/jobs`` as the benchmark cell submits it: the
     result's ``replay`` block carries the three counters and the digest of
@@ -243,4 +256,5 @@ def test_the_served_burst_job_reports_the_counters_and_a_submit_span():
     assert replay["queue_width_max"] == bucket_size(N_PODS)
     assert replay["steps_padded"] == SEGMENT_STEPS - 2
     assert replay["pairs_evaluated"] == N_PODS * N_NODES
+    assert replay["queue_slots_run"] == N_PODS
     assert replay["placements_digest"] == digest_of(oracle_placements("fits"))
