@@ -2,7 +2,7 @@
 
 PY ?= python
 
-.PHONY: test test-tpu bench chip-smoke-rehearsal serve lint lock-check faults trace jobs restart-check shard-check mesh-check obs-check stream-check
+.PHONY: test test-tpu chip-smoke-rehearsal serve lint lock-check faults trace jobs restart-check shard-check mesh-check obs-check stream-check
 
 test:
 	$(PY) -m pytest tests/ -q --deselect tests/test_tpu_parity.py
@@ -31,24 +31,25 @@ lock-check: lint
 # Sharded-replay verification (docs/scaling.md "Sharded device
 # replay"): the fast tier-1 sharded-vs-solo parity matrix (byte parity
 # on churn + full-record annotations + preemption, the explicit-mesh
-# contract, dead-device containment, the prewarm plane, and the bench
-# churn_shard rung) plus the slow 6k sharded lock leg.  Gated on lint
-# for the same reason lock-check is.
+# contract with the per-shard byte budget, dead-device containment of
+# a mesh wider than the host and of a fault-armed dispatch at tp 1 and
+# tp 8, the prewarm plane) plus the slow 6k sharded lock leg.  Gated on
+# lint for the same reason lock-check is.
 shard-check: lint
 	$(PY) -m pytest tests/test_replay_device.py tests/test_replay_cache.py -q -k "sharded or prewarm"
-	$(PY) -m pytest tests/test_bench.py -q -k "churn_shard and not fleet"
+	$(PY) -m pytest "tests/test_replay_faults.py::test_dead_device_is_carried_by_host_path[shard]" -q
 	$(PY) -m pytest tests/test_behavior_locks.py::test_churn_lock_6k_sharded_tp8 -q -rs -m slow
 
 # The 2-D mesh suite (round 19, docs/scaling.md "2-D mesh"): the tp x dp
-# fleet parity tests + the donated-carry byte-identity test, the
-# churn_fleet_shard bench rung evidence (counts_match, the (2, 4) grid,
-# per-shard bytes, dev_const zero-resharding counters), and the slow
-# tp=4 x dp=2 6k fleet lock leg — every lane 2524/471 stepwise against
-# the solo unsharded run.  Gated on lint like shard-check; the bench
-# children run themselves in tests/helpers.sanitized_cpu_env.
+# fleet parity test with its mesh evidence (lanes match solo, the
+# (2, 4) grid, per-shard bytes, dev_const zero-resharding hits) + the
+# donated-carry byte-identity test, the mesh fleet under a dead device,
+# and the slow tp=4 x dp=2 6k fleet lock leg — every lane 2524/471
+# stepwise against the solo unsharded run.  Gated on lint like
+# shard-check.
 mesh-check: lint
 	$(PY) -m pytest tests/test_replay_device.py -q -k "tp_dp or donation"
-	$(PY) -m pytest tests/test_bench.py -q -k "churn_fleet_shard"
+	$(PY) -m pytest "tests/test_replay_faults.py::test_dead_device_is_carried_by_host_path[fleet_mesh]" -q
 	$(PY) -m pytest tests/test_behavior_locks.py::test_churn_fleet_lock_6k_tp4_dp2 -q -rs -m slow
 
 # The fault suite (docs/faults.md) pinned to the CPU backend
@@ -121,10 +122,11 @@ obs-check: lint
 # Streaming-ingest verification (docs/scenario.md "Streaming ingest"):
 # the windowed-vs-materialized byte-identity suite (selector == batch
 # resample on shuffled input, window-boundary splits, producer-fault
-# degradation, mid-read bound refusal), the streaming behavior-lock leg
-# (borg_mini through tiny windows on both paths), and the churn_stream
-# bench rung evidence (mid-run RSS watermark, events/sec, counts_match,
-# dead-device one-JSON-line).  Sanitized CPU env so it runs under ANY
+# degradation, mid-read bound refusal, the streamed device replay
+# against the materialized one with its window / prefetch evidence),
+# the streaming behavior-lock leg (borg_mini through tiny windows on
+# both paths), and the streamed replay under a dead device.  Sanitized
+# CPU env so it runs under ANY
 # hardware condition; gated on lint because the trace-ingest
 # thread-role and the traces.stream span/site registrations are
 # exactly what the analyzer checks.
@@ -133,17 +135,11 @@ stream-check: lint
 	sys.exit(subprocess.call([sys.executable, '-m', 'pytest', \
 	'tests/test_traces_stream.py', \
 	'tests/test_behavior_locks.py::test_trace_lock_borg_mini_holds_with_streaming_ingest', \
+	'tests/test_replay_faults.py::test_dead_device_is_carried_by_host_path[stream]', \
 	'-q'], env=sanitized_cpu_env()))"
-	$(PY) -c "import subprocess, sys; from tests.helpers import sanitized_cpu_env; \
-	sys.exit(subprocess.call([sys.executable, '-m', 'pytest', \
-	'tests/test_bench.py', '-q', '-k', 'churn_stream'], \
-	env=sanitized_cpu_env()))"
 
 test-tpu:
 	$(PY) -m pytest tests/test_tpu_parity.py -q -rs
-
-bench:
-	$(PY) bench.py
 
 # chip_smoke.py's two phases at a tiny size with the server pinned to
 # the CPU: the rehearsal to run before spending chip time on
@@ -155,10 +151,10 @@ serve:
 	$(PY) -m ksim_tpu.cmd.simulator
 
 # Static contract analysis (docs/lint.md): compile the tree, then run
-# the AST analyzer over ksim_tpu/, bench.py, chip_smoke.py and tools/ — exits nonzero
+# the AST analyzer over ksim_tpu/, chip_smoke.py and tools/ — exits nonzero
 # on any unsuppressed finding.  tools/ksimlint is stdlib-only (it never
 # imports jax, numpy or ksim_tpu), so this is safe under ANY hardware
 # condition, including a backend whose init hangs — no CPU pin needed.
 lint:
-	$(PY) -m compileall -q ksim_tpu tools bench.py chip_smoke.py
+	$(PY) -m compileall -q ksim_tpu tools chip_smoke.py
 	$(PY) -m tools.ksimlint

@@ -2,7 +2,7 @@
 
 jax's jit cache already reuses a compiled executable for identical
 (statics, input avals) within one process — but it is silent (no
-hit/miss evidence reaches the bench JSON or /api/v1/metrics) and it
+hit/miss evidence reaches a job result or /api/v1/metrics) and it
 does not serialize FIRST calls: two tenant jobs hitting the same shape
 rung concurrently can both pay the multi-second XLA trace+compile
 before either lands in the cache.  This module adds the missing layer
@@ -13,7 +13,7 @@ the bucketed shape ladder + profile token that
   key, i.e. the one that compiles) and records which OWNERS (tenant
   jobs, via the scoped trace plane's ``job`` tag) used each rung — the
   "compile once, serve every tenant on that rung" claim becomes
-  machine-checkable straight from the bench record
+  machine-checkable straight from a job result
   (``shared_rungs``/``shared_single_compile_rungs``);
 - serializes the first call per key: one leader runs the compiling
   dispatch, concurrent same-rung callers WAIT (bounded) for it, then
@@ -306,7 +306,7 @@ class CompileCache:
 
     def snapshot(self) -> dict:
         """JSON-ready evidence (the ``compile_cache`` section of
-        /api/v1/metrics and the bench JSON): aggregate counters plus
+        /api/v1/metrics and of a job result): aggregate counters plus
         the cross-tenant sharing proof — ``shared_rungs`` = keys used
         by >= 2 distinct owners, ``shared_single_compile_rungs`` = the
         subset that also compiled exactly once (present entries never
@@ -341,8 +341,7 @@ class CompileCache:
             }
 
     def reset(self) -> None:
-        """Drop entries and counters (tests; bench children start cold
-        by construction — fresh process — so production never calls
+        """Drop entries and counters (tests; production never calls
         this)."""
         with self._lock:
             self._entries.clear()

@@ -201,8 +201,11 @@ _H2D: "OrderedDict[tuple, jnp.ndarray]" = OrderedDict()
 _H2D_LIMIT = int(os.environ.get("KSIM_H2D_CACHE", "0"))
 
 # lax.scan unroll factor for the sequential-commit loop (see
-# _Program._schedule_fn).
-SCAN_UNROLL = int(os.environ.get("KSIM_SCAN_UNROLL", "4"))
+# _Program._schedule_fn), and the slots per trip of the segment
+# program's pod loops (engine/replay.py).  A constant since PR 29: on
+# the chip 8 and 16 bought nothing against 4 and round more slots up
+# (PERF.md section 6, PR 28).
+SCAN_UNROLL = 4
 
 
 def _to_device(a) -> jnp.ndarray:
@@ -468,25 +471,16 @@ class _Program:
         self,
         plugins: tuple[ScoredPlugin, ...],
         record: str,
-        assume_skip: frozenset[str] = frozenset(),
         sampling_k: int | None = None,
     ) -> None:
         self.plugins = plugins
         self.record = record
-        # Plugin names whose per-pod Skip condition is STATICALLY true for
-        # every pod this program will see (host-side classification,
-        # Engine._light_mask): their filter contributes no failures and
-        # their score is zero, so the heavy bodies are never traced —
-        # unlike lax.cond, which vmap lowers to select (both branches
-        # execute for every pod in the batch program).
-        self.assume_skip = assume_skip
         # percentageOfNodesToScore emulation: find-K-feasible sampling in
         # the sequential scan (upstream numFeasibleNodesToFind,
         # schedule_one.go).  Static so lax.top_k can use it.
         self.sampling_k = sampling_k
         self._sig = (
             record,
-            assume_skip,
             sampling_k,
             tuple(
                 (
@@ -522,17 +516,10 @@ class _Program:
         return filter_ok, reason_bits, raw_scores, final_scores, total
 
     def _eval_filters(self, state: NodeStateView, pod: PodView, aux: dict, carries: dict):
-        n = state.valid.shape[0]
         reason_bits = []
         filter_ok = state.valid
         for sp in self.plugins:
             if not sp.filter_enabled:
-                continue
-            if sp.plugin.name in self.assume_skip:
-                # Statically-skipped plugin: its Skip branch yields code 0
-                # for every pod in this program's batch (the caller's
-                # classification guarantees the cond predicate is false).
-                reason_bits.append(jnp.zeros(n, jnp.int32))
                 continue
             kw = {"carry": carries[sp.plugin.name]} if sp.plugin.name in carries else {}
             ext = sp.extender
@@ -559,11 +546,6 @@ class _Program:
         total = jnp.zeros(n, dtype=jnp.int32)
         for sp in self.plugins:
             if not sp.score_enabled:
-                continue
-            if sp.plugin.name in self.assume_skip:
-                # Skip branch: raw 0 -> normalize of all-zeros -> final 0.
-                raw_scores.append(jnp.zeros(n, jnp.int32))
-                final_scores.append(jnp.zeros(n, jnp.int32))
                 continue
             kw = {"carry": carries[sp.plugin.name]} if sp.plugin.name in carries else {}
             ext = sp.extender
@@ -665,10 +647,9 @@ class _Program:
 
     # -- compiled entry points ----------------------------------------------
 
+    @partial(jax.jit, static_argnums=0)
     @device_kernel(static=("self",))
-    def _batch_eval(self, state, pods: PodBatch, aux: dict, carries: dict):
-        """Traceable body shared by the chunked and fused batch entries."""
-
+    def _batch_fn(self, state, pods: PodBatch, aux: dict, carries: dict):
         def per_pod(pb: PodBatch):
             pod = PodView(
                 requests=pb.requests,
@@ -682,34 +663,6 @@ class _Program:
             return self._pod_outputs(pb.valid, best, bits, raw, final, total)
 
         return jax.vmap(per_pod)(pods)
-
-    @partial(jax.jit, static_argnums=0)
-    @device_kernel(static=("self",))
-    def _batch_fn(self, state, pods: PodBatch, aux: dict, carries: dict):
-        return self._batch_eval(state, pods, aux, carries)
-
-    @partial(jax.jit, static_argnums=(0, 5))
-    @device_kernel(static=("self", "block"))
-    def _batch_fused_fn(
-        self, state, pods: PodBatch, aux: dict, carries: dict, block: int
-    ):
-        """The whole pod axis in ONE device program: lax.map over
-        block-sized vmap segments.  Two wins over the host chunk loop:
-        every [block, N] plugin intermediate stays on-chip (the chunked
-        path round-trips [chunk, N] tensors through HBM between plugin
-        stages — it is bandwidth-bound, which is why the sequential scan
-        was beating it), and the per-chunk dispatch latency collapses
-        into a single launch.  Unmeasured on a directly attached chip."""
-        P = pods.valid.shape[0]
-        blocks = jax.tree_util.tree_map(
-            lambda x: x.reshape((P // block, block) + x.shape[1:]), pods
-        )
-        out = jax.lax.map(
-            lambda pb: self._batch_eval(state, pb, aux, carries), blocks
-        )
-        return jax.tree_util.tree_map(
-            lambda x: x.reshape((P,) + x.shape[2:]), out
-        )
 
     def _sample_visited(self, filter_ok, start, n_real):
         """Upstream's find-K-feasible iteration as tensor ops
@@ -883,7 +836,6 @@ class Engine:
             )
         self._node_state = NodeStateView(**node_dev)
         self._pods = PodBatch(**pod_dev)
-        self._sharded = False
 
     @property
     def _plugins(self) -> tuple[ScoredPlugin, ...]:
@@ -907,7 +859,6 @@ class Engine:
         self._node_state = shlib.shard_node_state(self._node_state, mesh)
         self._pods = shlib.shard_pod_batch(self._pods, mesh)
         self._aux = shlib.shard_aux(self._aux, self._aux_axes, mesh)
-        self._sharded = True
         return self
 
     def batch_step(self, state, pods: PodBatch, aux: dict, carries: dict):
@@ -918,94 +869,14 @@ class Engine:
     def example_args(self):
         return (self._node_state, self._pods, self._aux, self._prog.init_carries(self._aux))
 
-    # Plugins whose per-pod Skip condition the Engine can evaluate
-    # host-side from the featurized snapshot (see _light_mask) — the
-    # candidates for a statically-skipping batch program.
-    _PARTITION_PLUGINS = ("PodTopologySpread", "InterPodAffinity")
-
-    def _partition_assume(self) -> frozenset[str]:
-        """Names from _PARTITION_PLUGINS present in this profile without
-        extenders (a Before/After hook may observe the heavy branch, so
-        hooked plugins are never statically skipped)."""
-        return frozenset(
-            sp.plugin.name
-            for sp in self._plugins
-            if sp.plugin.name in self._PARTITION_PLUGINS and sp.extender is None
-        )
-
-    def _light_mask(self, assume: frozenset[str]) -> np.ndarray | None:
-        """bool [P]: pods for which every plugin in ``assume`` provably
-        takes its Skip branch — the HOST-side mirror of the kernels' cond
-        predicates (conservative: any doubt classifies heavy).
-
-        - PodTopologySpread: no valid constraints at all (implies the
-          filter's ``any(active)`` and score/normalize's ``has_score_con``
-          are both false).
-        - InterPodAffinity: no required (anti-)affinity terms, no
-          preferred weights, and no existing pod's term selector matches
-          (filter pred: sum(raff)+sum(ranti)+sum(qm) > 0; score pred:
-          any(pref_w) | any(qm > 0)).
-        """
-        aux = self._feats.aux or {}
-        P = int(self._pods.valid.shape[0])
-        light = np.ones(P, dtype=bool)
-        try:
-            if "PodTopologySpread" in assume:
-                spread = aux["spread"]
-                light &= ~np.asarray(spread.con_valid).any(axis=1)
-            if "InterPodAffinity" in assume:
-                ipa = aux["interpod"]
-                terms = (
-                    np.asarray(ipa.req_aff).astype(np.int64)
-                    + np.asarray(ipa.req_anti)
-                    + np.asarray(ipa.pod_term_match)
-                )
-                light &= terms.sum(axis=1) == 0
-                light &= (np.asarray(ipa.pref_w) == 0).all(axis=1)
-        except (KeyError, AttributeError):
-            return None  # unfamiliar aux layout: never partition
-        return light
-
-    def _gather_pods(self, idx: np.ndarray, chunk: int) -> tuple[PodBatch, np.ndarray]:
-        """Pod rows for ``idx`` padded to ``chunk`` (pad rows read pod 0
-        but are marked invalid, so their outputs decode to selected=-1
-        and are dropped at reassembly).  Returns (PodBatch, index array
-        with -1 at pad positions)."""
-        pad = chunk - len(idx)
-        padded = np.concatenate([idx, np.zeros(pad, dtype=idx.dtype)]) if pad else idx
-        idx_dev = jnp.asarray(padded)
-        pods_c = jax.tree_util.tree_map(lambda x: x[idx_dev], self._pods)
-        if pad:
-            keep = jnp.asarray(np.arange(chunk) < len(idx))
-            pods_c = pods_c._replace(valid=pods_c.valid & keep)
-        out_idx = padded.astype(np.int64)
-        if pad:
-            out_idx = out_idx.copy()
-            out_idx[len(idx):] = -1
-        return pods_c, out_idx
-
-    def evaluate_batch_chunks(self, *, chunk: int | None = None, partition: bool = False):
-        """Yield per-chunk device results — the streaming form of
-        ``evaluate_batch``.  Each ``device_out`` is the device-resident
-        result pytree for one pod chunk; callers decode or transfer it
-        before the next iteration if they want bounded device memory
-        (record="full" at 16k x 8k is ~9GB of result tensors — far more
-        than it costs to recompute, so nothing is retained).
-
-        ``partition=False`` (default): yields ``(start, device_out)`` for
-        contiguous chunks, exactly the historical contract.
-
-        ``partition=True``: pods are CLASSED host-side by whether the
-        heavy constraint plugins' Skip conditions provably hold
-        (``_light_mask``), and light pods run through a program variant
-        that never traces those plugin bodies — under vmap, lax.cond
-        lowers to select, so the default batch program pays the heavy
-        branches for EVERY pod while the sequential scan skips them;
-        this restores the skip for the batch path.  Yields
-        ``(indices, device_out)`` where ``indices`` is an int64 array of
-        original pod positions per output row (-1 = padding row of a
-        ragged class tail).  Results are bit-identical to the
-        unpartitioned evaluation, in a different row order."""
+    def evaluate_batch_chunks(self, *, chunk: int | None = None):
+        """Yield ``(start, device_out)`` per contiguous pod chunk — the
+        streaming form of ``evaluate_batch``.  Each ``device_out`` is the
+        device-resident result pytree for one pod chunk; callers decode
+        or transfer it before the next iteration if they want bounded
+        device memory (record="full" at 16k x 8k is ~9GB of result
+        tensors — far more than it costs to recompute, so nothing is
+        retained)."""
         if self._prog.sampling_k is not None:
             raise ValueError(
                 "percentageOfNodesToScore emulation is scan-only "
@@ -1015,95 +886,24 @@ class Engine:
         if chunk is None:
             chunk = min(P, self._default_batch_chunk())
         carries = self._prog.init_carries(self._aux)
-        # dp-sharded pod arrays would turn the class gathers into
-        # cross-device collectives — partitioning is a single-chip
-        # optimization (the mesh path keeps the contiguous contract).
-        partition = partition and not self._sharded
-        assume = self._partition_assume() if partition else frozenset()
-        light = self._light_mask(assume) if assume else None
-        if partition and light is not None and light.any() and not light.all():
-            light_prog = _Program(self._plugins, self._record, assume_skip=assume)
-            for mask, prog in ((~light, self._prog), (light, light_prog)):
-                idx_all = np.nonzero(mask)[0]
-                for s in range(0, len(idx_all), chunk):
-                    pods_c, out_idx = self._gather_pods(
-                        idx_all[s : s + chunk], chunk
-                    )
-                    yield out_idx, prog._batch_fn(
-                        self._node_state, pods_c, self._aux, carries
-                    )
-            return
         for s in range(0, P, chunk):
             pods_c = jax.tree_util.tree_map(
                 lambda x: x[s : s + chunk], self._pods
             )
             yield s, self._prog._batch_fn(self._node_state, pods_c, self._aux, carries)
 
-    def evaluate_batch_fused(self, *, block: int = 256) -> EngineResult:
-        """One-dispatch batch evaluation for bounded-size record modes
-        (``selection``/``final``): see _Program._batch_fused_fn for why
-        this beats both the chunked batch AND the sequential scan on
-        TPU.  record="full" must stream — its result tensors exceed
-        device memory at large shapes — so it stays on
-        ``evaluate_batch``; dp-sharded engines likewise (the reshape
-        would fight the pod-axis sharding)."""
-        if self._record == "full":
-            raise ValueError(
-                "record='full' results must stream: use evaluate_batch"
-            )
-        if self._prog.sampling_k is not None:
-            raise ValueError(
-                "percentageOfNodesToScore emulation is scan-only "
-                "(batch evaluation has no sequential visit order)"
-            )
-        if self._sharded:
-            return self.evaluate_batch()
-        P = int(self._pods.valid.shape[0])
-        block = max(1, min(block, P))
-        while P % block:
-            block //= 2
-        out = self._prog._batch_fused_fn(
-            self._node_state,
-            self._pods,
-            self._aux,
-            self._prog.init_carries(self._aux),
-            block,
-        )
-        return self._to_result(_pull_tree_to_host(out))
-
-    def evaluate_batch(
-        self, *, chunk: int | None = None, partition: bool = False
-    ) -> EngineResult:
+    def evaluate_batch(self, *, chunk: int | None = None) -> EngineResult:
         """All pods x nodes against the fixed snapshot (no state commit).
 
         Pod-chunked like ``schedule`` so the recorded result tensors
         ([P, plugins, N] in record="full") never exceed one chunk's worth
-        of device memory; chunks stream to host and concatenate.
-        ``partition=True`` runs the classed-pod fast path (see
-        ``evaluate_batch_chunks``) and reassembles original pod order."""
+        of device memory; chunks stream to host and concatenate."""
         chunks = [
-            (key, _pull_tree_to_host(out))
-            for key, out in self.evaluate_batch_chunks(chunk=chunk, partition=partition)
+            _pull_tree_to_host(out)
+            for _s, out in self.evaluate_batch_chunks(chunk=chunk)
         ]
-        if chunks and isinstance(chunks[0][0], np.ndarray):
-            P = int(self._pods.valid.shape[0])
-            merged = jax.tree_util.tree_map(
-                lambda x: np.zeros((P,) + x.shape[1:], x.dtype), chunks[0][1]
-            )
-            for idx, out in chunks:
-                keep = idx >= 0
-                rows = idx[keep]
-
-                def scatter(dst, src):
-                    dst[rows] = src[keep]
-                    return dst
-
-                merged = jax.tree_util.tree_map(
-                    lambda d, s: scatter(d, np.asarray(s)), merged, out
-                )
-            return self._to_result(merged)
         merged = jax.tree_util.tree_map(
-            lambda *xs: np.concatenate(xs, axis=0), *(out for _s, out in chunks)
+            lambda *xs: np.concatenate(xs, axis=0), *chunks
         )
         return self._to_result(merged)
 

@@ -150,8 +150,8 @@ class FleetDriver:
         # 19 — the 2-D fleet lays lanes over dp AND node shards over tp).
         self._mesh: dict = {}  # guarded-by: _mesh_lock
         self._mesh_failed = False  # guarded-by: _mesh_lock
-        # Fleet evidence counters (the churn_fleet bench rung and the
-        # lock-check's lowered-once guard read them).  All fleet
+        # Fleet evidence counters (the lock-check's lowered-once guard
+        # reads them).  All fleet
         # orchestration runs on the main thread; the dispatch worker
         # below is side-effect-free on this object.
         self.shared_lowerings = 0  # guarded-by: main-thread

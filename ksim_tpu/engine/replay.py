@@ -165,7 +165,7 @@ PREEMPT_VICTIMS = int(os.environ.get("KSIM_REPLAY_VMAX", "8"))
 # circuit breaker that disables the device path for the rest of the
 # run, so a dead backend costs N watchdog timeouts total rather than
 # one per remaining segment.  Read at ReplayDriver construction so
-# tests (and bench children) tune them through the environment.
+# tests tune them through the environment.
 WATCHDOG_DEFAULT_S = 300.0  # generous: first dispatch includes XLA compile
 BREAKER_DEFAULT_N = 3
 
@@ -1470,7 +1470,7 @@ class ReplayDriver:
         # record="full" segments run at a shorter fixed K (their stacked
         # result tensors multiply device memory by K).
         self._full_k = max(1, min(self.k, FULL_SEGMENT_STEPS))
-        # Evidence counters (the bench rung reports them).  guarded-by:
+        # Evidence counters (``stats()`` reports them).  guarded-by:
         # main-thread — the driver's mutable state is thread-confined:
         # only the main thread writes it; the watchdogged dispatch
         # worker (``_run``, annotated worker-thread below) must stay
@@ -1502,7 +1502,7 @@ class ReplayDriver:
         self.unsupported: dict[str, int] = {}  # guarded-by: main-thread
         # Failure-containment state — PER DRIVER, never process-global
         # (two runners in one process must not trip each other's
-        # breaker).  The bench rung and runner stats surface all of it.
+        # breaker).  ``stats()`` surfaces all of it.
         self.watchdog_s = _watchdog_seconds()
         self.breaker_threshold = max(_breaker_threshold(), 1)
         self.device_errors = 0  # guarded-by: main-thread (degraded dispatches)
@@ -1561,7 +1561,7 @@ class ReplayDriver:
         _dc = os.environ.get("KSIM_REPLAY_DEV_CACHE")
         self._dev_cache_on: "bool | None" = _dc != "0" if _dc is not None else None
         self._prio_gen = 0
-        # Pipeline / O(delta) evidence counters (bench JSON, lock-check
+        # Pipeline / O(delta) evidence counters (job result, lock-check
         # guard).  ``lower_log`` records one entry per successful lower:
         # the window's event count vs the fresh per-pod featurize rows it
         # actually built — the counter-based O(delta) guard's input.
@@ -1600,7 +1600,7 @@ class ReplayDriver:
         return self._segment_seq
 
     def stats(self) -> dict:
-        """Degradation evidence for runner stats / the bench JSON."""
+        """Degradation evidence: a job result's ``replay`` block."""
         feat = self._featurizer
         return {
             # Which backend ran the segments (None until one dispatch
@@ -1632,7 +1632,7 @@ class ReplayDriver:
             # Incremental-lowering evidence (round 10): the cache's
             # hit/miss/invalidation counters and the driver featurizer's
             # fresh per-pod row builds make the O(delta) lowering claim
-            # machine-checkable straight from the bench JSON line.
+            # machine-checkable straight from the job result.
             "lower_cache": self._cache.stats(),
             "featurize_calls": feat.pod_rows_built if feat is not None else 0,
             "featurize_reused": feat.pod_rows_reused if feat is not None else 0,
@@ -2908,7 +2908,7 @@ class ReplayDriver:
         # budget is PER SHARD (round 17): each chip holds N/tp node
         # columns of every stacked tensor, so record="full" headroom
         # scales with the mesh.  Computed in every record mode (the
-        # lower_log / bench rung report it as sizing evidence); only
+        # lower_log reports it as sizing evidence); only
         # record="full" actually allocates, so only it rejects.
         bits_dt, final_dt = prog._result_dtypes()
         n_f = sum(1 for sp in plugins if sp.filter_enabled)

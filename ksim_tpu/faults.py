@@ -328,7 +328,7 @@ class FaultPlane:
             return s.fired if s else 0
 
     def snapshot(self) -> dict[str, dict[str, int]]:
-        """All per-site counters (bench evidence / debugging)."""
+        """All per-site counters (evidence / debugging)."""
         with self._lock:
             return {
                 site: {"calls": s.calls, "fired": s.fired}
@@ -337,9 +337,9 @@ class FaultPlane:
 
 
 #: The process-global plane every injection site checks.  ``KSIM_FAULTS``
-#: arms it at import so subprocess children (bench rungs) inherit fault
-#: config through the environment — the stdlib-only bench parent never
-#: has to import this module.
+#: arms it at import so subprocess children (fleet workers, the
+#: `make trace` children) inherit fault config through the environment —
+#: a stdlib-only parent never has to import this module.
 FAULTS = FaultPlane()
 
 _env_spec = os.environ.get("KSIM_FAULTS", "")

@@ -968,7 +968,7 @@ def main(argv: "list[str] | None" = None) -> int:
     """Worker-process entry point: ``python -m ksim_tpu.jobs.fleet
     --dir <KSIM_JOBS_DIR> [--worker-id w1] [--workers 2]``.  Builds a
     worker-role JobManager (which starts the fleet poller), prints
-    ``READY <worker id>`` for the spawning test/bench harness, and
+    ``READY <worker id>`` for the spawning harness, and
     parks until SIGTERM/SIGINT."""
     import argparse
     import signal

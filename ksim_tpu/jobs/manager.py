@@ -1757,7 +1757,7 @@ class JobManager:
         info = job._resume_info
         if info is not None:
             # eventsReplayed counts only THIS process's suffix — the
-            # restart-check/bench evidence that an incremental resume
+            # restart-check evidence that an incremental resume
             # did strictly less work than a from-scratch replay.
             doc["resume"] = {
                 "fromSegment": info["fromSegment"],
@@ -1804,7 +1804,7 @@ class JobManager:
 
     def join(self, timeout: "float | None" = None) -> bool:
         """Wait for every registered job to reach a terminal state
-        (tests / bench).  True when all finished inside the timeout."""
+        (tests).  True when all finished inside the timeout."""
         deadline = None if timeout is None else time.monotonic() + timeout
         for job in self.jobs():
             remaining = None if deadline is None else deadline - time.monotonic()

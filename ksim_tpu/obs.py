@@ -1146,9 +1146,9 @@ def provider_snapshots() -> dict[str, dict]:
 
 
 #: The process-global plane every span/event site checks.  ``KSIM_TRACE*``
-#: configures it at import so subprocess children (bench rungs, the make
-#: trace child) inherit tracing through the environment — the stdlib-only
-#: bench parent never has to import this module.
+#: configures it at import so subprocess children (fleet workers, the
+#: make trace children) inherit tracing through the environment — a
+#: stdlib-only parent never has to import this module.
 TRACE = TracePlane()
 TRACE.configure_from_env()
 

@@ -207,8 +207,8 @@ class InterPodAffinity:
             # span > ~21M — far beyond real clusters) and, unlike a float
             # division, identical on every XLA backend.  TPU's approximate
             # float32 divide truncated exact integer ratios one ulp low
-            # (100*3166/3166 -> 99), the root cause of BENCH_r04's 199-pod
-            # f32 churn drift vs CPU.  Out-of-range spans fall back to the
+            # (100*3166/3166 -> 99), the root cause of a 199-pod f32
+            # churn drift between the TPU and the CPU (round 4).  Out-of-range spans fall back to the
             # old float path (f64 under x64 — exact; f32 otherwise, with
             # the documented +-1 boundary tolerance).
             shifted = scores - mn  # >= 0 on ok nodes (mn is their min)

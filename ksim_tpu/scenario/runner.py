@@ -60,7 +60,6 @@ class ScenarioResult:
     # runner.step (which NESTS its service.schedule span — the two are
     # reported side by side, not additive).
     phase_seconds: dict[str, float] = field(default_factory=dict)
-    phase_counts: dict[str, int] = field(default_factory=dict)
     # Fleet replay (engine/fleet.py): the per-lane ScenarioResults, in
     # lane order.  The top-level counts/steps are then AGGREGATES over
     # the lanes (events/scheduled/unschedulable summed; ``steps`` stays
@@ -72,6 +71,19 @@ class ScenarioResult:
     @property
     def events_per_second(self) -> float:
         return self.events_applied / self.wall_seconds if self.wall_seconds else 0.0
+
+
+def _phase_split(phase0: dict) -> dict[str, float]:
+    """Seconds per span name since ``phase0`` (an earlier
+    ``TRACE.phase_totals()``).  The trace plane is process-global: the
+    totals are diffed around a run so earlier runs don't bleed into its
+    split."""
+    split = {}
+    for name, (total, count) in TRACE.phase_totals().items():
+        prev_total, prev_count = phase0.get(name, (0.0, 0))
+        if count > prev_count:
+            split[name] = round(total - prev_total, 6)
+    return split
 
 
 class _StreamFeeder:
@@ -734,13 +746,7 @@ class ScenarioRunner:
                 result.succeeded = True
                 break
         result.wall_seconds = time.perf_counter() - t0
-        # The trace plane is process-global: diff its totals around this
-        # run so concurrent earlier runs don't bleed into the split.
-        for name, (total, count) in TRACE.phase_totals().items():
-            prev_total, prev_count = phase0.get(name, (0.0, 0))
-            if count > prev_count:
-                result.phase_seconds[name] = round(total - prev_total, 6)
-                result.phase_counts[name] = count - prev_count
+        result.phase_seconds = _phase_split(phase0)
         return result
 
     def _run_streaming(self, stream) -> ScenarioResult:
@@ -820,11 +826,7 @@ class ScenarioRunner:
             # close() is idempotent and also covers clean exhaustion.
             stream.close()
         result.wall_seconds = time.perf_counter() - t0
-        for name, (total, count) in TRACE.phase_totals().items():
-            prev_total, prev_count = phase0.get(name, (0.0, 0))
-            if count > prev_count:
-                result.phase_seconds[name] = round(total - prev_total, 6)
-                result.phase_counts[name] = count - prev_count
+        result.phase_seconds = _phase_split(phase0)
         return result
 
     @staticmethod
@@ -945,9 +947,5 @@ class ScenarioRunner:
         # Solo semantics per lane: succeeded = a doneOperation completed.
         agg.succeeded = all(ln.result.succeeded for ln in lanes)
         agg.wall_seconds = wall
-        for name, (total, count) in TRACE.phase_totals().items():
-            prev_total, prev_count = phase0.get(name, (0.0, 0))
-            if count > prev_count:
-                agg.phase_seconds[name] = round(total - prev_total, 6)
-                agg.phase_counts[name] = count - prev_count
+        agg.phase_seconds = _phase_split(phase0)
         return agg

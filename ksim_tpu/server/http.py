@@ -384,8 +384,7 @@ class _Handler(BaseHTTPRequestHandler):
         the process-wide ``compile_cache``), and the job plane's
         ``jobs`` section (queue depth, worker occupancy, per-job
         status + private-plane snapshots).  Previously only
-        ``Metrics.snapshot()`` was served and the rest was visible
-        only in bench JSON."""
+        ``Metrics.snapshot()`` was served."""
         doc = self.server.di.scheduler_service.metrics.snapshot()
         # Process-level evidence (full collections, XLA compiles and
         # cache loads) reads like the scheduler's own counters/timers.

@@ -252,7 +252,7 @@ class Featurizer:
         # A caller with an identity-stable queue (the replay lower-cache
         # keeps surviving universe pods' objects alive across segments)
         # sees ``pod_rows_built`` grow with its per-window object churn,
-        # not with the universe size — the counters the bench /
+        # not with the universe size — the counters the
         # ``make lock-check`` O(delta) guard reads (docs/churn_floor.md
         # "Incremental lowering + pipelined executor").
         self.pod_rows_built = 0

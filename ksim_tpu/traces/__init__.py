@@ -26,8 +26,7 @@ record — resample.py), which is what lets the streaming and batch paths
 emit byte-identical operation sequences.
 
 Wired through the scenario spec (``source: {trace: ...}`` —
-scenario/spec.py), the job plane (docs/jobs.md), and bench
-(``churn_trace`` rung); the whole package is stdlib-only at import
+scenario/spec.py) and the job plane (docs/jobs.md); the whole package is stdlib-only at import
 time — machine-checked by the ksimlint import-boundary rule — so the
 parsers configure and fail cleanly in jax-free processes.
 """

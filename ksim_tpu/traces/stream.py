@@ -233,7 +233,7 @@ class TraceOperationStream:
         self._buf.clear()
 
     def stats(self) -> dict:
-        """Producer evidence for bench/tests: window/op/record counts,
+        """Producer evidence for tests: window/op/record counts,
         whether the run degraded to the materialized path, and the
         deepest the bounded queue ever got."""
         return {

@@ -29,7 +29,7 @@ def enable_compilation_cache() -> None:
     XLA compiles of the scheduling scan at large shapes cost seconds to
     tens of seconds each; the disk cache makes them one-time per machine
     instead of per process.  Called by the product entrypoints
-    (simulator/scheduler CLIs, fleet workers, bench) — NOT on library
+    (simulator/scheduler CLIs, fleet workers) — NOT on library
     import, so embedding applications keep control of jax.config.
 
     Placement has one knob, JAX's own: with ``JAX_COMPILATION_CACHE_DIR``

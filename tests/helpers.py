@@ -2,6 +2,7 @@
 
 from __future__ import annotations
 
+import json
 import os
 import random
 from typing import Any
@@ -250,3 +251,50 @@ def pods_by_node(pods: list[JSON]) -> dict[str, list[JSON]]:
             continue
         out.setdefault(p["spec"]["nodeName"], []).append(p)
     return out
+
+
+def write_synthetic_borg(path: str, records: int, seed: int = 0) -> None:
+    """A Borg-format JSONL trace of ``records`` SUBMIT/FINISH pairs,
+    deterministic from ``seed``.  Lifetimes stay short against the trace
+    span (~25 ms mean interarrival) so FINISH deletes interleave with
+    arrivals and the live pod population stays bounded."""
+    rng = random.Random(seed)
+    t_us = 0
+    with open(path, "w") as f:
+        for i in range(records):
+            t_us += rng.randrange(1_000, 50_000)
+            life_us = rng.randrange(500_000, 60_000_000)
+            req = {
+                "cpus": rng.choice((0.01, 0.025, 0.05, 0.1)),
+                "memory": rng.choice((0.005, 0.01, 0.02, 0.05)),
+            }
+            f.write(json.dumps({
+                "time": t_us, "type": "SUBMIT", "collection_id": i,
+                "instance_index": 0,
+                "priority": rng.choice((0, 103, 117, 200, 360)),
+                "resource_request": req,
+            }) + "\n")
+            f.write(json.dumps({
+                "time": t_us + life_us, "type": "FINISH",
+                "collection_id": i, "instance_index": 0,
+            }) + "\n")
+
+
+def replay_synthetic_borg(tmp_path):
+    """A 400-pair synthetic Borg trace (120 events kept, 8 nodes)
+    through the device path twice: streamed in 64-op windows, then
+    materialized.  Returns (stream, streamed runner, streamed result,
+    materialized result)."""
+    from ksim_tpu.scenario import ScenarioRunner
+    from ksim_tpu.traces import stream_trace_operations, trace_operations
+
+    path = str(tmp_path / "synthetic_borg.jsonl")
+    write_synthetic_borg(path, 400)
+    kw = dict(nodes=8, max_events=120, seed=0, ops_per_step=10)
+    stream = stream_trace_operations(path, "borg", window=64, queue_windows=2, **kw)
+    runner = ScenarioRunner(pod_bucket_min=64, device_replay=True)
+    streamed = runner.run(stream)
+    materialized = ScenarioRunner(pod_bucket_min=64, device_replay=True).run(
+        list(trace_operations(path, "borg", **kw))
+    )
+    return stream, runner, streamed, materialized

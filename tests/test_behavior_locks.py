@@ -1,9 +1,9 @@
 """The repo's churn behavior locks, asserted in-suite (round-5 verdict #1).
 
 The flagship replay's counts (seed 0, 2000 nodes — repo CLAUDE.md) were
-previously enforced only by bench discipline: a parity regression (the
+previously enforced only by discipline: a parity regression (the
 class the locks exist to catch) would fail only if someone re-ran the
-bench and eyeballed the counts.  BENCH_r04.json proved the gap — its TPU
+replay and eyeballed the counts.  A round-4 TPU run proved the gap — its
 churn recorded 52582/42840 against the 52781/42829 lock and nothing
 noticed, because the f32 fast mode diverged ACROSS PLATFORMS (TPU's
 approximate f32 divide truncated exact integer ratios one ulp low in
@@ -11,10 +11,10 @@ InterPodAffinity's normalize, and backend f32 log ulps flipped
 PodTopologySpread's round()).  Both kernels are now platform-
 deterministic by construction (integer normalize floor; trace-time log
 table + fixed-order reduce), so ONE set of counts is the contract on
-every backend, in both modes — these tests pin the 6k prefix (~15 s,
-the 50k run is bench-tier) exactly as the bench runs it
-(ScenarioRunner(max_pods_per_pass=1024, pod_bucket_min=128),
-ops_per_step=100; bench.py child_churn).
+every backend, in both modes — these tests pin the 6k prefix (~15 s;
+the 50k run is `make lock-check`) exactly as the benchmark's churn-2k
+deployment runs it (ScenarioRunner(max_pods_per_pass=1024,
+pod_bucket_min=128), ops_per_step=100).
 
 Reference intent: replay parity is the product metric — recorded
 results as ground truth (storereflector.go:78-146).
@@ -190,8 +190,7 @@ def test_churn_lock_6k_holds_under_dispatch_faults_with_recovery(monkeypatch):
 # hand-checked Borg fixture compiled at 24 nodes / ops_per_step=2 —
 # the SECOND locked-count family next to synthetic churn, and the
 # first priority-DIVERSE one (trace tiers land on PRIORITY_LADDER, so
-# windows are not priority-flat).  bench.py's churn_trace rung replays
-# the same compilation.
+# windows are not priority-flat).
 TRACE_LOCK_SCHEDULED = 56
 TRACE_LOCK_UNSCHEDULABLE = 19
 TRACE_LOCK_EVENTS = 126
@@ -236,6 +235,34 @@ def test_trace_lock_borg_mini_device_vs_per_pass():
     driver = dev_r.replay_driver
     assert driver.fallback_steps == 0, driver.unsupported
     assert driver.device_steps == len(dev.steps)
+
+
+def test_trace_borg_mini_device_run_reports_its_phase_split():
+    """What a trace replay hands its readers beside the counts: an
+    empty fallback histogram and ``ScenarioResult.phase_seconds`` — the
+    run's wall split by span name (the job result document's ``phases``,
+    `tools/trace_check.py`'s record) — with the device path's three
+    phases in it and no per-pass step."""
+    from ksim_tpu.traces import trace_operations
+
+    jax.config.update("jax_enable_x64", False)
+    ops = trace_operations(
+        "tests/fixtures/traces/borg_mini.jsonl", "borg", nodes=24, ops_per_step=2
+    )
+    runner = ScenarioRunner(pod_bucket_min=64, device_replay=True)
+    res = runner.run(list(ops))
+    assert (res.pods_scheduled, res.unschedulable_attempts) == (
+        TRACE_LOCK_SCHEDULED,
+        TRACE_LOCK_UNSCHEDULABLE,
+    )
+    assert runner.replay_driver.device_steps == len(res.steps)
+    assert dict(runner.replay_driver.unsupported) == {}
+    assert {"replay.lower", "replay.dispatch", "replay.reconcile"} <= set(
+        res.phase_seconds
+    )
+    assert "runner.step" not in res.phase_seconds
+    assert all(v >= 0 for v in res.phase_seconds.values())
+    assert sum(res.phase_seconds.values()) > 0
 
 
 def test_trace_lock_borg_mini_holds_with_streaming_ingest():
@@ -290,8 +317,7 @@ def test_churn_lock_50k_stepwise_device_vs_per_pass():
     device-resident path (preemption enabled — a no-op on this stream,
     which is exactly what the lock asserts) and require the 52781/42829
     totals plus stepwise-identical (scheduled, unschedulable, pending)
-    triples between the two paths.  ~10 min CPU; bench-tier before this
-    test existed."""
+    triples between the two paths.  ~10 min CPU."""
     jax.config.update("jax_enable_x64", False)
 
     def run(device: bool, preemption: bool):

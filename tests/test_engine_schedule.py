@@ -1,7 +1,6 @@
 """Sequential-commit scheduling loop vs an oracle greedy simulation."""
 
 import numpy as np
-import pytest
 
 from ksim_tpu.engine import Engine, ScoredPlugin
 from ksim_tpu.plugins import oracle
@@ -178,66 +177,3 @@ def test_engine_jit_cache_reused_across_instances():
     assert _Program._schedule_fn._cache_size() == size_sched
     assert _Program._batch_fn._cache_size() == size_batch
     assert not np.array_equal(res1.total, res2.total)  # new values flowed
-
-
-def test_partitioned_batch_matches_unpartitioned():
-    """partition=True classes pods host-side and runs light pods through
-    a program that statically skips the heavy constraint plugins
-    (engine/core.py evaluate_batch_chunks) — results must be
-    bit-identical to the contiguous evaluation in original pod order,
-    including the recorded result tensors.  random_cluster mixes
-    constraint-carrying and constraint-less pods, so both classes and a
-    ragged class tail (odd chunk) are exercised."""
-    nodes, pods = random_cluster(5, n_nodes=16, n_pods=60, bound_fraction=0.2)
-    queue = [p for p in pods if not p["spec"].get("nodeName")]
-    feats = Featurizer().featurize(nodes, pods, queue_pods=queue)
-    eng = Engine(feats, default_plugins(feats), record="full")
-    light = eng._light_mask(eng._partition_assume())
-    assert light is not None and light.any() and not light.all(), (
-        "fixture must exercise both classes"
-    )
-    plain = eng.evaluate_batch(chunk=13)
-    parted = eng.evaluate_batch(chunk=13, partition=True)
-    for field in ("reason_bits", "scores", "final_scores", "total", "feasible", "selected"):
-        assert np.array_equal(getattr(plain, field), getattr(parted, field)), field
-
-
-def test_partitioned_batch_trivial_classes_fall_back():
-    """All-light or all-heavy classifications take the contiguous path
-    (no gather, no second program)."""
-    nodes = [make_node(f"n{i}") for i in range(3)]
-    pods = [make_pod(f"p{i}") for i in range(5)]
-    feats = Featurizer().featurize(nodes, pods, queue_pods=pods)
-    eng = Engine(feats, default_plugins(feats), record="full")
-    keys = [k for k, _ in eng.evaluate_batch_chunks(chunk=4, partition=True)]
-    assert all(isinstance(k, int) for k in keys), keys
-    plain = eng.evaluate_batch(chunk=4)
-    parted = eng.evaluate_batch(chunk=4, partition=True)
-    assert np.array_equal(plain.selected, parted.selected)
-
-
-def test_fused_batch_matches_chunked():
-    """evaluate_batch_fused must equal the chunked evaluation in both
-    bounded record modes, for block sizes that do and don't divide the
-    padded pod count (the entry shrinks block until it divides)."""
-    nodes, pods = random_cluster(7, n_nodes=12, n_pods=50, bound_fraction=0.2)
-    queue = [p for p in pods if not p["spec"].get("nodeName")]
-    feats = Featurizer().featurize(nodes, pods, queue_pods=queue)
-    for record in ("selection", "final"):
-        eng = Engine(feats, default_plugins(feats), record=record)
-        plain = eng.evaluate_batch(chunk=13)
-        for block in (8, 256):
-            fused = eng.evaluate_batch_fused(block=block)
-            for field in ("final_scores", "total", "feasible", "selected"):
-                a, b = getattr(plain, field), getattr(fused, field)
-                if a is None and b is None:
-                    continue
-                assert np.array_equal(a, b), (record, block, field)
-
-
-def test_fused_batch_rejects_full_record():
-    nodes, pods = random_cluster(7, n_nodes=4, n_pods=6, bound_fraction=0.0)
-    feats = Featurizer().featurize(nodes, pods, queue_pods=pods)
-    eng = Engine(feats, default_plugins(feats), record="full")
-    with pytest.raises(ValueError):
-        eng.evaluate_batch_fused()

@@ -428,6 +428,8 @@ def test_two_same_rung_jobs_compile_once():
             assert result["replay"]["platform"] == "cpu", result["replay"]
             assert result["replay"]["device_kind"]
             assert result["replay"]["device_count"] >= 1
+            # Dispatch latency comes from the job's own trace plane.
+            assert result["latency"]["replay.dispatch"]["p50_seconds"] > 0
         s1 = j1.result_view()[1]["result"]
         s2 = j2.result_view()[1]["result"]
         assert (s1["podsScheduled"], s1["unschedulableAttempts"]) == (

@@ -624,6 +624,35 @@ def test_resume_from_every_checkpoint_boundary_byte_identical(tmp_path):
     assert replayed[0] < total_events
 
 
+@pytest.mark.slow
+def test_dead_device_job_lands_no_checkpoint_and_resume_serves_its_result(
+    tmp_path, monkeypatch
+):
+    """With every dispatch failing, the job runs on the per-pass host
+    path, which commits no segment: no checkpoint record ever lands,
+    the job still succeeds, and a resuming restart over the same dir
+    serves the journaled result instead of replaying anything."""
+    monkeypatch.setenv("KSIM_REPLAY_BREAKER_N", "2")
+    FAULTS.arm("replay.dispatch", "always@device")
+    jid, result = _run_checkpointed(tmp_path, churn_device_doc())
+    assert FAULTS.fired("replay.dispatch") >= 2
+    assert result["replay"]["device_steps"] == 0
+    recs = JobJournal(os.path.join(str(tmp_path), JOURNAL_NAME)).replay()
+    assert not [r for r in recs if r["t"] == "checkpoint"]
+    jm = JobManager(
+        workers=1, queue_limit=8, jobs_dir=str(tmp_path),
+        resume=True, checkpoint_every=0,
+    )
+    try:
+        job = jm.get(jid)
+        final = _wait(job, {"succeeded", "failed", "interrupted"}, 60.0)
+        assert final["state"] == "succeeded", final
+        assert final["resumed_from"] is None
+        assert _locked_counts(job.result_view()[1]) == _locked_counts(result)
+    finally:
+        jm.shutdown()
+
+
 def test_resume_with_torn_tail_after_checkpoint(tmp_path):
     """kill -9 mid-append AFTER the last checkpoint: the torn bytes are
     dropped by the journal's tail rule and the checkpoint restores."""

@@ -438,7 +438,7 @@ def test_exception_flow_fault_and_fallback_channels():
 
 def test_full_tree_has_zero_unsuppressed_findings():
     """The tier-1 in-process equivalent of `make lint`: every rule over
-    ksim_tpu/, bench.py and tools/ — zero unsuppressed findings.  The
+    ksim_tpu/, chip_smoke.py and tools/ — zero unsuppressed findings.  The
     analyzer is stdlib-only, so this needs no jax and no subprocess."""
     findings = run(REPO, DEFAULT_TARGETS)
     open_ = [f for f in findings if not f.suppressed]

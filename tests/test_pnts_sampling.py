@@ -143,8 +143,6 @@ def test_sampling_scan_only():
     eng, _ = _engine(8, [make_pod("p0")], 3)
     with pytest.raises(ValueError):
         eng.evaluate_batch()
-    with pytest.raises(ValueError):
-        eng.evaluate_batch_fused()
 
 
 def test_recorded_maps_cover_visited_nodes_only():

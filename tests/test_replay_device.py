@@ -68,7 +68,7 @@ def test_device_replay_matches_per_pass_churn(x64):
 def test_device_replay_lock_6k_seed0_f32():
     """The flagship locked prefix through the device-resident path:
     seed 0, 2000 nodes, 6k events -> 2524/471 (repo CLAUDE.md), exactly
-    as the bench runs it.  The driver must have covered the bulk of the
+    as the benchmark's churn-2k deployment runs it.  The driver must have covered the bulk of the
     steps on-device — a blanket fallback passing vacuously is a failure."""
     jax.config.update("jax_enable_x64", False)
     try:
@@ -713,6 +713,14 @@ def test_fleet_tp_dp_mesh_lanes_match_single_device(monkeypatch):
     # its segment programs must carry the declared tp=4 node width.
     tps = sorted({e["tp"] for ln in fleet_r.fleet_lanes for e in ln.driver.lower_log})
     assert tps == [4], tps
+    leader = max(
+        (ln.driver for ln in fleet_r.fleet_lanes), key=lambda d: len(d.lower_log)
+    )
+    assert all(e["full_bytes_per_shard"] > 0 for e in leader.lower_log)
+    assert fd.stats()["group_dispatches"] >= 1
+    # Zero resharding: once the ("mesh", 2, 4) layout is adopted, a
+    # steady-state window finds its constants on the device.
+    assert leader.stats()["dev_const"]["hits"] > 0, leader.stats()["dev_const"]
 
 
 def test_replay_donation_engages_and_stays_byte_identical():
@@ -833,6 +841,13 @@ def test_device_sharded_small_churn_byte_parity(monkeypatch):
     assert "shard_mesh" not in d.unsupported, d.unsupported
     assert _lowered_tps(d) == [8], d.lower_log
     assert _lowered_tps(solo_r.replay_driver) == [1]
+    assert d.fallback_steps == solo_r.replay_driver.fallback_steps == 0
+    # The full-record budget is per shard: tp=8 carries 1/8th of the
+    # solo run's bytes per chip.
+    per_shard = max(e["full_bytes_per_shard"] for e in d.lower_log)
+    assert per_shard * 8 == max(
+        e["full_bytes_per_shard"] for e in solo_r.replay_driver.lower_log
+    )
 
 
 def test_device_sharded_full_record_annotations_byte_parity(monkeypatch):

@@ -22,13 +22,23 @@ deselection.  Every small-stream probe stays tier-1.
 
 from __future__ import annotations
 
+import subprocess
+import sys
+import time
+from pathlib import Path
+
 import jax
 import pytest
 
 from ksim_tpu.faults import FAULTS, InjectedFault
-from ksim_tpu.scenario import ScenarioRunner, churn_scenario
+from ksim_tpu.scenario import ScenarioRunner, churn_scenario, spec_from_operations
 from ksim_tpu.scenario.runner import Operation
-from tests.helpers import make_node, make_pod
+from tests.helpers import (
+    make_node,
+    make_pod,
+    replay_synthetic_borg,
+    sanitized_cpu_env,
+)
 
 LOCK = (2524, 471)  # scheduled/unschedulable, seed 0 / 2000 nodes / 6k events
 
@@ -42,7 +52,7 @@ def _clean_fault_plane():
 
 @pytest.fixture(autouse=True)
 def _f32_fast_mode():
-    # The locked counts hold in both modes; f32 is how the bench runs it.
+    # The locked counts hold in both modes; f32 is how the benchmark runs it.
     jax.config.update("jax_enable_x64", False)
     yield
     jax.config.update("jax_enable_x64", True)
@@ -369,6 +379,209 @@ def test_breaker_state_is_per_driver(monkeypatch):
     r2.run(_small_stream())
     assert not r2.replay_driver.breaker_tripped
     assert r2.replay_driver.device_steps > 0
+
+
+# ---------------------------------------------------------------------------
+# A dead device, surface by surface: every dispatch fails
+# (``replay.dispatch=always@device``, breaker at 2) and each surface
+# that drives the device path must finish on the per-pass host path
+# with the counts of the run it is compared with.  The solo surface is
+# test_permanent_device_failure_trips_breaker above (6k stream, locked
+# counts, the whole breaker account).
+# ---------------------------------------------------------------------------
+
+_CHURN_KW = dict(
+    max_pods_per_pass=1024, pod_bucket_min=128, device_replay=True, preemption=True
+)
+
+
+def _churn(n_events=300, n_nodes=64):
+    return churn_scenario(0, n_nodes=n_nodes, n_events=n_events, ops_per_step=100)
+
+
+def _counts(res):
+    return [res.pods_scheduled, res.unschedulable_attempts]
+
+
+def _churn_job_doc(n_events):
+    return {
+        "spec": {
+            "simulator": {
+                "preemption": True,
+                "maxPodsPerPass": 1024,
+                "podBucketMin": 128,
+                "deviceReplay": True,
+            },
+            "scenario": spec_from_operations(list(_churn(n_events))),
+        }
+    }
+
+
+def _job_counts(job):
+    state, result, err = job.result_view()
+    assert state == "succeeded", (state, err)
+    return [
+        result["result"]["podsScheduled"],
+        result["result"]["unschedulableAttempts"],
+    ]
+
+
+def _dead_jobs(tmp_path, monkeypatch):
+    """2 jobs on a 2-worker pool: all succeed with the solo counts."""
+    from ksim_tpu.jobs import JobManager
+
+    jm = JobManager(workers=2, queue_limit=4)
+    try:
+        doc = _churn_job_doc(300)
+        jobs = [jm.submit(doc) for _ in range(2)]
+        assert jm.join(timeout=300)
+    finally:
+        jm.shutdown(timeout=5)
+    solo = ScenarioRunner(**_CHURN_KW).run(_churn())
+    assert solo.pods_scheduled > 0
+    for job in jobs:
+        assert _job_counts(job) == _counts(solo)
+        assert job.result_view()[1]["replay"]["device_steps"] == 0
+
+
+def _dead_fleet(tmp_path, monkeypatch, lanes=3):
+    """3 lanes: every lane lands the solo counts, none on the device."""
+    solo = ScenarioRunner(**_CHURN_KW).run(_churn())
+    assert solo.pods_scheduled > 0
+    fleet = ScenarioRunner(**_CHURN_KW, fleet=lanes)
+    res = fleet.run(_churn())
+    assert [_counts(r) for r in res.lanes] == [_counts(solo)] * lanes
+    stats = fleet.fleet_driver.stats()
+    assert stats["lanes_on_device"] == 0.0
+    assert stats["lane_device_steps"] == [0] * lanes
+
+
+def _dead_fleet_mesh(tmp_path, monkeypatch):
+    """2 lanes over dp of a (2, 4) fleet mesh, node tensors over tp."""
+    monkeypatch.setenv("KSIM_FLEET_DP", "2")
+    monkeypatch.setenv("KSIM_REPLAY_TP", "4")
+    _dead_fleet(tmp_path, monkeypatch, lanes=2)
+
+
+def _dead_trace(tmp_path, monkeypatch):
+    """The bundled Borg fixture keeps its locked counts (56 / 19)."""
+    from ksim_tpu.traces import trace_operations
+
+    ops = trace_operations(
+        "tests/fixtures/traces/borg_mini.jsonl", "borg", nodes=24, ops_per_step=2
+    )
+    base = ScenarioRunner(pod_bucket_min=64).run(list(ops))
+    dev_r = ScenarioRunner(pod_bucket_min=64, device_replay=True)
+    dev = dev_r.run(list(ops))
+    assert _counts(dev) == _counts(base) == [56, 19]
+    assert dev_r.replay_driver.device_steps == 0
+    assert dev_r.replay_driver.unsupported.get("device_error", 0) >= 2
+
+
+def _dead_stream(tmp_path, monkeypatch):
+    """Streamed ingest degrades mid-pipeline and still equals the
+    materialized run; the producer's own fallback is a separate plane."""
+    stream, dev_r, streamed, mat = replay_synthetic_borg(tmp_path)
+    assert _counts(streamed) == _counts(mat)
+    assert streamed.pods_scheduled > 0
+    assert stream.stats()["fallback"] == 0
+    assert dev_r.replay_driver.device_steps == 0
+
+
+def _dead_shard(tmp_path, monkeypatch):
+    """tp 1 and tp 8 both degrade and agree."""
+    sigs = []
+    for tp in (1, 8):
+        monkeypatch.setenv("KSIM_REPLAY_TP", str(tp))
+        runner = ScenarioRunner(**_CHURN_KW)
+        res = runner.run(_churn())
+        sigs.append(_fleet_sig(res))
+        assert runner.replay_driver.device_steps == 0
+        assert runner.replay_driver.unsupported.get("device_error", 0) >= 1
+    assert sigs[0] == sigs[1] and sum(s[1] for s in sigs[0]) > 0
+
+
+def _dead_workers(tmp_path, monkeypatch):
+    """2 worker PROCESSES behind a front door (the fault plane rides
+    their environment): the job succeeds with the in-process solo
+    counts, and the fleet-scope scrape still answers — the telemetry
+    pull is host-side I/O a dead chip must not take down."""
+    from ksim_tpu import obs
+    from ksim_tpu.jobs import JobManager
+
+    d = str(tmp_path)
+    env = sanitized_cpu_env({
+        "KSIM_FAULTS": "replay.dispatch=always@device",
+        "KSIM_REPLAY_BREAKER_N": "2",
+        "KSIM_WORKERS_POLL_S": "0.1",
+        "KSIM_WORKERS_LEASE_S": "8",
+        "KSIM_OBS_PUBLISH_S": "1",
+        "KSIM_JOBS_CHECKPOINT_EVERY": "0",
+    })
+    procs = [
+        subprocess.Popen(
+            [
+                sys.executable, "-m", "ksim_tpu.jobs",
+                "--dir", d, "--worker-id", f"w{i}", "--workers", "1",
+            ],
+            env=env, cwd=Path(__file__).resolve().parent.parent,
+            stdout=subprocess.PIPE, text=True,
+        )
+        for i in range(2)
+    ]
+    fd = None
+    try:
+        for i, proc in enumerate(procs):
+            assert proc.stdout.readline().strip() == f"READY w{i}"
+        fd = JobManager(
+            workers=0, queue_limit=4, jobs_dir=d,
+            role="frontdoor", worker_id="fd", lease_s=8.0, poll_s=0.1,
+        )
+        job = fd.submit(_churn_job_doc(200))
+        deadline = time.monotonic() + 240
+        while job.status()["state"] not in ("succeeded", "failed", "interrupted"):
+            assert time.monotonic() < deadline, job.status()
+            time.sleep(0.2)
+        solo = ScenarioRunner(**_CHURN_KW).run(_churn(200))
+        assert _job_counts(job) == _counts(solo)
+        fleet_doc = obs.merge_fleet_docs(obs.read_fleet_snapshots(d))
+        expo = obs.render_prometheus(fleet_doc)
+        obs.parse_prometheus(expo)
+        assert expo
+    finally:
+        for proc in procs:
+            proc.terminate()
+        for proc in procs:
+            try:
+                proc.wait(timeout=15)
+            except subprocess.TimeoutExpired:
+                proc.kill()
+                proc.wait()
+        if fd is not None:
+            fd.shutdown()
+
+
+_DEAD_SURFACES = {
+    "jobs": _dead_jobs,
+    "fleet": _dead_fleet,
+    "trace": _dead_trace,
+    "stream": _dead_stream,
+    "shard": _dead_shard,
+    "workers": _dead_workers,
+    "fleet_mesh": _dead_fleet_mesh,
+}
+
+
+@pytest.mark.parametrize("surface", list(_DEAD_SURFACES))
+def test_dead_device_is_carried_by_host_path(surface, monkeypatch, tmp_path):
+    monkeypatch.setenv("KSIM_REPLAY_BREAKER_N", "2")
+    monkeypatch.delenv("KSIM_REPLAY_TP", raising=False)
+    monkeypatch.delenv("KSIM_FLEET_DP", raising=False)
+    FAULTS.arm("replay.dispatch", "always@device")
+    _DEAD_SURFACES[surface](tmp_path, monkeypatch)
+    # Not vacuous: the armed site fired in this process (for `workers`,
+    # in the solo run the job is compared with).
+    assert FAULTS.fired("replay.dispatch") >= 2
 
 
 # ---------------------------------------------------------------------------

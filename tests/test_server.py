@@ -215,7 +215,7 @@ def test_metrics_and_ui(server):
 
 def test_metrics_merges_faults_trace_and_replay_stats(server):
     """One GET shows the whole degradation-evidence surface (the former
-    gap: fault counters and replay stats were bench-JSON-only)."""
+    gap: fault counters and replay stats were not served)."""
     from ksim_tpu.faults import FAULTS, InjectedFault
     from ksim_tpu.obs import TRACE
 

@@ -173,6 +173,40 @@ def test_window_boundary_splits_a_create_delete_pair():
 
 
 # ---------------------------------------------------------------------------
+# The windowed pipeline under the device executor
+# ---------------------------------------------------------------------------
+
+
+def test_streamed_device_replay_equals_materialized_and_prefetches(tmp_path):
+    """A synthetic Borg trace (400 SUBMIT/FINISH pairs, 120 events kept)
+    through the windowed pipeline feeding the device executor, then
+    through the materialized path: the same counts, more than one
+    64-op window, no producer fallback, and the executor drained at
+    least one ready window while a dispatch was in flight."""
+    import jax
+
+    from tests.helpers import replay_synthetic_borg
+
+    prev_x64 = jax.config.jax_enable_x64
+    jax.config.update("jax_enable_x64", False)
+    try:
+        stream, dev_r, streamed, mat = replay_synthetic_borg(tmp_path)
+    finally:
+        jax.config.update("jax_enable_x64", prev_x64)
+    assert (streamed.pods_scheduled, streamed.unschedulable_attempts) == (
+        mat.pods_scheduled, mat.unschedulable_attempts,
+    )
+    assert streamed.events_applied == mat.events_applied > 0
+    stats = stream.stats()
+    assert stats["window_ops"] == 64 and stats["queue_windows"] == 2
+    assert stats["windows"] >= 2
+    assert stats["fallback"] == 0
+    drv = dev_r.replay_driver
+    assert drv.device_steps > 0
+    assert drv.stats()["ingest_prefetches"] >= 1
+
+
+# ---------------------------------------------------------------------------
 # Producer-fault degradation (the armed-chaos satellite)
 # ---------------------------------------------------------------------------
 

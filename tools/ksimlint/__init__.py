@@ -9,8 +9,9 @@ machine-checked invariants:
                         state.
 - ``kernel-purity``     ``@device_kernel`` trace-time bodies stay free
                         of host effects and f32-determinism hazards.
-- ``import-boundary``   the stdlib-only surfaces (bench.py parent,
-                        obs/faults/errors, this analyzer) never reach
+- ``import-boundary``   the stdlib-only surfaces (chip_smoke.py and
+                        trace_check.py parents, obs/faults/errors,
+                        this analyzer) never reach
                         jax/numpy at import time.
 - ``registry-literals`` every fault-site / span / event / fallback
                         reason literal resolves into its registry.

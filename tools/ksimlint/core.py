@@ -1,9 +1,8 @@
 """ksimlint core: source loading, comment directives, the rule runner.
 
 Stdlib-only BY CONTRACT (enforced by ksimlint's own import-boundary
-rule): the analyzer runs in the sanitized environment, in bench
-children's parents, and in CI shells where jax backend init may be
-wedged — it must never import jax, numpy, or ksim_tpu itself.  All
+rule): the analyzer runs in the sanitized environment, in stdlib-only
+parents, and in CI shells where jax backend init may be wedged — it must never import jax, numpy, or ksim_tpu itself.  All
 facts about the codebase are extracted from Python ASTs and the token
 stream, never by importing the code under analysis.
 
@@ -32,7 +31,7 @@ from dataclasses import dataclass, replace
 
 #: What ``make lint`` (and the no-argument CLI) analyzes.  tests/ is
 #: deliberately out of scope: fixtures there contain SEEDED violations.
-DEFAULT_TARGETS: tuple[str, ...] = ("ksim_tpu", "bench.py", "chip_smoke.py", "tools")
+DEFAULT_TARGETS: tuple[str, ...] = ("ksim_tpu", "chip_smoke.py", "tools")
 
 _DISABLE_RE = re.compile(r"ksimlint:\s*disable=([\w,-]+)")
 

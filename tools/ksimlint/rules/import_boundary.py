@@ -3,14 +3,12 @@
 A module import graph over the tree proves, statically, the contracts
 that today live in docstrings and CLAUDE.md prose:
 
-- ``bench.py``'s PARENT process never imports jax/numpy/ksim_tpu — the
-  one JSON line must exist under ANY hardware condition, including a
-  backend whose init hangs.  Child payloads (the
-  ``child*`` / ``_child*`` functions, which only ever run in
-  subprocesses) are the sanctioned exception.
-- ``tools/trace_check.py`` and ``chip_smoke.py`` follow the same
-  parent/child split (``chip_smoke.py``'s parent must never hold the
-  chip its one server child needs).
+- The PARENT process of ``tools/trace_check.py`` and ``chip_smoke.py``
+  never imports jax/numpy/ksim_tpu: its verdict must exist under ANY
+  hardware condition, including a backend whose init hangs, and
+  ``chip_smoke.py``'s parent must never hold the chip its one server
+  child needs.  Child payloads (the ``child*`` / ``_child*`` functions,
+  which only ever run in subprocesses) are the sanctioned exception.
 - ``ksim_tpu/obs.py``, ``ksim_tpu/faults.py`` and ``ksim_tpu/errors.py``
   must not reach jax or numpy AT IMPORT TIME, transitively through
   their ksim_tpu-internal imports (function-scope lazy imports — the
@@ -54,7 +52,6 @@ class Boundary:
 
 
 DEFAULT_BOUNDARIES: tuple[Boundary, ...] = (
-    Boundary("bench.py", _ACCEL | {"ksim_tpu"}, "parent-child"),
     Boundary("tools/trace_check.py", _ACCEL | {"ksim_tpu"}, "parent-child"),
     Boundary("chip_smoke.py", _ACCEL | {"ksim_tpu"}, "parent-child"),
     Boundary("tools/ksimlint", _ACCEL | {"ksim_tpu", "tests"}, "everywhere"),
@@ -63,7 +60,7 @@ DEFAULT_BOUNDARIES: tuple[Boundary, ...] = (
     Boundary("ksim_tpu/errors.py", _ACCEL, "import-time"),
     # The trace ingestion plane: parsers/registry/resample must stay
     # stdlib-only at import time (they configure and fail cleanly in
-    # jax-free processes — the bench parent, the HTTP surface); jax may
+    # jax-free processes — stdlib-only parents, the HTTP surface); jax may
     # enter only through the compile path's function-scope imports.
     Boundary("ksim_tpu/traces", _ACCEL, "import-time"),
 )

@@ -24,9 +24,9 @@ any hardware condition, like ``make faults``), then validates:
   owning worker's lane, and at least one complete
   submit→claim→run flow-event triple (``s``/``t``/``f``) per job.
 
-The parent process is stdlib-only (the bench.py crash-containment
-pattern: jax backend init can hang on a dead backend, so anything that
-must complete runs jax only in subprocesses)."""
+The parent process is stdlib-only (crash containment: jax backend init
+can hang on a dead backend, so anything that must complete runs jax
+only in subprocesses)."""
 
 from __future__ import annotations
 
