@@ -93,6 +93,7 @@ durability: ``KSIM_JOBS_DIR``, ``KSIM_JOBS_RESUME``,
 
 from __future__ import annotations
 
+import gc
 import hashlib
 import json
 import logging
@@ -106,7 +107,13 @@ from ksim_tpu.errors import RunCancelled
 from ksim_tpu.faults import FAULTS, FaultPlane
 from ksim_tpu.jobs.journal import JOURNAL_NAME, JobJournal
 from ksim_tpu.jobs.queue import JobQueue, JobQueueFull
-from ksim_tpu.obs import TRACE, TracePlane, runtime_growth, runtime_totals
+from ksim_tpu.obs import (
+    TRACE,
+    TracePlane,
+    collect_scheduled,
+    runtime_growth,
+    runtime_totals,
+)
 
 logger = logging.getLogger(__name__)
 
@@ -325,6 +332,59 @@ def _parse_job_spec(
     return ops, dict(sim), priority, fault_spec
 
 
+# -- the old generation ------------------------------------------------------
+#
+# CPython 3.12 runs a full (generation-2) collection when the objects
+# promoted since the last one pass a quarter of the old generation, and
+# a full collection visits every tracked object: summed over a job that
+# is ~4x (objects the job promotes) x (cost of a visit), whatever the
+# heap's size, all of it inside the request.  A job's graph dies with
+# the job (``Job._let_go_locked``), so the place to walk the old
+# generation is the job's end, once, over the little that is left.
+# While any job of this process runs, the automatic full collection is
+# therefore moved out of reach, and the worker runs one itself when its
+# job is done (``JobManager._run_job``).  Young collections stay
+# CPython's.  With no job running the thresholds are the process's own
+# again: the interactive path's store lives across requests and has no
+# low point to collect at, so it keeps CPython's rule.
+
+#: The generation-2 threshold while a job runs, in generation-1
+#: collections since the last full one (CPython's default: 10).  The
+#: safety net of a job that never ends: a generation-1 collection
+#: promotes at most ~7,700 objects (10 young ones of 700 each), so the
+#: automatic full collection comes back after at most ~16 M promoted
+#: objects.  Sized from what the largest cell reads (PERF.md section 6,
+#: PR 30): the 50,000-event stream job runs 688 generation-1
+#: collections (the 6,000-event prefix 36; CPU sandbox counts), and
+#: ``collected`` read 0 in every full collection of the parent's stream
+#: jobs on the chip — a job of the cells' sizes makes no cyclic garbage,
+#: so a walk inside it finds nothing.  Three stream jobs' worth.
+GEN2_THRESHOLD_WHILE_RUNNING = 2048
+
+_old_gen_lock = threading.Lock()
+_old_gen_running = 0  # guarded-by: _old_gen_lock
+_old_gen_idle_threshold = 10  # guarded-by: _old_gen_lock
+
+
+def _old_gen_job_starts() -> None:
+    global _old_gen_running, _old_gen_idle_threshold
+    with _old_gen_lock:
+        _old_gen_running += 1
+        if _old_gen_running == 1:
+            young, middle, _old_gen_idle_threshold = gc.get_threshold()
+            gc.set_threshold(young, middle, GEN2_THRESHOLD_WHILE_RUNNING)
+
+
+def _old_gen_job_ends() -> None:
+    """When it was the last running job: CPython's own rule again."""
+    global _old_gen_running
+    with _old_gen_lock:
+        _old_gen_running -= 1
+        if _old_gen_running == 0:
+            young, middle, _ = gc.get_threshold()
+            gc.set_threshold(young, middle, _old_gen_idle_threshold)
+
+
 class Job:
     """One tenant job: spec + isolation planes + the event log the SSE
     stream replays.  Mutable state lives under ``_cond`` (the SSE
@@ -343,6 +403,7 @@ class Job:
         max_events: int,
         faults: "FaultPlane | None",
         tenant: str = "default",
+        runtime0: "dict | None" = None,
     ) -> None:
         self.id = job_id
         self.ordinal = ordinal
@@ -353,6 +414,10 @@ class Job:
         self.tenant = tenant
         self.cancel = threading.Event()
         self.created = time.time()
+        # The process totals (obs.runtime_totals) when this job's POST
+        # arrived: the result's ``runtime`` block is their growth from
+        # here, so a full collection during the submit is in it.
+        self.runtime0 = runtime0 if runtime0 is not None else runtime_totals()
         self.steps_total = len({op.step for op in ops})
         # The job's PRIVATE trace plane: ring + histograms, every record
         # tagged with the job id; the sink feeds the SSE event log.
@@ -384,8 +449,10 @@ class Job:
         # the in-memory-only plane).
         self.doc: Any = None
         # Diagnostics handles, set by the worker (the job's own store/
-        # runner — tests assert cancel-rollback consistency through
-        # them; None for queued jobs).
+        # runner; None for queued jobs) and let go again at the terminal
+        # transition (``_let_go_locked``): a retained terminal job keeps
+        # what a client can still ask for — status, result document,
+        # event log, trace ring — and nothing of the cluster it ran.
         self.store = None
         self.runner = None
         # Incremental resume (docs/jobs.md): the journaled checkpoint
@@ -455,6 +522,28 @@ class Job:
 
     # -- state machine ---------------------------------------------------
 
+    def _let_go_locked(self) -> tuple:  # ksimlint: lock-held(_cond)
+        """Terminal: drop the job's graph — store, runner (service,
+        featurizer with its row table and parse memo, driver, device
+        buffers, lower cache), the parsed operations, the simulator
+        spec with its inline snapshot, the recovery checkpoints.
+        Returns what was held, for the caller to drop OUTSIDE the lock
+        (freeing a 10,000-pod cluster takes a while)."""
+        held = (
+            self.store, self.runner, self.ops, self.sim,
+            self.checkpoints, self._last_checkpoint,
+        )
+        self.store = self.runner = self._last_checkpoint = None
+        self.ops, self.sim, self.checkpoints = [], {}, []
+        return held
+
+    def let_go(self) -> None:
+        """Drop the job's graph now, ahead of the terminal transition
+        (the worker, before its scheduled collection)."""
+        with self._cond:
+            held = self._let_go_locked()
+        del held
+
     def claim(self) -> bool:
         """queued -> running (the worker's atomic take); False if the
         job was cancelled while queued."""
@@ -484,6 +573,8 @@ class Job:
             if error:
                 ev["error"] = error
             self._emit_locked(ev, True)
+            held = self._let_go_locked()
+        del held
 
     def restore(
         self,
@@ -541,6 +632,7 @@ class Job:
         mirror never regresses: a duplicate terminal record from the
         cancel race (front door finalized queued, worker journaled
         cancelled) folds to the same state."""
+        held = None
         with self._cond:
             if self.state in TERMINAL_STATES:
                 return
@@ -554,9 +646,11 @@ class Job:
             if state in TERMINAL_STATES:
                 self.finished = float(finished) if finished else time.time()
                 self.checkpoint_segment = None  # terminal: not carried
+                held = self._let_go_locked()
             else:
                 self.checkpoint_segment = segment
             self._cond.notify_all()
+        del held
 
     def request_cancel(self) -> str:
         """Set the cancel flag; a QUEUED job finalizes immediately, a
@@ -564,11 +658,15 @@ class Job:
         any in-flight segment).  Returns the state after the request."""
         self.cancel.set()
         with self._cond:
+            held = None
             if self.state == "queued":
                 self.state = "cancelled"
                 self.finished = time.time()
                 self._emit_locked({"event": "state", "state": "cancelled"}, True)
-            return self.state
+                held = self._let_go_locked()
+            state = self.state
+        del held
+        return state
 
     def sse_attach(self) -> None:
         """One SSE reader subscribed (server/http.py pairs every attach
@@ -671,11 +769,14 @@ class JobManager:
     # docs/lint.md "Lock order").  Under the registry lock the submit
     # path notifies jobs/queue conditions and consults the planes;
     # the JOURNAL lock is never taken under the registry lock outside
-    # construction-time recovery (waived inline in ``_recover``).
+    # construction-time recovery (waived inline in ``_recover``).  A
+    # job built under the registry lock (recovery, adoption) opens its
+    # ``runtime`` window there: obs' runtime lock is a leaf.
     # ksimlint: lock-order(JobManager._lock<Job._cond)
     # ksimlint: lock-order(JobManager._lock<JobQueue._cond)
     # ksimlint: lock-order(JobManager._lock<FaultPlane._lock)
     # ksimlint: lock-order(JobManager._lock<TracePlane._lock)
+    # ksimlint: lock-order(JobManager._lock<obs._runtime_lock)
 
     def __init__(
         self,
@@ -1197,6 +1298,7 @@ class JobManager:
         *,
         priority: "int | None" = None,
         tenant: "str | None" = None,
+        runtime0: "dict | None" = None,
     ) -> Job:
         """Validate + enqueue one tenant job document.  Raises
         ``ScenarioSpecError`` on a bad spec (HTTP 400),
@@ -1208,6 +1310,11 @@ class JobManager:
         ``tenant`` (the HTTP layer's ``X-Ksim-Tenant`` header) wins
         over ``spec.tenant``; absent both, jobs pool under ``default``.
 
+        ``runtime0`` is the ``obs.runtime_totals()`` reading that opens
+        the job's ``runtime`` window: the HTTP layer takes it when the
+        POST arrives, before it reads the body; absent, it is taken
+        here, before the spec is parsed.
+
         The submission ordinal (the ``KSIM_JOBS_FAULTS`` key) commits
         only on a SUCCESSFUL enqueue: a refused submission must not
         shift which job an armed chaos schedule lands on (that would be
@@ -1218,6 +1325,8 @@ class JobManager:
         ``job._cond``, matching every other path."""
         from ksim_tpu.traces.schema import TraceBoundExceeded
 
+        if runtime0 is None:
+            runtime0 = runtime_totals()
         try:
             ops, sim, spec_priority, fault_spec = _parse_job_spec(
                 doc,
@@ -1309,6 +1418,7 @@ class JobManager:
                 max_events=self._max_events,
                 faults=faults,
                 tenant=tenant,
+                runtime0=runtime0,
             )
             # The queued event lands BEFORE the queue hand-off: once
             # put() returns, a worker may claim (and emit "running")
@@ -1420,12 +1530,18 @@ class JobManager:
             if job is None:
                 return
             if not job.claim():
+                job = None
                 continue  # cancelled while queued
             with self._lock:
                 self._active += 1
+            _old_gen_job_starts()
             try:
                 self._run_job(job)
             finally:
+                # Nothing of the finished job stays on this frame while
+                # the queue is waited on.
+                job = None
+                _old_gen_job_ends()
                 with self._lock:
                     self._active -= 1
 
@@ -1434,7 +1550,17 @@ class JobManager:
         scoped override routes every span/event of the whole pipeline —
         runner, service, replay driver, even the dispatch worker thread
         (the executor re-installs the scope there) — onto the job's
-        private plane, tagged ``job=<id>``."""
+        private plane, tagged ``job=<id>``.
+
+        The order at the job's end: result document, then the job's
+        graph is let go, then the ONE full collection of this job walks
+        what is left (the warm process, whatever cycles the job made),
+        then the ``runtime`` block closes and the terminal transition
+        is published.  The collection runs before the terminal event on
+        purpose: it holds the GIL, so a client waiting on the event
+        stream waits for it wherever it is put, and put here it is
+        inside this job's own ``runtime`` block and trace ring instead
+        of between two jobs' windows, in nobody's."""
         # WAL: the running record lands BEFORE any work — a restart
         # that finds it (and no terminal record) knows the job died
         # mid-run and flags it ``interrupted``.  An unappendable
@@ -1443,17 +1569,17 @@ class JobManager:
             job.finish("failed", error="journal append failed (running)")
             return
         try:
-            runtime0 = runtime_totals()
+            state, result, error = self._attempt(job)
             with TRACE.scoped(job.trace):
-                with TRACE.span("jobs.run", steps=job.steps_total):
-                    FAULTS.check("jobs.run")
-                    if job.faults is not None:
-                        job.faults.check("jobs.run")
-                    res, runner = self._execute(job)
-            result = self._result_doc(job, res, runner)
+                job.let_go()
+                collect_scheduled()
+            if state != "succeeded":
+                job.finish(state, error=error)
+                self._journal_state(job, state, error=error)  # best-effort: terminal
+                return
             # Full collections and XLA compiles / cache loads the PROCESS
-            # saw while this job ran (other jobs' included).
-            result["runtime"] = runtime_growth(runtime0)
+            # saw since this job's POST arrived (other jobs' included).
+            result["runtime"] = runtime_growth(job.runtime0)
             # WAL: result + terminal record become durable BEFORE the
             # in-memory success — a success the journal cannot vouch
             # for must not be reported (it would vanish on restart).
@@ -1465,17 +1591,28 @@ class JobManager:
                     job.finish("failed", error="journal append failed (result)")
                     return
             job.finish("succeeded", result=result)
-        except RunCancelled:
-            job.finish("cancelled")
-            self._journal_state(job, "cancelled")  # best-effort: terminal
-            logger.info("job %s cancelled", job.id)
-        except Exception as e:
-            logger.exception("job %s failed", job.id)
-            error = f"{type(e).__name__}: {e}"
-            job.finish("failed", error=error)
-            self._journal_state(job, "failed", error=error)  # best-effort
         finally:
             self._maybe_compact()
+
+    def _attempt(self, job: Job) -> "tuple[str, dict | None, str | None]":
+        """Replay the job and build its result document: ``(terminal
+        state, result, error)``.  Every way out of here leaves nothing
+        of the job's graph on a frame or a traceback — the caller lets
+        the graph go and collects before it publishes the state."""
+        try:
+            with TRACE.scoped(job.trace):
+                with TRACE.span("jobs.run", steps=job.steps_total):
+                    FAULTS.check("jobs.run")
+                    if job.faults is not None:
+                        job.faults.check("jobs.run")
+                    res, runner = self._execute(job)
+            return "succeeded", self._result_doc(job, res, runner), None
+        except RunCancelled:
+            logger.info("job %s cancelled", job.id)
+            return "cancelled", None, None
+        except Exception as e:
+            logger.exception("job %s failed", job.id)
+            return "failed", None, f"{type(e).__name__}: {e}"
 
     def _execute(self, job: Job):
         """Build the job's isolated simulator stack from its spec and

@@ -93,6 +93,7 @@ __all__ = [
     "note_xla_cache_load",
     "runtime_snapshot",
     "runtime_totals",
+    "collect_scheduled",
     "runtime_growth",
     "publish_snapshot",
     "read_fleet_snapshots",
@@ -162,7 +163,8 @@ SPAN_NAMES: tuple[str, ...] = (
     #                    (timers ``export_snap`` / ``export_encode``)
     "service.gc",  # one full (generation-2) garbage collection, on the
     #                collecting thread's plane (args generation,
-    #                collected) — recorded by this module's gc hook
+    #                collected, scheduled) — recorded by this module's
+    #                gc hook
     "writeback.push",  # live-cluster write-back push
     "kubeapi.request",  # any kube-apiserver HTTP request
     "jobs.run",  # one tenant job end-to-end on a job-plane worker
@@ -1163,14 +1165,19 @@ TRACE.configure_from_env()
 _runtime_lock = threading.Lock()
 _runtime_counters = {"xla_compiles": 0, "xla_cache_loads": 0}  # guarded-by: _runtime_lock
 _runtime_hists = {  # guarded-by: _runtime_lock
+    # Every full collection, and beside it those the job plane ran on
+    # purpose (``collect_scheduled``): the difference is what CPython's
+    # growth trigger fired inside somebody's request.
     "gc_gen2": LatencyHistogram(),
+    "gc_scheduled": LatencyHistogram(),
     "xla_compile": LatencyHistogram(),
 }
-#: Finished full collections not yet folded into ``gc_gen2``: the gc
-#: hook appends seconds here lock-free (it may run on a thread that
-#: holds ``_runtime_lock``), readers fold under the lock.
+#: Finished full collections not yet folded into the histograms: the gc
+#: hook appends ``(seconds, scheduled)`` here lock-free (it may run on a
+#: thread that holds ``_runtime_lock``), readers fold under the lock.
 _gc_done: deque = deque()
-_gc_open: "tuple | None" = None  # (t0_ns, plane, jax_ctx) of the running full collection
+_gc_open: "tuple | None" = None  # (t0_ns, plane, jax_ctx, scheduled) of the running full collection
+_gc_scheduled = False  # True while collect_scheduled() is inside gc.collect()
 
 
 def _gc_callback(phase: str, info: dict) -> None:
@@ -1188,30 +1195,51 @@ def _gc_callback(phase: str, info: dict) -> None:
             plane = None
         elif plane._jax_bridge:
             ctx = _jax_annotation("service.gc")
-        _gc_open = (time.perf_counter_ns(), plane, ctx)
+        _gc_open = (time.perf_counter_ns(), plane, ctx, _gc_scheduled)
         return
     opened, _gc_open = _gc_open, None
     if opened is None:
         return
     t1 = time.perf_counter_ns()
-    t0, plane, ctx = opened
+    t0, plane, ctx, scheduled = opened
     if ctx is not None:
         _jax_annotation_exit(ctx)
-    _gc_done.append((t1 - t0) / 1e9)
+    _gc_done.append(((t1 - t0) / 1e9, scheduled))
     if plane is not None:
         plane._defer_span(
             "service.gc", t0, t1,
-            {"generation": info["generation"], "collected": info.get("collected", 0)},
+            {
+                "generation": info["generation"],
+                "collected": info.get("collected", 0),
+                "scheduled": scheduled,
+            },
         )
 
 
 gc.callbacks.append(_gc_callback)
 
 
+def collect_scheduled() -> int:
+    """One full collection run on purpose, at a point its caller chose
+    (the job plane: at a job's end, once its graph is let go and the
+    heap is smallest — jobs/manager.py).  Counted like any full collection
+    (``gc_gen2``, the ``service.gc`` span) and once more under
+    ``gc_scheduled``.  Returns what ``gc.collect`` returns."""
+    global _gc_scheduled
+    _gc_scheduled = True
+    try:
+        return gc.collect()
+    finally:
+        _gc_scheduled = False
+
+
 def _fold_gc() -> None:  # ksimlint: lock-held(_runtime_lock)
-    hist = _runtime_hists["gc_gen2"]
+    every, scheduled = _runtime_hists["gc_gen2"], _runtime_hists["gc_scheduled"]
     while _gc_done:  # only lock holders pop
-        hist.observe(_gc_done.popleft())
+        seconds, was_scheduled = _gc_done.popleft()
+        every.observe(seconds)
+        if was_scheduled:
+            scheduled.observe(seconds)
 
 
 def note_xla_compile(seconds: float) -> None:
@@ -1247,9 +1275,12 @@ def runtime_totals() -> dict:
     with _runtime_lock:
         _fold_gc()
         gc2, xla = _runtime_hists["gc_gen2"], _runtime_hists["xla_compile"]
+        sched = _runtime_hists["gc_scheduled"]
         return {
             "gc_gen2_collections": gc2.count,
             "gc_gen2_pause_s": gc2.total,
+            "gc_scheduled_collections": sched.count,
+            "gc_scheduled_pause_s": sched.total,
             "xla_compiles": _runtime_counters["xla_compiles"],
             "xla_compile_s": xla.total,
             "xla_cache_loads": _runtime_counters["xla_cache_loads"],

@@ -16,6 +16,7 @@ from typing import Any, Iterable, Sequence
 
 from ksim_tpu.obs import TRACE
 from ksim_tpu.scheduler.service import SchedulerService
+from ksim_tpu.state import objcache
 from ksim_tpu.state.cluster import ClusterStore
 from ksim_tpu.state.resources import JSON, name_of, namespace_of
 
@@ -656,7 +657,28 @@ class ScenarioRunner:
         producer emits them, never materialized whole, with ingest
         overlapping the in-flight device dispatch.  Streaming is the
         solo fresh-run path: fleet replays and incremental resume both
-        need the full sorted step-key index up front."""
+        need the full sorted step-key index up front.
+
+        The whole replay — per-pass steps, device lowering, reconcile —
+        runs with the service's parse memo installed on this thread
+        (state/objcache.py): what the run memoizes dies with the
+        service, not with the process."""
+        with objcache.scope(self.service.memo):
+            return self._run(
+                ops,
+                lane_ops=lane_ops,
+                resume_cursor=resume_cursor,
+                resume_result=resume_result,
+            )
+
+    def _run(
+        self,
+        ops: Iterable[Operation],
+        *,
+        lane_ops: "dict[int, Iterable[Operation]] | None",
+        resume_cursor: int,
+        resume_result: "ScenarioResult | None",
+    ) -> ScenarioResult:
         if getattr(ops, "streaming_ops", False):
             if self._fleet is not None or lane_ops:
                 raise ValueError(
@@ -895,6 +917,9 @@ class ScenarioRunner:
             lane_runner._lane = idx
             lane_runner._lane_faults = planes.get(idx)
             lane_runner.service._trace_lane = idx
+            # One memo for the whole fleet: the cohort's universe is
+            # lowered once, from the leader's objects, for every lane.
+            lane_runner.service.memo = self.service.memo
             own = lane_ops.get(idx) if lane_ops else None
             if own is not None:
                 # A per-lane stream: this trajectory is divergent from

@@ -52,6 +52,7 @@ from ksim_tpu.scheduler.permit import (
 )
 from ksim_tpu.errors import NotFoundError
 from ksim_tpu.obs import TRACE
+from ksim_tpu.state import objcache
 from ksim_tpu.state.cluster import ClusterStore, WatchEvent
 from ksim_tpu.state.featurizer import FeaturizedSnapshot, Featurizer
 from ksim_tpu.state.resources import JSON, name_of, namespace_of
@@ -119,6 +120,11 @@ class SchedulerService:
         shard_mesh=None,
     ) -> None:
         self._store = store
+        # The parse memo of this store's objects (state/objcache.py):
+        # its keys are ids of the store's own copies, so it lives and
+        # dies with the service; every pass and every replay of this
+        # service installs it on its thread.
+        self.memo = objcache.Memo()
         # Preemption-eviction observers (add_eviction_listener): notified
         # with (namespace, name) right AFTER a victim's successful store
         # delete, so a live write-back can distinguish engine evictions
@@ -514,7 +520,7 @@ class SchedulerService:
     # ksimlint: lock-order(SchedulerService._pass_lock<TracePlane._lock)
     # ksimlint: lock-order(SchedulerService._pass_lock<util._xla_watch_lock)
     def _schedule_pending_inner(self) -> dict[str, str | None]:
-        with self._pass_lock:
+        with self._pass_lock, objcache.scope(self.memo):
             # The span covers the pass body only (not the lock wait):
             # queue-contention latency would otherwise masquerade as
             # scheduling latency in the histogram.  A fleet-lane service

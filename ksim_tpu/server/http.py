@@ -84,6 +84,7 @@ from ksim_tpu.obs import (
     read_fleet_traces,
     render_prometheus,
     runtime_snapshot,
+    runtime_totals,
 )
 from ksim_tpu.server.di import DIContainer
 
@@ -500,6 +501,10 @@ class _Handler(BaseHTTPRequestHandler):
         from ksim_tpu.jobs import JobLimitExceeded, JobQueueFull, JobThrottled
         from ksim_tpu.scenario.spec import ScenarioSpecError
 
+        # The job's ``runtime`` window opens here, with the POST: a full
+        # collection while the body is read and parsed is one its
+        # client waits on.
+        runtime0 = runtime_totals()
         try:
             doc = self._body()
         except Exception:
@@ -515,7 +520,9 @@ class _Handler(BaseHTTPRequestHandler):
             self._json(500, {"message": "Internal Server Error"})
             return
         try:
-            job = jm.submit(doc, tenant=self.headers.get("X-Ksim-Tenant"))
+            job = jm.submit(
+                doc, tenant=self.headers.get("X-Ksim-Tenant"), runtime0=runtime0
+            )
         except ScenarioSpecError as e:
             self._json(400, {"message": str(e)})
             return

@@ -260,11 +260,12 @@ def encode_affinity(
     T = _vpad(len(vocab.term_list))
     Q0 = len(vocab.req_list)
     reqs_token = tuple(vocab.reqs)
-    reqs_tok = objcache.intern_token(reqs_token)
+    memo = objcache.current()
+    reqs_tok = memo.intern_token(reqs_token)
 
     def node_row(node: JSON) -> np.ndarray:
-        key = ("affnode", objcache.ref_id(node), reqs_tok)
-        hit = objcache.get(key)
+        key = ("affnode", memo.ref_id(node), reqs_tok)
+        hit = memo.get(key)
         if hit is not objcache.MISS:
             return hit
         lbls = dict(labels_of(node))
@@ -276,7 +277,7 @@ def encode_affinity(
                 row[qi] = match_node_selector_requirement(r, field_lbls)
             else:
                 row[qi] = match_node_selector_requirement(req, lbls)
-        return objcache.put(key, row)
+        return memo.put(key, row)
 
     def build_node_matrix() -> np.ndarray:
         m = np.zeros((n_padded, max(Q, 1)), dtype=bool)

@@ -464,10 +464,11 @@ class Featurizer:
         # so lowered rows can be memoized on the dict's identity as long
         # as the unit scaling they were lowered with is part of the key.
         units_token = (resources, tuple(units[r] for r in resources))
+        memo = objcache.current()
 
         def lower(d: dict[str, int]) -> np.ndarray:
-            key = ("lower", objcache.ref_id(d), units_token)
-            hit = objcache.get(key)
+            key = ("lower", memo.ref_id(d), units_token)
+            hit = memo.get(key)
             if hit is not objcache.MISS:
                 return hit
             row = np.zeros(R, dtype=np.int64)
@@ -476,7 +477,7 @@ class Featurizer:
                 if i is not None:
                     u = units[r]
                     row[i] = v // u if v % u == 0 else -(-v // u)
-            return objcache.put(key, row)
+            return memo.put(key, row)
 
         N = len(nodes)
         NP, PP = bucket_size(N, self._node_bucket_min), bucket_size(P, self._pod_bucket_min)

@@ -33,6 +33,7 @@ A one-shot ``Featurizer()`` runs the same code with an empty table.
 
 from __future__ import annotations
 
+import weakref
 from dataclasses import dataclass
 from itertools import repeat
 from typing import Any, Callable, Sequence
@@ -133,7 +134,11 @@ class RowFamily:
         self.token: Any = _UNSET
         self.valid = np.zeros(cap, dtype=bool)
         self.cols = {c.name: self._alloc(c, cap, 0) for c in self.columns}
-        self._table = table
+        # Weak: the table owns its families, and a back reference would
+        # make a cycle of the table with every pod manifest it holds —
+        # a finished job's cluster would wait for the collector instead
+        # of going by reference count.
+        self._table = weakref.ref(table)
 
     @staticmethod
     def _alloc(c: Column, cap: int, width: int) -> np.ndarray:
@@ -142,7 +147,7 @@ class RowFamily:
 
     def take(self, col: str) -> np.ndarray:
         """The column's rows for the pods of the current call."""
-        return self.cols[col][self._table.idx]
+        return self.cols[col][self._table().idx]
 
     def _reset(self, token: Any, widths: "dict[str, int]") -> None:
         self.token = token

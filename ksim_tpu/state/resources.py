@@ -103,11 +103,12 @@ def pod_requests(pod: JSON, *, non_zero: bool = False) -> dict[str, int]:
     containers missing cpu/memory requests (NonMissingContainerRequests in
     upstream noderesources/resource_allocation.go calculatePodResourceRequest).
     """
-    key = ("preq", objcache.ref_id(pod), non_zero)
-    hit = objcache.get(key)
+    memo = objcache.current()
+    key = ("preq", memo.ref_id(pod), non_zero)
+    hit = memo.get(key)
     if hit is not objcache.MISS:
         return hit
-    return objcache.put(key, _pod_requests(pod, non_zero))
+    return memo.put(key, _pod_requests(pod, non_zero))
 
 
 def _pod_requests(pod: JSON, non_zero: bool) -> dict[str, int]:

@@ -342,14 +342,18 @@ def test_cancel_mid_segment_rolls_back_store():
             if done:
                 break
         assert seen, "reconcile hang never fired — wrong fault wiring"
+        # The handle is taken while the job runs: a terminal job lets
+        # its store go (docs/jobs.md "What a finished job keeps").
+        store = job.store
+        assert store is not None
         assert jm.cancel(job.id) in ("running", "cancelled")
         assert job.wait_done(60)
         state, result, err = job.result_view()
         assert state == "cancelled", (state, err)
+        assert job.store is None and job.runner is None
         # Store consistency: the rolled-back first segment left nothing.
-        assert job.store is not None
-        assert job.store.list("pods") == []
-        assert job.store.list("nodes") == []
+        assert store.list("pods") == []
+        assert store.list("nodes") == []
     finally:
         jm.shutdown(timeout=5)
 
@@ -389,6 +393,8 @@ def test_cancel_running_fleet_job_lands_at_round_boundary():
             if done:
                 break
         assert seen, "fleet job never committed a round"
+        runner = job.runner  # taken while it runs: a terminal job lets it go
+        assert runner is not None
         assert jm.cancel(job.id) in ("running", "cancelled")
         assert job.wait_done(120)
         state = job.status()["state"]
@@ -396,8 +402,8 @@ def test_cancel_running_fleet_job_lands_at_round_boundary():
         # the flag flipped — the boundary semantics are pinned
         # deterministically at the runner layer (test_replay_device).
         assert state in ("cancelled", "succeeded")
-        if state == "cancelled" and job.runner.fleet_lanes:
-            for ln in job.runner.fleet_lanes:
+        if state == "cancelled" and runner.fleet_lanes:
+            for ln in runner.fleet_lanes:
                 assert ln.runner.store._txn is None
     finally:
         jm.shutdown(timeout=5)

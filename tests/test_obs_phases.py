@@ -197,8 +197,9 @@ def test_job_result_runtime_block_and_device_wait(served_job):
     result, trace = served_job
     runtime = result["runtime"]
     assert set(runtime) == {
-        "gc_gen2_collections", "gc_gen2_pause_s", "xla_compiles",
-        "xla_compile_s", "xla_cache_loads",
+        "gc_gen2_collections", "gc_gen2_pause_s",
+        "gc_scheduled_collections", "gc_scheduled_pause_s",
+        "xla_compiles", "xla_compile_s", "xla_cache_loads",
     }
     assert all(v >= 0 for v in runtime.values())
     wait = result["replay"]["device_wait_s"]
