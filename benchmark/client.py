@@ -33,6 +33,11 @@ class BenchFailure(Exception):
     result."""
 
 
+class CannotSucceed(BenchFailure):
+    """What the run has seen already breaks a guarantee of the configuration:
+    no later request can make it correct, so it ends now."""
+
+
 class Server:
     """One server child (``server_child.py``) on a free port, cwd = the
     checkout root."""
@@ -43,6 +48,9 @@ class Server:
             self.port = s.getsockname()[1]
         self._log = tempfile.TemporaryFile()
         self.late_s = 0.0  # worst probe overrun since reset_lateness()
+        #: Names of trace events on a job's stream that end the run at once
+        #: (``run.py`` sets them from the configuration's guarantees).
+        self.fatal_events: tuple = ()
         self.proc = subprocess.Popen(
             [sys.executable, os.path.join(HERE, "server_child.py"), platform, str(chips),
              "--port", str(self.port)],
@@ -131,6 +139,11 @@ class Server:
                 if ev.get("event") == "state" and ev.get("state") in TERMINAL_STATES:
                     state = ev["state"]
                     break
+                if ev.get("event") == "trace" and ev.get("name") in self.fatal_events:
+                    self.request("DELETE", f"/api/v1/jobs/{job['id']}", timeout=30.0)
+                    raise CannotSucceed(
+                        f"job {job['id']} emitted {ev['name']} after "
+                        f"{time.monotonic() - t0:.1f} s: {json.dumps(ev.get('args') or {})}")
         except (OSError, http.client.HTTPException) as e:
             self.alive()
             raise BenchFailure(f"event stream of {job['id']} broke: {e}") from e

@@ -1,8 +1,8 @@
 #!/usr/bin/env python3
 """Where every pod stands at a job's end, in 64 characters: the digest a
 job's result document carries as ``replay.placements_digest``, computed here
-from the plain sequential replay (``replay.py``) and from nothing of the
-program.  A configuration that lists it under ``guarantees.replay_equals``
+from the cell's plain reference (``replay.py``, or the one its configuration
+names) and from nothing of the program.  A configuration that lists it under ``guarantees.replay_equals``
 holds every job's placements to the reference's, pod by pod — the counts
 alone do not show a pod that landed elsewhere (in ``burst-5k`` the control at
 bfloat16 moves 8,941 of 10,000 pods and none of the counts).
@@ -32,8 +32,6 @@ def digest(placements: dict) -> str:
 
 
 def main(argv=None) -> int:
-    import generators
-    import replay
     import run as harness
 
     ap = argparse.ArgumentParser()
@@ -43,8 +41,9 @@ def main(argv=None) -> int:
     c = harness.load_cell(harness.load("BENCHMARK.json"), args.workload, args.rehearsal)
     inputs = harness.build_inputs(c["config"], c["traffic"], 0)
     cap = c["config"]["simulator"].get("maxPodsPerPass")
-    exact = replay.replay(inputs["operations"], max_pods_per_pass=cap)["placements"]
-    control = replay.replay(inputs["operations"], max_pods_per_pass=cap, precision="bf16")["placements"]
+    replay = c["reference"].replay
+    exact = replay(inputs["operations"], max_pods_per_pass=cap)["placements"]
+    control = replay(inputs["operations"], max_pods_per_pass=cap, precision="bf16")["placements"]
     print(json.dumps({
         "workload": args.workload, "rehearsal": args.rehearsal, "pods": len(exact),
         "placements_digest": digest(exact), "control_digest": digest(control),

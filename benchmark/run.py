@@ -3,19 +3,26 @@
 
     python3 benchmark/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
-Generates the cell's inputs from ``--seed`` (``generators.py``), starts ONE
+Generates the cell's inputs from ``--seed`` (the generator kind the
+configuration names, ``kinds/``), starts ONE
 server child — the only process that touches JAX — warms the cell's own
 request up until a repeat adds nothing to the compile cache (all of which is
 ``setup_s``), drives the request in a closed loop for ``--seconds``
 (``window.py``), stops the server, checks the documents the window produced
-(``checks.py``; the plain references ``replay.py`` and ``reference.py``) and prints, as the last line of stdout,
+(``checks.py``; the plain references: ``replay.py`` or the one the
+configuration names for jobs, ``reference.py`` for exports) and prints, as the last line of stdout,
 one JSON object: ``correct``, ``attempted``, ``failed``, ``metrics``,
 ``device`` and, traced, ``breakdown``.  Earlier lines carry the individual
 request times, the compile-cache entry counts around the window, how late
 the client's probes ran and every number compared beside its limit.
 
 Everything that belongs to one configuration, traffic mix, cell or metric
-is a data file found by its name (README.md); this file names none of them.
+is a data file found by its name (README.md), and so are the generator kinds
+and the plain references of jobs; this file names none of them.  A run that
+cannot succeed — a generator kind or reference no file provides, a job that
+leaves the device path where the configuration guarantees every step on it, a
+warm-up document without a key the guarantees name — ends at once, non-zero,
+with the reason and no result line.
 ``--rehearsal`` runs the same code at the sizes the data files give under
 ``"rehearsal"`` with the server pinned to the CPU, and prints counts only.
 """
@@ -41,9 +48,9 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 sys.path.insert(0, HERE)
 
+import byname
 import checks
 import client
-import generators
 import readers
 import reference
 import replay
@@ -52,6 +59,9 @@ import xplane
 
 READY_CAP_S = 240.0
 REQUEST_CAP_S = 1100.0
+#: The event a job's stream carries when a step leaves the device path
+#: (``ksim_tpu/engine/replay.py`` ``_reject``, with the reason).
+FALLBACK_EVENT = "replay.fallback"
 #: The program's host spans (ksim_tpu/obs.py SPAN_NAMES) that the
 #: ``KSIM_TRACE_JAX=1`` bridge writes into the profiler's trace.
 SPAN_PREFIXES = ("replay.", "service.", "jobs.", "runner.", "engine.", "scenario.")
@@ -83,23 +93,17 @@ def cache_entries() -> int:
 
 
 def build_inputs(config: dict, traffic: dict, seed: int) -> dict:
-    gen = config["generator"]
-    base = gen["base_seed"]
-    if config["request"] == "job":
-        n_events = traffic.get("events", gen["n_events"])
-        ops = generators.shuffle_operations(seed, generators.churn_operations(
-            base, n_nodes=gen["n_nodes"], n_events=n_events, ops_per_step=gen["ops_per_step"],
-            pod_create_frac=gen.get("pod_create_frac", 0.65),
-            pod_delete_frac=gen.get("pod_delete_frac", 0.25)))
-        body = {"spec": {"simulator": config["simulator"], "scenario": {"operations": ops}}}
-        return {"body": json.dumps(body).encode(), "units": n_events, "operations": ops,
-                "steps": len({op["step"] for op in ops})}
-    nodes, pods = generators.shuffle_cluster(seed, *generators.random_cluster(
-        base, gen["n_nodes"], gen["n_pods"], bound_fraction=gen.get("bound_fraction", 0.0)))
-    snapshot = {"nodes": nodes, "pods": pods, "pvs": [], "pvcs": [], "storageClasses": [],
-                "priorityClasses": [], "namespaces": [], "schedulerConfig": None}
-    return {"body": json.dumps(snapshot).encode(), "units": len(pods),
-            "nodes": nodes, "pods": pods}
+    """``body``, ``units`` and what the checks need, from the generator kind
+    the configuration names (``kinds/<kind>.py``)."""
+    kind = (config.get("generator") or {}).get("kind")
+    return byname.load("kinds", kind, "inputs").inputs(config, traffic, seed)
+
+
+def reference_of(config: dict):
+    """The module whose ``replay`` judges a job configuration's jobs: the
+    ``references/<name>.py`` it names under ``reference``, else ``replay.py``."""
+    name = config.get("reference")
+    return replay if name is None else byname.load("references", name, "replay")
 
 
 # -- the traced slice ---------------------------------------------------------
@@ -162,6 +166,59 @@ class Slice:
         return found[0] if found else None
 
 
+class StallWatch(threading.Thread):
+    """Sleeps ``STEP_S`` at a time through the window and keeps the wake-ups
+    that came ``LATE_S`` late or more, as ``[seconds into the window, seconds
+    late]``.  A host that held every process shows here; a server that
+    stalled alone does not (``slow_requests`` then says where).  Diagnostic
+    only: no metric reads it."""
+
+    STEP_S, LATE_S = 0.05, 0.25
+
+    def __init__(self) -> None:
+        super().__init__(daemon=True)
+        self.late: list = []
+        self._halt = threading.Event()
+
+    def run(self) -> None:
+        start = due = time.monotonic()
+        while not self._halt.is_set():
+            due += self.STEP_S
+            time.sleep(max(due - time.monotonic(), 0.0))
+            over = time.monotonic() - due
+            if over >= self.LATE_S:
+                self.late.append([round(due - start, 2), round(over, 3)])
+                due = time.monotonic()
+
+    def halt(self) -> list:
+        self._halt.set()
+        self.join()
+        return self.late
+
+
+def slow_requests(counted: list, is_job: bool, factor: float = 1.5, most: int = 6) -> list:
+    """The counted requests that took ``factor`` times the median or more,
+    with what the server's own document says of them (a job's phases, its
+    run's wall and its runtime account): where a stall landed."""
+    if not counted:
+        return []
+    cut = factor * window.median_wall_s(counted)
+    out = []
+    for i, r in enumerate(counted):
+        if r["wall_s"] >= cut:
+            row = {"index": i, "wall_s": round(r["wall_s"], 4)}
+            if is_job:
+                doc = r["doc"]
+                row["server_wall_s"] = (doc.get("result") or {}).get("wallSeconds")
+                row["phases"] = {k: round(v, 3) if isinstance(v, float) else v
+                                 for k, v in (doc.get("phases") or {}).items()}
+                row["runtime"] = doc.get("runtime")
+            else:
+                row["phases_s"] = {k: round(v, 3) for k, v in r["phases_s"].items()}
+            out.append(row)
+    return out[:most]
+
+
 # -- the parts of a run -------------------------------------------------------
 
 
@@ -177,8 +234,10 @@ def load_cell(bench: dict, name: str, rehearsal: bool) -> dict:
         config = overlay(config, config.get("rehearsal"))
         traffic = overlay(traffic, traffic.get("rehearsal"))
         locks = cell_doc.get("rehearsal_locks") or {}
+    is_job = config["request"] == "job"
     return {"cell": cell, "config": config, "traffic": traffic, "locks": locks,
-            "is_job": config["request"] == "job", "guarantees": config["guarantees"],
+            "is_job": is_job, "guarantees": config["guarantees"],
+            "reference": reference_of(config) if is_job else None,
             "platform": "cpu" if rehearsal else "tpu", "rehearsal": rehearsal}
 
 
@@ -194,7 +253,10 @@ def start_server(c: dict, trace: bool) -> "tuple[client.Server, str]":
     if trace:
         env.update(KSIM_TRACE="1", KSIM_TRACE_JAX="1",
                    BENCH_PROFILE_DIR=os.path.join(work, "profile"))
-    return client.Server(ROOT, env, c["platform"], c["cell"]["chips"]), work
+    server = client.Server(ROOT, env, c["platform"], c["cell"]["chips"])
+    if c["guarantees"].get("every_step_on_device"):
+        server.fatal_events = (FALLBACK_EVENT,)
+    return server, work
 
 
 def device_report(work: str) -> dict:
@@ -223,6 +285,8 @@ class Driver:
             else:
                 rec = self.server.run_import(self.inputs["body"], self.inputs["units"], deadline)
                 rec["failed"] = False
+        except client.CannotSucceed:
+            raise
         except client.BenchFailure as e:
             self.server.alive()  # a dead server ends the run; a refused request is a failure
             log(f"request failed: {e}")
@@ -265,6 +329,13 @@ class Driver:
             rec.pop("raw", None)
             if rec.get("failed"):
                 raise client.BenchFailure("a warm-up request failed")
+            if self.c["is_job"]:
+                have = rec["doc"].get("replay") or {}
+                lacks = sorted(set(self.c["guarantees"].get("replay_equals") or ()) - set(have))
+                if lacks:
+                    raise client.CannotSucceed(
+                        f"the warm-up job's result has no replay.{', replay.'.join(lacks)}, "
+                        "which the configuration's guarantees name")
             now_entries = cache_entries()
             log(f"warm-up {i}: {rec['wall_s']:.3f} s, compile-cache entries {entries} -> {now_entries}")
             warm.append(rec)
@@ -277,19 +348,27 @@ class Driver:
         self.between()
         self.server.late_s = 0.0
         entries = cache_entries()
-        win = window.run_window(seconds, self.windowed_request, self.between)
+        watch = StallWatch()
+        watch.start()
+        try:
+            win = window.run_window(seconds, self.windowed_request, self.between)
+        finally:
+            win_late = watch.halt()
         win["cache_entries"] = (entries, cache_entries())
+        win["client_late_wakeups"] = win_late
         return win
 
 
 def replayed_counts(c: dict, inputs: dict, precision: str = "exact") -> "list | None":
-    """What the plain sequential replay (``replay.py``) of the submitted
-    operations counts: events applied, pods scheduled, unschedulable
-    attempts.  ``None`` — which no job equals — where it does not cover them."""
+    """What the cell's plain reference (``replay.py``, or the one its
+    configuration names) counts over the submitted operations: events
+    applied, pods scheduled, unschedulable attempts.  ``None`` — which no job
+    equals — where it does not cover them."""
     started = time.monotonic()
     try:
-        got = replay.replay(inputs["operations"], precision=precision,
-                            max_pods_per_pass=c["config"]["simulator"].get("maxPodsPerPass"))
+        got = c["reference"].replay(
+            inputs["operations"], precision=precision,
+            max_pods_per_pass=c["config"]["simulator"].get("maxPodsPerPass"))
     except replay.NotCovered as e:
         log(f"reference replay: not covered: {e}")
         return None
@@ -311,7 +390,7 @@ def judge(c: dict, inputs: dict, seed: int, win: dict, warm: list, reservoir: li
     if c["is_job"]:
         # Every seed submits the base stream's scheduling problem (the seed
         # orders arrivals inside a step only), so its lock holds at every seed.
-        lock = c["locks"].get(str(c["config"]["generator"]["base_seed"]))
+        lock = c["locks"].get(str(c["config"]["generator"].get("base_seed", 0)))
         want = replayed_counts(c, inputs)
         for r in counted:
             out += checks.check_job(r["doc"], guarantees, steps=inputs["steps"], lock=lock)
@@ -348,16 +427,29 @@ def reference_comparisons(nodes: list, exported_pods: list, sample: set, guarant
     ]
 
 
-def print_comparisons(comparisons: list) -> bool:
-    """One line per distinct comparison; ``correct`` is their conjunction."""
-    seen = set()
+def print_comparisons(comparisons: list) -> "tuple[bool, list]":
+    """One line per distinct comparison; ``correct`` is their conjunction.
+    Also returns the distinct ones as ``[name, value, limit, passed]``: the
+    result line's last key and the run's last lines on stderr."""
+    seen, distinct = set(), []
     for cmp_ in comparisons:
         key = (cmp_["name"], json.dumps(cmp_["value"], sort_keys=True), cmp_["ok"])
         if key not in seen:
             seen.add(key)
             print(json.dumps({"compared": cmp_["name"], "value": cmp_["value"],
                               "limit": cmp_["limit"], "ok": cmp_["ok"]}))
-    return all(cmp_["ok"] for cmp_ in comparisons)
+            distinct.append([cmp_["name"], cmp_["value"], cmp_["limit"], cmp_["ok"]])
+    distinct.sort(key=lambda row: not row[3])  # stable: the failed ones last
+    return all(cmp_["ok"] for cmp_ in comparisons), distinct
+
+
+def log_compared(compared: list) -> None:
+    """Every number compared beside its limit, failed ones last: the run's
+    last lines on stderr."""
+    for name, value, limit, ok in compared:
+        print(f"compared {name}: {json.dumps(value)} limit {json.dumps(limit)} "
+              f"{'ok' if ok else 'NOT OK'}", file=sys.stderr)
+    sys.stderr.flush()
 
 
 def reduce_slice(trace_file: str, profile_dir: str, counted: list) -> dict:
@@ -400,9 +492,14 @@ def run(args) -> int:
     if args.workload not in {w["name"] for w in bench["workloads"]}:
         log(f"no workload {args.workload!r} in BENCHMARK.json")
         return 2
-    c = load_cell(bench, args.workload, args.rehearsal)
+    seconds = bench["run_seconds"] if args.seconds is None else args.seconds
+    try:
+        c = load_cell(bench, args.workload, args.rehearsal)
+        inputs = build_inputs(c["config"], c["traffic"], args.seed)
+    except byname.Unknown as e:
+        log(f"FAILED before the server starts: {e}")
+        return 2
     cell = c["cell"]
-    inputs = build_inputs(c["config"], c["traffic"], args.seed)
     log(f"{cell['name']}: inputs from seed {args.seed}: {len(inputs['body'])} bytes, "
         f"{inputs['units']} units per request")
     server, work = start_server(c, bool(args.trace))
@@ -414,16 +511,20 @@ def run(args) -> int:
 
     signal.signal(signal.SIGTERM, on_term)
     try:
-        got = drive(server, c, inputs, args.seed, args.seconds, slice_)
+        got = drive(server, c, inputs, args.seed, seconds, slice_)
         trace_file = slice_.collect(time.monotonic() + 300) if slice_ is not None else None
     except (client.BenchFailure, OSError, ValueError, KeyError) as e:
+        if slice_ is not None:
+            slice_.stop()
         log(f"FAILED: {type(e).__name__}: {e}")
         log("server output, last 40 lines:\n" + server.log_tail())
         server.stop()
         shutil.rmtree(work, ignore_errors=True)
         return 1
     warm, win, setup_s = got["warm"], got["win"], got["setup_s"]
+    log("window closed" + (f"; trace file {'in hand' if trace_file else 'missing'}" if slice_ else ""))
     server.stop()
+    log("server stopped; judging")
     device = device_report(work)
     # The server child is the only child this process has waited for.
     rss_mb = resource.getrusage(resource.RUSAGE_CHILDREN).ru_maxrss / 1024.0
@@ -449,8 +550,10 @@ def run(args) -> int:
     print(json.dumps({"compile_cache_entries": {
         "window_start": win["cache_entries"][0], "window_end": win["cache_entries"][1],
         "added_in_window": win["cache_entries"][1] - win["cache_entries"][0]},
-        "client_worst_probe_overrun_s": not_timed or round(server.late_s, 6)}))
-    correct = print_comparisons(
+        "client_worst_probe_overrun_s": not_timed or round(server.late_s, 6),
+        "client_late_wakeups": not_timed or win["client_late_wakeups"][:20],
+        "slow_requests": not_timed or slow_requests(counted, c["is_job"])}))
+    correct, compared = print_comparisons(
         judge(c, inputs, args.seed, win, warm, got["reservoir"], device, got["ident"]))
 
     trace = None
@@ -475,6 +578,7 @@ def run(args) -> int:
         # Counts only: nothing here may read as a chip run.
         counts = {k: v["value"] for k, v in metrics.items()
                   if kinds[k] in ("job_result", "metrics_counter")}
+        log_compared(compared)
         print(json.dumps({
             "rehearsal": True, "platform": device.get("platform"), "correct": correct,
             "attempted": win["attempted"], "failed": win["failed"], "counts": counts,
@@ -489,9 +593,12 @@ def run(args) -> int:
     if args.trace:
         if not trace or trace["busy_s"] <= 0:
             log("FAILED: the traced slice holds no device operation")
+            log_compared(compared)
             return 1
         dev["busy_s"], dev["window_s"] = trace["busy_s"], trace["window_s"]
         line["breakdown"] = {"device_ops": trace["device_ops"], "idle_gaps": trace["idle_gaps"]}
+    line["compared"] = compared
+    log_compared(compared)
     print(json.dumps(line), flush=True)
     return 0
 
@@ -500,7 +607,8 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--seconds", type=float, default=10.0)
+    ap.add_argument("--seconds", type=float, default=None,
+                    help="the measured window; default: BENCHMARK.json's run_seconds")
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
     ap.add_argument("--rehearsal", action="store_true",
                     help="tiny sizes, server on JAX_PLATFORMS=cpu, counts only (debugging)")

@@ -1,10 +1,13 @@
 """A whole run of the harness (rehearsal sizes, so no chip is looked for)
 with the timed path broken underneath: an answer altered where the client
 receives it.  ``correct`` has to come out false — and true when nothing is
-broken."""
+broken.  A run that cannot succeed (a job that leaves the device path, a
+result without a key the guarantees name) ends at once, non-zero, with no
+result line."""
 
 import json
 import re
+import time
 
 import client
 import run as harness
@@ -100,3 +103,55 @@ def test_a_job_with_other_counts_than_the_replay_is_caught(monkeypatch, capsys):
     assert rc == 1 and doc["correct"] is False
     failed = [json.loads(ln)["compared"] for ln in lines if '"ok": false' in ln]
     assert failed == ["job.counts_vs_reference_replay"]
+
+
+def test_a_job_that_falls_back_ends_the_run_at_once(monkeypatch, capsys):
+    """A stream the lowering refuses (a pod with a host port in step 3): the
+    job's event stream says ``replay.fallback`` with the reason, the client
+    cancels the job and the run ends non-zero within seconds, before any
+    warm-up has finished, with the reason on stderr and nothing on stdout."""
+    real = harness.build_inputs
+
+    def refused(config, traffic, seed):
+        inputs = real(config, traffic, seed)
+        pod = next(op for op in inputs["operations"] if op["step"] == 3
+                   and op.get("createOperation", {}).get("object", {}).get("kind") == "Pod")
+        pod["createOperation"]["object"]["spec"]["containers"][0]["ports"] = [{"hostPort": 8080}]
+        body = json.loads(inputs["body"])
+        body["spec"]["scenario"]["operations"] = inputs["operations"]
+        return dict(inputs, body=json.dumps(body).encode())
+
+    monkeypatch.setattr(harness, "build_inputs", refused)
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    started = time.monotonic()
+    rc = harness.main(["--workload", "churn-2k_prefix6k", "--seed", "3", "--seconds", "1",
+                       "--trace", "0", "--rehearsal"])
+    took = time.monotonic() - started
+    out = capsys.readouterr()
+    assert rc != 0 and out.out == ""
+    assert "CannotSucceed" in out.err and "replay.fallback" in out.err and "host_ports" in out.err
+    after = float(re.search(r"emitted replay.fallback after ([0-9.]+) s", out.err).group(1))
+    assert after < 30 and took < 60   # the server's start and its first compile, not a request cap
+
+
+def test_a_result_without_a_guaranteed_key_ends_the_run_at_the_first_warm_up(monkeypatch, capsys):
+    """A program that does not give a guarantee the configuration names (a
+    parent that lacks a new one): the first warm-up's document shows it, and
+    the run ends there instead of measuring what cannot be correct."""
+    real = client.Server.run_job
+    calls = []
+
+    def altered(self, body, deadline):
+        rec = real(self, body, deadline)
+        calls.append(1)
+        del rec["doc"]["replay"]["unsupported"]
+        return rec
+
+    monkeypatch.setattr(client.Server, "run_job", altered)
+    rc, _, lines = None, None, None
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    rc = harness.main(["--workload", "churn-2k_prefix6k", "--seed", "0", "--seconds", "1",
+                       "--trace", "0", "--rehearsal"])
+    out = capsys.readouterr()
+    assert rc != 0 and out.out == "" and len(calls) == 1
+    assert "CannotSucceed" in out.err and "replay.unsupported" in out.err

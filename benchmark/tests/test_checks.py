@@ -5,7 +5,7 @@ import copy
 import json
 
 import checks
-import generators
+from kinds import cluster
 import reference
 
 GUARANTEES = {
@@ -59,7 +59,7 @@ def fabricate_export(seed, n_nodes=12, n_pods=40, precision="exact"):
     """An export as a correct server would write it: pods walked in name
     order, each bound to its best node, annotated with the reference's own
     verdicts and scores."""
-    nodes, pods = generators.random_cluster(seed, n_nodes, n_pods)
+    nodes, pods = cluster.random_cluster(seed, n_nodes, n_pods)
     state = {n["metadata"]["name"]: reference.NodeState(n) for n in nodes}
     out = []
     for rv, pod in enumerate(pods, start=100):

@@ -17,6 +17,7 @@ import generators
 import placements
 import replay
 import run as harness
+from kinds import churn
 
 CELL = "burst-5k_onestep"
 
@@ -30,7 +31,7 @@ def operations(rehearsal: bool) -> list:
     c = cell(rehearsal)
     gen = c["config"]["generator"]
     assert "maxPodsPerPass" not in c["config"]["simulator"]
-    return generators.churn_operations(
+    return churn.churn_operations(
         gen["base_seed"], n_nodes=gen["n_nodes"], n_events=c["traffic"]["events"],
         ops_per_step=gen["ops_per_step"], pod_create_frac=gen["pod_create_frac"],
         pod_delete_frac=gen["pod_delete_frac"])
