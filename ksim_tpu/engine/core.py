@@ -518,6 +518,12 @@ class _Program:
     def _eval_filters(self, state: NodeStateView, pod: PodView, aux: dict, carries: dict):
         reason_bits = []
         filter_ok = state.valid
+        if "node_mask" in aux:
+            # Nodes a caller ruled out beforehand (``Engine(node_mask=)``:
+            # the per-pass path's verdict with the nominated pods counted
+            # in).  Scores normalize over what is left, as over any
+            # feasible set.
+            filter_ok = filter_ok & aux["node_mask"]
         for sp in self.plugins:
             if not sp.filter_enabled:
                 continue
@@ -783,8 +789,13 @@ class Engine:
         record: str = "full",  # full | final | selection
         sampling_k: int | None = None,
         metrics=None,
+        node_mask: "np.ndarray | None" = None,
     ) -> None:
-        """``sampling_k`` enables percentageOfNodesToScore emulation on
+        """``node_mask`` (bool over the padded node axis) rules nodes out
+        before any filter runs, for every pod of the batch; the recorded
+        reason bits stay the filters' own.
+
+        ``sampling_k`` enables percentageOfNodesToScore emulation on
         the ``schedule`` path: each pod's cycle visits nodes from a
         rotating start index and stops after finding K feasible — only
         visited nodes are scored/recorded, exactly upstream's adaptive
@@ -834,6 +845,11 @@ class Engine:
             node_dev, pod_dev, self._aux = _pack_tree_to_device(
                 (node_host, pod_host, aux_host)
             )
+        if node_mask is not None:
+            # Joins the aux tree after the pack: a key of its own, so a
+            # pass without a mask runs the program it always ran.
+            self._aux = dict(self._aux, node_mask=jnp.asarray(node_mask, bool))
+            self._aux_axes = dict(self._aux_axes, node_mask="node")
         self._node_state = NodeStateView(**node_dev)
         self._pods = PodBatch(**pod_dev)
 

@@ -46,14 +46,27 @@ byte-identically.  The design choices that guarantee it:
 
 Two former fallback classes are now lowered instead (round 7):
 
-- **DefaultPreemption** runs ON-DEVICE: the per-candidate fit re-check
-  and the MoreImportantPod reprieve loop are masked tensor ops over the
-  universe (bounded candidate scan + ``lax.fori_loop`` reprieve; the
-  pickOneNode narrowing cascade is one lexicographic argmin), against a
-  LIVE mid-pass state that tracks this pass's binds plus earlier
-  victims — exactly the store view the per-pass dry-run reads.  Bounds
-  exceeded -> per-step overflow flag -> segment discarded before any
-  store effect.
+- **DefaultPreemption** runs ON-DEVICE, as v1.30 defines it
+  (scheduler/preemption.py has the definitions and the conventions):
+  every pod of a pass is evaluated against the state as its
+  predecessors left it — their binds, their victims gone, their
+  nominations — with the nominated pods of its priority or above
+  counted into a second filter run, and its own nominated node tried
+  first; a pod that fits nowhere searches for victims on the spot.  The
+  search evaluates the preemptor's candidates TOGETHER where every
+  filter verdict of the window is node-local (no DoNotSchedule spread
+  constraint and no required pod (anti-)affinity in the universe: the
+  lowering reads that off the lowered tensors, ``_SegmentStatics.
+  local``): one sort groups the lower-priority pods by node in
+  MoreImportantPod order, the filter chain runs over the whole node
+  axis for "everything lower gone" and once per reprieve rank, and
+  pickOneNode is one lexicographic argmin over the first
+  ``candidate_count(live nodes)`` candidates in name order.  A window
+  that holds such a constraint walks the candidates in name order
+  instead, one exact hypothetical state a check, until upstream's
+  count is found.  The only bound is ``VMAX`` lower-priority pods a
+  node: exceeded -> per-step overflow flag -> segment discarded before
+  any store effect.
 - **record="full"** streams the per-attempt reason-bit / raw / final
   score tensors out of the scan as stacked segment outputs (shorter
   fixed K to bound device memory); the host decodes them into the exact
@@ -124,7 +137,7 @@ FALLBACK_REASONS: frozenset[str] = frozenset(
         "drain_without_requeue", "duplicate_pod_keys",
         # lowering-time guards
         "interpod_local_mismatch", "preemption_filter_set",
-        "preemption_bits_width", "full_record_bytes",
+        "preemption_bits_width", "full_record_bytes", "priority_levels",
         # post-dispatch validation discards
         "featurize_prediction", "preemption_overflow",
         # degradation ladder (docs/churn_floor.md round 8)
@@ -150,13 +163,19 @@ SEGMENT_STEPS = int(os.environ.get("KSIM_REPLAY_K", "16"))
 FULL_SEGMENT_STEPS = int(os.environ.get("KSIM_REPLAY_FULL_K", "4"))
 FULL_RECORD_BYTES = int(os.environ.get("KSIM_REPLAY_FULL_BYTES", str(1 << 30)))
 
-# On-device preemption victim-search bounds (static shapes for the
-# candidate scan and the unrolled reprieve loop).  A step whose search
-# would exceed either bound sets an overflow flag and the whole segment
-# is DISCARDED before any store effect ("preemption_overflow" fallback)
-# — bounded-exact, never approximate.
-PREEMPT_CANDIDATES = int(os.environ.get("KSIM_REPLAY_CMAX", "16"))
+# On-device preemption: the victims-per-node bound (a static shape of the
+# victim table).  A search that meets a node holding more pods of a lower
+# priority sets an overflow flag and the whole segment is DISCARDED
+# before any store effect ("preemption_overflow" fallback) —
+# bounded-exact, never approximate.  The candidate bound is upstream's
+# own, ``candidate_count(live nodes)``, derived per step by the lowering.
 PREEMPT_VICTIMS = int(os.environ.get("KSIM_REPLAY_VMAX", "8"))
+
+# Distinct pod priorities a window's universe may hold with the victim
+# search on: the nominated pods' per-node load is carried per level
+# ([N, levels, ...]).  More makes the window fall back
+# ("priority_levels"); real clusters hold a handful of classes.
+PREEMPT_LEVELS_MAX = 32
 
 # Failure containment (docs/churn_floor.md "Failure containment"):
 # each segment dispatch runs on a worker thread bounded by the watchdog
@@ -182,8 +201,8 @@ def _replay_tp() -> int:
     """``KSIM_REPLAY_TP``: lay every node-axis tensor of the segment
     program over a ``make_mesh(tp, dp=1)`` node mesh (round 17).  1 (the
     default) keeps the single-device layout.  The byte bound
-    (``KSIM_REPLAY_FULL_BYTES``) and the preemption search bounds
-    (``KSIM_REPLAY_CMAX``/``KSIM_REPLAY_VMAX``) are PER-SHARD budgets —
+    (``KSIM_REPLAY_FULL_BYTES``) and the preemption victim bound
+    (``KSIM_REPLAY_VMAX``) are PER-SHARD budgets —
     record="full" and bounded-exact preemption scale with the mesh.
     Read at ReplayDriver construction; an explicit service ``shard_mesh``
     takes precedence over the env knob."""
@@ -440,8 +459,16 @@ class _SegmentStatics:
     n_dom: int  # inter-pod padded domain count (segment id space)
     record: str = "selection"  # "selection" | "full" (streamed results)
     preempt: bool = False  # on-device DefaultPreemption victim search
-    c_max: int = PREEMPT_CANDIDATES  # candidate-node scan bound (per shard)
-    v_max: int = PREEMPT_VICTIMS  # victims-per-candidate bound (per shard)
+    v_max: int = PREEMPT_VICTIMS  # lower-priority pods a node (per shard)
+    # With ``preempt``: the universe's distinct priorities (bucketed), the
+    # level axis of the nominated pods' carried load; and whether every
+    # filter verdict of the window is node-local, which lets the victim
+    # search evaluate all candidates at once over the node axis.
+    n_lvl: int = 1
+    local: bool = False
+    # Whether any pod of the universe matches or carries an inter-pod
+    # term: where none does, a victim's going cannot move the domain view.
+    ip_terms: bool = True
     tp: int = 1  # node-axis mesh width (round 17 sharded replay)
     # Round 19: the vmap axis name the fleet program maps lanes over, or
     # None for a solo program.  With it set, the preemption-search gate
@@ -521,7 +548,11 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
     # the widest block <= SCAN_UNROLL that divides the queue, so a block
     # never reaches past slot q - 1 (every bucket_size rung is a
     # multiple of 4, the default).
-    blk = math.gcd(SCAN_UNROLL, st.q)
+    # The victim-search variant runs one slot a trip: its slot body holds
+    # the whole search, and four copies of it quadruple the compile
+    # (243 s against ~1 min on the chip, PR 32) for a loop overhead that
+    # is small beside a slot of that variant.
+    blk = 1 if st.preempt else math.gcd(SCAN_UNROLL, st.q)
     max_backoff, flush_cap = _backoff_constants()
     # _record_attempts' delay is min(2^(attempts_new-1), MAX) — computed
     # as a shift with the exponent clamped where the cap saturates.
@@ -542,14 +573,91 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
     # Raw scores keep the width the mode computes them in (core.py
     # records them unconverted): int64 in exact (x64) mode, else int32.
     raw_dtype = jax.dtypes.canonicalize_dtype(int)
-    # Effective search bounds: the configured statics are PER-SHARD
-    # budgets (round 17) — multiplied by the mesh width, then clamped to
-    # the padded axes (top_k needs k <= axis; small universes can't
-    # overflow a bound wider than themselves anyway).  At tp=1 this is
-    # the historical global bound; bounded-exact semantics are unchanged
-    # (overflow still discards the segment before any store effect).
-    c_eff = min(st.c_max * st.tp, N)
+    # The effective victim bound: the configured static is a PER-SHARD
+    # budget (round 17) — multiplied by the mesh width, then clamped to
+    # the padded pod axis.  Bounded-exact: a node that holds more pods
+    # of a lower priority discards the segment before any store effect.
     v_eff = min(st.v_max * st.tp, P)
+    L = st.n_lvl
+    # The nominated pods' per-node load, carried per priority level
+    # (``nm_*[n, l]`` = the sum over the pods nominated to node n whose
+    # level is l or above, i.e. what a pod of level l has to count in):
+    # the state keys, and the per-pod rows each sums (``nm_cnt`` is a
+    # head count and has none).  A
+    # window whose verdicts are all node-local (st.local) never reads
+    # the spread / inter-pod terms of a nominee, and carries none.
+    nm_keys = ("nm_req", "nm_cnt")
+    nm_rows = {"nm_req": prow["requests"]}
+    if st.preempt and not st.local:
+        nm_keys += ("nm_sel", "nm_qm", "nm_eat", "nm_vw")
+        nm_rows.update(nm_sel=sel_rows, nm_qm=qm_rows, nm_eat=eat_rows, nm_vw=vw_rows)
+
+    if st.preempt:
+        # What a search reads of a node's lower-priority pods, side by
+        # side, so that the victim table costs ONE gather of pod rows (a
+        # gather of 6,144 x 8 rows is ~0.3 ms on the chip, and there were
+        # three): requests, priority, start rank.
+        R = prow["requests"].shape[1]
+        pod_facts = jnp.concatenate(
+            [
+                prow["requests"],
+                prow["priority"][:, None].astype(prow["requests"].dtype),
+                prow["start_rank"][:, None].astype(prow["requests"].dtype),
+            ],
+            axis=1,
+        )
+
+    def _nom_apply(live: dict, nodes, rows, sign: int) -> dict:
+        """Put (sign +1) or take (-1) the load of pod ``rows`` [E], as
+        nominated, on ``nodes`` [E] (N = none: the update drops): every
+        level at or below the pod's own counts it."""
+        m = jnp.arange(L, dtype=jnp.int32)[None, :] <= prow["level"][rows][:, None]
+        live = dict(live)
+        heads = live["nm_cnt"]
+        live["nm_cnt"] = heads.at[nodes].add(m.astype(heads.dtype) * sign, mode="drop")
+        for key, table in nm_rows.items():
+            arr = live[key]
+            upd = (
+                m[:, :, None].astype(arr.dtype)
+                * table[rows][:, None, :].astype(arr.dtype)
+                * sign
+            )
+            live[key] = arr.at[nodes].add(upd, mode="drop")
+        return live
+
+    def _nom_at(live: dict, lvl) -> dict:
+        """What a pod of level ``lvl`` counts in on every node."""
+        return {
+            key: jax.lax.dynamic_index_in_dim(live[key], lvl, 1, keepdims=False)
+            for key in nm_keys
+        }
+
+    # One-row updates of the pod loop's carried arrays, as one-hot
+    # elementwise passes: a scatter of one row costs the chip ~18 us a
+    # slot (my chip runs, PR 32), an elementwise pass over a few thousand
+    # rows a fraction of that — the idiom of ``NodeStateView.commit`` and
+    # of the plugins' ``carry_commit`` (no gather / scatter in the scan
+    # step).  ``idx`` out of range (N, P, -1) touches nothing.
+    def _hit(arr, idx):
+        hit = jnp.arange(arr.shape[0], dtype=jnp.int32) == idx
+        return hit.reshape((-1,) + (1,) * (arr.ndim - 1))
+
+    def _row_add(arr, idx, row):
+        return arr + jnp.where(_hit(arr, idx), jnp.asarray(row, arr.dtype)[None], 0)
+
+    def _row_set(arr, idx, row):
+        return jnp.where(_hit(arr, idx), jnp.asarray(row, arr.dtype)[None], arr)
+
+    def _nom_apply_one(live: dict, node, row, sign: int) -> dict:
+        """``_nom_apply`` for one pod inside the pod loop."""
+        m = jnp.arange(L, dtype=jnp.int32) <= prow["level"][row]
+        live = dict(live)
+        live["nm_cnt"] = _row_add(live["nm_cnt"], node, m.astype(jnp.int32) * sign)
+        for key, table in nm_rows.items():
+            live[key] = _row_add(
+                live[key], node, m[:, None] * table[row][None, :] * sign
+            )
+        return live
 
     def _victim_deltas(rows, act):
         """Summed universe-row contributions of ``rows`` where ``act``
@@ -560,29 +668,14 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
         return dict(
             req=jnp.sum(jnp.where(w, prow["requests"][safe], 0), axis=0),
             nz=jnp.sum(jnp.where(w, prow["nonzero_requests"][safe], 0), axis=0),
-            cnt=jnp.sum(act.astype(jnp.int32)),
-            sel=jnp.sum(jnp.where(w, sel_rows[safe].astype(jnp.int32), 0), axis=0),
-            qm=jnp.sum(jnp.where(w, qm_rows[safe].astype(jnp.int32), 0), axis=0),
-            eat=jnp.sum(jnp.where(w, eat_rows[safe], 0), axis=0),
-            vw=jnp.sum(jnp.where(w, vw_rows[safe], 0), axis=0),
+            # (x64 mode promotes a sum of i32 to i64; the carried
+            # aggregates these are taken from stay i32.)
+            cnt=jnp.sum(act.astype(jnp.int32)).astype(jnp.int32),
+            sel=jnp.sum(jnp.where(w, sel_rows[safe].astype(jnp.int32), 0), axis=0).astype(jnp.int32),
+            qm=jnp.sum(jnp.where(w, qm_rows[safe].astype(jnp.int32), 0), axis=0).astype(jnp.int32),
+            eat=jnp.sum(jnp.where(w, eat_rows[safe], 0), axis=0).astype(eat_rows.dtype),
+            vw=jnp.sum(jnp.where(w, vw_rows[safe], 0), axis=0).astype(vw_rows.dtype),
         )
-
-    def _sub_victims(live: dict, node_t, d: dict) -> dict:
-        """live minus a victim-delta dict at node index ``node_t`` (OOB
-        index drops — pass N to no-op)."""
-        live = dict(live)
-        live["requested"] = live["requested"].at[node_t].add(-d["req"], mode="drop")
-        live["nonzero_requested"] = live["nonzero_requested"].at[node_t].add(
-            -d["nz"], mode="drop"
-        )
-        live["pod_count"] = live["pod_count"].at[node_t].add(-d["cnt"], mode="drop")
-        live["spread"] = live["spread"].at[node_t].add(
-            -d["sel"].astype(live["spread"].dtype), mode="drop"
-        )
-        live["ip_cnt"] = live["ip_cnt"].at[node_t].add(-d["qm"], mode="drop")
-        live["ip_eat"] = live["ip_eat"].at[node_t].add(-d["eat"], mode="drop")
-        live["ip_vw"] = live["ip_vw"].at[node_t].add(-d["vw"], mode="drop")
-        return live
 
     def apply_pod_deletes(s: dict, pdel: jnp.ndarray) -> dict:
         v = pdel >= 0
@@ -608,6 +701,11 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
         gone = jnp.where(v, pdel, P)
         s["alive"] = s["alive"].at[gone].set(False, mode="drop")
         s["bound"] = s["bound"].at[gone].set(-1, mode="drop")
+        if st.preempt:
+            # A deleted pod's nomination goes with it.
+            nn = jnp.where(v, s["nom_node"][safe], -1)
+            s = _nom_apply(s, jnp.where(nn >= 0, nn, N), safe, -1)
+            s["nom_node"] = s["nom_node"].at[gone].set(-1, mode="drop")
         return s
 
     def apply_node_events(s: dict, ndel, ncre) -> dict:
@@ -624,6 +722,19 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
         s["ip_cnt"] = jnp.where(keep[:, None], s["ip_cnt"], 0)
         s["ip_eat"] = jnp.where(keep[:, None], s["ip_eat"], 0)
         s["ip_vw"] = jnp.where(keep[:, None], s["ip_vw"], 0)
+        if st.preempt:
+            # A node that goes takes the nominations onto it along (the
+            # store keeps the dead name on the pod; it counts for
+            # nothing on either path and goes at the pod's next attempt).
+            for key in nm_keys:
+                arr = s[key]
+                s[key] = jnp.where(
+                    keep.reshape((N,) + (1,) * (arr.ndim - 1)), arr, 0
+                )
+            nn = s["nom_node"]
+            s["nom_node"] = jnp.where(
+                (nn >= 0) & dmask[jnp.clip(nn, 0, N - 1)], -1, nn
+            )
         # Drained nodes' pods re-enter the pending queue (the runner's
         # requeue_on_node_delete — their backoff state is untouched, the
         # per-pass entry was popped when they scheduled).
@@ -634,29 +745,248 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
         )
         return s
 
-    def _preempt_search(s, live, pod, bits_mat, rank_names, want_k, lower):
+    def _lane_cond(pred, on_true, on_false):
+        """``lax.cond`` on a per-trajectory predicate.  In the fleet
+        program (st.lane_axis set) the predicate is psum-reduced over the
+        vmap lane axis first, which keeps it UNBATCHED — a real XLA
+        conditional instead of the both-branches select a batched
+        predicate forces (docs/scaling.md "2-D mesh (round 19)") — and a
+        lane whose own predicate is false keeps ``on_false``'s value:
+        if ANY lane takes the branch, every lane computes it."""
+        if st.lane_axis is None:
+            return jax.lax.cond(pred, on_true, on_false)
+        go = jax.lax.psum(pred.astype(jnp.int32), st.lane_axis) > 0
+        out = jax.lax.cond(go, on_true, on_false)
+        return jax.tree.map(lambda a, b: jnp.where(pred, a, b), out, on_false())
+
+    def _lane_max(n):
+        """A loop bound every lane of the fleet program shares (pmax over
+        the lane axis; the solo program's own): a batched bound would
+        batch the loop's predicate.  The loops below are written so that
+        a trip past a lane's own bound changes nothing for it."""
+        return n if st.lane_axis is None else jax.lax.pmax(n, st.lane_axis)
+
+    def _filters_with(nstate, pcarries, live, pod, over):
+        """The filter chain's verdicts with the nominated pods of
+        ``over`` (``_nom_at``) counted in as if they ran on their nodes
+        (RunFilterPluginsWithNominatedPods' first run)."""
+        view = nstate._replace(
+            requested=nstate.requested + over["nm_req"].astype(nstate.requested.dtype),
+            pod_count=nstate.pod_count + over["nm_cnt"].astype(nstate.pod_count.dtype),
+        )
+        carr = pcarries
+        if not st.local:
+            carr = dict(pcarries)
+            spread_c = pcarries["PodTopologySpread"]
+            carr["PodTopologySpread"] = spread_c + over["nm_sel"].astype(spread_c.dtype)
+            carr["InterPodAffinity"] = _derive_interpod(
+                {
+                    "cnt": live["ip_cnt"] + over["nm_qm"].astype(live["ip_cnt"].dtype),
+                    "eat": live["ip_eat"] + over["nm_eat"],
+                    "vw": live["ip_vw"] + over["nm_vw"],
+                },
+                ipa,
+                st,
+            )
+        return prog._eval_filters(view, pod, aux, carr)[0]
+
+    def _lower_table(live, lower):
+        """The pods of ``lower`` (bool [P]) grouped by the node they are
+        bound to, each node's in MoreImportantPod order: (count [N],
+        rows [N, V], present [N, V]).  One sort of the pod axis by
+        (node, importance rank) and a binary search per node for where
+        its group starts; a node's pods beyond the V-th are cut (the
+        caller raises the overflow flag for a node it examines).  With
+        them ``facts`` [N, V, R + 2]: each such pod's requests, priority
+        and start rank (``pod_facts``), zero where absent."""
+        nkey = jnp.where(lower, live["bound"], N).astype(jnp.int32)
+        sn, _si, srow = jax.lax.sort(
+            (nkey, prow["imp_rank"], jnp.arange(P, dtype=jnp.int32)), num_keys=2
+        )
+        edges = jnp.searchsorted(
+            sn, jnp.arange(N + 1, dtype=jnp.int32), side="left"
+        ).astype(jnp.int32)
+        start, cnt = edges[:-1], edges[1:] - edges[:-1]
+        kk = jnp.arange(v_eff, dtype=jnp.int32)
+        vact = kk[None, :] < cnt[:, None]
+        vrow = jnp.where(
+            vact, srow[jnp.clip(start[:, None] + kk[None, :], 0, P - 1)], 0
+        )
+        facts = jnp.where(vact[..., None], pod_facts[vrow], 0)
+        return cnt, vrow, vact, facts
+
+    def _victims_over_nodes(nstate, pcarries, live, pod, cnt, vreq, vact, examine, over, has_nom):
+        """selectVictimsOnNode for EVERY node at once, for a window whose
+        filter verdicts are node-local (st.local): a node's verdict reads
+        that node's own load, so one evaluation of the chain over the
+        node axis, on a state where every node's lower-priority pods are
+        gone, answers each node's own question; then the pods come back
+        rank by rank (each node's k-th most important at step k) and stay
+        where the chain still passes.  Both filter runs where nominated
+        pods count.  Returns (fits with all lower gone [N], victim mask
+        [N, V])."""
+        req_dt, cnt_dt = nstate.requested.dtype, nstate.pod_count.dtype
+        base_req = nstate.requested - jnp.sum(vreq, axis=1).astype(req_dt)
+        base_cnt = nstate.pod_count - jnp.sum(vact, axis=1).astype(cnt_dt)
+
+        def fit(req, pc):
+            view = nstate._replace(requested=req, pod_count=pc)
+            ok_a = prog._eval_filters(view, pod, aux, pcarries)[0]
+            ok_b = jax.lax.cond(
+                has_nom,
+                lambda: _filters_with(view, pcarries, live, pod, over),
+                lambda: jnp.ones(N, bool),
+            )
+            return ok_a & ok_b
+
+        fit0 = fit(base_req, base_cnt) & examine
+
+        def reprieve(k, c):
+            req, pc, vic = c
+            a = jax.lax.dynamic_index_in_dim(vact, k, 1, keepdims=False)
+            r = jax.lax.dynamic_index_in_dim(vreq, k, 1, keepdims=False)
+            t_req = req + r.astype(req_dt)
+            t_pc = pc + a.astype(cnt_dt)
+            ok_k = fit(t_req, t_pc)
+            back = a & ok_k  # reprieved: stays re-added
+            vic = jax.lax.dynamic_update_index_in_dim(vic, a & ~ok_k, k, 1)
+            return (
+                jnp.where(back[:, None], t_req, req),
+                jnp.where(back, t_pc, pc),
+                vic,
+            )
+
+        # A rank past a node's own pods is absent there (vact false):
+        # the trip is a no-op for it.
+        ranks = jnp.max(jnp.where(fit0, jnp.minimum(cnt, v_eff), 0)).astype(jnp.int32)
+        _req, _pc, vic = jax.lax.fori_loop(
+            0,
+            _lane_max(ranks),
+            reprieve,
+            (base_req, base_cnt, jnp.zeros((N, v_eff), bool)),
+        )
+        return fit0, vic & fit0[:, None]
+
+    def _victims_by_walk(nstate, pcarries, live, pod, cnt, vrow, vact, examine, over, has_nom, rank_names, want_k):
+        """selectVictimsOnNode node by node, for a window that holds a
+        DoNotSchedule spread constraint or a required pod (anti-)affinity
+        (their verdicts read other nodes' pods): the nodes to examine in
+        live name order, one exact hypothetical state a check — that
+        node's lower-priority pods gone, the spread and inter-pod carries
+        re-derived from the modified locals — through the whole compiled
+        chain, both filter runs, until ``want_k`` candidates are found.
+        Returns (candidate [N], victim mask [N, V])."""
+        order = jnp.argsort(jnp.where(examine, rank_names, _I32_MAX)).astype(jnp.int32)
+        n_exam = jnp.sum(examine.astype(jnp.int32)).astype(jnp.int32)
+
+        def eval_fit(node_i, rows, act):
+            d = _victim_deltas(rows, act)
+            view = nstate._replace(
+                requested=nstate.requested.at[node_i].add(-d["req"]),
+                nonzero_requested=nstate.nonzero_requested.at[node_i].add(-d["nz"]),
+                pod_count=nstate.pod_count.at[node_i].add(-d["cnt"]),
+            )
+            spread_c = pcarries["PodTopologySpread"]
+            loc = {
+                "ip_cnt": live["ip_cnt"].at[node_i].add(-d["qm"]),
+                "ip_eat": live["ip_eat"].at[node_i].add(-d["eat"]),
+                "ip_vw": live["ip_vw"].at[node_i].add(-d["vw"]),
+            }
+            carr = dict(pcarries)
+            carr["PodTopologySpread"] = spread_c.at[node_i].add(
+                -d["sel"].astype(spread_c.dtype)
+            )
+            carr["InterPodAffinity"] = _derive_interpod(
+                {"cnt": loc["ip_cnt"], "eat": loc["ip_eat"], "vw": loc["ip_vw"]},
+                ipa,
+                st,
+            )
+            ok_a = prog._eval_filters(view, pod, aux, carr)[0][node_i]
+            ok_b = jax.lax.cond(
+                has_nom,
+                lambda: _filters_with(view, carr, loc, pod, over)[node_i],
+                lambda: jnp.ones((), bool),
+            )
+            return ok_a & ok_b
+
+        def wanted(i, found):
+            return (i < n_exam) & (found < want_k)
+
+        def walk(c):
+            i, found, is_c, vic = c
+            # In the fleet program a lane that is done idles here while
+            # another still walks (its trips write nothing).
+            more = wanted(i, found)
+            n_i = order[jnp.minimum(i, N - 1)]
+            rows, act = vrow[n_i], vact[n_i] & more
+            fit0 = eval_fit(n_i, rows, act)
+
+            def reprieve(v, rc):
+                removed, vc = rc
+                test = removed.at[v].set(False)
+                ok_v = eval_fit(n_i, rows, act & test)
+                removed = jnp.where(act[v] & ok_v, test, removed)
+                return removed, vc.at[v].set(act[v] & ~ok_v)
+
+            _removed, vc = jax.lax.fori_loop(
+                0,
+                _lane_max(
+                    jnp.where(fit0, jnp.minimum(cnt[n_i], v_eff), 0).astype(jnp.int32)
+                ),
+                reprieve,
+                (act, jnp.zeros(v_eff, bool)),
+            )
+            cand = more & fit0 & jnp.any(vc)
+            at = jnp.where(more, n_i, N)
+            return (
+                i + more.astype(jnp.int32),
+                found + cand.astype(jnp.int32),
+                is_c.at[at].set(cand, mode="drop"),
+                vic.at[at].set(vc & cand, mode="drop"),
+            )
+
+        def any_lane_wants(c):
+            go = wanted(c[0], c[1])
+            if st.lane_axis is None:
+                return go
+            return jax.lax.psum(go.astype(jnp.int32), st.lane_axis) > 0
+
+        _i, _found, is_c, vic = jax.lax.while_loop(
+            any_lane_wants,
+            walk,
+            (
+                jnp.int32(0),
+                jnp.int32(0),
+                jnp.zeros(N, bool),
+                jnp.zeros((N, v_eff), bool),
+            ),
+        )
+        return is_c, vic
+
+    def _preempt_search(s, nstate, pcarries, live, pod, lvl, bits_mat, ev_k, lower):
         """DefaultPreemption's victim search for one unschedulable pod,
-        against the LIVE mid-pass state (earlier binds + earlier
-        victims), as bounded tensor ops:
+        against the pass's state as its predecessors left it (their
+        binds, their victims gone, their nominations):
 
-        - candidate nodes = nodes holding >= 1 lower-priority victim,
+        - nodes to examine = nodes holding >= 1 pod of a lower priority,
           resolvable per the reason-bit table (full-record mode only —
-          the per-pass path has no bits in selection mode), examined in
-          live name order like upstream's node loop (first c_max;
-          overflow discards the segment);
-        - per candidate, the fit re-check runs the profile's compiled
-          filter chain over a hypothetical state with the victims'
-          aggregates subtracted (the lowering gates on the filter set
-          matching the oracle fit chain, preemption.py
-          ORACLE_FIT_FILTER_NAMES);
-        - the reprieve loop re-adds victims in MoreImportantPod order
-          (the pre-lowered imp_rank) as a bounded fori_loop (first
-          v_max; overflow discards);
-        - pickOneNodeForPreemption is the lexicographic min over
-          (max victim prio, prio sum, count, -latest earliest-top-start,
-          discovery order) — exactly the host's narrowing cascade.
-
-        Returns (live', nominated_slot, victim_rows, overflow)."""
+          the per-pass path has no bits in selection mode);
+        - a node is a candidate when the pod passes every filter there
+          with all of those pods gone — as the node stands and with the
+          nominated pods of the pod's priority or above counted in — and
+          at least one of them has to stay gone once they come back in
+          MoreImportantPod order (the pre-lowered imp_rank); the lowering
+          gates on the profile's filter set matching the oracle fit
+          chain (preemption.py ORACLE_FIT_FILTER_NAMES);
+        - the first ``want`` candidates in live name order are kept
+          (upstream's candidate_count; it stops looking there);
+        - pickOneNodeForPreemption is the lexicographic min over (max
+          victim prio, sum of prio + 2**31 as two 16-bit limbs, count,
+          -latest earliest-top-start, name order) — the host's
+          narrowing cascade;
+        Returns the verdict — the nominated slot (-1: none), the victim
+        rows in reprieve order, the overflow flag, the candidates kept —
+        and changes nothing (``_apply_preemption`` does)."""
         valid_now = s["valid"]
         if st.record == "full":
             fail = bits_mat != 0  # [F, N]
@@ -668,133 +998,135 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
             resolvable = const["resolv"][first, bval] & fail_any
         else:
             resolvable = jnp.ones(N, bool)
-        tgtn = jnp.where(lower, live["bound"], N)
-        vcnt = jnp.zeros(N, jnp.int32).at[tgtn].add(1, mode="drop")
-        examine = (vcnt > 0) & valid_now & resolvable
-        over_c = jnp.sum(examine.astype(jnp.int32)) > c_eff
-        keyed = jnp.where(examine, rank_names, _I32_MAX)
-        negk, cand_nodes = jax.lax.top_k(-keyed, c_eff)
-        cand_act = negk > -_I32_MAX
-
-        def eval_fit(node_i, rows, act):
-            """Does the preemptor pass every filter at node_i with the
-            ``act`` rows' aggregates removed?  Full kernel-chain eval
-            (spread/inter-pod are global: their carries re-derive from
-            the modified locals)."""
-            d = _victim_deltas(rows, act)
-            view = NodeStateView(
-                allocatable=nstat["allocatable"],
-                allowed_pods=nstat["allowed_pods"],
-                valid=valid_now,
-                unschedulable=nstat["unschedulable"],
-                requested=live["requested"].at[node_i].add(-d["req"]),
-                nonzero_requested=live["nonzero_requested"].at[node_i].add(-d["nz"]),
-                pod_count=live["pod_count"].at[node_i].add(-d["cnt"]),
+        rank_names, want_k = ev_k["name_rank"], ev_k["want"]
+        cnt, vrow, vact, facts = _lower_table(live, lower)
+        examine = (cnt > 0) & valid_now & resolvable
+        over_v = jnp.any(examine & (cnt > v_eff))
+        over = _nom_at(live, lvl)
+        has_nom = jnp.any(over["nm_cnt"] > 0)
+        if st.local:
+            is_c, vic = _victims_over_nodes(
+                nstate, pcarries, live, pod, cnt, facts[..., :R], vact, examine,
+                over, has_nom,
             )
-            carr = prog.init_carries(aux)
-            carr["PodTopologySpread"] = live["spread"].at[node_i].add(
-                -d["sel"].astype(live["spread"].dtype)
+            is_c = is_c & jnp.any(vic, axis=1)
+        else:
+            is_c, vic = _victims_by_walk(
+                nstate, pcarries, live, pod, cnt, vrow, vact, examine, over,
+                has_nom, rank_names, want_k,
             )
-            carr["InterPodAffinity"] = _derive_interpod(
-                {
-                    "cnt": live["ip_cnt"].at[node_i].add(-d["qm"]),
-                    "eat": live["ip_eat"].at[node_i].add(-d["eat"]),
-                    "vw": live["ip_vw"].at[node_i].add(-d["vw"]),
-                },
-                ipa,
-                st,
-            )
-            okf, _bits = prog._eval_filters(view, pod, aux, carr)
-            return okf[node_i]
-
-        def cand_body(i, acc):
-            is_c, maxp_a, sump_a, cnt_a, est_a, nrank_a, node_a, vic_a, over = acc
-            n_i = cand_nodes[i]
-            act = cand_act[i]
-            on_n = lower & (live["bound"] == n_i)
-            kv = jnp.where(on_n, prow["imp_rank"], _I32_MAX)
-            negv, vrows = jax.lax.top_k(-kv, v_eff)
-            vact = negv > -_I32_MAX
-            over = over | (act & (jnp.sum(on_n.astype(jnp.int32)) > v_eff))
-            fit0 = eval_fit(n_i, vrows, vact)
-
-            def rep_body(v, rc):
-                removed, vic = rc
-                test = removed.at[v].set(False)
-                okv = eval_fit(n_i, vrows, vact & test)
-                back = vact[v] & okv  # reprieved: stays re-added
-                removed = jnp.where(back, test, removed)
-                vic = vic.at[v].set(vact[v] & ~okv)
-                return removed, vic
-
-            _removed, vic = jax.lax.fori_loop(
-                0, v_eff, rep_body, (vact, jnp.zeros(v_eff, bool))
-            )
-            vprio = prow["priority"][vrows]
-            have = jnp.any(vic)
-            maxp = jnp.max(jnp.where(vic, vprio, _I32_MIN))
-            est = jnp.min(
-                jnp.where(vic & (vprio == maxp), prow["start_rank"][vrows], _I32_MAX)
-            )
-            return (
-                is_c.at[i].set(act & fit0),
-                maxp_a.at[i].set(maxp),
-                sump_a.at[i].set(jnp.sum(jnp.where(vic, vprio, 0))),
-                cnt_a.at[i].set(jnp.sum(vic.astype(jnp.int32))),
-                est_a.at[i].set(
-                    jnp.where(have, est, jnp.reshape(const["empty_start_rank"], ()))
-                ),
-                nrank_a.at[i].set(rank_names[n_i]),
-                node_a.at[i].set(n_i),
-                vic_a.at[i].set(jnp.where(vic, vrows, -1)),
-                over,
-            )
-
-        C = c_eff
-        acc0 = (
-            jnp.zeros(C, bool),
-            jnp.zeros(C, jnp.int32),
-            jnp.zeros(C, jnp.int32),
-            jnp.zeros(C, jnp.int32),
-            jnp.zeros(C, jnp.int32),
-            jnp.zeros(C, jnp.int32),
-            jnp.zeros(C, jnp.int32),
-            jnp.full((C, v_eff), -1, jnp.int32),
-            over_c,
-        )
-        is_c, maxp_a, sump_a, cnt_a, est_a, nrank_a, node_a, vic_a, over = (
-            jax.lax.fori_loop(0, C, cand_body, acc0)
-        )
-        # Upstream stops after `want` successful candidates (discovery =
-        # name order); narrowing criteria 1-4 then "first" compose into
-        # one lexicographic argmin.
-        pos = jnp.cumsum(is_c.astype(jnp.int32)) - 1
+        # Upstream stops after `want` candidates (discovery = name
+        # order); a node whose pods were all reprieved is none.
+        in_name_order = is_c[ev_k["name_order"]]
+        pos = (jnp.cumsum(in_name_order.astype(jnp.int32)) - 1)[ev_k["name_pos"]]
         keep = is_c & (pos < want_k)
         any_c = jnp.any(keep)
+        vprio = facts[..., R].astype(jnp.int32)  # [N, V]
+        maxp = jnp.max(jnp.where(vic, vprio, _I32_MIN), axis=1)
+        # Sum of (priority + 2**31) over the victims, exactly, in 32-bit
+        # lanes: each term as two 16-bit limbs, the low sum's carry
+        # folded into the high one.
+        lo = jnp.sum(jnp.where(vic, vprio & 0xFFFF, 0), axis=1)
+        hi = jnp.sum(jnp.where(vic, (vprio >> 16) + 0x8000, 0), axis=1) + (lo >> 16)
+        lo = lo & 0xFFFF
+        n_vic = jnp.sum(vic.astype(jnp.int32), axis=1)
+        est = jnp.min(
+            jnp.where(
+                vic & (vprio == maxp[:, None]),
+                facts[..., R + 1].astype(jnp.int32),
+                _I32_MAX,
+            ),
+            axis=1,
+        )
         m = keep
         for arr, take_min in (
-            (maxp_a, True),
-            (sump_a, True),
-            (cnt_a, True),
-            (est_a, False),
-            (nrank_a, True),
+            (maxp, True),
+            (hi, True),
+            (lo, True),
+            (n_vic, True),
+            (est, False),
+            (rank_names, True),
         ):
             kv = jnp.where(m, arr, _I32_MAX if take_min else _I32_MIN)
             tgt = jnp.min(kv) if take_min else jnp.max(kv)
             m = m & (arr == tgt)
-        chosen = jnp.argmax(m)
-        nom = jnp.where(any_c, node_a[chosen], -1).astype(jnp.int32)
-        vic_rows = jnp.where(any_c, vic_a[chosen], -1)
+        chosen = jnp.argmax(m).astype(jnp.int32)
+        return {
+            "nom": jnp.where(any_c, chosen, -1).astype(jnp.int32),
+            "vic": jnp.where(any_c & vic[chosen], vrow[chosen], -1),
+            "over": over_v,
+            "cands": jnp.sum(keep.astype(jnp.int32)).astype(jnp.int32),
+        }
+
+    def _apply_preemption(nstate, pcarries, live, pod, lvl, found):
+        """What a search's verdict (``found``: the nominated slot or -1,
+        the victim rows) does to the pass's state: the victims go,
+        nominations of a lower priority onto the node are cleared, the pod
+        is nominated there.  Runs for EVERY attempt, outside the search's
+        conditional, as scatters that drop where nothing was found: the
+        conditional then hands back a few scalars and not the whole
+        carried state.  Returns (nstate', pcarries', live', cleared)."""
+        nom, vic_rows = found["nom"], found["vic"]
+        any_c = nom >= 0
+        chosen = jnp.maximum(nom, 0)
         vact2 = vic_rows >= 0
         d = _victim_deltas(vic_rows, vact2)
-        live = _sub_victims(live, jnp.where(any_c, nom, N), d)
-        gone = jnp.where(vact2, vic_rows, P)
-        live["alive"] = live["alive"].at[gone].set(False, mode="drop")
-        live["bound"] = live["bound"].at[gone].set(-1, mode="drop")
-        live["nominated"] = (
-            live["nominated"].at[jnp.where(any_c, pod.index, P)].set(True, mode="drop")
+        spread_c = pcarries["PodTopologySpread"]
+        nstate = nstate._replace(
+            requested=_row_add(nstate.requested, nom, -d["req"]),
+            nonzero_requested=_row_add(nstate.nonzero_requested, nom, -d["nz"]),
+            pod_count=_row_add(nstate.pod_count, nom, -d["cnt"]),
         )
-        return live, nom, vic_rows, over
+        pcarries = dict(pcarries)
+        pcarries["PodTopologySpread"] = _row_add(spread_c, nom, -d["sel"])
+        live = dict(live)
+        if st.ip_terms:
+            live["ip_cnt"] = _row_add(live["ip_cnt"], nom, -d["qm"])
+            live["ip_eat"] = _row_add(live["ip_eat"], nom, -d["eat"])
+            live["ip_vw"] = _row_add(live["ip_vw"], nom, -d["vw"])
+            # The domain view follows the locals (preferred terms score
+            # on it): re-derived only where a victim carried a term.
+            ip_moved = (
+                jnp.any(d["qm"] != 0) | jnp.any(d["eat"] != 0) | jnp.any(d["vw"] != 0)
+            )
+            ip_prev = pcarries["InterPodAffinity"]
+            pcarries["InterPodAffinity"] = jax.lax.cond(
+                ip_moved,
+                lambda: _derive_interpod(
+                    {"cnt": live["ip_cnt"], "eat": live["ip_eat"], "vw": live["ip_vw"]},
+                    ipa,
+                    st,
+                ),
+                lambda: ip_prev,
+            )
+        gone = jnp.any(
+            (jnp.arange(P, dtype=jnp.int32)[None, :] == vic_rows[:, None])
+            & vact2[:, None],
+            axis=0,
+        )
+        live["alive"] = live["alive"] & ~gone
+        live["bound"] = jnp.where(gone, -1, live["bound"])
+        # prepareCandidate: nominations of a lower priority onto the node
+        # are cleared — on every level below the pod's the node keeps
+        # what the pod's own level counts, and nothing else.
+        cleared = (
+            any_c
+            & (live["nom_node"] == nom)
+            & (prow["priority"] < prow["priority"][pod.index])
+        )
+        live["nom_node"] = jnp.where(cleared, -1, live["nom_node"])
+        below = jnp.arange(L, dtype=jnp.int32) < lvl
+        for key in nm_keys:
+            arr = live[key]
+            row = arr[chosen]  # [L, ...]
+            at_lvl = jax.lax.dynamic_index_in_dim(row, lvl, 0, keepdims=True)
+            sel_b = below.reshape((L,) + (1,) * (row.ndim - 1))
+            live[key] = _row_set(arr, nom, jnp.where(sel_b, at_lvl, row))
+        live = _nom_apply_one(live, nom, pod.index, +1)
+        live["nom_node"] = _row_set(
+            live["nom_node"], jnp.where(any_c, pod.index, P), nom
+        )
+        return nstate, pcarries, live, jnp.sum(cleared.astype(jnp.int32)).astype(jnp.int32)
 
     def step(carry, ev_k):
         def run_step(s):
@@ -814,7 +1146,10 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
             if st.preempt:
                 z["nom"] = jnp.full(st.q, -1, jnp.int32)
                 z["vic"] = jnp.full((st.q, v_eff), -1, jnp.int32)
+                z["clr"] = jnp.zeros(st.q, jnp.int32)
                 z["overflow"] = jnp.zeros((), bool)
+                z["searches"] = jnp.zeros((), jnp.int32)
+                z["cands"] = jnp.zeros((), jnp.int32)
             if st.record == "full":
                 z["bits"] = jnp.zeros((st.q, n_filters, N), bits_dtype)
                 z["raw"] = jnp.zeros((st.q, n_scores, N), raw_dtype)
@@ -937,57 +1272,32 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
             {"cnt": s["ip_cnt"], "eat": s["ip_eat"], "vw": s["ip_vw"]}, ipa, st
         )
         rank = ev_k["rank"]  # i32 [N], canonical slot, big when dead
-        if st.preempt:
-            # The mid-pass LIVE view (what the store holds while
-            # _bind_results iterates): this pass's binds so far PLUS
-            # preemption victims removed so far.  The scan's filter/
-            # score state (nstate + pcarries) stays binds-only — the
-            # per-pass engine ran on the pre-pass snapshot.
-            live0 = {
-                k: s[k]
-                for k in (
-                    "alive", "bound", "requested", "nonzero_requested",
-                    "pod_count", "spread", "ip_cnt", "ip_eat", "ip_vw",
-                    "nominated",
-                )
-            }
-        else:
-            live0 = {}
 
-        def _bind_live(live: dict, pb, best) -> dict:
-            """Apply one pod attempt's bind to the live view (a failed
-            attempt — best < 0 — drops every scatter, so this is a
-            no-op for it).  Shared VERBATIM by the bind scan and the
-            gated search scan below: the search phase re-derives the
-            exact live sequence by replaying these binds, so the op
-            order (and with it f32/i32 bit-exactness) must be the one
-            sequence both phases execute."""
-            j = pb.index
-            tgtb = jnp.where(best >= 0, best, N)
-            bj = jnp.where(best >= 0, j, P)
-            live = dict(live)
-            live["requested"] = live["requested"].at[tgtb].add(
-                pb.requests, mode="drop"
-            )
-            live["nonzero_requested"] = live["nonzero_requested"].at[tgtb].add(
-                pb.nonzero_requests, mode="drop"
-            )
-            live["pod_count"] = live["pod_count"].at[tgtb].add(1, mode="drop")
-            live["spread"] = live["spread"].at[tgtb].add(
-                sel_rows[j].astype(live["spread"].dtype), mode="drop"
-            )
-            live["ip_cnt"] = live["ip_cnt"].at[tgtb].add(
-                qm_rows[j].astype(live["ip_cnt"].dtype), mode="drop"
-            )
-            live["ip_eat"] = live["ip_eat"].at[tgtb].add(eat_rows[j], mode="drop")
-            live["ip_vw"] = live["ip_vw"].at[tgtb].add(vw_rows[j], mode="drop")
-            live["bound"] = live["bound"].at[bj].set(best, mode="drop")
-            # The apiserver clears nominations on bind.
-            live["nominated"] = live["nominated"].at[bj].set(False, mode="drop")
-            return live
+        def select_host(ok, total, valid):
+            """selectHost with the canonical-slot tie-break: max summed
+            score, minimal rank — the node the per-pass argmax (lowest
+            slot index) picks; -1 where nothing is feasible."""
+            feasible = jnp.any(ok)
+            masked = jnp.where(ok, total, _I32_MIN)
+            cand = ok & (masked == jnp.max(masked))
+            best = jnp.argmin(jnp.where(cand, rank, _I32_MAX)).astype(jnp.int32)
+            return jnp.where(feasible & valid, best, -1)
+
+        def record_rows(_bits, _raw, _final):
+            return {
+                "bits": (
+                    jnp.stack(_bits) if _bits else jnp.zeros((0, N), jnp.int32)
+                ).astype(bits_dtype),
+                "raw": (
+                    jnp.stack(_raw) if _raw else jnp.zeros((0, N), jnp.int32)
+                ).astype(raw_dtype),
+                "final": (
+                    jnp.stack(_final) if _final else jnp.zeros((0, N), jnp.int32)
+                ).astype(final_dtype),
+            }
 
         def pod_body(pcarry, pb):
-            nstate, pcarries, live = pcarry
+            nstate, pcarries = pcarry
             from ksim_tpu.plugins.base import PodView
 
             pod = PodView(
@@ -1000,52 +1310,100 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
             ok, _bits, _raw, _final, total = prog._eval_one(
                 nstate, pod, aux, pcarries
             )
-            # selectHost with the canonical-slot tie-break: max summed
-            # score, minimal rank — the node the per-pass argmax (lowest
-            # slot index) picks.
-            feasible = jnp.any(ok)
-            masked = jnp.where(ok, total, _I32_MIN)
-            cand = ok & (masked == jnp.max(masked))
-            best = jnp.argmin(jnp.where(cand, rank, _I32_MAX)).astype(jnp.int32)
-            best = jnp.where(feasible & pb.valid, best, -1)
+            best = select_host(ok, total, pb.valid)
             nstate = nstate.commit(best, pb.requests, pb.nonzero_requests)
             pcarries = prog._commit_carries(pcarries, pod, best, aux)
             out_pod = {"best": best}
             if st.record == "full":
-                out_pod["bits"] = (
-                    jnp.stack(_bits) if _bits else jnp.zeros((0, N), jnp.int32)
-                ).astype(bits_dtype)
-                out_pod["raw"] = (
-                    jnp.stack(_raw) if _raw else jnp.zeros((0, N), jnp.int32)
-                ).astype(raw_dtype)
-                out_pod["final"] = (
-                    jnp.stack(_final) if _final else jnp.zeros((0, N), jnp.int32)
-                ).astype(final_dtype)
-            if st.preempt:
-                # Phase A (round 19): apply the bind and emit only the
-                # search TRIGGER — the victim search itself moved to a
-                # second, step-level `lax.cond`-gated scan below.  The
-                # trigger is computed against the binds-only live view,
-                # which can only OVER-approximate the exact one: a
-                # search removes victims (alive <- False, bound <- -1),
-                # shrinking `lower`, and never touches anything `best`
-                # depends on (nstate / pcarries are binds-only — see
-                # the live0 comment above).  So exact-pred true implies
-                # pred_hat true, and a step whose every pred_hat is
-                # false provably ran no search — its binds-only live IS
-                # the exact post-step live.
-                live = _bind_live(live, pb, best)
-                j = pb.index
-                prio_p = prow["priority"][j]
-                lower = (
-                    live["alive"] & (live["bound"] >= 0) & (prow["priority"] < prio_p)
-                )
-                out_pod["pred_hat"] = (
-                    pb.valid
-                    & (best < 0)
-                    & prow["preempt_ok"][j]
-                    & jnp.any(lower)
-                )
+                out_pod.update(record_rows(_bits, _raw, _final))
+            return (nstate, pcarries), out_pod
+
+        invalid_search = {
+            "nom": jnp.int32(-1),
+            "vic": jnp.full(v_eff, -1, jnp.int32),
+            "over": jnp.zeros((), bool),
+            "cands": jnp.int32(0),
+        }
+
+        def pod_body_preempt(pcarry, pb):
+            """One attempt of a window with the victim search on: the
+            pod is evaluated against the state its predecessors of this
+            pass left (binds, victims gone, nominations), binds or
+            searches, and hands the state on."""
+            nstate, pcarries, live = pcarry
+            from ksim_tpu.plugins.base import PodView
+
+            pod = PodView(
+                requests=pb.requests,
+                nonzero_requests=pb.nonzero_requests,
+                tolerates_unschedulable=pb.tolerates_unschedulable,
+                has_requests=pb.has_requests,
+                index=pb.index,
+            )
+            j = pb.index
+            lvl = prow["level"][j]
+            # The pod's own nomination is off the books from here on: it
+            # does not count against itself, and the attempt ends it
+            # either way (a bind, a new nomination, or giving it up).
+            n0 = jnp.where(pb.valid, live["nom_node"][j], -1)
+            live = _nom_apply_one(live, n0, j, -1)
+            live["nom_node"] = _row_set(live["nom_node"], jnp.where(n0 >= 0, j, P), -1)
+            ok, _bits = prog._eval_filters(nstate, pod, aux, pcarries)
+            # RunFilterPluginsWithNominatedPods: a node has to pass as it
+            # stands and with the nominated pods of this priority or
+            # above counted in (skipped where none is).
+            over = _nom_at(live, lvl)
+            has_nom = jnp.any(over["nm_cnt"] > 0)
+            ok = ok & _lane_cond(
+                has_nom,
+                lambda: _filters_with(nstate, pcarries, live, pod, over),
+                lambda: jnp.ones(N, bool),
+            )
+            # evaluateNominatedNode: the nominated node, if it passes,
+            # is the whole feasible set.
+            own = (n0 >= 0) & ok[jnp.clip(n0, 0, N - 1)]
+            ok = jnp.where(own, ok & (jnp.arange(N) == n0), ok)
+            _raw, _final, total = prog._eval_scores(nstate, pod, aux, pcarries, ok)
+            best = select_host(ok, total, pb.valid)
+            nstate = nstate.commit(best, pb.requests, pb.nonzero_requests)
+            pcarries = prog._commit_carries(pcarries, pod, best, aux)
+            if st.ip_terms:
+                live["ip_cnt"] = _row_add(live["ip_cnt"], best, qm_rows[j])
+                live["ip_eat"] = _row_add(live["ip_eat"], best, eat_rows[j])
+                live["ip_vw"] = _row_add(live["ip_vw"], best, vw_rows[j])
+            live["bound"] = _row_set(live["bound"], jnp.where(best >= 0, j, P), best)
+            out_pod = {"best": best}
+            if st.record == "full":
+                out_pod.update(record_rows(_bits, _raw, _final))
+            lower = (
+                live["alive"]
+                & (live["bound"] >= 0)
+                & (prow["priority"] < prow["priority"][j])
+            )
+            failed = pb.valid & (best < 0)
+            pred = failed & prow["preempt_ok"][j] & jnp.any(lower)
+            bits_mat = out_pod["bits"] if st.record == "full" and n_filters else None
+
+            found = _lane_cond(
+                pred,
+                lambda: _preempt_search(
+                    s, nstate, pcarries, live, pod, lvl, bits_mat, ev_k, lower
+                ),
+                lambda: dict(invalid_search),
+            )
+            nstate, pcarries, live, cleared = _apply_preemption(
+                nstate, pcarries, live, pod, lvl, found
+            )
+            # -2: the attempt failed, preemption did not help, and the
+            # nomination the pod came with is given up.
+            out_pod["nom"] = jnp.where(
+                failed & (found["nom"] < 0) & (n0 >= 0), -2, found["nom"]
+            ).astype(jnp.int32)
+            out_pod["vic"] = found["vic"]
+            out_pod["over"] = found["over"]
+            out_pod["cands"] = found["cands"]
+            out_pod["clr"] = cleared
+            out_pod["searched"] = pred
             return (nstate, pcarries, live), out_pod
 
         invalid_pod = {"best": jnp.int32(-1)}
@@ -1054,127 +1412,44 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
             invalid_pod["raw"] = jnp.zeros((n_scores, N), raw_dtype)
             invalid_pod["final"] = jnp.zeros((n_scores, N), final_dtype)
         if st.preempt:
-            invalid_pod["pred_hat"] = jnp.zeros((), bool)
-        (node_state, carries, live), pod_outs = run_slots(
-            pod_body, (node_state, carries, live0), pods_q, invalid_pod
-        )
+            invalid_pod.update(
+                invalid_search, clr=jnp.int32(0), searched=jnp.zeros((), bool)
+            )
+            # Name order of the live nodes, once a step: position ->
+            # slot and back (dead nodes rank last).
+            name_order = jnp.argsort(ev_k["name_rank"]).astype(jnp.int32)
+            ev_k = dict(
+                ev_k,
+                name_order=name_order,
+                name_pos=jnp.argsort(name_order).astype(jnp.int32),
+            )
+            live0 = {
+                k: s[k]
+                for k in ("alive", "bound", "ip_cnt", "ip_eat", "ip_vw", "nom_node")
+                + nm_keys
+            }
+            (node_state, carries, live), pod_outs = run_slots(
+                pod_body_preempt, (node_state, carries, live0), pods_q, invalid_pod
+            )
+        else:
+            (node_state, carries), pod_outs = run_slots(
+                pod_body, (node_state, carries), pods_q, invalid_pod
+            )
         sel = pod_outs["best"]
         bound_mask = (idx_q < P) & (sel >= 0)
         fail_mask = (idx_q < P) & (sel < 0)
+        s["requested"] = node_state.requested
+        s["nonzero_requested"] = node_state.nonzero_requested
+        s["pod_count"] = node_state.pod_count
+        # The committed spread carry is node-local — carry it forward.
+        s["spread"] = carries["PodTopologySpread"]
         if st.preempt:
-            # Phase B (round 19): the victim search, behind ONE
-            # step-level conditional.  `go` is the disjunction of the
-            # phase-A triggers; in the fleet program (st.lane_axis set)
-            # it is additionally psum-reduced over the vmap lane axis,
-            # which makes the predicate UNBATCHED — the cond lowers to
-            # a real XLA conditional instead of the both-branches
-            # select a batched predicate forces (the select bomb,
-            # docs/scaling.md "2-D mesh (round 19)").  Lane semantics:
-            # if ANY lane wants a search this step, EVERY lane replays
-            # the search scan (lanes without triggers recompute their
-            # binds-only live, byte-identically); steps where no lane
-            # triggers skip the ~c_eff*(v_eff+1) search machinery
-            # entirely.
-            go = jnp.any(pod_outs["pred_hat"])
-            if st.lane_axis is not None:
-                go = jax.lax.psum(go.astype(jnp.int32), st.lane_axis) > 0
-
-            with_bits = st.record == "full" and n_filters > 0
-            search_xs = (pods_q, sel) + (
-                (pod_outs["bits"],) if with_bits else ()
-            )
-
-            invalid_search = {
-                "nom": jnp.int32(-1),
-                "vic": jnp.full(v_eff, -1, jnp.int32),
-                "over": jnp.zeros((), bool),
-            }
-
-            def search_pods(_):
-                # Exact replay: rescan the queue from the pre-pass live
-                # snapshot, re-applying each bind via the SAME
-                # _bind_live the bind scan used and running the
-                # original per-pod search cond — the one interleaved
-                # bind/search sequence round 12 shipped, byte for byte.
-                # (`best` comes in from phase A: searches never feed
-                # back into it.)  Stored bits are value-identical to
-                # the raw i32 stack the old in-scan search consumed:
-                # _result_dtypes picks bits_dtype wide enough for every
-                # declared reason bit.
-                def search_body(live, xs):
-                    if with_bits:
-                        pb, best, bits_mat = xs
-                    else:
-                        (pb, best), bits_mat = xs, None
-                    from ksim_tpu.plugins.base import PodView
-
-                    pod = PodView(
-                        requests=pb.requests,
-                        nonzero_requests=pb.nonzero_requests,
-                        tolerates_unschedulable=pb.tolerates_unschedulable,
-                        has_requests=pb.has_requests,
-                        index=pb.index,
-                    )
-                    live = _bind_live(live, pb, best)
-                    j = pb.index
-                    prio_p = prow["priority"][j]
-                    lower = (
-                        live["alive"]
-                        & (live["bound"] >= 0)
-                        & (prow["priority"] < prio_p)
-                    )
-                    pred = (
-                        pb.valid
-                        & (best < 0)
-                        & prow["preempt_ok"][j]
-                        & jnp.any(lower)
-                    )
-
-                    def do_search(op):
-                        lv, lw = op
-                        return _preempt_search(
-                            s, lv, pod, bits_mat, ev_k["name_rank"],
-                            ev_k["want"], lw,
-                        )
-
-                    def no_search(op):
-                        lv, _lw = op
-                        return (
-                            lv,
-                            invalid_search["nom"],
-                            invalid_search["vic"],
-                            invalid_search["over"],
-                        )
-
-                    live, nom, vicr, over = jax.lax.cond(
-                        pred, do_search, no_search, (live, lower)
-                    )
-                    return live, {"nom": nom, "vic": vicr, "over": over}
-
-                # The same slots as the bind loop: it replays that queue.
-                return run_slots(
-                    search_body, dict(live0), search_xs, invalid_search
-                )
-
-            def skip_search(_):
-                return dict(live), all_invalid(invalid_search)
-
-            live, souts = jax.lax.cond(go, search_pods, skip_search, 0)
-        if st.preempt:
-            # live already holds binds + victim removals: it IS the
-            # post-step state.
-            for k in (
-                "alive", "bound", "requested", "nonzero_requested",
-                "pod_count", "spread", "ip_cnt", "ip_eat", "ip_vw",
-                "nominated",
-            ):
+            # live already holds binds, victim removals and nominations:
+            # it IS the post-step state.
+            for k in live:
                 s[k] = live[k]
+            nomd = s["nom_node"][clamped] >= 0
         else:
-            s["requested"] = node_state.requested
-            s["nonzero_requested"] = node_state.nonzero_requested
-            s["pod_count"] = node_state.pod_count
-            # The committed spread carry is node-local — carry it forward.
-            s["spread"] = carries["PodTopologySpread"]
             bind_node = jnp.where(bound_mask, sel, N)
             s["ip_cnt"] = s["ip_cnt"].at[bind_node].add(
                 qm_rows[clamped].astype(s["ip_cnt"].dtype), mode="drop"
@@ -1189,13 +1464,13 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
                 .at[jnp.where(bound_mask, idx_q, P)]
                 .set(False, mode="drop")
             )
+            nomd = s["nominated"][clamped]
         # Backoff bookkeeping (_record_attempts): success pops the entry,
         # failure doubles the delay (capped) — UNLESS the pod holds a
         # nomination (from this pass or an earlier one): a nominated pod
         # expects to schedule as soon as its victims are gone, so the
         # per-pass path pops its entry instead of backing it off.
         a_prev = s["attempts"][clamped]
-        nomd = s["nominated"][clamped]
         delay = jnp.minimum(1 << jnp.minimum(a_prev, shift_cap), max_backoff)
         succ_idx = jnp.where(bound_mask, idx_q, P)
         pop_idx = jnp.where(fail_mask & nomd, idx_q, P)
@@ -1233,9 +1508,12 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
             ).astype(jnp.int32),
         }
         if st.preempt:
-            out["nom"] = souts["nom"]
-            out["vic"] = souts["vic"]
-            out["overflow"] = jnp.any(souts["over"])
+            out["nom"] = pod_outs["nom"]
+            out["vic"] = pod_outs["vic"]
+            out["clr"] = pod_outs["clr"]
+            out["overflow"] = jnp.any(pod_outs["over"])
+            out["searches"] = jnp.sum(pod_outs["searched"].astype(jnp.int32)).astype(jnp.int32)
+            out["cands"] = jnp.sum(pod_outs["cands"]).astype(jnp.int32)
         if st.record == "full":
             out["bits"] = pod_outs["bits"]
             out["raw"] = pod_outs["raw"]
@@ -1378,6 +1656,12 @@ class AttemptOutcome:
     nominated: str | None  # newly nominated node (preemption)
     victims: list[tuple[str, str]]  # (namespace, name) in reprieve order
     anno: dict | None  # record="full" annotations (None in selection)
+    # The attempt failed, preemption did not help, and the pod gives up
+    # the nomination it came with.
+    gave_up: bool = False
+    # Pods of a lower priority nominated to ``nominated`` lose their
+    # nomination (how many; the reconcile finds them in the store).
+    cleared: int = 0
 
 
 @dataclass
@@ -1389,6 +1673,8 @@ class StepOutcome:
     pending_after: int
     eligible: int  # queue size before the cap (0 = the pass never featurized)
     slots_run: int  # queue slots the device's pod loops ran for this step
+    searches: int = 0  # victim searches the step ran on the device
+    candidates: int = 0  # candidates those searches kept, summed
     # (namespace, name, node_name) in queue (commit) order.
     binds: list[tuple[str, str, str]] = field(default_factory=list)
     # Per-attempt detail (preemption / full-record segments); None means
@@ -1405,6 +1691,9 @@ class SegmentOutcome:
     # Device end-of-segment views for the store parity check.
     bound_view: dict[str, str]  # pod key -> node name
     pending_view: set[str]  # pod keys
+    # pod key -> the node it is nominated to (None: the segment's
+    # program carries no nominations).
+    nominated_view: "dict[str, str] | None" = None
 
 
 def _cleaned_pending(pod: JSON) -> JSON:
@@ -1492,6 +1781,14 @@ class ReplayDriver:
         self.steps_padded = 0  # guarded-by: main-thread
         self.pairs_evaluated = 0  # guarded-by: main-thread
         self.queue_slots_run = 0  # guarded-by: main-thread
+        # The on-device victim search, over the committed segments:
+        # searches run, candidates they kept, victims evicted,
+        # nominations made; and the segments discarded for VMAX.
+        self.preempt_searches = 0  # guarded-by: main-thread
+        self.preempt_candidates = 0  # guarded-by: main-thread
+        self.preempt_victims = 0  # guarded-by: main-thread
+        self.preempt_nominations = 0  # guarded-by: main-thread
+        self.preempt_overflows = 0  # guarded-by: main-thread
         # Streaming ingest overlap (round 22, traces/stream.py): a
         # runner-provided NONBLOCKING drain of the trace-ingest queue,
         # called on the main thread while the dispatch worker owns the
@@ -1615,6 +1912,11 @@ class ReplayDriver:
             "steps_padded": self.steps_padded,
             "pairs_evaluated": self.pairs_evaluated,
             "queue_slots_run": self.queue_slots_run,
+            "preempt_searches": self.preempt_searches,
+            "preempt_candidates": self.preempt_candidates,
+            "preempt_victims": self.preempt_victims,
+            "preempt_nominations": self.preempt_nominations,
+            "preempt_overflows": self.preempt_overflows,
             "ingest_prefetches": self.ingest_prefetches,
             "device_errors": self.device_errors,
             "watchdog_timeouts": self.watchdog_timeouts,
@@ -2144,6 +2446,10 @@ class ReplayDriver:
         # the segment): the backend is alive — reset the breaker window.
         self.note_dispatch_healthy(plan)
         if isinstance(res, str):
+            if res == "preemption_overflow":
+                self.preempt_overflows += 1
+                if plan.log_entry is not None:
+                    plan.log_entry["preempt_overflows"] = 1
             # Post-dispatch validation discard (featurize_prediction /
             # preemption_overflow): store untouched, fall back.
             self._reject(res)
@@ -2863,6 +3169,36 @@ class ReplayDriver:
         cap = svc._max_pods_per_pass or (1 << 30)
         q = bucket_size(max(min(cap, hard_bound), 1))
 
+        # Victim-search statics.  The priority levels: a nominated pod
+        # counts for the pods of its level and below, so the nominees'
+        # load is carried per level (bucketed: the level count is a
+        # compiled shape).  And whether every filter verdict of the
+        # window is node-local — no pod of the universe carries a
+        # DoNotSchedule spread constraint or a required pod
+        # (anti-)affinity term — which lets the search evaluate all of a
+        # preemptor's candidates at once over the node axis
+        # (_victims_over_nodes); otherwise it walks them in name order
+        # (_victims_by_walk).  Read off the lowered tensors: the
+        # window's objects choose, nothing else does.
+        n_lvl, search_local, levels, ip_terms = 1, False, None, True
+        if preempt_plan:
+            ip_terms = bool(
+                ipa.pod_term_match.any() or ipa.pod_eat.any() or ipa.pod_vw.any()
+            )
+            distinct = sorted(set(prios))
+            if len(distinct) > PREEMPT_LEVELS_MAX:
+                raise _Unsupported("priority_levels")
+            n_lvl = vocab_pad(len(distinct), 2)
+            level_of = {v: i for i, v in enumerate(distinct)}
+            levels = np.zeros(P, np.int32)
+            levels[: len(prios)] = [level_of[v] for v in prios]
+            search_local = not (
+                (spread.con_valid & (spread.con_mode == 0)).any()
+                or ipa.req_aff.any()
+                or ipa.req_anti.any()
+                or ipa.pod_eat.any()
+            )
+
         statics = _SegmentStatics(
             k=K,
             q=q,
@@ -2871,8 +3207,10 @@ class ReplayDriver:
             n_dom=n_dom_pad,
             record=self._record_mode,
             preempt=preempt_plan,
-            c_max=PREEMPT_CANDIDATES,
             v_max=PREEMPT_VICTIMS,
+            n_lvl=n_lvl,
+            local=search_local,
+            ip_terms=ip_terms,
             tp=tp,
         )
         const = {
@@ -2899,10 +3237,15 @@ class ReplayDriver:
             "node_delete": node_delete,
         }
         U = len(universe_pods)
-        nominated0 = np.zeros(P, bool)
+        # Nominations that stand (pending pods only, onto a node that is
+        # live: the runner clears the others with the node).
+        nom_node0 = np.full(P, -1, np.int32)
         for p in cur_pods:
-            if p.get("status", {}).get("nominatedNodeName"):
-                nominated0[row_of[_pod_key(p)]] = True
+            nn = p.get("status", {}).get("nominatedNodeName")
+            if nn and not p.get("spec", {}).get("nodeName"):
+                ns = slot_of.get(nn)
+                if ns is not None and valid0[ns]:
+                    nom_node0[row_of[_pod_key(p)]] = ns
         # Stacked result tensors multiply one pass's [Q, F|S, N]
         # footprint by K on-device — bound it before dispatch.  The
         # budget is PER SHARD (round 17): each chip holds N/tp node
@@ -2956,7 +3299,7 @@ class ReplayDriver:
                 sorted(range(U), key=lambda j: mik(universe_pods[j]))
             ):
                 imp_rank[j] = r
-            starts = sorted({stime(p) for p in universe_pods} | {""})
+            starts = sorted({stime(p) for p in universe_pods})
             srank = {sv: i for i, sv in enumerate(starts)}
             for j, p in enumerate(universe_pods):
                 start_rank[j] = srank[stime(p)]
@@ -2968,8 +3311,8 @@ class ReplayDriver:
                 imp_rank=imp_rank,
                 start_rank=start_rank,
                 preempt_ok=preempt_ok,
+                level=levels,
             )
-            const["empty_start_rank"] = np.asarray(srank[""], np.int32)
             ev["name_rank"] = name_ranks
             ev["want"] = want
             if self._record_mode == "full":
@@ -3004,13 +3347,42 @@ class ReplayDriver:
             "bound": bound0,
             "attempts": attempts0,
             "retry_at": retry0,
-            "nominated": nominated0,
             "spread": spread.init_counts,
             "ip_cnt": ip_cnt0,
             "ip_eat": ip_eat0,
             "ip_vw": ip_vw0,
             "pass_count": np.asarray(svc._pass_count, np.int32),
         }
+        if preempt_plan:
+            # The nominees' per-node load by level (``_segment_body``'s
+            # nm_keys): level l of a node sums the pods nominated to it
+            # whose level is l or above.
+            state0["nom_node"] = nom_node0
+            held = np.nonzero(nom_node0 >= 0)[0]
+            at = nom_node0[held]
+
+            def by_level(rows_of: "np.ndarray | None", width: tuple, dtype):
+                out = np.zeros((N, n_lvl) + width, dtype)
+                for l in range(n_lvl):
+                    m = levels[held] >= l
+                    np.add.at(
+                        out[:, l],
+                        at[m],
+                        1 if rows_of is None else rows_of[held[m]].astype(dtype),
+                    )
+                return out
+
+            req = feats.pods.requests
+            state0["nm_req"] = by_level(req, req.shape[1:], req.dtype)
+            state0["nm_cnt"] = by_level(None, (), np.int32)
+            if not search_local:
+                sel = spread.pod_sel_match
+                state0["nm_sel"] = by_level(sel, sel.shape[1:], spread.init_counts.dtype)
+                state0["nm_qm"] = by_level(ipa.pod_term_match, (T,), np.int32)
+                state0["nm_eat"] = by_level(ipa.pod_eat, (T,), np.int32)
+                state0["nm_vw"] = by_level(ipa.pod_vw, (T,), np.int32)
+        else:
+            state0["nominated"] = np.zeros(P, bool)
         # O(delta) evidence: fresh per-pod featurize rows this lower
         # actually built vs the window's event count (the lock-check
         # guard asserts steady-state proportionality; counters, not
@@ -3030,6 +3402,11 @@ class ReplayDriver:
             # Filled in when the segment commits:
             "pairs_evaluated": 0,
             "slots_run": 0,
+            "preempt_searches": 0,
+            "preempt_candidates": 0,
+            "preempt_victims": 0,
+            "preempt_nominations": 0,
+            "preempt_overflows": 0,
         }
         self.lower_log.append(log_entry)
         return _SegmentPlan(
@@ -3274,9 +3651,10 @@ class ReplayDriver:
         if st.preempt and bool(
             np.any(np.asarray(pulled["overflow"])[: plan.n_steps])
         ):
-            # A victim search exceeded the static candidate/victim
-            # bounds: the computed outcomes past that point assumed a
-            # truncated search.  Store untouched — discard, fall back.
+            # A victim search met a node with more pods of a lower
+            # priority than the victim table holds: the computed
+            # outcomes past that point assumed a cut table.  Store
+            # untouched — discard, fall back.
             return "preemption_overflow"
 
         sel = np.asarray(pulled["sel"])  # [K, Q]
@@ -3285,6 +3663,7 @@ class ReplayDriver:
         detailed = st.preempt or st.record == "full"
         noms = np.asarray(pulled["nom"]) if st.preempt else None
         vics = np.asarray(pulled["vic"]) if st.preempt else None
+        clrs = np.asarray(pulled["clr"]) if st.preempt else None
         steps: list[StepOutcome] = []
         render_ctx = None
         for k in range(plan.n_steps):
@@ -3307,14 +3686,18 @@ class ReplayDriver:
                     node = plan.node_names[sl] if sl >= 0 else None
                     nominated = None
                     victims: list[tuple[str, str]] = []
+                    gave_up, cleared = False, 0
                     if st.preempt:
                         nsl = int(noms[k, qq])
                         nominated = plan.node_names[nsl] if nsl >= 0 else None
-                        for vr in vics[k, qq]:
-                            if vr >= 0:
-                                vkey = plan.universe_keys[int(vr)]
-                                vns, _, vnm = vkey.partition("/")
-                                victims.append((vns, vnm))
+                        gave_up = nsl == -2
+                        if nsl >= 0:
+                            cleared = int(clrs[k, qq])
+                            for vr in vics[k, qq]:
+                                if vr >= 0:
+                                    vkey = plan.universe_keys[int(vr)]
+                                    vns, _, vnm = vkey.partition("/")
+                                    victims.append((vns, vnm))
                     attempts.append(
                         AttemptOutcome(
                             namespace=ns,
@@ -3323,6 +3706,8 @@ class ReplayDriver:
                             nominated=nominated,
                             victims=victims,
                             anno=annos[i],
+                            gave_up=gave_up,
+                            cleared=cleared,
                         )
                     )
                     if node is not None:
@@ -3339,6 +3724,8 @@ class ReplayDriver:
                     pending_after=int(pulled["pending_after"][k]),
                     eligible=int(eligible[k]),
                     slots_run=int(pulled["slots"][k]),
+                    searches=int(pulled["searches"][k]) if st.preempt else 0,
+                    candidates=int(pulled["cands"][k]) if st.preempt else 0,
                     binds=binds,
                     attempts=attempts,
                 )
@@ -3385,12 +3772,20 @@ class ReplayDriver:
         pending_view = {
             plan.universe_keys[j] for j in np.nonzero(alive & (bound < 0))[0]
         }
+        nominated_view = None
+        if st.preempt:
+            nom_node = np.asarray(pulled_state["nom_node"])[:P]
+            nominated_view = {
+                plan.universe_keys[j]: plan.node_names[int(nom_node[j])]
+                for j in np.nonzero(alive & (bound < 0) & (nom_node >= 0))[0]
+            }
         return SegmentOutcome(
             steps=steps,
             pass_count=int(np.asarray(pulled_state["pass_count"]).ravel()[0]),
             backoff=backoff,
             bound_view=bound_view,
             pending_view=pending_view,
+            nominated_view=nominated_view,
         )
 
     # -- reconcile -----------------------------------------------------------
@@ -3428,10 +3823,23 @@ class ReplayDriver:
         self.pairs_evaluated += pairs
         slots = sum(o.slots_run for o in seg.steps)
         self.queue_slots_run += slots
+        preempt = {
+            "preempt_searches": sum(o.searches for o in seg.steps),
+            "preempt_candidates": sum(o.candidates for o in seg.steps),
+            "preempt_victims": sum(
+                len(a.victims) for o in seg.steps for a in o.attempts or ()
+            ),
+            "preempt_nominations": sum(
+                1 for o in seg.steps for a in o.attempts or () if a.nominated
+            ),
+        }
+        for key, n in preempt.items():
+            setattr(self, key, getattr(self, key) + n)
         plan = self._last_plan  # None on a fleet follower: it lowered nothing
         if plan is not None and plan.log_entry is not None:
             plan.log_entry["pairs_evaluated"] = pairs
             plan.log_entry["slots_run"] = slots
+            plan.log_entry.update(preempt)
 
     def verify_segment(self, seg: SegmentOutcome) -> None:
         """Verify the staged store converged to the device's view of the
@@ -3454,6 +3862,26 @@ class ReplayDriver:
                 f"{len(seg.bound_view)}, pending {len(store_pending)} vs "
                 f"{len(seg.pending_view)}"
             )
+        if seg.nominated_view is not None:
+            # Nominations that stand: a name the store still holds for a
+            # node that is gone counts on neither side.
+            live = {name_of(n) for n in self.store.list("nodes", copy_objs=False)}
+            store_nominated = {}
+            for p in self.store.pods_without_node():
+                nn = p.get("status", {}).get("nominatedNodeName")
+                if nn and nn in live:
+                    store_nominated[_pod_key(p)] = nn
+            if store_nominated != seg.nominated_view:
+                odd = {
+                    k
+                    for k in set(store_nominated) | set(seg.nominated_view)
+                    if store_nominated.get(k) != seg.nominated_view.get(k)
+                }
+                raise ReplayParityError(
+                    "device-resident replay diverged from the store after "
+                    f"reconcile: {len(odd)} nomination(s) differ (e.g. "
+                    f"{sorted(odd)[:3]})"
+                )
 
     def sync_service(self, seg: SegmentOutcome) -> None:
         """Sync service bookkeeping (pass counter, backoff table) to the
@@ -3791,7 +4219,7 @@ def _plan_const_parts(plan: "_SegmentPlan"):
 
     aux_host, _axes = _aux_host(plan.aux)
     const = dict(plan.const)
-    extra = {k: const[k] for k in ("resolv", "empty_start_rank") if k in const}
+    extra = {k: const[k] for k in ("resolv",) if k in const}
     return (const["node"], const["pods"], extra, aux_host)
 
 
@@ -3919,7 +4347,8 @@ def _tp_mesh(tp: int):
 #: next to the node tensors (docs/scaling.md memory budgets).
 _NODE_STATE_KEYS = frozenset(
     {"valid", "requested", "nonzero_requested", "pod_count",
-     "spread", "ip_cnt", "ip_eat", "ip_vw"}
+     "spread", "ip_cnt", "ip_eat", "ip_vw",
+     "nm_req", "nm_cnt", "nm_sel", "nm_qm", "nm_eat", "nm_vw"}
 )
 
 
@@ -3951,7 +4380,7 @@ def _plan_shard_specs(plan: "_SegmentPlan", transient, mesh):
     pods_spec = {k: repl(v) for k, v in plan.const["pods"].items()}
     extra_spec = {
         k: repl(plan.const[k])
-        for k in ("resolv", "empty_start_rank")
+        for k in ("resolv",)
         if k in plan.const
     }
     from ksim_tpu.engine.core import _aux_host
@@ -4145,7 +4574,11 @@ def _exec_and_pull(plan: "_SegmentPlan", launch, **tags):
             (
                 {
                     k: final_state[k]
-                    for k in ("alive", "bound", "attempts", "retry_at", "pass_count")
+                    for k in (
+                        "alive", "bound", "attempts", "retry_at", "pass_count",
+                        "nom_node",
+                    )
+                    if k in final_state
                 },
                 outs,
             )

@@ -144,6 +144,12 @@ SPAN_NAMES: tuple[str, ...] = (
     "replay.decode",  # dispatch worker: host decode of the pulled
     #                   tensors into the SegmentOutcome
     "replay.reconcile",  # staged store reconcile (the segment txn)
+    "replay.reconcile.evict",  # child of replay.reconcile: one step's
+    #                            preemptions written back, from the
+    #                            first preemptor's attempt to the last
+    #                            one's — nomination patches, victims'
+    #                            evictions, nominations cleared (args
+    #                            preemptions, victims)
     "runner.step",  # one per-pass host step (ops + flush + schedule)
     "service.schedule",  # one scheduling pass (scheduler/service.py)
     "service.featurize",  # pass phase: featurize (Metrics timer

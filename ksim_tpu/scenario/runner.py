@@ -478,38 +478,29 @@ class ScenarioRunner:
         after commit)."""
         self._apply_batch(batch)
         if outcome.attempts is not None:
-            from ksim_tpu.engine.annotations import apply_results_to_pod
-
-            for att in outcome.attempts:
-                if att.anno or att.node or att.nominated:
-
-                    def mutate(obj: JSON, att=att) -> None:
-                        if att.anno:
-                            annos = obj.setdefault("metadata", {}).setdefault(
-                                "annotations", {}
-                            )
-                            apply_results_to_pod(annos, att.anno)
-                        if att.node:
-                            obj.setdefault("spec", {})["nodeName"] = att.node
-                            obj.setdefault("status", {})["phase"] = "Running"
-                            obj.get("status", {}).pop("nominatedNodeName", None)
-                        elif att.nominated:
-                            obj.setdefault("status", {})[
-                                "nominatedNodeName"
-                            ] = att.nominated
-
-                    self.store.patch(
-                        "pods", att.name, att.namespace, mutate, copy_ret=False
-                    )
-                # Victim evictions go through the service so delete
-                # semantics match the per-pass path; listener callbacks
-                # defer to post-commit (a rolled-back segment must never
-                # have announced an eviction that did not happen).
-                for vns, vname in att.victims:
-                    self.service._evict_victim(
-                        {"metadata": {"name": vname, "namespace": vns}},
-                        listener_sink=eviction_sink,
-                    )
+            # The write-back of the step's preemptions (nominations, their
+            # victims' evictions, the nominations they clear) under ONE
+            # child span, from the first preemptor's attempt to the last
+            # one's: a job that evicts 15,000 pods must not hide that cost
+            # in the parent, and a span a preemption would push everything
+            # else out of the job's ring.
+            atts = outcome.attempts
+            hits = [
+                i for i, a in enumerate(atts) if a.victims or a.nominated or a.gave_up
+            ]
+            first, end = (hits[0], hits[-1] + 1) if hits else (len(atts), len(atts))
+            for att in atts[:first]:
+                self._stage_attempt(att, eviction_sink)
+            if hits:
+                with TRACE.span(
+                    "replay.reconcile.evict",
+                    preemptions=len(hits),
+                    victims=sum(len(atts[i].victims) for i in hits),
+                ):
+                    for att in atts[first:end]:
+                        self._stage_attempt(att, eviction_sink)
+            for att in atts[end:]:
+                self._stage_attempt(att, eviction_sink)
         else:
             for ns, name, node in outcome.binds:
 
@@ -519,6 +510,55 @@ class ScenarioRunner:
                     obj.get("status", {}).pop("nominatedNodeName", None)
 
                 self.store.patch("pods", name, ns, bind, copy_ret=False)
+
+    def _stage_attempt(self, att, eviction_sink: list[tuple[str, str]]) -> None:
+        """One attempt's store writes, mirroring the per-pass rebuild:
+        result annotations, the bind or the nomination (or the
+        nomination given up), then the victims' evictions and the
+        lower-priority nominations the preemptor's node loses."""
+        from ksim_tpu.engine.annotations import apply_results_to_pod
+
+        if att.anno or att.node or att.nominated or att.gave_up:
+
+            def mutate(obj: JSON) -> None:
+                if att.anno:
+                    annos = obj.setdefault("metadata", {}).setdefault(
+                        "annotations", {}
+                    )
+                    apply_results_to_pod(annos, att.anno)
+                if att.node:
+                    obj.setdefault("spec", {})["nodeName"] = att.node
+                    obj.setdefault("status", {})["phase"] = "Running"
+                    obj.get("status", {}).pop("nominatedNodeName", None)
+                elif att.nominated:
+                    obj.setdefault("status", {})[
+                        "nominatedNodeName"
+                    ] = att.nominated
+                elif att.gave_up:
+                    obj.get("status", {}).pop("nominatedNodeName", None)
+
+            self.store.patch(
+                "pods", att.name, att.namespace, mutate, copy_ret=False
+            )
+        # Victim evictions go through the service so delete
+        # semantics match the per-pass path; listener callbacks
+        # defer to post-commit (a rolled-back segment must never
+        # have announced an eviction that did not happen).
+        for vns, vname in att.victims:
+            self.service._evict_victim(
+                {"metadata": {"name": vname, "namespace": vns}},
+                listener_sink=eviction_sink,
+            )
+        if att.cleared:
+            from ksim_tpu.state.priorities import build_priority_resolver
+
+            self.service._clear_lower_nominations(
+                att.nominated,
+                self.store.get("pods", att.name, att.namespace),
+                priority_of=build_priority_resolver(
+                    self.store.list("priorityclasses", copy_objs=False)
+                ),
+            )
 
     def _record_device_step(
         self, step: int, batch: list[Operation], outcome, result: ScenarioResult

@@ -9,18 +9,37 @@ simulator/scheduler/plugin/resultstore/store.go:439-456).
 
 This module is the HOST implementation and the parity source of truth:
 the per-pass scheduling path runs it directly, with the exact-parity
-oracle for fit checks (plugins/oracle.py).  Since round 7 the
-device-resident replay (engine/replay.py) lowers the same search into
-the segment scan — bounded candidate/reprieve loops through the
-compiled filter kernels — gated on the profile's filter set matching
+oracle for fit checks (plugins/oracle.py).  The device-resident replay
+(engine/replay.py) lowers the same search into the segment scan — one
+search over the node axis where every verdict is node-local, a walk of
+the candidates in name order through the compiled filter kernels where
+it is not — gated on the profile's filter set matching
 ``ORACLE_FIT_FILTER_NAMES`` below, and verified against this module on
-the hand-derived fixtures (tests/fixtures/preemption_victims.py).
-Changing any semantics here must change the device lowering and the
-fixtures together.  Simplifications vs upstream, documented: no
-PodDisruptionBudgets in the snapshot model (the reference's 7-kind
-snapshot has none either, snapshot/snapshot.go:33-42), so the
-PDB-violation criteria are trivially zero; victim start times fall back
-to creationTimestamp when status.startTime is absent.
+the hand-derived fixtures (tests/fixtures/preemption_victims.py) and
+against the benchmark's plain replay (benchmark/replay.py).  Changing
+any semantics here must change the device lowering and the fixtures
+together.
+
+What v1.30 defines and this module follows (PR 32; before it three
+of them were departures): a node is a candidate only with ONE VICTIM at
+least (``dryRunPreemption`` drops a node whose every lower-priority pod
+was reprieved); the dry run's fit checks count the pods NOMINATED to a
+node, of the preemptor's priority or above, as if they ran there, and a
+node has to pass with them and without
+(``RunFilterPluginsWithNominatedPods``; the scheduling pass does the
+same and tries a pod's own nominated node first, scheduler/service.py);
+``pickOneNodeForPreemption`` sums the victims' priorities with
+MaxInt32+1 added to each, so that fewer victims weigh less.
+Conventions where upstream leaves the outcome to chance or to the
+clock: the candidate walk starts at the first node by name, the first
+found wins ties, a victim is gone at once.  Simplifications vs
+upstream, documented: no PodDisruptionBudgets in the snapshot model
+(the reference's 7-kind snapshot has none either,
+snapshot/snapshot.go:33-42), so the PDB-violation criteria are
+trivially zero; victim start times fall back to creationTimestamp when
+status.startTime is absent; the nominees of EVERY node are counted in
+at once (upstream adds a node's own), which differs only under a
+topology key wider than a node.
 """
 
 from __future__ import annotations
@@ -37,6 +56,11 @@ NOMINATED_MESSAGE = "preemption victim"
 # Upstream DefaultPreemptionArgs defaults.
 MIN_CANDIDATE_NODES_PERCENTAGE = 10
 MIN_CANDIDATE_NODES_ABSOLUTE = 100
+
+#: pickOneNodeForPreemption adds this to every victim's priority before
+#: summing (``int64(math.MaxInt32) + 1``): priorities may be negative,
+#: and a node with fewer victims must not lose to one with more.
+VICTIM_PRIORITY_OFFSET = 2**31
 
 # The filter chain _FitState.fits runs, BY KERNEL NAME.  The device
 # replay's on-device victim search (engine/replay.py) re-checks fits
@@ -234,6 +258,36 @@ class PreemptionDecision:
     victims: list[JSON]
 
 
+def nominated_node_of(pod: JSON) -> str | None:
+    """``status.nominatedNodeName`` of a pod that is still pending."""
+    if pod.get("spec", {}).get("nodeName"):
+        return None
+    return pod.get("status", {}).get("nominatedNodeName") or None
+
+
+def as_if_bound(pod: JSON, node_name: str) -> JSON:
+    """A nominated pod counted as if it ran on ``node_name`` (a shallow
+    copy: the object itself stays pending)."""
+    ghost = dict(pod)
+    ghost["spec"] = dict(pod.get("spec") or {}, nodeName=node_name)
+    return ghost
+
+
+def nominees_counted_for(
+    pod: JSON, nominees: Sequence[tuple[JSON, str]], priority_of=pod_priority
+) -> list[JSON]:
+    """The nominated pods ``RunFilterPluginsWithNominatedPods`` adds when
+    it evaluates ``pod``: every other pod with a nomination whose
+    priority is ``pod``'s or above, as if bound to its nominated node."""
+    prio = priority_of(pod)
+    me = (namespace_of(pod), name_of(pod))
+    return [
+        as_if_bound(q, node)
+        for q, node in nominees
+        if (namespace_of(q), name_of(q)) != me and priority_of(q) >= prio
+    ]
+
+
 def _select_victims_on_node(
     pod: JSON,
     node_idx: int,
@@ -242,40 +296,51 @@ def _select_victims_on_node(
     namespaces: Sequence[JSON],
     volumes: dict | None = None,
     priority_of=pod_priority,
+    *,
+    potential: Sequence[JSON],
+    counted: Sequence[JSON] = (),
 ) -> list[JSON] | None:
     """Upstream selectVictimsOnNode: remove all lower-priority pods, check
     feasibility, then reprieve as many as possible in importance order.
-    Returns the victim list, or None when the node is not a candidate."""
-    node_name = name_of(nodes[node_idx])
-    prio = priority_of(pod)
-    potential = [
-        p
-        for p in cluster_pods
-        if p.get("spec", {}).get("nodeName") == node_name
-        and p.get("status", {}).get("phase") not in ("Succeeded", "Failed")
-        and priority_of(p) < prio
-    ]
-    if not potential:
-        return None
-    state = _FitState(nodes, cluster_pods, namespaces, volumes)
+    ``potential`` are the node's pods of a lower priority (at least one).
+    Returns the victim list, or None when the node is not a candidate:
+    the pod does not fit even with all of them gone, or every one of them
+    could be reprieved (a candidate needs one victim at least).
+    ``counted`` are the nominated pods to count in
+    (``nominees_counted_for``): every fit check has to pass with them and
+    without."""
+    states = [_FitState(nodes, cluster_pods, namespaces, volumes)]
+    if counted:
+        states.append(
+            _FitState(nodes, list(cluster_pods) + list(counted), namespaces, volumes)
+        )
+
+    def fits() -> bool:
+        return all(st.fits(pod, node_idx) for st in states)
+
     for v in potential:
-        state.remove(v)
-    if not state.fits(pod, node_idx):
+        for st in states:
+            st.remove(v)
+    if not fits():
         return None
     victims: list[JSON] = []
     # Reprieve in MoreImportantPod order (no PDBs -> single bucket).
     for v in sorted(potential, key=lambda p: _more_important(p, priority_of)):
-        state.add(v)
-        if not state.fits(pod, node_idx):
-            state.remove(v)
+        for st in states:
+            st.add(v)
+        if not fits():
+            for st in states:
+                st.remove(v)
             victims.append(v)
-    return victims
+    return victims or None
 
 
 def _pick_one_node(candidates: list[Candidate], priority_of=pod_priority) -> Candidate:
     """Upstream pickOneNodeForPreemption, PDB criteria degenerate:
-    lowest highest-victim-priority, then smallest priority sum, then
-    fewest victims, then latest earliest victim start time, then first."""
+    lowest highest-victim-priority, then smallest priority sum (each
+    victim's with ``VICTIM_PRIORITY_OFFSET`` added), then fewest
+    victims, then latest earliest victim start time, then first.  Every
+    candidate has a victim (``_select_victims_on_node``)."""
     best = candidates
 
     def narrow(keyfn, take_min=True):
@@ -287,14 +352,14 @@ def _pick_one_node(candidates: list[Candidate], priority_of=pod_priority) -> Can
     def earliest_high_priority_start(c: Candidate) -> str:
         """util.GetEarliestPodStartTime: the earliest start time among the
         HIGHEST-priority victims only."""
-        if not c.victims:
-            return ""
         top = max(priority_of(v) for v in c.victims)
         return min(_start_time(v) for v in c.victims if priority_of(v) == top)
 
-    narrow(lambda c: max((priority_of(v) for v in c.victims), default=-(2**31)))
+    narrow(lambda c: max(priority_of(v) for v in c.victims))
     if len(best) > 1:
-        narrow(lambda c: sum(priority_of(v) for v in c.victims))
+        narrow(
+            lambda c: sum(priority_of(v) + VICTIM_PRIORITY_OFFSET for v in c.victims)
+        )
     if len(best) > 1:
         narrow(lambda c: len(c.victims))
     if len(best) > 1:
@@ -311,8 +376,13 @@ def find_preemption(
     namespaces: Sequence[JSON] = (),
     volumes: dict | None = None,
     priority_of=pod_priority,
+    nominees: Sequence[tuple[JSON, str]] = (),
 ) -> PreemptionDecision:
     """DefaultPreemption for one unschedulable pod.
+
+    ``nominees`` are the pending pods that hold a nomination, each with
+    its node (any priority, ``pod`` itself allowed: those the dry run
+    counts in are chosen here).
 
     ``candidate_mask`` marks nodes whose filter failure is resolvable by
     removing pods (the engine derives it from recorded reason bits via
@@ -325,11 +395,23 @@ def find_preemption(
     want = candidate_count(n)
     candidates: list[Candidate] = []
     pods_list = list(cluster_pods)
+    prio = priority_of(pod)
+    # One walk over the pods instead of one a node: a node that holds no
+    # pod of a lower priority builds no hypothetical state at all.
+    lower_on = {
+        node: [p for p in on if priority_of(p) < prio]
+        for node, on in _pods_by_node(pods_list).items()
+    }
+    counted = nominees_counted_for(pod, nominees, priority_of)
     for ni in range(n):
         if candidate_mask is not None and not candidate_mask[ni]:
             continue
+        potential = lower_on.get(name_of(nodes[ni]))
+        if not potential:
+            continue
         victims = _select_victims_on_node(
-            pod, ni, nodes, pods_list, namespaces, volumes, priority_of
+            pod, ni, nodes, pods_list, namespaces, volumes, priority_of,
+            potential=potential, counted=counted,
         )
         if victims is None:
             continue

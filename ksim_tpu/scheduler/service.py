@@ -631,42 +631,77 @@ class SchedulerService:
                     prof=prof,
                 )
                 continue
-            with TRACE.phase("service.featurize", self.metrics, "featurize"):
-                feats = featurizer.featurize(
-                    nodes,
-                    (),
-                    queue_pods=queue,
-                    bound_pods=bound_pods,
-                    namespaces=namespaces,
-                    **volume_kw,
-                )
-            plugins = tuple(factory(feats))
-            sampling_k = self._sampling_k_for(prof, len(nodes))
-            with self.metrics.timer("engine"):
-                eng = Engine(
-                    feats,
-                    plugins,
-                    record=self._record,
-                    sampling_k=sampling_k,
-                    metrics=self.metrics,
-                )
-                if self._shard_mesh is not None:
-                    eng.shard(self._shard_mesh)
-                res, _ = eng.schedule(
-                    pull_state=False,
-                    sampling_start=self._pnts_start.get(sched_name, 0),
-                )
-            if sampling_k is not None and res.sampling_next_start is not None:
-                self._pnts_start[sched_name] = res.sampling_next_start
-            with TRACE.phase("service.bind", self.metrics, "bind") as ph:
-                render_s, store_s = self._bind_results(
-                    queue, feats, plugins, res, placements, prof=prof
-                )
-                ph.set(render_s=round(render_s, 6), store_s=round(store_s, 6))
-            # Per-pod work is summed with two clock reads a pod and
-            # recorded once per pass: never a span per pod.
-            self.metrics.observe("render", render_s)
-            self.metrics.observe("bind_store", store_s)
+            # A pass is one engine run over the whole queue unless pods
+            # hold nominations (DefaultPreemption): a nominated pod of the
+            # evaluated pod's priority or above is counted in its filters,
+            # a pod's own nominated node is tried first, and a preemptor's
+            # victims are gone for everyone behind it.  The queue is then
+            # walked in runs: pods no nomination concerns go through the
+            # engine together, up to the first that preempts; a pod one
+            # does concern is evaluated alone (``_schedule_among_nominees``).
+            # A pass without nominations and without a successful
+            # preemption is the one run it always was.
+            remaining = queue
+            while remaining:
+                nominees = []
+                if self._preemption:
+                    # The pass's own snapshot serves the first run.
+                    nominees = self._live_nominees(
+                        nodes, without if remaining is queue else None
+                    )
+                head = self._run_without_nominees(remaining, nominees)
+                if head:
+                    run = remaining[:head]
+                    # The first run reads the snapshot taken above; a
+                    # later one follows binds and evictions of this pass.
+                    bound_now = (
+                        bound_pods if remaining is queue else self._bound_pods_now()
+                    )
+                    with TRACE.phase("service.featurize", self.metrics, "featurize"):
+                        feats = featurizer.featurize(
+                            nodes,
+                            (),
+                            queue_pods=run,
+                            bound_pods=bound_now,
+                            namespaces=namespaces,
+                            **volume_kw,
+                        )
+                    plugins = tuple(factory(feats))
+                    sampling_k = self._sampling_k_for(prof, len(nodes))
+                    with self.metrics.timer("engine"):
+                        eng = Engine(
+                            feats,
+                            plugins,
+                            record=self._record,
+                            sampling_k=sampling_k,
+                            metrics=self.metrics,
+                        )
+                        if self._shard_mesh is not None:
+                            eng.shard(self._shard_mesh)
+                        res, _ = eng.schedule(
+                            pull_state=False,
+                            sampling_start=self._pnts_start.get(sched_name, 0),
+                        )
+                    if sampling_k is not None and res.sampling_next_start is not None:
+                        self._pnts_start[sched_name] = res.sampling_next_start
+                else:
+                    run = remaining[:1]
+                    with self.metrics.timer("engine"):
+                        feats, plugins, res = self._schedule_among_nominees(
+                            run[0], nominees, nodes, featurizer, factory,
+                            namespaces, volume_kw, prof,
+                        )
+                with TRACE.phase("service.bind", self.metrics, "bind") as ph:
+                    render_s, store_s, done = self._bind_results(
+                        run, feats, plugins, res, placements, prof=prof,
+                        nominees=nominees,
+                    )
+                    ph.set(render_s=round(render_s, 6), store_s=round(store_s, 6))
+                # Per-pod work is summed with two clock reads a pod and
+                # recorded once per pass: never a span per pod.
+                self.metrics.observe("render", render_s)
+                self.metrics.observe("bind_store", store_s)
+                remaining = remaining[done:]
         # Bound _own_rvs growth for library use (schedule_pending without
         # the watch loop draining events).  The limit scales with the pass
         # size so one large pass never trims its own still-queued events
@@ -970,23 +1005,168 @@ class SchedulerService:
                 except Exception:
                     logger.exception("eviction listener failed")
 
+    def _bound_pods_now(self) -> list[JSON]:
+        """The pods that hold a node right now (permit-assumed included),
+        as the pass's first snapshot takes them."""
+        without = self._assume_waiting(self._store.pods_without_node())
+        assumed = [p for p in without if p.get("spec", {}).get("nodeName")]
+        bound = self._store.pods_with_node()
+        return bound + assumed if assumed else bound
+
+    def _live_nominees(self, nodes, pending=None) -> list[tuple[JSON, str]]:
+        """(pod, node name) of every pending pod nominated to a node that
+        still exists (a nomination to a node that went counts for
+        nothing, and is cleared at the pod's next failed attempt).
+        ``pending``: the pods without a node, where the caller holds
+        them already."""
+        from ksim_tpu.scheduler.preemption import nominated_node_of
+
+        live = None
+        out = []
+        for p in self._store.pods_without_node() if pending is None else pending:
+            node = nominated_node_of(p)
+            if node is None:
+                continue
+            if live is None:
+                live = {name_of(n) for n in nodes}
+            if node in live:
+                out.append((p, node))
+        return out
+
+    def _run_without_nominees(self, queue, nominees) -> int:
+        """How many pods at the head of ``queue`` no nomination concerns:
+        a pod is concerned when it holds one itself, or when another
+        pod of its priority or above does."""
+        if not nominees:
+            return len(queue)
+        top = max(self._priority_of(q) for q, _node in nominees)
+        holders = {(namespace_of(q), name_of(q)) for q, _node in nominees}
+        for j, pod in enumerate(queue):
+            if (namespace_of(pod), name_of(pod)) in holders or top >= self._priority_of(pod):
+                return j
+        return len(queue)
+
+    def _schedule_among_nominees(
+        self, pod, nominees, nodes, featurizer, factory, namespaces, volume_kw, prof
+    ):
+        """One pod's cycle where nominations count
+        (RunFilterPluginsWithNominatedPods, evaluateNominatedNode): a node
+        has to pass the filters as it stands AND with the nominated pods
+        of the pod's priority or above counted as if they ran on their
+        nodes; the pod's own nominated node, if it passes, is taken
+        before any other.  Returns (feats, plugins, result) of a
+        one-pod pass, for ``_bind_results``.
+
+        The verdict with the nominees counted in comes from a second
+        engine run over a throw-away featurizer (the persistent one never
+        sees a pod that is not there) and enters the real run as its
+        ``node_mask``; scores normalize over what both runs leave."""
+        import numpy as np
+
+        from ksim_tpu.scheduler import preemption as pre
+
+        bound = self._bound_pods_now()
+        counted = pre.nominees_counted_for(pod, nominees, self._priority_of)
+        with TRACE.phase("service.featurize", self.metrics, "featurize"):
+            feats = featurizer.featurize(
+                nodes, (), queue_pods=[pod], bound_pods=bound,
+                namespaces=namespaces, **volume_kw,
+            )
+        plugins = tuple(factory(feats))
+        n_pad = int(feats.nodes.valid.shape[0])
+        mask = None
+        if counted:
+            if self._plugins_factory is not None or prof is None:
+                side = Featurizer(pod_bucket_min=self._pod_bucket_min)
+            else:
+                side = prof.featurizer(pod_bucket_min=self._pod_bucket_min)
+            feats_n = side.featurize(
+                nodes, (), queue_pods=[pod], bound_pods=bound + counted,
+                namespaces=namespaces, **volume_kw,
+            )
+            res_n = Engine(feats_n, tuple(factory(feats_n)), record="full").evaluate_batch()
+            ok_n = np.asarray(res_n.reason_bits[0] == 0).all(axis=0)
+            passed = {
+                feats_n.nodes.names[i]
+                for i in range(feats_n.nodes.count)
+                if ok_n[i]
+            }
+            mask = np.zeros(n_pad, bool)
+            for i in range(feats.nodes.count):
+                mask[i] = feats.nodes.names[i] in passed
+
+        def run(node_mask):
+            eng = Engine(
+                feats, plugins, record=self._record, metrics=self.metrics,
+                node_mask=node_mask,
+            )
+            return eng.schedule(pull_state=False)[0]
+
+        own = pre.nominated_node_of(pod)
+        if own is not None and own in feats.nodes.names[: feats.nodes.count]:
+            first = np.zeros(n_pad, bool)
+            first[feats.nodes.names.index(own)] = True
+            if mask is not None:
+                first &= mask
+            res = run(first)
+            if int(res.selected[0]) >= 0:
+                return feats, plugins, res
+        return feats, plugins, run(mask)
+
+    def _clear_lower_nominations(
+        self, node_name: str, pod: JSON, priority_of=None
+    ) -> None:
+        """prepareCandidate: pods of a lower priority nominated to the
+        node ``pod`` just took have to look again.  ``priority_of``
+        defaults to the running pass's resolver (the device replay's
+        reconcile, which runs no pass, brings its own)."""
+        from ksim_tpu.scheduler.preemption import nominated_node_of
+
+        priority_of = priority_of or self._priority_of
+        prio = priority_of(pod)
+        for q in self._store.pods_without_node():
+            if nominated_node_of(q) != node_name or priority_of(q) >= prio:
+                continue
+
+            def clear(obj: JSON) -> JSON:
+                new = dict(obj)
+                status = dict(obj.get("status") or {})
+                status.pop("nominatedNodeName", None)
+                new["status"] = status
+                return new
+
+            try:
+                updated = self._store.rewrap("pods", name_of(q), namespace_of(q), clear)
+            except NotFoundError:
+                continue
+            with self._own_rvs_lock:
+                self._own_rvs.add(updated["metadata"]["resourceVersion"])
+
     def _bind_results(
-        self, queue, feats, plugins, res, placements, prof=None
-    ) -> tuple[float, float]:
-        """Decode, render and write back every pod of the pass; returns
-        the seconds summed over the pods inside ``render_pod_results``
-        and inside ``store.rewrap`` (the rest of the ``bind`` timer is
-        the host hook chains and the loop itself)."""
+        self, queue, feats, plugins, res, placements, prof=None, nominees=None
+    ) -> tuple[float, float, int]:
+        """Decode, render and write back the pods of one engine run, up
+        to and including the first whose preemption found victims (their
+        going and its nomination change what every later pod sees: the
+        pass evaluates the rest anew); returns the seconds summed over
+        the pods inside ``render_pod_results`` and inside
+        ``store.rewrap`` (the rest of the ``bind`` timer is the host hook
+        chains and the loop itself) and how many pods were written.
+        ``nominees``: the nominations that stand as the run begins
+        (``_live_nominees``; none changes before the run ends), for the
+        dry runs of its preemptors."""
         clock = time.perf_counter
         render_s = store_s = 0.0
         render_ctx = RenderCtx(feats, plugins) if self._record == "full" else None
+        done = 0
         for j, pod in enumerate(queue):
+            done = j + 1
             sel = int(res.selected[j])
             node_name = feats.nodes.names[sel] if sel >= 0 else None
             nominated, victims, postfilter = None, [], None
             if node_name is None:
                 nominated, victims, postfilter = self._run_post_filter(
-                    pod, feats, plugins, res, j, prof=prof
+                    pod, feats, plugins, res, j, prof=prof, nominees=nominees
                 )
             # Reserve runs first on a selected node (upstream cycle
             # order: Reserve -> Permit -> WaitOnPermit -> PreBind ->
@@ -1083,6 +1263,11 @@ class SchedulerService:
                     status.pop("nominatedNodeName", None)
                 elif nominated:
                     status["nominatedNodeName"] = nominated
+                elif self._preemption:
+                    # A failed attempt that preemption did not help
+                    # gives an earlier nomination up (upstream clears
+                    # it with the failure's nominating info).
+                    status.pop("nominatedNodeName", None)
                 new["spec"] = spec
                 new["status"] = status
                 return new
@@ -1113,7 +1298,10 @@ class SchedulerService:
             for v in victims:
                 self._evict_victim(v)
             placements[f"{namespace_of(pod)}/{name_of(pod)}"] = node_name
-        return render_s, store_s
+            if nominated and node_name is None:
+                self._clear_lower_nominations(nominated, pod)
+                break
+        return render_s, store_s, done
 
     # -- host extension points (PreEnqueue/PostFilter/PreBind/Bind/PostBind) -
 
@@ -1156,7 +1344,7 @@ class SchedulerService:
             logger.exception("%s hook of plugin %s failed", point, name)
             return None, f"{point} error: {e}"
 
-    def _run_post_filter(self, pod, feats, plugins, res, j, prof=None):
+    def _run_post_filter(self, pod, feats, plugins, res, j, prof=None, nominees=None):
         """The PostFilter chain: DefaultPreemption (structural) first in
         its default-config position, then out-of-tree ``post_filter``
         hooks in plugin order until one nominates a node — upstream
@@ -1169,7 +1357,7 @@ class SchedulerService:
         )
         if default_on:
             nominated, victims, post = self._attempt_preemption(
-                pod, feats, plugins, res, j
+                pod, feats, plugins, res, j, nominees=nominees
             )
         if nominated is not None:
             return nominated, victims, post
@@ -1712,9 +1900,11 @@ class SchedulerService:
                 self._backoff[key] = (attempts, self._pass_count + delay)
             self.metrics.inc("pods_permit_rejected")
 
-    def _attempt_preemption(self, pod, feats, plugins, res, j):
+    def _attempt_preemption(self, pod, feats, plugins, res, j, nominees=None):
         """DefaultPreemption for one unschedulable pod (PostFilter).
-        Returns (nominated_node, victims, postfilter_annotation_map)."""
+        Returns (nominated_node, victims, postfilter_annotation_map).
+        ``nominees``: the nominations that stand (``_live_nominees``);
+        read from the store where the caller does not hold them."""
         from ksim_tpu.scheduler import preemption as pre
 
         n_valid = feats.nodes.count
@@ -1746,6 +1936,7 @@ class SchedulerService:
             pod, nodes, cluster_pods, candidate_mask=live_mask,
             namespaces=namespaces, volumes=volumes,
             priority_of=self._priority_of,
+            nominees=self._live_nominees(nodes) if nominees is None else nominees,
         )
         post = pre.render_postfilter_result(failed_nodes, decision.nominated_node)
         return decision.nominated_node, decision.victims, post

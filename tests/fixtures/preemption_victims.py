@@ -126,4 +126,24 @@ CASES = [
         "expected_nominated": "b",
         "expected_victims": ["vb"],
     },
+    {
+        # Criterion 2 as upstream writes it: every victim's priority
+        # enters the sum with MaxInt32+1 added, so that fewer victims
+        # weigh less.  The preemptor needs the whole node (cpu 2 of 2),
+        # so every pod falls on both.  Highest priorities tie (3 == 3).
+        # a: 3 + 1 + 2 x 2**31; b: 3 + 0 + 0 + 3 x 2**31 -> a.  The bare
+        # sums (4 against 3) would have chosen b (PR 32).
+        "name": "priority_sum_counts_each_victim",
+        "nodes": [("a", "2"), ("b", "2")],
+        "victims": [
+            ("a-hi", "a", "1", 3, None),
+            ("a-lo", "a", "1", 1, None),
+            ("b-hi", "b", "1", 3, None),
+            ("b-m", "b", "500m", 0, None),
+            ("b-n", "b", "500m", 0, None),
+        ],
+        "preemptor": ("2", 10, None),
+        "expected_nominated": "a",
+        "expected_victims": ["a-hi", "a-lo"],
+    },
 ]

@@ -42,9 +42,11 @@ MODES = [False, True]
 MODE_IDS = ["f32-fast", "exact-x64"]
 
 
-def _blocks(n: int) -> int:
-    """``n`` attempts rounded up to whole blocks of the pod loop."""
-    return -(-n // SCAN_UNROLL) * SCAN_UNROLL
+def _blocks(n: int, searching: bool = False) -> int:
+    """``n`` attempts rounded up to whole blocks of the pod loop: blocks
+    of ``SCAN_UNROLL`` slots, or of one slot in a window that lowers with
+    the victim search (its slot body holds the whole search; PR 32)."""
+    return n if searching else -(-n // SCAN_UNROLL) * SCAN_UNROLL
 
 
 def _churn_ops():
@@ -170,8 +172,13 @@ def test_device_path_equals_per_pass_and_runs_only_the_attempts(
     assert stats["device_steps"] == len(committed_steps) >= len(dev["steps"]) // 2
     attempts = [o.scheduled + o.unschedulable for o in committed_steps]
     assert sum(attempts) > 0
-    assert [o.slots_run for o in committed_steps] == [_blocks(a) for a in attempts]
-    assert stats["queue_slots_run"] == sum(_blocks(a) for a in attempts)
+    # A step's block is its window's: one slot where the window lowered
+    # with the victim search (priorities differ in it), SCAN_UNROLL else.
+    for o, a in zip(committed_steps, attempts):
+        assert o.slots_run in (_blocks(a), _blocks(a, searching=True))
+    if not preempt:
+        assert [o.slots_run for o in committed_steps] == [_blocks(a) for a in attempts]
+    assert stats["queue_slots_run"] == sum(o.slots_run for o in committed_steps)
     assert sum(attempts) <= stats["queue_slots_run"] <= sum(attempts) + (
         SCAN_UNROLL - 1
     ) * sum(1 for a in attempts if a)
@@ -274,8 +281,9 @@ def test_fleet_lanes_with_different_attempt_counts_equal_their_solo_runs(lane_pl
     plan, state_a, state_b = lane_plan
     solo = [_solo(plan, s) for s in (state_a, state_b)]
     slots = [np.asarray(outs["slots"]) for _fin, outs in solo]
-    assert slots[0].tolist() == [_blocks(10), 0, 0, 0]
-    assert slots[1].tolist() == [_blocks(3), 0, 0, 0]
+    assert plan.statics.preempt
+    assert slots[0].tolist() == [_blocks(10, searching=True), 0, 0, 0]
+    assert slots[1].tolist() == [_blocks(3, searching=True), 0, 0, 0]
     fin, outs = replay._fleet_exec(plan, [state_a, state_b])
     for lane, (solo_fin, solo_outs) in enumerate(solo):
         # (The packed transfer hands scalars back as shape (1,).)
