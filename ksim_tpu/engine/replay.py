@@ -57,11 +57,21 @@ Two former fallback classes are now lowered instead (round 7):
   filter verdict of the window is node-local (no DoNotSchedule spread
   constraint and no required pod (anti-)affinity in the universe: the
   lowering reads that off the lowered tensors, ``_SegmentStatics.
-  local``): one sort groups the lower-priority pods by node in
-  MoreImportantPod order, the filter chain runs over the whole node
-  axis for "everything lower gone" and once per reprieve rank, and
-  pickOneNode is one lexicographic argmin over the first
-  ``candidate_count(live nodes)`` candidates in name order.  A window
+  local``): the victim table groups the lower-priority pods by node
+  in MoreImportantPod order (one sort of the pod axis), the filter
+  chain runs over the whole node axis for "everything lower gone" and
+  once per reprieve rank, and pickOneNode is one lexicographic argmin
+  over the first ``candidate_count(live nodes)`` candidates in name
+  order.  The table is built once a PASS AND PRIORITY LEVEL, by the
+  first search of the level, and carried through the pass's pod loop:
+  the universe axis is in queue order (priority descending), so a pod
+  that binds in the pass is never of a lower priority than a
+  preemptor behind it, and the only pods that go inside a pass are a
+  preemptor's own victims — between two searches of a level the
+  table only shrinks, by those victims, on the one node chosen, and
+  each verdict takes them out of that node's row
+  (``_repair_table``).  A search that finds the table current sorts
+  and gathers nothing of the pod axis.  A window
   that holds such a constraint walks the candidates in name order
   instead, one exact hypothetical state a check, until upstream's
   count is found.  The only bound is ``VMAX`` lower-priority pods a
@@ -593,18 +603,14 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
         nm_rows.update(nm_sel=sel_rows, nm_qm=qm_rows, nm_eat=eat_rows, nm_vw=vw_rows)
 
     if st.preempt:
-        # What a search reads of a node's lower-priority pods, side by
-        # side, so that the victim table costs ONE gather of pod rows (a
-        # gather of 6,144 x 8 rows is ~0.3 ms on the chip, and there were
-        # three): requests, priority, start rank.
+        # What a search reads of a node's lower-priority pods, each a
+        # column of the pod axis: its requests (one a resource), its
+        # priority, its start rank.  The victim table gathers them once
+        # a pass and level (``_lower_table``).
         R = prow["requests"].shape[1]
-        pod_facts = jnp.concatenate(
-            [
-                prow["requests"],
-                prow["priority"][:, None].astype(prow["requests"].dtype),
-                prow["start_rank"][:, None].astype(prow["requests"].dtype),
-            ],
-            axis=1,
+        pod_facts = tuple(prow["requests"][:, c] for c in range(R)) + (
+            prow["priority"],
+            prow["start_rank"],
         )
 
     def _nom_apply(live: dict, nodes, rows, sign: int) -> dict:
@@ -647,6 +653,11 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
 
     def _row_set(arr, idx, row):
         return jnp.where(_hit(arr, idx), jnp.asarray(row, arr.dtype)[None], arr)
+
+    def _col_set(arr, idx, col):
+        """``_row_set`` for an array whose LAST axis is the indexed one."""
+        hit = jnp.arange(arr.shape[-1], dtype=jnp.int32) == idx
+        return jnp.where(hit, jnp.asarray(col, arr.dtype)[..., None], arr)
 
     def _nom_apply_one(live: dict, node, row, sign: int) -> dict:
         """``_nom_apply`` for one pod inside the pod loop."""
@@ -791,14 +802,35 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
         return prog._eval_filters(view, pod, aux, carr)[0]
 
     def _lower_table(live, lower):
-        """The pods of ``lower`` (bool [P]) grouped by the node they are
-        bound to, each node's in MoreImportantPod order: (count [N],
-        rows [N, V], present [N, V]).  One sort of the pod axis by
-        (node, importance rank) and a binary search per node for where
-        its group starts; a node's pods beyond the V-th are cut (the
-        caller raises the overflow flag for a node it examines).  With
-        them ``facts`` [N, V, R + 2]: each such pod's requests, priority
-        and start rank (``pod_facts``), zero where absent."""
+        """The victim table: the pods of ``lower`` (bool [P]) grouped by
+        the node they are bound to, each node's in MoreImportantPod
+        order — ``cnt`` [N], ``vrow`` (universe rows), ``vact``
+        (present) and ``facts`` (each such pod's requests, priority and
+        start rank, ``pod_facts``; zero where absent).  One sort of the
+        pod axis by (node, importance rank), a binary search per node
+        for where its group starts, two gathers of N x V rows; a node's
+        pods beyond the V-th are cut (a search raises the overflow flag
+        for such a node it examines, and the segment is discarded: a cut
+        row is never refilled).
+
+        Kept with the NODE AXIS LAST — ``vrow`` / ``vact`` [V, N],
+        ``facts`` R + 2 arrays [V, N] — so that the carried arrays are
+        dense on the chip (a trailing axis of 5 or 8 is padded to 128
+        lanes, and the compiler keeps such an axis last whatever the
+        order it is written in: 25 MB for the facts where 1 MB does) and
+        one node's entries are one column (``_col_set``); a search
+        reads them as they are kept.
+
+        Built ONCE A PASS AND PRIORITY LEVEL, by the first attempt of
+        the level that searches, and carried through the pass's pod loop
+        (``pod_body_preempt``).  Between two searches of a level the set
+        it groups only SHRINKS: the queue is in priority order (the
+        universe axis, checked by the lowering), so a pod that binds in
+        the pass is never of a lower priority than a preemptor behind
+        it; events and deletes happen between passes; the only pods that
+        go inside a pass are a preemptor's own victims, all on the one
+        node it chose.  ``_repair_table`` takes those out of that node's
+        row after every verdict."""
         nkey = jnp.where(lower, live["bound"], N).astype(jnp.int32)
         sn, _si, srow = jax.lax.sort(
             (nkey, prow["imp_rank"], jnp.arange(P, dtype=jnp.int32)), num_keys=2
@@ -808,12 +840,46 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
         ).astype(jnp.int32)
         start, cnt = edges[:-1], edges[1:] - edges[:-1]
         kk = jnp.arange(v_eff, dtype=jnp.int32)
-        vact = kk[None, :] < cnt[:, None]
+        vact = kk[:, None] < cnt[None, :]
         vrow = jnp.where(
-            vact, srow[jnp.clip(start[:, None] + kk[None, :], 0, P - 1)], 0
+            vact, srow[jnp.clip(start[None, :] + kk[:, None], 0, P - 1)], 0
         )
-        facts = jnp.where(vact[..., None], pod_facts[vrow], 0)
-        return cnt, vrow, vact, facts
+        facts = tuple(jnp.where(vact, col[vrow], 0) for col in pod_facts)
+        return {"cnt": cnt, "vrow": vrow, "vact": vact, "facts": facts}
+
+    def _repair_table(table, found):
+        """The carried victim table after a search's verdict (``found``):
+        the chosen node's victims leave its row, the pods that stay close
+        up in order (``found["vic"]`` is slot-aligned with the row: -1
+        where the pod was reprieved or absent), the count falls by the
+        victims.  One row, written as one-hot passes like the rest of the
+        pod loop's state; no nomination (``nom`` < 0) touches nothing."""
+        nom = found["nom"]
+        chosen = jnp.maximum(nom, 0)
+
+        def at(arr):
+            return jax.lax.dynamic_index_in_dim(arr, chosen, arr.ndim - 1, keepdims=False)
+
+        kk = jnp.arange(v_eff, dtype=jnp.int32)
+        stay = at(table["vact"]) & (found["vic"] < 0)  # [V]
+        dest = jnp.cumsum(stay.astype(jnp.int32)) - 1
+        # place[d, s]: the pod in slot s stays and lands in slot d.
+        place = stay[None, :] & (dest[None, :] == kk[:, None])
+        n_vic = jnp.sum((found["vic"] >= 0).astype(jnp.int32))
+        return dict(
+            table,
+            cnt=_row_add(table["cnt"], nom, -n_vic),
+            vrow=_col_set(
+                table["vrow"],
+                nom,
+                jnp.sum(jnp.where(place, at(table["vrow"])[None, :], 0), axis=1),
+            ),
+            vact=_col_set(table["vact"], nom, jnp.any(place, axis=1)),
+            facts=tuple(
+                _col_set(f, nom, jnp.sum(jnp.where(place, at(f)[None, :], 0), axis=1))
+                for f in table["facts"]
+            ),
+        )
 
     def _victims_over_nodes(nstate, pcarries, live, pod, cnt, vreq, vact, examine, over, has_nom):
         """selectVictimsOnNode for EVERY node at once, for a window whose
@@ -823,11 +889,14 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
         gone, answers each node's own question; then the pods come back
         rank by rank (each node's k-th most important at step k) and stay
         where the chain still passes.  Both filter runs where nominated
-        pods count.  Returns (fits with all lower gone [N], victim mask
-        [N, V])."""
+        pods count.  ``vreq``: the victims' requests, one [V, N] array a
+        resource; ``vact`` [V, N].  Returns (fits with all lower gone
+        [N], victim mask [V, N])."""
         req_dt, cnt_dt = nstate.requested.dtype, nstate.pod_count.dtype
-        base_req = nstate.requested - jnp.sum(vreq, axis=1).astype(req_dt)
-        base_cnt = nstate.pod_count - jnp.sum(vact, axis=1).astype(cnt_dt)
+        base_req = nstate.requested - jnp.stack(
+            [jnp.sum(f, axis=0) for f in vreq], axis=1
+        ).astype(req_dt)
+        base_cnt = nstate.pod_count - jnp.sum(vact, axis=0).astype(cnt_dt)
 
         def fit(req, pc):
             view = nstate._replace(requested=req, pod_count=pc)
@@ -843,13 +912,16 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
 
         def reprieve(k, c):
             req, pc, vic = c
-            a = jax.lax.dynamic_index_in_dim(vact, k, 1, keepdims=False)
-            r = jax.lax.dynamic_index_in_dim(vreq, k, 1, keepdims=False)
+            a = jax.lax.dynamic_index_in_dim(vact, k, 0, keepdims=False)
+            r = jnp.stack(
+                [jax.lax.dynamic_index_in_dim(f, k, 0, keepdims=False) for f in vreq],
+                axis=1,
+            )
             t_req = req + r.astype(req_dt)
             t_pc = pc + a.astype(cnt_dt)
             ok_k = fit(t_req, t_pc)
             back = a & ok_k  # reprieved: stays re-added
-            vic = jax.lax.dynamic_update_index_in_dim(vic, a & ~ok_k, k, 1)
+            vic = jax.lax.dynamic_update_index_in_dim(vic, a & ~ok_k, k, 0)
             return (
                 jnp.where(back[:, None], t_req, req),
                 jnp.where(back, t_pc, pc),
@@ -863,9 +935,9 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
             0,
             _lane_max(ranks),
             reprieve,
-            (base_req, base_cnt, jnp.zeros((N, v_eff), bool)),
+            (base_req, base_cnt, jnp.zeros((v_eff, N), bool)),
         )
-        return fit0, vic & fit0[:, None]
+        return fit0, vic & fit0[None, :]
 
     def _victims_by_walk(nstate, pcarries, live, pod, cnt, vrow, vact, examine, over, has_nom, rank_names, want_k):
         """selectVictimsOnNode node by node, for a window that holds a
@@ -875,7 +947,7 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
         node's lower-priority pods gone, the spread and inter-pod carries
         re-derived from the modified locals — through the whole compiled
         chain, both filter runs, until ``want_k`` candidates are found.
-        Returns (candidate [N], victim mask [N, V])."""
+        Returns (candidate [N], victim mask [V, N])."""
         order = jnp.argsort(jnp.where(examine, rank_names, _I32_MAX)).astype(jnp.int32)
         n_exam = jnp.sum(examine.astype(jnp.int32)).astype(jnp.int32)
 
@@ -918,7 +990,7 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
             # another still walks (its trips write nothing).
             more = wanted(i, found)
             n_i = order[jnp.minimum(i, N - 1)]
-            rows, act = vrow[n_i], vact[n_i] & more
+            rows, act = vrow[:, n_i], vact[:, n_i] & more
             fit0 = eval_fit(n_i, rows, act)
 
             def reprieve(v, rc):
@@ -942,7 +1014,7 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
                 i + more.astype(jnp.int32),
                 found + cand.astype(jnp.int32),
                 is_c.at[at].set(cand, mode="drop"),
-                vic.at[at].set(vc & cand, mode="drop"),
+                vic.at[:, at].set(vc & cand, mode="drop"),
             )
 
         def any_lane_wants(c):
@@ -958,15 +1030,18 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
                 jnp.int32(0),
                 jnp.int32(0),
                 jnp.zeros(N, bool),
-                jnp.zeros((N, v_eff), bool),
+                jnp.zeros((v_eff, N), bool),
             ),
         )
         return is_c, vic
 
-    def _preempt_search(s, nstate, pcarries, live, pod, lvl, bits_mat, ev_k, lower):
+    def _preempt_search(s, nstate, pcarries, live, pod, lvl, bits_mat, ev_k, table):
         """DefaultPreemption's victim search for one unschedulable pod,
         against the pass's state as its predecessors left it (their
-        binds, their victims gone, their nominations):
+        binds, their victims gone, their nominations), over the pass's
+        carried victim ``table`` of the pod's priority level
+        (``_lower_table``: current when this runs; the search itself
+        sorts, searches and gathers nothing of the pod axis):
 
         - nodes to examine = nodes holding >= 1 pod of a lower priority,
           resolvable per the reason-bit table (full-record mode only —
@@ -999,17 +1074,17 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
         else:
             resolvable = jnp.ones(N, bool)
         rank_names, want_k = ev_k["name_rank"], ev_k["want"]
-        cnt, vrow, vact, facts = _lower_table(live, lower)
+        cnt, vrow, vact = table["cnt"], table["vrow"], table["vact"]
+        vreq, vprio, vstart = table["facts"][:R], table["facts"][R], table["facts"][R + 1]
         examine = (cnt > 0) & valid_now & resolvable
         over_v = jnp.any(examine & (cnt > v_eff))
         over = _nom_at(live, lvl)
         has_nom = jnp.any(over["nm_cnt"] > 0)
         if st.local:
             is_c, vic = _victims_over_nodes(
-                nstate, pcarries, live, pod, cnt, facts[..., :R], vact, examine,
-                over, has_nom,
+                nstate, pcarries, live, pod, cnt, vreq, vact, examine, over, has_nom,
             )
-            is_c = is_c & jnp.any(vic, axis=1)
+            is_c = is_c & jnp.any(vic, axis=0)
         else:
             is_c, vic = _victims_by_walk(
                 nstate, pcarries, live, pod, cnt, vrow, vact, examine, over,
@@ -1021,22 +1096,21 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
         pos = (jnp.cumsum(in_name_order.astype(jnp.int32)) - 1)[ev_k["name_pos"]]
         keep = is_c & (pos < want_k)
         any_c = jnp.any(keep)
-        vprio = facts[..., R].astype(jnp.int32)  # [N, V]
-        maxp = jnp.max(jnp.where(vic, vprio, _I32_MIN), axis=1)
+        maxp = jnp.max(jnp.where(vic, vprio, _I32_MIN), axis=0)
         # Sum of (priority + 2**31) over the victims, exactly, in 32-bit
         # lanes: each term as two 16-bit limbs, the low sum's carry
         # folded into the high one.
-        lo = jnp.sum(jnp.where(vic, vprio & 0xFFFF, 0), axis=1)
-        hi = jnp.sum(jnp.where(vic, (vprio >> 16) + 0x8000, 0), axis=1) + (lo >> 16)
+        lo = jnp.sum(jnp.where(vic, vprio & 0xFFFF, 0), axis=0)
+        hi = jnp.sum(jnp.where(vic, (vprio >> 16) + 0x8000, 0), axis=0) + (lo >> 16)
         lo = lo & 0xFFFF
-        n_vic = jnp.sum(vic.astype(jnp.int32), axis=1)
+        n_vic = jnp.sum(vic.astype(jnp.int32), axis=0)
         est = jnp.min(
             jnp.where(
-                vic & (vprio == maxp[:, None]),
-                facts[..., R + 1].astype(jnp.int32),
+                vic & (vprio == maxp[None, :]),
+                vstart,
                 _I32_MAX,
             ),
-            axis=1,
+            axis=0,
         )
         m = keep
         for arr, take_min in (
@@ -1053,7 +1127,7 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
         chosen = jnp.argmax(m).astype(jnp.int32)
         return {
             "nom": jnp.where(any_c, chosen, -1).astype(jnp.int32),
-            "vic": jnp.where(any_c & vic[chosen], vrow[chosen], -1),
+            "vic": jnp.where(any_c & vic[:, chosen], vrow[:, chosen], -1),
             "over": over_v,
             "cands": jnp.sum(keep.astype(jnp.int32)).astype(jnp.int32),
         }
@@ -1150,6 +1224,7 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
                 z["overflow"] = jnp.zeros((), bool)
                 z["searches"] = jnp.zeros((), jnp.int32)
                 z["cands"] = jnp.zeros((), jnp.int32)
+                z["builds"] = jnp.zeros((), jnp.int32)
             if st.record == "full":
                 z["bits"] = jnp.zeros((st.q, n_filters, N), bits_dtype)
                 z["raw"] = jnp.zeros((st.q, n_scores, N), raw_dtype)
@@ -1329,8 +1404,9 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
             """One attempt of a window with the victim search on: the
             pod is evaluated against the state its predecessors of this
             pass left (binds, victims gone, nominations), binds or
-            searches, and hands the state on."""
-            nstate, pcarries, live = pcarry
+            searches, and hands the state on — the victim table of the
+            pass with it (``_lower_table``)."""
+            nstate, pcarries, live, table = pcarry
             from ksim_tpu.plugins.base import PodView
 
             pod = PodView(
@@ -1384,16 +1460,25 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
             pred = failed & prow["preempt_ok"][j] & jnp.any(lower)
             bits_mat = out_pod["bits"] if st.record == "full" and n_filters else None
 
+            # The table is built where a search finds it stale: at the
+            # pass's first search, and where the level moves.
+            build = pred & (table["lvl"] != lvl)
+            table = _lane_cond(
+                build,
+                lambda: dict(_lower_table(live, lower), lvl=lvl),
+                lambda: table,
+            )
             found = _lane_cond(
                 pred,
                 lambda: _preempt_search(
-                    s, nstate, pcarries, live, pod, lvl, bits_mat, ev_k, lower
+                    s, nstate, pcarries, live, pod, lvl, bits_mat, ev_k, table
                 ),
                 lambda: dict(invalid_search),
             )
             nstate, pcarries, live, cleared = _apply_preemption(
                 nstate, pcarries, live, pod, lvl, found
             )
+            table = _repair_table(table, found)
             # -2: the attempt failed, preemption did not help, and the
             # nomination the pod came with is given up.
             out_pod["nom"] = jnp.where(
@@ -1404,7 +1489,8 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
             out_pod["cands"] = found["cands"]
             out_pod["clr"] = cleared
             out_pod["searched"] = pred
-            return (nstate, pcarries, live), out_pod
+            out_pod["built"] = build
+            return (nstate, pcarries, live, table), out_pod
 
         invalid_pod = {"best": jnp.int32(-1)}
         if st.record == "full":
@@ -1413,7 +1499,10 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
             invalid_pod["final"] = jnp.zeros((n_scores, N), final_dtype)
         if st.preempt:
             invalid_pod.update(
-                invalid_search, clr=jnp.int32(0), searched=jnp.zeros((), bool)
+                invalid_search,
+                clr=jnp.int32(0),
+                searched=jnp.zeros((), bool),
+                built=jnp.zeros((), bool),
             )
             # Name order of the live nodes, once a step: position ->
             # slot and back (dead nodes rank last).
@@ -1428,8 +1517,22 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
                 for k in ("alive", "bound", "ip_cnt", "ip_eat", "ip_vw", "nom_node")
                 + nm_keys
             }
-            (node_state, carries, live), pod_outs = run_slots(
-                pod_body_preempt, (node_state, carries, live0), pods_q, invalid_pod
+            # A pass starts with no victim table (level -1: events,
+            # deletes and the last pass's binds have moved ``live``).
+            table0 = {
+                "cnt": jnp.zeros(N, jnp.int32),
+                "vrow": jnp.zeros((v_eff, N), jnp.int32),
+                "vact": jnp.zeros((v_eff, N), bool),
+                "facts": tuple(
+                    jnp.zeros((v_eff, N), col.dtype) for col in pod_facts
+                ),
+                "lvl": jnp.int32(-1),
+            }
+            (node_state, carries, live, _table), pod_outs = run_slots(
+                pod_body_preempt,
+                (node_state, carries, live0, table0),
+                pods_q,
+                invalid_pod,
             )
         else:
             (node_state, carries), pod_outs = run_slots(
@@ -1514,6 +1617,7 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
             out["overflow"] = jnp.any(pod_outs["over"])
             out["searches"] = jnp.sum(pod_outs["searched"].astype(jnp.int32)).astype(jnp.int32)
             out["cands"] = jnp.sum(pod_outs["cands"]).astype(jnp.int32)
+            out["builds"] = jnp.sum(pod_outs["built"].astype(jnp.int32)).astype(jnp.int32)
         if st.record == "full":
             out["bits"] = pod_outs["bits"]
             out["raw"] = pod_outs["raw"]
@@ -1675,6 +1779,7 @@ class StepOutcome:
     slots_run: int  # queue slots the device's pod loops ran for this step
     searches: int = 0  # victim searches the step ran on the device
     candidates: int = 0  # candidates those searches kept, summed
+    table_builds: int = 0  # victim tables the step built (once a pass and level)
     # (namespace, name, node_name) in queue (commit) order.
     binds: list[tuple[str, str, str]] = field(default_factory=list)
     # Per-attempt detail (preemption / full-record segments); None means
@@ -1782,9 +1887,12 @@ class ReplayDriver:
         self.pairs_evaluated = 0  # guarded-by: main-thread
         self.queue_slots_run = 0  # guarded-by: main-thread
         # The on-device victim search, over the committed segments:
-        # searches run, candidates they kept, victims evicted,
-        # nominations made; and the segments discarded for VMAX.
+        # searches run, victim tables built for them (one a pass and
+        # priority level: near the searches = the carried table is not
+        # engaging), candidates they kept, victims evicted, nominations
+        # made; and the segments discarded for VMAX.
         self.preempt_searches = 0  # guarded-by: main-thread
+        self.preempt_table_builds = 0  # guarded-by: main-thread
         self.preempt_candidates = 0  # guarded-by: main-thread
         self.preempt_victims = 0  # guarded-by: main-thread
         self.preempt_nominations = 0  # guarded-by: main-thread
@@ -1913,6 +2021,7 @@ class ReplayDriver:
             "pairs_evaluated": self.pairs_evaluated,
             "queue_slots_run": self.queue_slots_run,
             "preempt_searches": self.preempt_searches,
+            "preempt_table_builds": self.preempt_table_builds,
             "preempt_candidates": self.preempt_candidates,
             "preempt_victims": self.preempt_victims,
             "preempt_nominations": self.preempt_nominations,
@@ -2898,6 +3007,15 @@ class ReplayDriver:
         prios = None
         if preempt_plan:
             prios = [priority_of(p) for p in universe_pods]
+            # The pass's victim table (``_lower_table``) is kept across
+            # the searches of a priority level because a pod that binds
+            # is never of a lower priority than a preemptor behind it.
+            if any(a < b for a, b in zip(prios, prios[1:])):
+                raise AssertionError(
+                    "the universe axis is not in queue order (priority "
+                    "descending): the victim table a pass carries would miss "
+                    "the pods that bind between two searches of a level"
+                )
             if (
                 self._record_mode == "selection"
                 and not any(
@@ -3403,6 +3521,7 @@ class ReplayDriver:
             "pairs_evaluated": 0,
             "slots_run": 0,
             "preempt_searches": 0,
+            "preempt_table_builds": 0,
             "preempt_candidates": 0,
             "preempt_victims": 0,
             "preempt_nominations": 0,
@@ -3726,6 +3845,7 @@ class ReplayDriver:
                     slots_run=int(pulled["slots"][k]),
                     searches=int(pulled["searches"][k]) if st.preempt else 0,
                     candidates=int(pulled["cands"][k]) if st.preempt else 0,
+                    table_builds=int(pulled["builds"][k]) if st.preempt else 0,
                     binds=binds,
                     attempts=attempts,
                 )
@@ -3825,6 +3945,7 @@ class ReplayDriver:
         self.queue_slots_run += slots
         preempt = {
             "preempt_searches": sum(o.searches for o in seg.steps),
+            "preempt_table_builds": sum(o.table_builds for o in seg.steps),
             "preempt_candidates": sum(o.candidates for o in seg.steps),
             "preempt_victims": sum(
                 len(a.victims) for o in seg.steps for a in o.attempts or ()

@@ -101,6 +101,7 @@ def _run(steps, *, device, k=4, record="selection"):
         assert driver.fallback_steps == 0, driver.unsupported
         assert driver.device_steps == len(result.steps)
         got["stats"] = driver.stats()
+        got["local"] = driver._last_plan.statics.local
     return got
 
 
@@ -135,6 +136,8 @@ def test_preemption_basic_is_the_hand_derived_answer(n, device):
         stats = got["stats"]
         assert stats["preempt_searches"] == n and stats["preempt_victims"] == 3 * n
         assert stats["preempt_nominations"] == n and stats["preempt_overflows"] == 0
+        # One pass, one priority: the n searches share one victim table.
+        assert stats["preempt_table_builds"] == 1
         # Preemptor k finds the n - k nodes no earlier one took.
         assert stats["preempt_candidates"] == n * (n + 1) // 2
 
@@ -307,6 +310,89 @@ def test_a_victim_that_only_the_anti_affinity_names(device):
         assert got["stats"]["preempt_searches"] == 1
 
 
+def _three_tiers_one_pass(*, walk=False):
+    """One pass over three tiers of pending pods (priorities 20, 10, 0) in
+    which binds and searches interleave, two preemptors take the SAME node
+    and the preemptors' priority changes mid-pass.  Nodes (cpu) and what they
+    hold (cpu, priority): n-v (1): v-lo (1, 0); n-w (1): w-lo (1, 5); n-x
+    (8): x-a (1, 6), x-b (4, 1), x-c (1, 1), x-d (2, 0); n-y (4): y-hi
+    (2, 30), y-lo (2, 15); n-z (2,100m): z-hi (1, 30).  All full but n-z.
+    The queue, by priority then name:
+
+    - t20-a (6 cpu) fits nowhere.  Lower than 20: everything but y-hi and
+      z-hi.  Only n-x is large enough: all four off, it fits; they come back
+      most important first: x-a (7 of 8), x-b (11: stays off), x-c (8), x-d
+      (10: off).  Victims x-b, x-d — the second and the fourth of the node's
+      row, which closes up to x-a, x-c.  Nominated to n-x.
+    - t20-b (500m) binds on n-z, the one node with room (n-x counts t20-a in).
+    - t20-c (2 cpu) fits nowhere.  n-x, from the closed-up row and with
+      t20-a counted in (6): x-a and x-c off, it fits (8); neither can come
+      back (9): victims x-a, x-c.  n-y: y-lo off, it fits; victim y-lo.  The
+      highest victim priority decides: 6 on n-x against 15 on n-y: n-x again.
+    - t10-a (1 cpu) fits nowhere (n-x holds 8 cpu of nominees of its priority
+      or above).  Lower than 10 now: v-lo and w-lo — NOT y-lo, which the
+      table of the pods before it held.  Both nodes are candidates with one
+      victim; priority 0 against 5: n-v, victim v-lo.
+    - t10-b (250m) binds on n-z.
+    - t10-c (2 cpu) fits nowhere; w-lo is the one pod lower, on a node too
+      small: no candidate, no nomination (a table kept from priority 20
+      would offer y-lo).
+    - t00-a (250m) binds on n-z.
+
+    Four searches over two tables (priorities 20 and 10).  Next pass: t20-a
+    and t20-c bind on n-x, t10-a on n-v, each on its nominated node; t10-c
+    sits the pass out; the 100m tail takes what n-z has left.  ``walk``: the
+    last pod carries a required anti-affinity (against nothing there is), so
+    the window's search walks its candidates in name order."""
+    def node(name, cpu):
+        return ("nodes", make_node(name, cpu=cpu, memory="8Gi",
+                                   labels={"kubernetes.io/hostname": name}))
+
+    def bound(name, cpu, priority, on):
+        return ("pods", _constrained(name, cpu, priority, name, node=on))
+
+    def pending(name, cpu, priority, anti=None):
+        return ("pods", _constrained(name, cpu, priority, name, anti=anti))
+
+    return [
+        [node("n-v", "1"), node("n-w", "1"), node("n-x", "8"), node("n-y", "4"),
+         node("n-z", "2100m"),
+         bound("v-lo", "1", 0, "n-v"), bound("w-lo", "1", 5, "n-w"),
+         bound("x-a", "1", 6, "n-x"), bound("x-b", "4", 1, "n-x"),
+         bound("x-c", "1", 1, "n-x"), bound("x-d", "2", 0, "n-x"),
+         bound("y-hi", "2", 30, "n-y"), bound("y-lo", "2", 15, "n-y"),
+         bound("z-hi", "1", 30, "n-z")],
+        [pending("t20-a", "6", 20), pending("t20-b", "500m", 20), pending("t20-c", "2", 20),
+         pending("t10-a", "1", 10), pending("t10-b", "250m", 10), pending("t10-c", "2", 10),
+         pending("t00-a", "250m", 0, anti="nothing" if walk else None)],
+        [pending("tail", "100m", 0)],
+    ]
+
+
+@PATHS
+@pytest.mark.parametrize("walk", [False, True], ids=["node_axis", "walk"])
+def test_two_preemptors_share_a_node_and_the_priority_changes_mid_pass(walk, device):
+    """The pass's victim table: the second preemptor of a node reads the row
+    the first one's verdict closed up, and the first preemptor of a lower
+    priority gets a table of its own."""
+    got = _run(_three_tiers_one_pass(walk=walk), device=device)
+    assert got["steps"] == [(3, 4), (4, 0)]
+    assert got["evicted"] == ["x-b", "x-d", "x-a", "x-c", "v-lo"]
+    assert got["placements"] == {
+        "w-lo": "n-w", "y-hi": "n-y", "y-lo": "n-y", "z-hi": "n-z",
+        "t20-a": "n-x", "t20-b": "n-z", "t20-c": "n-x", "t10-a": "n-v",
+        "t10-b": "n-z", "t10-c": None, "t00-a": "n-z", "tail": "n-z",
+    }
+    assert got["nominated"] == {}
+    if device:
+        assert got["local"] is not walk
+        stats = got["stats"]
+        assert stats["preempt_searches"] == 4 and stats["preempt_victims"] == 5
+        assert stats["preempt_nominations"] == 3 and stats["preempt_overflows"] == 0
+        # One table a pass and priority that searched: 20 and 10.
+        assert stats["preempt_table_builds"] == 2
+
+
 def test_nine_lower_priority_pods_on_a_node_discard_the_segment():
     """VMAX = 8 pods of a lower priority a node: one more and the segment is
     discarded as ``preemption_overflow`` before any store effect; the
@@ -327,41 +413,108 @@ def test_nine_lower_priority_pods_on_a_node_discard_the_segment():
     assert runner.store.get("pods", "p")["status"]["nominatedNodeName"] == "n0"
 
 
+def _plan_of(late):
+    """The lowered window (and the run's counters) of: two nodes of 2 cpu,
+    two 1,500m pods of priority 0 in step 1, one of priority ``late`` in
+    step 2 (one window of two steps)."""
+    nodes = [("nodes", make_node(f"n{i}", cpu="2", memory="8Gi")) for i in range(2)]
+    runner = _runner(nodes, device=True)
+    runner.run(_ops(
+        [("pods", _pod(f"p{i}", "1500m", 0)) for i in range(2)],
+        [("pods", _pod("late", "1500m", late))],
+    ))
+    driver = runner.replay_driver
+    assert driver.fallback_steps == 0, driver.unsupported
+    return driver._last_plan, driver.stats()
+
+
+def _traced(plan):
+    """The segment program of ``plan``, traced."""
+    from ksim_tpu.engine import replay
+
+    const, (ev, st) = replay._pack_plan_buffers(plan, (plan.ev, plan.state0))
+    return jax.make_jaxpr(
+        lambda c, e, s: replay._segment_body(plan.statics, plan.prog, c, e, s)
+    )(const, ev, st)
+
+
 def test_a_priority_flat_window_lowers_without_the_search():
     """The accepted benchmark cells are priority-flat: with preemption on
     they must still run the program they always ran.  The statics say so,
     the counters say so, and the traced program holds no sort (the victim
-    table is the only sort of the segment program); a window with two
-    priorities holds one."""
-    from ksim_tpu.engine import replay
-
-    def plan_of(late):
-        """Two nodes of 2 cpu, two 1,500m pods of priority 0 in step 1, one
-        of priority ``late`` in step 2 (one window of two steps)."""
-        nodes = [("nodes", make_node(f"n{i}", cpu="2", memory="8Gi")) for i in range(2)]
-        runner = _runner(nodes, device=True)
-        runner.run(_ops(
-            [("pods", _pod(f"p{i}", "1500m", 0)) for i in range(2)],
-            [("pods", _pod("late", "1500m", late))],
-        ))
-        driver = runner.replay_driver
-        assert driver.fallback_steps == 0, driver.unsupported
-        return driver._last_plan, driver.stats()
-
+    table's and the nodes' name order are the sorts of the segment
+    program); a window with two priorities holds them."""
     def sorts(plan):
-        const, (ev, st) = replay._pack_plan_buffers(plan, (plan.ev, plan.state0))
-        text = str(jax.make_jaxpr(
-            lambda c, e, s: replay._segment_body(plan.statics, plan.prog, c, e, s)
-        )(const, ev, st))
-        return text.count("sort[")
+        return str(_traced(plan)).count("sort[")
 
-    flat, flat_stats = plan_of(0)
+    flat, flat_stats = _plan_of(0)
     assert flat.statics.preempt is False and "nom_node" not in flat.state0
     assert flat_stats["preempt_searches"] == 0 and sorts(flat) == 0
-    tiered, tiered_stats = plan_of(5)
+    assert flat_stats["preempt_table_builds"] == 0
+    tiered, tiered_stats = _plan_of(5)
     assert tiered.statics.preempt is True and tiered.statics.local is True
     assert tiered_stats["preempt_searches"] == 1 and tiered_stats["preempt_victims"] == 1
+    assert tiered_stats["preempt_table_builds"] == 1
     assert sorts(tiered) >= 1
+
+
+def test_a_search_with_a_current_table_sorts_and_searches_nothing():
+    """The victim table is built in a conditional of its own, taken once a
+    pass and priority; the search's conditional reads the carried table.  In
+    the traced program of a window with two priorities: ONE sort of the pod
+    axis by (node, importance) and ONE ``searchsorted``, both inside the
+    build's branch, whose conditional hands back the table; the search's
+    conditional (it hands back the verdict: slot, victim rows, overflow,
+    candidates) holds neither in any branch."""
+    plan, stats = _plan_of(5)
+    assert stats["preempt_searches"] == 1 and stats["preempt_table_builds"] == 1
+    program = _traced(plan).jaxpr
+
+    def inner(eqn):
+        for value in eqn.params.values():
+            for v in value if isinstance(value, (tuple, list)) else (value,):
+                v = getattr(v, "jaxpr", v)
+                if hasattr(v, "eqns"):
+                    yield v
+
+    def table_work(eqn):
+        return (eqn.primitive.name == "sort" and eqn.params["num_keys"] == 2) or (
+            eqn.primitive.name in ("jit", "pjit") and eqn.params.get("name") == "searchsorted"
+        )
+
+    def count(jaxpr, pred):
+        return sum(
+            pred(e) + sum(count(j, pred) for j in inner(e)) for e in jaxpr.eqns
+        )
+
+    def conds(jaxpr):
+        for e in jaxpr.eqns:
+            if e.primitive.name == "cond":
+                yield e
+            for j in inner(e):
+                yield from conds(j)
+
+    assert count(program, table_work) == 2  # the sort and the edge search
+    shapes = lambda e: sorted((v.aval.shape, str(v.aval.dtype)) for v in e.outvars)
+    builds = [
+        e for e in conds(program)
+        if any(table_work(q) for b in e.params["branches"] for q in b.jaxpr.eqns)
+    ]
+    assert len(builds) == 1
+    n_nodes = plan.state0["valid"].shape[0]
+    v = min(plan.statics.v_max * plan.statics.tp, plan.state0["alive"].shape[0])
+    assert ((n_nodes,), "int32") in shapes(builds[0])  # cnt
+    assert ((v, n_nodes), "int32") in shapes(builds[0])  # vrow
+    assert ((v, n_nodes), "bool") in shapes(builds[0])  # vact
+    searches = [
+        e for e in conds(program)
+        if shapes(e) == sorted([((), "int32"), ((), "int32"), ((), "bool"), ((v,), "int32")])
+    ]
+    assert len(searches) == 1
+    assert sum(
+        count(b.jaxpr, lambda e: table_work(e) or e.primitive.name == "sort")
+        for b in searches[0].params["branches"]
+    ) == 0
 
 
 def test_the_write_back_of_a_steps_preemptions_is_one_child_span():
