@@ -461,6 +461,45 @@ def _plugin_sig(plugin: Any) -> tuple:
     return tuple(sig)
 
 
+@device_kernel()
+def sample_visited(feasible, real, start, n_real, k):
+    """Upstream's find-K-feasible iteration as tensor ops
+    (schedule_one.go findNodesThatPassFilters + numFeasibleNodesToFind,
+    idealized as the sequential visit order — upstream's parallel
+    workers make the exact visited set racy; the deterministic
+    sequential semantics is the reproducible contract).  The ONE
+    definition of the walk: the per-pass scan and the device replay's
+    pod loop both call it.
+
+    ``feasible`` / ``real`` are bool masks over the node axis IN VISIT
+    ORDER (the real nodes fill positions 0 .. n_real - 1), ``feasible``
+    already confined to ``real``.  Nodes are visited from the rotating
+    ``start`` (taken modulo ``n_real``) and the iteration stops once
+    ``k`` feasible nodes are found, or every node was seen: a node is
+    visited exactly when fewer than ``k`` feasible nodes come before it
+    in visit order.  With ``c`` the running count of feasible nodes in
+    index order and ``T`` its total, the count before node i in visit
+    order is ``c[i-1] - c[start-1]`` at or after ``start``, else
+    ``T - c[start-1] + c[i-1]`` — one prefix sum, no sort, and ``k`` an
+    operand.
+
+    Returns (visited, sample = feasible & visited, next start):
+    nextStartNodeIndex advances by the nodes processed this cycle
+    (feasible found + filtered-out visited = every visited node)."""
+    n = feasible.shape[0]
+    i = jnp.arange(n, dtype=jnp.int32)
+    nr = jnp.maximum(n_real, 1).astype(jnp.int32)
+    start = (start % nr).astype(jnp.int32)
+    f = feasible.astype(jnp.int32)
+    before = jnp.cumsum(f, dtype=jnp.int32) - f  # feasible nodes below i
+    total = jnp.sum(f, dtype=jnp.int32)
+    at_start = jnp.sum(jnp.where(i < start, f, 0), dtype=jnp.int32)
+    ahead = jnp.where(i >= start, before - at_start, total - at_start + before)
+    visited = real & (ahead < k)
+    n_visited = jnp.sum(visited, dtype=jnp.int32)
+    return visited, feasible & visited, (start + n_visited) % nr
+
+
 class _Program:
     """The static half of an Engine: plugin set + record mode, hashable by
     signature.  jax.jit keys its cache on this object (static argnum 0),
@@ -471,17 +510,18 @@ class _Program:
         self,
         plugins: tuple[ScoredPlugin, ...],
         record: str,
-        sampling_k: int | None = None,
+        sampled: bool = False,
     ) -> None:
         self.plugins = plugins
         self.record = record
         # percentageOfNodesToScore emulation: find-K-feasible sampling in
         # the sequential scan (upstream numFeasibleNodesToFind,
-        # schedule_one.go).  Static so lax.top_k can use it.
-        self.sampling_k = sampling_k
+        # schedule_one.go).  Only WHETHER the scan samples is static: K
+        # is an operand of ``_schedule_sampled_fn``.
+        self.sampled = sampled
         self._sig = (
             record,
-            sampling_k,
+            sampled,
             tuple(
                 (
                     _plugin_sig(sp.plugin),
@@ -670,41 +710,10 @@ class _Program:
 
         return jax.vmap(per_pod)(pods)
 
-    def _sample_visited(self, filter_ok, start, n_real):
-        """Upstream's find-K-feasible iteration as tensor ops
-        (schedule_one.go findNodesThatPassFilters + numFeasibleNodesToFind,
-        idealized as the sequential visit order — upstream's parallel
-        workers make the exact visited set racy; the deterministic
-        sequential semantics is the reproducible contract).
-
-        Nodes are visited in index order from the rotating ``start``;
-        iteration stops once ``sampling_k`` feasible nodes are found.
-        Returns (visited [N] bool, sample = feasible&visited,
-        new_start)."""
-        k = self.sampling_k
-        n = filter_ok.shape[0]
-        big = jnp.iinfo(jnp.int32).max
-        i = jnp.arange(n, dtype=jnp.int32)
-        nr = jnp.maximum(n_real, 1)
-        in_real = i < n_real
-        p = (i - start) % nr  # visit position of node i
-        p = jnp.where(in_real, p, big)
-        feas_pos = jnp.where(filter_ok & in_real, p, big)
-        # K-th smallest feasible visit position (big when < K feasible).
-        kth = -jax.lax.top_k(-feas_pos, k)[0][k - 1]
-        n_feas = jnp.sum((filter_ok & in_real).astype(jnp.int32))
-        threshold = jnp.where(n_feas >= k, kth, n_real - 1)
-        visited = in_real & (p <= threshold)
-        sample = filter_ok & visited
-        # nextStartNodeIndex advances by the nodes processed this cycle
-        # (feasible found + filtered-out visited = every visited node).
-        new_start = (start + threshold + 1) % nr
-        return visited, sample, new_start
-
     @partial(jax.jit, static_argnums=0)
     @device_kernel(static=("self",))
     def _schedule_sampled_fn(
-        self, state, pods: PodBatch, aux: dict, carries: dict, start, n_real
+        self, state, pods: PodBatch, aux: dict, carries: dict, start, n_real, k
     ):
         """The sequential-commit scan with percentageOfNodesToScore
         emulation: filter everywhere (the mask is needed to FIND the
@@ -722,7 +731,10 @@ class _Program:
                 index=pb.index,
             )
             ok, bits = self._eval_filters(node_state, pod, aux, plugin_carries)
-            visited, sample, new_start = self._sample_visited(ok, start, n_real)
+            in_real = jnp.arange(ok.shape[0], dtype=jnp.int32) < n_real
+            visited, sample, new_start = sample_visited(
+                ok & in_real, in_real, start, n_real, k
+            )
             # Padding pods never ran a cycle upstream: no rotation.
             new_start = jnp.where(pb.valid, new_start, start)
             raw, final, total = self._eval_scores(
@@ -819,7 +831,8 @@ class Engine:
                 f"padded axis is {int(feats.nodes.valid.shape[0])})"
             )
         self._feats = feats
-        self._prog = _Program(tuple(plugins), record, sampling_k=sampling_k)
+        self._prog = _Program(tuple(plugins), record, sampled=sampling_k is not None)
+        self._sampling_k = sampling_k
         n = feats.nodes
         p = feats.pods
         node_host = dict(
@@ -893,7 +906,7 @@ class Engine:
         device memory (record="full" at 16k x 8k is ~9GB of result
         tensors — far more than it costs to recompute, so nothing is
         retained)."""
-        if self._prog.sampling_k is not None:
+        if self._prog.sampled:
             raise ValueError(
                 "percentageOfNodesToScore emulation is scan-only "
                 "(batch evaluation has no sequential visit order)"
@@ -983,8 +996,9 @@ class Engine:
             chunk = min(P, self._default_schedule_chunk())
         state, carries = self._node_state, self._prog.init_carries(self._aux)
         outs = []
-        sampled = self._prog.sampling_k is not None
+        sampled = self._prog.sampled
         start = jnp.asarray(sampling_start, dtype=jnp.int32)
+        k = jnp.asarray(self._sampling_k or 0, dtype=jnp.int32)
         n_real = jnp.asarray(int(self._feats.nodes.count), dtype=jnp.int32)
         for s in range(0, P, chunk):
             pods_c = jax.tree_util.tree_map(
@@ -993,7 +1007,7 @@ class Engine:
             with TRACE.phase("engine.exec", self._metrics, "engine_exec"):
                 if sampled:
                     state, carries, start, out = self._prog._schedule_sampled_fn(
-                        state, pods_c, self._aux, carries, start, n_real
+                        state, pods_c, self._aux, carries, start, n_real, k
                     )
                 else:
                     state, carries, out = self._prog._schedule_fn(

@@ -82,6 +82,19 @@ Two former fallback classes are now lowered instead (round 7):
   fixed K to bound device memory); the host decodes them into the exact
   per-pass result annotations at segment boundaries.
 
+A third since PR 34: **sampled scoring** (the service's
+``node_sampling``: upstream's ``percentageOfNodesToScore``).  An attempt
+of a step whose live node count is sampled walks the node axis from a
+start index carried through the pod loop, the steps and the segment
+(``state0["sample_start"]``; written back to the service at commit),
+stops at the step's ``ev["sample_k"]`` feasible nodes, and scores,
+normalises and selects over those (``engine/core.py sample_visited``:
+a prefix count, no sort; k and the index are operands).  The walk
+goes in the service's node order: this table's slot order where the
+lowering saw them agree at every step, else by the rank tensor
+(``_SegmentStatics.sample``).  A universe that does not sample lowers
+the program it always lowered.
+
 Segments shorter than the compiled K (stream tails, mid-window
 vocabulary misses) are tail-padded with inactive no-op steps and reuse
 the existing compile.  Anything outside the remaining vocabulary
@@ -134,7 +147,7 @@ logger = logging.getLogger(__name__)
 FALLBACK_REASONS: frozenset[str] = frozenset(
     {
         # service/profile configuration outside the vocabulary
-        "record_mode", "extenders", "pnts_emulation", "shard_mesh",
+        "record_mode", "extenders", "shard_mesh",
         "featurizer_override", "multi_profile", "no_profile",
         "queue_hooks", "permit_waiters", "plugin_extender",
         # object vocabulary misses
@@ -479,6 +492,14 @@ class _SegmentStatics:
     # Whether any pod of the universe matches or carries an inter-pod
     # term: where none does, a victim's going cannot move the domain view.
     ip_terms: bool = True
+    # percentageOfNodesToScore (the service's ``node_sampling``): 0 = every
+    # attempt scores every feasible node (the program every window lowered
+    # before sampling reached this path, unchanged); 1 = an attempt walks
+    # the node axis from the carried start index, in SLOT order, which the
+    # lowering saw to be the service's node order at every step; 2 = the
+    # walk goes by the step's rank tensor (two gathers of the node axis a
+    # slot: node churn has moved the two orders apart).
+    sample: int = 0
     tp: int = 1  # node-axis mesh width (round 17 sharded replay)
     # Round 19: the vmap axis name the fleet program maps lanes over, or
     # None for a solo program.  With it set, the preemption-search gate
@@ -540,9 +561,11 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
     ev: per-step event streams, leading axis K — pod/node create/delete
         index lists (-1 padded), the flush flag, the canonical rank
         tensor, the per-step active flag (False = tail padding: the step
-        is a pure no-op), and (preemption) the per-step name-order node
-        ranks + upstream candidate count.
-    state0: the carried cluster tensor state at segment start.
+        is a pure no-op), (preemption) the per-step name-order node
+        ranks + upstream candidate count, and (sampling) the per-step
+        numFeasibleNodesToFind.
+    state0: the carried cluster tensor state at segment start; with
+        sampling, the walk's start index.
 
     Returns (final_state, outputs) where outputs stack per-step selected
     node rows + attempted pod rows and the step aggregates, plus (full
@@ -552,7 +575,7 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
     import jax.numpy as jnp
 
     from ksim_tpu.plugins.base import NodeStateView, PodBatch
-    from ksim_tpu.engine.core import SCAN_UNROLL
+    from ksim_tpu.engine.core import SCAN_UNROLL, sample_visited
 
     # Slots per trip of the pod loops (``run_slots`` in ``_run_step``):
     # the widest block <= SCAN_UNROLL that divides the queue, so a block
@@ -1225,10 +1248,17 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
                 z["searches"] = jnp.zeros((), jnp.int32)
                 z["cands"] = jnp.zeros((), jnp.int32)
                 z["builds"] = jnp.zeros((), jnp.int32)
+            if st.sample:
+                z["walks"] = jnp.zeros((), jnp.int32)
+                z["nvis"] = jnp.zeros((), jnp.int32)
+                z["nsc"] = jnp.zeros((), jnp.int32)
             if st.record == "full":
                 z["bits"] = jnp.zeros((st.q, n_filters, N), bits_dtype)
                 z["raw"] = jnp.zeros((st.q, n_scores, N), raw_dtype)
                 z["final"] = jnp.zeros((st.q, n_scores, N), final_dtype)
+                if st.sample:
+                    z["walked"] = jnp.zeros(st.q, bool)
+                    z["visited"] = jnp.zeros((st.q, N), bool)
             return s, z
 
         # Tail-padded (inactive) steps are pure no-ops: same compiled K
@@ -1358,6 +1388,48 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
             best = jnp.argmin(jnp.where(cand, rank, _I32_MAX)).astype(jnp.int32)
             return jnp.where(feasible & valid, best, -1)
 
+        if st.sample:
+            # percentageOfNodesToScore: the step's numFeasibleNodesToFind
+            # (0: this many nodes are not sampled) and the visit order.
+            # The walk never sorts: ``sample_visited`` counts feasible
+            # nodes by prefix sum, with k and the start index as operands.
+            n_live = jnp.sum(s["valid"], dtype=jnp.int32)
+            k_step = ev_k["sample_k"]
+            sampling = k_step > 0
+            k_walk = jnp.where(sampling, k_step, _I32_MAX)
+            if st.sample == 2:
+                # Visit order is the service's node order (the rank
+                # tensor), not this table's slot order: position -> slot
+                # once a step, then two gathers of the node axis a slot.
+                by_rank = jnp.argsort(rank).astype(jnp.int32)
+                rank_pos = jnp.minimum(rank, N - 1)
+                in_order = jnp.arange(N, dtype=jnp.int32) < n_live
+
+        def sample_walk(ok, start, go):
+            """One attempt's walk for a sample (``st.sample``): what is
+            scored, the start index it leaves and what it counted.
+            ``go`` False (a padding slot, a pod that takes its nominated
+            node) walks nowhere: ``ok`` stands and the index stays."""
+            if st.sample == 2:
+                vis_o, sam_o, nxt = sample_visited(
+                    ok[by_rank] & in_order, in_order, start, n_live, k_walk
+                )
+                visited = vis_o[rank_pos] & s["valid"]
+                sample = sam_o[rank_pos] & ok
+            else:
+                visited, sample, nxt = sample_visited(
+                    ok, s["valid"], start, n_live, k_walk
+                )
+            walked = go & sampling
+            out = {
+                "walked": walked,
+                "nvis": jnp.where(walked, jnp.sum(visited, dtype=jnp.int32), 0),
+                "nsc": jnp.where(walked, jnp.sum(sample, dtype=jnp.int32), 0),
+            }
+            if st.record == "full":
+                out["visited"] = visited
+            return jnp.where(go, sample, ok), jnp.where(go, nxt, start), out
+
         def record_rows(_bits, _raw, _final):
             return {
                 "bits": (
@@ -1372,7 +1444,7 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
             }
 
         def pod_body(pcarry, pb):
-            nstate, pcarries = pcarry
+            nstate, pcarries, *walk = pcarry
             from ksim_tpu.plugins.base import PodView
 
             pod = PodView(
@@ -1382,16 +1454,28 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
                 has_requests=pb.has_requests,
                 index=pb.index,
             )
-            ok, _bits, _raw, _final, total = prog._eval_one(
-                nstate, pod, aux, pcarries
-            )
+            if st.sample:
+                # Filter everywhere (the mask FINDS the sample), then
+                # score, normalise and choose over the sample only.
+                ok, _bits = prog._eval_filters(nstate, pod, aux, pcarries)
+                ok, start, walk_out = sample_walk(ok, walk[0], pb.valid)
+                walk = [start]
+                _raw, _final, total = prog._eval_scores(
+                    nstate, pod, aux, pcarries, ok
+                )
+            else:
+                ok, _bits, _raw, _final, total = prog._eval_one(
+                    nstate, pod, aux, pcarries
+                )
             best = select_host(ok, total, pb.valid)
             nstate = nstate.commit(best, pb.requests, pb.nonzero_requests)
             pcarries = prog._commit_carries(pcarries, pod, best, aux)
             out_pod = {"best": best}
             if st.record == "full":
                 out_pod.update(record_rows(_bits, _raw, _final))
-            return (nstate, pcarries), out_pod
+            if st.sample:
+                out_pod.update(walk_out)
+            return (nstate, pcarries, *walk), out_pod
 
         invalid_search = {
             "nom": jnp.int32(-1),
@@ -1406,7 +1490,7 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
             pass left (binds, victims gone, nominations), binds or
             searches, and hands the state on — the victim table of the
             pass with it (``_lower_table``)."""
-            nstate, pcarries, live, table = pcarry
+            nstate, pcarries, live, table, *walk = pcarry
             from ksim_tpu.plugins.base import PodView
 
             pod = PodView(
@@ -1439,6 +1523,11 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
             # is the whole feasible set.
             own = (n0 >= 0) & ok[jnp.clip(n0, 0, N - 1)]
             ok = jnp.where(own, ok & (jnp.arange(N) == n0), ok)
+            if st.sample:
+                # findNodesThatPassFilters comes after the nominated
+                # node: a pod that takes it walks nowhere.
+                ok, start, walk_out = sample_walk(ok, walk[0], pb.valid & ~own)
+                walk = [start]
             _raw, _final, total = prog._eval_scores(nstate, pod, aux, pcarries, ok)
             best = select_host(ok, total, pb.valid)
             nstate = nstate.commit(best, pb.requests, pb.nonzero_requests)
@@ -1490,13 +1579,24 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
             out_pod["clr"] = cleared
             out_pod["searched"] = pred
             out_pod["built"] = build
-            return (nstate, pcarries, live, table), out_pod
+            if st.sample:
+                out_pod.update(walk_out)
+            return (nstate, pcarries, live, table, *walk), out_pod
 
         invalid_pod = {"best": jnp.int32(-1)}
         if st.record == "full":
             invalid_pod["bits"] = jnp.zeros((n_filters, N), bits_dtype)
             invalid_pod["raw"] = jnp.zeros((n_scores, N), raw_dtype)
             invalid_pod["final"] = jnp.zeros((n_scores, N), final_dtype)
+        walk0 = ()
+        if st.sample:
+            invalid_pod.update(
+                walked=jnp.zeros((), bool), nvis=jnp.int32(0), nsc=jnp.int32(0)
+            )
+            if st.record == "full":
+                invalid_pod["visited"] = jnp.zeros(N, bool)
+            # The start index rides the pod loop's carry.
+            walk0 = (s["sample_start"].reshape(()).astype(jnp.int32),)
         if st.preempt:
             invalid_pod.update(
                 invalid_search,
@@ -1528,16 +1628,18 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
                 ),
                 "lvl": jnp.int32(-1),
             }
-            (node_state, carries, live, _table), pod_outs = run_slots(
+            (node_state, carries, live, _table, *walk_end), pod_outs = run_slots(
                 pod_body_preempt,
-                (node_state, carries, live0, table0),
+                (node_state, carries, live0, table0, *walk0),
                 pods_q,
                 invalid_pod,
             )
         else:
-            (node_state, carries), pod_outs = run_slots(
-                pod_body, (node_state, carries), pods_q, invalid_pod
+            (node_state, carries, *walk_end), pod_outs = run_slots(
+                pod_body, (node_state, carries, *walk0), pods_q, invalid_pod
             )
+        if st.sample:
+            s["sample_start"] = walk_end[0].reshape(s["sample_start"].shape)
         sel = pod_outs["best"]
         bound_mask = (idx_q < P) & (sel >= 0)
         fail_mask = (idx_q < P) & (sel < 0)
@@ -1618,10 +1720,18 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
             out["searches"] = jnp.sum(pod_outs["searched"].astype(jnp.int32)).astype(jnp.int32)
             out["cands"] = jnp.sum(pod_outs["cands"]).astype(jnp.int32)
             out["builds"] = jnp.sum(pod_outs["built"].astype(jnp.int32)).astype(jnp.int32)
+        if st.sample:
+            # Summed here, on the device, and pulled with the outputs.
+            out["walks"] = jnp.sum(pod_outs["walked"], dtype=jnp.int32)
+            out["nvis"] = jnp.sum(pod_outs["nvis"], dtype=jnp.int32)
+            out["nsc"] = jnp.sum(pod_outs["nsc"], dtype=jnp.int32)
         if st.record == "full":
             out["bits"] = pod_outs["bits"]
             out["raw"] = pod_outs["raw"]
             out["final"] = pod_outs["final"]
+            if st.sample:
+                out["walked"] = pod_outs["walked"]
+                out["visited"] = pod_outs["visited"]
         return s, out
 
     final_state, outs = jax.lax.scan(step, dict(state0), ev)
@@ -1780,6 +1890,11 @@ class StepOutcome:
     searches: int = 0  # victim searches the step ran on the device
     candidates: int = 0  # candidates those searches kept, summed
     table_builds: int = 0  # victim tables the step built (once a pass and level)
+    # percentageOfNodesToScore: attempts that walked for a sample, the
+    # nodes they visited and the nodes they scored, summed on the device.
+    sampled: int = 0
+    visited: int = 0
+    scored: int = 0
     # (namespace, name, node_name) in queue (commit) order.
     binds: list[tuple[str, str, str]] = field(default_factory=list)
     # Per-attempt detail (preemption / full-record segments); None means
@@ -1799,6 +1914,9 @@ class SegmentOutcome:
     # pod key -> the node it is nominated to (None: the segment's
     # program carries no nominations).
     nominated_view: "dict[str, str] | None" = None
+    # The sampling walk's start index as the segment leaves it (None: the
+    # segment's program does not sample).
+    sample_start: "int | None" = None
 
 
 def _cleaned_pending(pod: JSON) -> JSON:
@@ -1897,6 +2015,12 @@ class ReplayDriver:
         self.preempt_victims = 0  # guarded-by: main-thread
         self.preempt_nominations = 0  # guarded-by: main-thread
         self.preempt_overflows = 0  # guarded-by: main-thread
+        # percentageOfNodesToScore on the device, over the committed
+        # segments: attempts that walked for a sample, the nodes they
+        # visited and the nodes they scored — the device's own sums.
+        self.sampled_attempts = 0  # guarded-by: main-thread
+        self.nodes_visited = 0  # guarded-by: main-thread
+        self.nodes_scored = 0  # guarded-by: main-thread
         # Streaming ingest overlap (round 22, traces/stream.py): a
         # runner-provided NONBLOCKING drain of the trace-ingest queue,
         # called on the main thread while the dispatch worker owns the
@@ -2026,6 +2150,11 @@ class ReplayDriver:
             "preempt_victims": self.preempt_victims,
             "preempt_nominations": self.preempt_nominations,
             "preempt_overflows": self.preempt_overflows,
+            "sampled_attempts": self.sampled_attempts,
+            "nodes_visited": self.nodes_visited,
+            "nodes_scored": self.nodes_scored,
+            # The index the walk stands at (either path moves it).
+            "sampling_start": self.service._pnts_start.get(self._sched_name or "", 0),
             "ingest_prefetches": self.ingest_prefetches,
             "device_errors": self.device_errors,
             "watchdog_timeouts": self.watchdog_timeouts,
@@ -2087,9 +2216,6 @@ class ReplayDriver:
             return False
         if getattr(svc, "_extenders", None):
             self._reject("extenders")
-            return False
-        if svc._pnts_emulation:
-            self._reject("pnts_emulation")
             return False
         if svc._shard_mesh is not None:
             # Round 17: a node-axis (tp) mesh is SUPPORTED — the segment
@@ -3041,6 +3167,12 @@ class ReplayDriver:
                 self._featurizer = svc._profiles[self._sched_name].featurizer()
         feat = self._featurizer
         rows0 = (feat.pod_rows_built, feat.pod_rows_reused, feat.pod_rows_rebuilt)
+        if svc._node_sampling:
+            # The service's featurizer meets the nodes of a pass in name
+            # order; give this table's new slots the same order, so that
+            # a stream without node churn walks for its samples in slot
+            # order (``_SegmentStatics.sample`` 1: no gather in the slot).
+            created_nodes = sorted(created_nodes, key=name_of)
         universe_nodes = list(cur_nodes) + created_nodes
         bound_pods = store.pods_with_node()
         feats = self._featurizer.featurize(
@@ -3234,6 +3366,20 @@ class ReplayDriver:
             if j is not None:
                 rank_row[j] = slot
         need_names = preempt_plan or self._record_mode == "full"
+        # percentageOfNodesToScore: each step's numFeasibleNodesToFind
+        # from its live node count (0: no sampling at that size), and
+        # whether the walk may go in slot order — at every step the live
+        # nodes fill slots 0 .. n - 1 in the service's own order (a
+        # stream without node churn) — or has to go by the rank tensor.
+        sampling = svc._node_sampling
+        sample_prof = (
+            svc._profiles.get(self._sched_name)
+            if svc._plugins_factory is None
+            else None
+        )
+        sample_k = np.zeros(K, np.int32)
+        slot_order = True
+        live_row = valid0.copy()
         live_sorted: list[str] = sorted(node_names)
         live_slots = (
             np.asarray([slot_of[nm] for nm in live_sorted], np.int64)
@@ -3262,6 +3408,20 @@ class ReplayDriver:
                 for nm, slot in changed:
                     rank_row[slot_of[nm]] = slot
             ranks[k] = rank_row
+            if sampling and step_active[k]:
+                n_live = len(live_sorted)
+                sample_k[k] = svc._sampling_k_for(sample_prof, n_live) or 0
+                live_row[[slot_of[nm] for nm in step_node_deletes[k]]] = False
+                live_row[[slot_of[nm] for nm in step_node_creates[k]]] = True
+                # A step that attempts a pod reads a rank row that is
+                # current: its own sync's, or the last one's with no node
+                # event since (``_decode_outputs`` discards the segment
+                # otherwise).  Only those rows have to agree.
+                if pred_featurizes[k] or not any(step_node_event[: k + 1]):
+                    slot_order = slot_order and bool(
+                        live_row[:n_live].all()
+                        and (rank_row[:n_live] == np.arange(n_live)).all()
+                    )
             if need_names:
                 want[k] = candidate_count(len(live_sorted))
                 name_ranks[k, live_slots] = np.arange(
@@ -3329,6 +3489,7 @@ class ReplayDriver:
             n_lvl=n_lvl,
             local=search_local,
             ip_terms=ip_terms,
+            sample=0 if not sample_k.any() else 1 if slot_order else 2,
             tp=tp,
         )
         const = {
@@ -3354,6 +3515,8 @@ class ReplayDriver:
             "node_create": node_create,
             "node_delete": node_delete,
         }
+        if statics.sample:
+            ev["sample_k"] = sample_k
         U = len(universe_pods)
         # Nominations that stand (pending pods only, onto a node that is
         # live: the runner clears the others with the node).
@@ -3378,6 +3541,7 @@ class ReplayDriver:
             n_f * np.dtype(bits_dt).itemsize
             + n_s * 4
             + n_s * np.dtype(final_dt).itemsize
+            + (1 if statics.sample else 0)  # the visited mask
         )
         full_bytes_shard = K * q * (N // tp) * per_cell
         if self._record_mode == "full" and full_bytes_shard > FULL_RECORD_BYTES:
@@ -3501,6 +3665,12 @@ class ReplayDriver:
                 state0["nm_vw"] = by_level(ipa.pod_vw, (T,), np.int32)
         else:
             state0["nominated"] = np.zeros(P, bool)
+        if statics.sample:
+            # The walk continues where the service's last attempt, on
+            # either path, left it.
+            state0["sample_start"] = np.asarray(
+                svc._pnts_start.get(self._sched_name, 0), np.int32
+            )
         # O(delta) evidence: fresh per-pod featurize rows this lower
         # actually built vs the window's event count (the lock-check
         # guard asserts steady-state proportionality; counters, not
@@ -3526,6 +3696,10 @@ class ReplayDriver:
             "preempt_victims": 0,
             "preempt_nominations": 0,
             "preempt_overflows": 0,
+            "sampled_attempts": 0,
+            "nodes_visited": 0,
+            "nodes_scored": 0,
+            "sampling_start": None,
         }
         self.lower_log.append(log_entry)
         return _SegmentPlan(
@@ -3615,6 +3789,12 @@ class ReplayDriver:
         bits = np.asarray(pulled["bits"][k])[att][:, :, slots]
         raw = np.asarray(pulled["raw"][k])[att][:, :, slots]
         fin = np.asarray(pulled["final"][k])[att][:, :, slots]
+        # percentageOfNodesToScore: an attempt that walked records the
+        # nodes it visited only, as the per-pass path does.
+        vis = walked = None
+        if plan.statics.sample:
+            walked = np.asarray(pulled["walked"][k])[att]
+            vis = np.asarray(pulled["visited"][k])[att][:, slots]
         sel_sub = np.asarray(
             [pos_of.get(int(s), -1) if s >= 0 else -1 for s in sel_k], np.int64
         )
@@ -3647,7 +3827,8 @@ class ReplayDriver:
                     }
             out.append(
                 render_pod_results(
-                    None, plugins, res, i, postfilter=postfilter, ctx=ctx
+                    None, plugins, res, i, postfilter=postfilter, ctx=ctx,
+                    visited=vis[i] if vis is not None and walked[i] else None,
                 )
             )
         return out
@@ -3846,6 +4027,9 @@ class ReplayDriver:
                     searches=int(pulled["searches"][k]) if st.preempt else 0,
                     candidates=int(pulled["cands"][k]) if st.preempt else 0,
                     table_builds=int(pulled["builds"][k]) if st.preempt else 0,
+                    sampled=int(pulled["walks"][k]) if st.sample else 0,
+                    visited=int(pulled["nvis"][k]) if st.sample else 0,
+                    scored=int(pulled["nsc"][k]) if st.sample else 0,
                     binds=binds,
                     attempts=attempts,
                 )
@@ -3906,6 +4090,11 @@ class ReplayDriver:
             bound_view=bound_view,
             pending_view=pending_view,
             nominated_view=nominated_view,
+            sample_start=(
+                int(np.asarray(pulled_state["sample_start"]).ravel()[0])
+                if st.sample
+                else None
+            ),
         )
 
     # -- reconcile -----------------------------------------------------------
@@ -3954,13 +4143,19 @@ class ReplayDriver:
                 1 for o in seg.steps for a in o.attempts or () if a.nominated
             ),
         }
-        for key, n in preempt.items():
+        sampling = {
+            "sampled_attempts": sum(o.sampled for o in seg.steps),
+            "nodes_visited": sum(o.visited for o in seg.steps),
+            "nodes_scored": sum(o.scored for o in seg.steps),
+        }
+        for key, n in (preempt | sampling).items():
             setattr(self, key, getattr(self, key) + n)
         plan = self._last_plan  # None on a fleet follower: it lowered nothing
         if plan is not None and plan.log_entry is not None:
             plan.log_entry["pairs_evaluated"] = pairs
             plan.log_entry["slots_run"] = slots
-            plan.log_entry.update(preempt)
+            plan.log_entry.update(preempt | sampling)
+            plan.log_entry["sampling_start"] = seg.sample_start
 
     def verify_segment(self, seg: SegmentOutcome) -> None:
         """Verify the staged store converged to the device's view of the
@@ -4012,6 +4207,9 @@ class ReplayDriver:
         svc._pass_count = seg.pass_count
         with svc._backoff_lock:
             svc._backoff = dict(seg.backoff)
+        if seg.sample_start is not None:
+            # A per-pass step after this segment continues the walk.
+            svc._pnts_start[self._sched_name] = seg.sample_start
         # A committed segment proves the whole device->store pipeline is
         # healthy: reset the reconcile side of the breaker window.
         self._consecutive_reconcile_faults = 0
@@ -4697,7 +4895,7 @@ def _exec_and_pull(plan: "_SegmentPlan", launch, **tags):
                     k: final_state[k]
                     for k in (
                         "alive", "bound", "attempts", "retry_at", "pass_count",
-                        "nom_node",
+                        "nom_node", "sample_start",
                     )
                     if k in final_state
                 },

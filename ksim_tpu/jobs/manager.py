@@ -1630,6 +1630,7 @@ class JobManager:
             runner = ScenarioRunner(
                 record=sim.get("recordMode", "selection"),
                 preemption=bool(sim.get("preemption", False)),
+                node_sampling=bool(sim.get("nodeSampling", False)),
                 max_pods_per_pass=sim.get("maxPodsPerPass"),
                 pod_bucket_min=sim.get("podBucketMin"),
                 device_replay=True,
@@ -1660,6 +1661,7 @@ class JobManager:
                 config=sim.get("schedulerConfig"),
                 record=sim.get("recordMode", "selection"),
                 preemption=bool(sim.get("preemption", False)),
+                node_sampling=bool(sim.get("nodeSampling", False)),
                 max_pods_per_pass=sim.get("maxPodsPerPass"),
                 pod_bucket_min=sim.get("podBucketMin"),
             )
@@ -1827,6 +1829,7 @@ class JobManager:
                         config=sim.get("schedulerConfig"),
                         record=sim.get("recordMode", "selection"),
                         preemption=bool(sim.get("preemption", False)),
+                        node_sampling=bool(sim.get("nodeSampling", False)),
                         max_pods_per_pass=sim.get("maxPodsPerPass"),
                         pod_bucket_min=sim.get("podBucketMin"),
                     )

@@ -192,6 +192,7 @@ class ScenarioRunner:
         *,
         record: str = "selection",
         preemption: bool = False,
+        node_sampling: bool = False,
         requeue_on_node_delete: bool = True,
         max_pods_per_pass: int | None = None,
         pod_bucket_min: int | None = None,
@@ -280,6 +281,7 @@ class ScenarioRunner:
                 self.store,
                 record=record,
                 preemption=preemption,
+                node_sampling=node_sampling,
                 max_pods_per_pass=max_pods_per_pass,
                 pod_bucket_min=pod_bucket_min,
             )
@@ -295,6 +297,7 @@ class ScenarioRunner:
         self._lane_cfg = dict(
             record=record,
             preemption=preemption,
+            node_sampling=node_sampling,
             max_pods_per_pass=max_pods_per_pass,
             pod_bucket_min=pod_bucket_min,
         )

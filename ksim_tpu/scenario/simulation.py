@@ -91,6 +91,7 @@ def run_scheduler_simulation(doc: "JSONObj | str | bytes") -> JSONObj:
         config=sim_spec.get("schedulerConfig"),
         record=sim_spec.get("recordMode", "selection"),
         preemption=bool(sim_spec.get("preemption", False)),
+        node_sampling=bool(sim_spec.get("nodeSampling", False)),
         max_pods_per_pass=sim_spec.get("maxPodsPerPass"),
         allow_plugin_imports=True,  # operator-owned spec (see docstring)
     )

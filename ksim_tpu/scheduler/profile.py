@@ -365,7 +365,7 @@ class CompiledProfile:
     pre_enqueue_hooks: tuple = ()
     # KubeSchedulerProfile.percentageOfNodesToScore (v1.30: per-profile
     # override of the global field; None = inherit, 0 = adaptive).  Used
-    # only by the opt-in sampling emulation (KSIM_PNTS_EMULATION=1).
+    # only where sampling is asked for (SchedulerService(node_sampling=True)).
     percentage_of_nodes_to_score: int | None = None
     # Plugins added only through a per-point set: name -> points enabled.
     point_only: dict[str, frozenset[str]] = field(default_factory=dict)

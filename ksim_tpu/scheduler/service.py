@@ -113,6 +113,7 @@ class SchedulerService:
         record: str = "full",
         featurizer: Featurizer | None = None,
         preemption: bool = True,
+        node_sampling: bool = False,
         max_pods_per_pass: int | None = None,
         pod_bucket_min: int | None = None,
         config_path: str | None = None,
@@ -217,13 +218,18 @@ class SchedulerService:
         self._poke = threading.Event()
         self._pass_count = 0
         self.metrics = Metrics()
-        # percentageOfNodesToScore emulation (opt-in replay-fidelity
-        # mode, KSIM_PNTS_EMULATION=1): per-profile rotating start index
-        # — upstream's sched.nextStartNodeIndex lives on the scheduler,
-        # one per profile binary.
-        self._pnts_emulation = (
-            os.environ.get("KSIM_PNTS_EMULATION", "") == "1"
-        )
+        # percentageOfNodesToScore (``node_sampling=True``; a job asks
+        # with ``spec.simulator.nodeSampling``): an attempt walks the
+        # nodes from a rotating start index and scores the first
+        # numFeasibleNodesToFind feasible ones only, as every
+        # kube-scheduler on more than 100 nodes does.  An upstream
+        # default this simulator leaves off unless asked, like
+        # ``preemption`` on the job plane.  One start index a profile —
+        # upstream's sched.nextStartNodeIndex lives on the scheduler,
+        # one per profile binary — carried for the life of the service
+        # (and in ``checkpoint_carries``); the per-pass path and the
+        # device replay (engine/replay.py) both continue it.
+        self._node_sampling = bool(node_sampling)
         self._pnts_start: dict[str, int] = {}
 
     MAX_BACKOFF_PASSES = 16
@@ -614,7 +620,7 @@ class SchedulerService:
                 # filtering and scoring — exact upstream semantics require
                 # pod-at-a-time evaluation (the reference's scheduler is
                 # per-pod anyway; extenders are the slow path by design).
-                if self._pnts_emulation and not getattr(
+                if self._node_sampling and not getattr(
                     self, "_pnts_extender_warned", False
                 ):
                     # Sampling emulation does not apply on this path (it
@@ -622,7 +628,7 @@ class SchedulerService:
                     # silently scoring every node under the flag.
                     self._pnts_extender_warned = True
                     logger.warning(
-                        "KSIM_PNTS_EMULATION=1 is inert for profiles "
+                        "node_sampling is inert for profiles "
                         "with extenders (per-pod evaluation path scores "
                         "all nodes)"
                     )
@@ -689,7 +695,7 @@ class SchedulerService:
                     with self.metrics.timer("engine"):
                         feats, plugins, res = self._schedule_among_nominees(
                             run[0], nominees, nodes, featurizer, factory,
-                            namespaces, volume_kw, prof,
+                            namespaces, volume_kw, prof, sched_name,
                         )
                 with TRACE.phase("service.bind", self.metrics, "bind") as ph:
                     render_s, store_s, done = self._bind_results(
@@ -942,11 +948,11 @@ class SchedulerService:
 
     def _sampling_k_for(self, prof, n_nodes: int) -> int | None:
         """numFeasibleNodesToFind (schedule_one.go): None = score all
-        nodes (emulation off, small cluster, or percentage resolves to
+        nodes (``node_sampling`` off, small cluster, or percentage resolves to
         everything).  A per-profile percentageOfNodesToScore overrides
         the global field; 0/unset means the adaptive formula
         50 - n/125, floored at 5%."""
-        if not self._pnts_emulation:
+        if not self._node_sampling:
             return None
         if n_nodes < self._MIN_FEASIBLE_NODES_TO_FIND:
             return None
@@ -1047,7 +1053,8 @@ class SchedulerService:
         return len(queue)
 
     def _schedule_among_nominees(
-        self, pod, nominees, nodes, featurizer, factory, namespaces, volume_kw, prof
+        self, pod, nominees, nodes, featurizer, factory, namespaces, volume_kw,
+        prof, sched_name,
     ):
         """One pod's cycle where nominations count
         (RunFilterPluginsWithNominatedPods, evaluateNominatedNode): a node
@@ -1060,7 +1067,12 @@ class SchedulerService:
         The verdict with the nominees counted in comes from a second
         engine run over a throw-away featurizer (the persistent one never
         sees a pod that is not there) and enters the real run as its
-        ``node_mask``; scores normalize over what both runs leave."""
+        ``node_mask``; scores normalize over what both runs leave.
+
+        With ``node_sampling`` the nominated node is tried alone — no
+        walk, the start index stays — and only the run over the other
+        nodes samples (upstream's evaluateNominatedNode comes before
+        findNodesThatPassFilters)."""
         import numpy as np
 
         from ksim_tpu.scheduler import preemption as pre
@@ -1095,12 +1107,18 @@ class SchedulerService:
             for i in range(feats.nodes.count):
                 mask[i] = feats.nodes.names[i] in passed
 
-        def run(node_mask):
+        def run(node_mask, sampling_k=None):
             eng = Engine(
                 feats, plugins, record=self._record, metrics=self.metrics,
-                node_mask=node_mask,
+                node_mask=node_mask, sampling_k=sampling_k,
             )
-            return eng.schedule(pull_state=False)[0]
+            res = eng.schedule(
+                pull_state=False,
+                sampling_start=self._pnts_start.get(sched_name, 0),
+            )[0]
+            if res.sampling_next_start is not None:
+                self._pnts_start[sched_name] = res.sampling_next_start
+            return res
 
         own = pre.nominated_node_of(pod)
         if own is not None and own in feats.nodes.names[: feats.nodes.count]:
@@ -1111,7 +1129,7 @@ class SchedulerService:
             res = run(first)
             if int(res.selected[0]) >= 0:
                 return feats, plugins, res
-        return feats, plugins, run(mask)
+        return feats, plugins, run(mask, self._sampling_k_for(prof, len(nodes)))
 
     def _clear_lower_nominations(
         self, node_name: str, pod: JSON, priority_of=None

@@ -1,4 +1,5 @@
-"""percentageOfNodesToScore emulation (opt-in replay-fidelity mode).
+"""percentageOfNodesToScore emulation (opt-in: ``SchedulerService(
+node_sampling=True)``, a job's ``spec.simulator.nodeSampling``).
 
 Upstream kube-scheduler v1.30 samples which feasible nodes get scored
 once a cluster exceeds 100 nodes: it visits nodes in index order from a
@@ -176,8 +177,9 @@ def test_service_sampling_k_resolution():
     from ksim_tpu.scheduler.service import SchedulerService
     from ksim_tpu.state.cluster import ClusterStore
 
-    svc = SchedulerService(ClusterStore(), record="selection", preemption=False)
-    svc._pnts_emulation = True
+    svc = SchedulerService(
+        ClusterStore(), record="selection", preemption=False, node_sampling=True
+    )
     # 99 nodes: below minFeasibleNodesToFind -> score all.
     assert svc._sampling_k_for(None, 99) is None
     # 5000 nodes, adaptive: 50 - 40 = 10% -> 500.
@@ -195,13 +197,13 @@ def test_service_sampling_k_resolution():
     # >= 100 percent -> everything.
     svc._config = {"percentageOfNodesToScore": 100}
     assert svc._sampling_k_for(None, 5000) is None
-    # Emulation off -> always None.
-    svc._pnts_emulation = False
+    # Sampling not asked for (the constructor's default) -> always None.
+    svc = SchedulerService(ClusterStore(), record="selection", preemption=False)
     assert svc._sampling_k_for(None, 5000) is None
 
 
-def test_service_end_to_end_sampling(monkeypatch):
-    """KSIM_PNTS_EMULATION=1 + 120 nodes: the service schedules through
+def test_service_end_to_end_sampling():
+    """``node_sampling=True`` + 120 nodes: the service schedules through
     the sampled scan (adaptive K=100 of 120), records visited-restricted
     maps, and persists the rotating start across passes."""
     import json
@@ -210,13 +212,11 @@ def test_service_end_to_end_sampling(monkeypatch):
     from ksim_tpu.scheduler.service import SchedulerService
     from ksim_tpu.state.cluster import ClusterStore
 
-    monkeypatch.setenv("KSIM_PNTS_EMULATION", "1")
     store = ClusterStore()
     for i in range(120):
         store.create("nodes", make_node(f"n{i:03d}"))
     store.create("pods", make_pod("p0", cpu="100m", memory="64Mi"))
-    svc = SchedulerService(store, record="full", preemption=False)
-    assert svc._pnts_emulation
+    svc = SchedulerService(store, record="full", preemption=False, node_sampling=True)
     placements = svc.schedule_pending()
     assert placements["default/p0"] is not None
     # K=100 of 120 from start 0: nodes 0..99 visited; start advanced.
@@ -236,7 +236,7 @@ def test_service_end_to_end_sampling(monkeypatch):
 
 def test_sampled_schedule_sharded_equals_single_device():
     """The sampling emulation composes with the tp mesh: the rotating
-    start/n_real scalars replicate and the visited/top_k machinery runs
+    start/n_real/k scalars replicate and the walk's prefix count runs
     under GSPMD identically to single-device."""
     from ksim_tpu.engine.sharding import make_mesh
 

@@ -929,7 +929,42 @@ def test_device_sharded_preemption_parity_narrows_tiny_universe(monkeypatch):
     assert _lowered_tps(d) == [2], d.lower_log  # the floor, not the request
 
 
-def test_device_sharded_explicit_mesh_contract():
+@pytest.fixture
+def fresh_mesh_compiles():
+    """The mesh twin of the segment program compiled IN this process, not
+    loaded from the persistent compile cache.
+
+    On the 8-virtual-device CPU mesh this test's tp=8 program holds two
+    independent collectives (an all-gather and an all-reduce).  Compiled
+    here it runs; the SAME program deserialized from a warm persistent
+    cache (``cpu_aot_loader``) stops in them — some devices wait in the
+    one, some in the other, none in both — until ``rendezvous.cc`` aborts
+    the whole process after 40 s ("Termination timeout ... only 6 of them
+    arrived"): 4 of 4 runs against a warm cache, 0 of 3 against a cold
+    one, alone on an idle 8-core machine, the parent's tree and this one
+    alike (PR 34).  That is what failed under the driver's six workers
+    in PR 33 — its cache was warm because PR 33 left the flat program's
+    text as it was — and not load: no limit of ours bounds the wait, so
+    the test brings its own compile.  Real chips do not rendezvous
+    through XLA:CPU; only the virtual mesh is exposed."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from ksim_tpu.engine import replay
+
+    def persistent_cache(on: bool) -> None:
+        # An executable this process already holds would be reused as it is.
+        replay._segment_fn_nodonate.clear_cache()
+        jax.config.update("jax_enable_compilation_cache", on)
+        compilation_cache.reset_cache()
+
+    persistent_cache(False)
+    try:
+        yield
+    finally:
+        persistent_cache(True)
+
+
+def test_device_sharded_explicit_mesh_contract(fresh_mesh_compiles):
     """An explicit service shard_mesh is a layout contract: a dp=1 tp
     mesh is honored by the device path (every segment lowered at its
     width); any other shape falls back per-pass with the narrowed
