@@ -2177,6 +2177,7 @@ class ReplayDriver:
             "featurize_calls": feat.pod_rows_built if feat is not None else 0,
             "featurize_reused": feat.pod_rows_reused if feat is not None else 0,
             "featurize_rebuilt": feat.pod_rows_rebuilt if feat is not None else 0,
+            "featurize_copied": feat.pod_rows_copied if feat is not None else 0,
             "featurize_passes": feat.featurize_passes if feat is not None else 0,
             "prelower": {
                 "windows": self.prelower_windows,
@@ -3166,7 +3167,10 @@ class ReplayDriver:
             else:
                 self._featurizer = svc._profiles[self._sched_name].featurizer()
         feat = self._featurizer
-        rows0 = (feat.pod_rows_built, feat.pod_rows_reused, feat.pod_rows_rebuilt)
+        rows0 = (
+            feat.pod_rows_built, feat.pod_rows_reused, feat.pod_rows_rebuilt,
+            feat.pod_rows_copied,
+        )
         if svc._node_sampling:
             # The service's featurizer meets the nodes of a pass in name
             # order; give this table's new slots the same order, so that
@@ -3682,6 +3686,7 @@ class ReplayDriver:
             "rows_built": feat.pod_rows_built - rows0[0],
             "rows_reused": feat.pod_rows_reused - rows0[1],
             "rows_rebuilt": feat.pod_rows_rebuilt - rows0[2],
+            "rows_copied": feat.pod_rows_copied - rows0[3],
             "cache_hit": use_cache,
             "tp": tp,
             "full_bytes_per_shard": int(full_bytes_shard),

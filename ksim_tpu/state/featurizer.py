@@ -148,8 +148,8 @@ class FeaturizedSnapshot:
 _BASE_SET = frozenset(BASE_RESOURCES)
 
 # Table families of the base pod rows (state/podtable.py).
+_IDENTITY_COLUMNS = (Column("key", object, None),)
 _STATIC_COLUMNS = (
-    Column("key", object, None),
     Column("vals", object, None),  # ((resource, raw value), ...)
     Column("tol", bool, False),
     Column("has", bool, False),
@@ -161,8 +161,9 @@ _REQUEST_COLUMNS = (
 
 
 def _vals_apply(counters: "dict[str, dict[int, int]]", pairs, sign: int) -> None:
-    """Add (+1) or remove (-1) ``(resource, raw value)`` pairs in a
-    per-resource multiset of values."""
+    """Add (``sign`` > 0) or remove (< 0) ``(resource, raw value)``
+    pairs, ``abs(sign)`` times each, in a per-resource multiset of
+    values."""
     for r, v in pairs:
         c = counters.setdefault(r, {})
         nv = c.get(v, 0) + sign
@@ -263,6 +264,13 @@ class Featurizer:
     def pod_rows_rebuilt(self) -> int:
         return self._table.rows_rebuilt
 
+    @property
+    def pod_rows_copied(self) -> int:
+        """Of ``pod_rows_built``, the pods whose manifest equalled a
+        live pod's but for its identity: their rows were copied, no
+        builder ran (state/podtable.py)."""
+        return self._table.rows_copied
+
     def slot_names(self) -> list[str]:
         """The current node-slot order, lowest slot first — the carry a
         segment checkpoint records so ``seed_slots`` can reinstall it on
@@ -361,16 +369,23 @@ class Featurizer:
 
         # The pod axis: one identity lookup per pod, shared by every
         # family (state/podtable.py); all per-pod Python below runs
-        # inside a family's row builder, for new pods only.
+        # inside a family's row builder, for new pods only and once per
+        # distinct manifest among them.
         table = self._table
         queue_vals = self._queue_vals
         static = table.family("static", _STATIC_COLUMNS)
         P = len(sched_pods)
 
+        def count_vals(rows: np.ndarray, sign: int) -> None:
+            """The multiset counts pods: every row's pairs, those of a
+            copied row too — taken once per distinct manifest, times
+            the rows that share it."""
+            reps, counts = table.by_content(rows)
+            for pairs, k in zip(static.cols["vals"][reps], counts):
+                _vals_apply(queue_vals, pairs, sign * k)
+
         def forget_vals(rows: np.ndarray) -> None:
-            for pairs in static.cols["vals"][rows]:
-                if pairs is not None:
-                    _vals_apply(queue_vals, pairs, -1)
+            count_vals(rows[static.valid[rows]], -1)
 
         n_new = table.index(sched_pods, forget_vals)
         self.pod_rows_built += n_new
@@ -384,9 +399,7 @@ class Featurizer:
             # Every raw value that enters math, plus the zero-valued keys
             # (a requested resource joins the axis whatever its value).
             pairs = tuple(reqs.items()) + tuple((r, v) for r, v in nz.items() if v)
-            _vals_apply(queue_vals, pairs, +1)
             return (
-                namespaced_key(p),
                 pairs,
                 tolerations_tolerate_taint(pod_tolerations(p), UNSCHEDULABLE_TAINT),
                 # Upstream fitsRequest early-exit predicate: base requests
@@ -397,7 +410,10 @@ class Featurizer:
                 or any(k not in _BASE_SET and k != PODS for k in reqs),
             )
 
-        table.sync(static, None, static_row)
+        count_vals(table.sync(static, None, static_row), +1)
+        # The one column that reads who the pod is.
+        ident = table.family("identity", _IDENTITY_COLUMNS)
+        table.sync(ident, None, lambda p: (namespaced_key(p),), shared=False)
 
         # Bound pods' raw request values as an incrementally-maintained
         # multiset per resource: the resource axis and exact gcd units
@@ -641,7 +657,7 @@ class Featurizer:
                 valid=nvalid,
             ),
             pods=PodTensors(
-                keys=static.take("key").tolist(),
+                keys=ident.take("key").tolist(),
                 requests=preq,
                 nonzero_requests=pnz,
                 valid=pvalid,

@@ -15,12 +15,21 @@ rows.  This table keeps the lowered rows instead:
   invalidated), so the table pins exactly the pods of the last call and
   a departed pod is referenced by nothing here.  Row storage compacts
   when released slots outnumber live rows (length <= 2 x live).
+- Every row carries a CONTENT ID: pods whose manifests are equal once
+  the identity fields (``_IDENTITY``) are taken out share one.  Pods
+  come from templates (a Deployment's replicas, scheduler_perf's
+  pod-default), and a family's row is a function of the manifest's
+  content and the family's token, never of who the pod is.  The id is
+  a small int, recycled when its last row is released, so the key map
+  is bounded by the live rows exactly as the table is and holds bytes,
+  never a manifest.
 - A ``RowFamily`` is a set of named growable arrays with one row per
   table row, valid for a TOKEN (a vocabulary lineage, a resource axis,
-  namespace labels).  ``sync`` runs the family's per-pod builder for the
-  rows of this call that are not valid under the token — the new pods,
-  or every row when the token moved (counted in ``rows_rebuilt``) — and
-  writes them with one assignment per column.
+  namespace labels).  ``sync`` makes the rows of this call valid under
+  the token — the new pods, or every row when the token moved (counted
+  in ``rows_rebuilt``): the family's per-pod builder runs once for each
+  content id that has no valid row yet, and every other row of that
+  content is filled by one vectorised copy per column.
 - Encoders produce each ``[P, ...]`` output with a vectorised gather of
   ``family.take(col)`` into the padded buffer.  Rows hold ids of
   PERSISTENT append-only vocabularies (``Interner``, reset-valved);
@@ -33,6 +42,7 @@ A one-shot ``Featurizer()`` runs the same code with an empty table.
 
 from __future__ import annotations
 
+import marshal
 import weakref
 from dataclasses import dataclass
 from itertools import repeat
@@ -54,6 +64,31 @@ SCALAR, LIST, ROW = "scalar", "list", "row"
 _MIN_CAP = 64
 _CHUNK = 128
 _UNSET = object()
+
+# What tells two replicas of one template apart.  A deny-list on
+# purpose: a field nobody thought of makes two manifests differ (the
+# builders run for both, as before), never share a row.
+_IDENTITY = frozenset((
+    "name", "uid", "resourceVersion", "creationTimestamp", "generateName",
+    "selfLink", "managedFields",
+))
+
+
+def content_key(pod: JSON) -> "bytes | None":
+    """The manifest less its identity fields, as bytes: equal keys mean
+    equal content.  Marshal format 2 writes values only (later formats
+    add back-references and interning flags that depend on which string
+    OBJECTS a manifest happens to share); dicts go in their own order,
+    so a reordered manifest may miss and can never collide.  None for a
+    manifest marshal cannot take: that pod shares with nobody."""
+    meta = pod.get("metadata")
+    if type(meta) is dict:
+        pod = dict(pod)
+        pod["metadata"] = {k: v for k, v in meta.items() if k not in _IDENTITY}
+    try:
+        return marshal.dumps(pod, 2)
+    except ValueError:
+        return None
 
 
 @dataclass(frozen=True)
@@ -194,6 +229,10 @@ class RowFamily:
             else:
                 col[rows] = np.asarray([rec[ci] for rec in recs], dtype=c.dtype)
 
+    def _copy(self, dst: np.ndarray, src: np.ndarray) -> None:
+        for col in self.cols.values():
+            col[dst] = col[src]
+
 
 class PodTable:
     def __init__(self) -> None:
@@ -205,6 +244,13 @@ class PodTable:
         self._born = np.zeros(_MIN_CAP, dtype=np.int64)
         self._n_live = 0
         self._gen = 0
+        # Content ids: per row, and per id its key (None: free, or a
+        # manifest without one) and its live rows; freed ids are reused.
+        self._cid = np.zeros(_MIN_CAP, dtype=np.intp)
+        self._cid_of: dict[bytes, int] = {}
+        self._cid_key: "list[bytes | None]" = []
+        self._cid_rows: list[int] = []
+        self._cid_free: list[int] = []
         self._fams: dict[str, RowFamily] = {}
         self._vocabs: dict[str, Interner] = {}
         # Row index per pod of the current call.
@@ -212,6 +258,9 @@ class PodTable:
         # Rows recomputed for a pod the table already held, because a
         # family's token moved (summed over families).
         self.rows_rebuilt = 0
+        # Pods new to the table whose content a live row (or an earlier
+        # pod of the same call) already had.
+        self.rows_copied = 0
 
     def __len__(self) -> int:
         return len(self._pods)
@@ -279,13 +328,59 @@ class PodTable:
                 for c in fam.columns:
                     if c.dtype is object:
                         fam.cols[c.name][gone] = None
+            self._forget_content(gone)
+        # After the release, so that "copied" means a row that stays.
+        if n > n0:
+            self._cid[n0:n] = self._content_ids(self._pods[n0:n])
         if n > 2 * self._n_live:
             idx = self._compact(idx, n)
         self.idx = idx
         return n - n0
 
+    def _content_ids(self, pods: "list[JSON]") -> "list[int]":
+        """A content id for each of ``pods``, new to the table."""
+        ids, keys, left, free = self._cid_of, self._cid_key, self._cid_rows, self._cid_free
+        out = []
+        for key in map(content_key, pods):
+            c = None if key is None else ids.get(key)
+            if c is not None:
+                left[c] += 1
+                self.rows_copied += 1
+            else:
+                if free:
+                    c = free.pop()
+                    keys[c], left[c] = key, 1
+                else:
+                    c = len(keys)
+                    keys.append(key)
+                    left.append(1)
+                if key is not None:
+                    ids[key] = c
+            out.append(c)
+        return out
+
+    def _forget_content(self, gone: np.ndarray) -> None:
+        """Released rows leave their content ids; an id no row holds
+        any more gives its key up."""
+        left = self._cid_rows
+        ids, counts = np.unique(self._cid[gone], return_counts=True)
+        for c, k in zip(ids.tolist(), counts.tolist()):
+            left[c] -= k
+            if not left[c]:
+                key = self._cid_key[c]
+                if key is not None:
+                    del self._cid_of[key]
+                    self._cid_key[c] = None
+                self._cid_free.append(c)
+
+    def by_content(self, rows: np.ndarray) -> "tuple[np.ndarray, list[int]]":
+        """One of ``rows`` for each content among them, and how many of
+        ``rows`` have that content."""
+        _, first, counts = np.unique(self._cid[rows], return_index=True, return_counts=True)
+        return rows[first], counts.tolist()
+
     def _resize(self, cap: int, keep: "np.ndarray | None" = None) -> None:
-        for name in ("_live", "_born"):
+        for name in ("_live", "_born", "_cid"):
             old = getattr(self, name)
             src = old if keep is None else old[keep]
             new = np.zeros(cap, dtype=old.dtype)
@@ -303,32 +398,59 @@ class PodTable:
         self._resize(max(_MIN_CAP, 2 * keep.size), keep)
         return lut[idx]
 
+    def _sources(self, fam: RowFamily, rows: np.ndarray) -> np.ndarray:
+        """For each of the stale ``rows`` (queue order) the row its
+        content comes from: a row of that content already valid under
+        the family's token, else the first of ``rows`` with that content
+        — itself, then, and the builder has to run for it."""
+        cids = self._cid[rows]
+        src_of = np.full(len(self._cid_key), -1, dtype=np.intp)
+        uniq, first = np.unique(cids, return_index=True)
+        src_of[uniq] = rows[first]
+        # Every valid row is live (a release invalidates), so its
+        # content id is current; which of several wins does not matter.
+        have = np.nonzero(fam.valid)[0]
+        src_of[self._cid[have]] = have
+        return src_of[cids]
+
     def sync(
         self,
         fam: RowFamily,
         token: Any,
         build: "Callable[[JSON], tuple]",
         widths: "dict[str, int] | None" = None,
-    ) -> None:
+        shared: bool = True,
+    ) -> np.ndarray:
         """Make ``fam``'s rows for the current call valid under
-        ``token``: ``build(pod)`` — one value per column, in column
-        order — runs for the rows that are not, in queue order.
+        ``token`` and return the rows that were not, in queue order.
+        ``build(pod)`` — one value per column, in column order — runs
+        for the first of them of each content id no valid row has, in
+        queue order (a persistent vocabulary meets new keys in the order
+        a walk over every pod would register them); the others copy a
+        valid row of their content.  ``shared=False`` is for a family
+        that reads who the pod is: ``build`` then runs for every row.
         ``widths`` gives the ROW columns' widths under this token."""
         if fam.token != token:
             fam._reset(token, widths or {})
         idx = self.idx
         stale = ~fam.valid[idx]
         if not stale.any():
-            return
+            return idx[:0]
         rows, first = np.unique(idx[stale], return_index=True)
         rows = rows[np.argsort(first, kind="stable")]
         self.rows_rebuilt += int(np.count_nonzero(self._born[rows] < self._gen))
+        src = self._sources(fam, rows) if shared else rows
+        todo = rows[src == rows]
         pinned = self._pods
         # In chunks: a chunk's records (a tuple and a few lists per pod)
         # are written and dropped before they outnumber the collector's
         # young-generation threshold, so a cold call of a thousand pods
         # promotes none of them into the old generation.
-        for at in range(0, rows.size, _CHUNK):
-            part = rows[at : at + _CHUNK]
+        for at in range(0, todo.size, _CHUNK):
+            part = todo[at : at + _CHUNK]
             fam._write(part, [build(pinned[r]) for r in part.tolist()])
+        if todo.size < rows.size:
+            dup = src != rows
+            fam._copy(rows[dup], src[dup])
         fam.valid[rows] = True
+        return rows

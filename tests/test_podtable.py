@@ -18,6 +18,7 @@ from ksim_tpu.state.podtable import (
     Column,
     Interner,
     PodTable,
+    content_key,
     first_seen,
     rank_lut,
 )
@@ -140,6 +141,181 @@ def test_empty_call_releases_everything():
     assert table.index([]) == 0
     table.sync(fam, 0, _build(1), {"vec": 1})
     assert table.live == 0 and fam.take("n").shape == (0,)
+
+
+# -- rows by content: one build per distinct manifest ------------------------
+
+
+def _replicas(contents: "list[int]", start: int = 0) -> list[dict]:
+    """One pod per entry: ``n`` is all of its content, the name (and a
+    uid, a creationTimestamp) all of its identity."""
+    return [
+        {"metadata": {"name": f"r{start + i}", "uid": f"u{start + i}",
+                      "creationTimestamp": f"t{start + i}", "labels": {"c": str(n)}}, "n": n}
+        for i, n in enumerate(contents)
+    ]
+
+
+def _pods_of(contents: "list[int]") -> list[dict]:
+    """Distinct manifests with the same ``n``: nothing is shared."""
+    return [{"metadata": {"name": f"d{i}", "annotations": {"i": str(i)}}, "n": n}
+            for i, n in enumerate(contents)]
+
+
+_COL_NAMES = tuple(c.name for c in _COLS)
+
+
+def _rows(fam) -> list[tuple]:
+    return list(zip(*(fam.take(c).tolist() for c in _COL_NAMES)))
+
+
+def test_builder_runs_once_per_content_in_first_appearance_order_and_rows_are_copied():
+    table = PodTable()
+    fam = table.family("f", _COLS)
+    contents = [7, 3, 7, 7, 9, 3, 2, 9]
+    calls: list[int] = []
+    assert table.index(_replicas(contents)) == 8
+    fresh = table.sync(fam, "tok", _build(3, calls), {"vec": 3})
+    assert calls == [7, 3, 9, 2]  # first appearance, queue order
+    assert fresh.tolist() == list(range(8))  # every stale row, copies included
+    assert table.rows_copied == 4
+    # SCALAR, LIST, ROW and object columns: what a build per pod gives.
+    want = PodTable()
+    every = want.family("f", _COLS)
+    want.index(_pods_of(contents))
+    want.sync(every, "tok", _build(3), {"vec": 3})
+    assert _rows(fam) == _rows(every)
+    assert fam.take("obj").tolist() == [("t", n) for n in contents]
+    # Under the same token a second family call builds nothing.
+    table.sync(fam, "tok", _build(3, calls), {"vec": 3})
+    assert len(calls) == 4 and table.rows_rebuilt == 0
+
+
+def test_content_met_in_an_earlier_call_copies_and_each_family_keeps_its_own_count():
+    table = PodTable()
+    a = table.family("a", _COLS)
+    b = table.family("b", _COLS)
+    first = _replicas([1, 2, 1])
+    calls_a: list[int] = []
+    calls_b: list[int] = []
+    table.index(first)
+    table.sync(a, 0, _build(2, calls_a), {"vec": 2})
+    more = _replicas([2, 5, 1, 5], start=3)
+    table.index(first + more)
+    table.sync(a, 0, _build(2, calls_a), {"vec": 2})
+    # Family b meets all seven rows cold: once per content, again.
+    table.sync(b, 0, _build(2, calls_b), {"vec": 2})
+    assert calls_a == [1, 2, 5] and calls_b == [1, 2, 5]
+    assert a.take("n").tolist() == b.take("n").tolist() == [1, 2, 1, 2, 5, 1, 5]
+    assert table.rows_copied == 1 + 3
+
+
+def test_released_representative_leaves_its_copies_valid_and_later_pods_still_copy():
+    table = PodTable()
+    fam = table.family("f", _COLS)
+    pods = _replicas([4, 4, 4, 6])
+    calls: list[int] = []
+    table.index(pods)
+    table.sync(fam, 0, _build(2, calls), {"vec": 2})
+    assert calls == [4, 6]
+    # The pod the builder ran for goes; its content lives on in two rows.
+    late = _replicas([4], start=10)
+    assert table.index(pods[1:] + late) == 1
+    table.sync(fam, 0, _build(2, calls), {"vec": 2})
+    assert calls == [4, 6]  # nothing built
+    assert fam.take("n").tolist() == [4, 4, 6, 4]
+    assert fam.take("ids").tolist() == [[-1, -1], [-1, -1], [0, 1], [-1, -1]]
+    assert fam.take("obj").tolist() == [("t", 4), ("t", 4), ("t", 6), ("t", 4)]
+    assert table.rows_copied == 2 + 1
+
+
+def test_last_row_of_a_content_drops_its_key_and_the_map_pins_no_manifest():
+    table = PodTable()
+    fam = table.family("f", _COLS)
+    stream = _pods(10_000)  # 10,000 distinct manifests
+    table.index(stream)
+    table.sync(fam, 0, _build(1), {"vec": 1})
+    assert len(table._cid_of) == 10_000 and table.rows_copied == 0
+    owned = _owned_containers([table._cid_of, table._cid_key, table._cid_rows, table._cid_free])
+    assert not any(id(p) in owned or id(p["metadata"]) in owned for p in stream)
+    assert all(type(k) is bytes for k in table._cid_of)
+    table.index([])
+    assert table._cid_of == {} and not any(table._cid_key) and not any(table._cid_rows)
+    # Freed ids are handed out again: the id space is bounded by the
+    # live rows like the table itself.
+    table.index(_pods(50, start=20_000))
+    assert len(table._cid_key) == 10_000 and len(table._cid_of) == 50
+    # A content that left and returns is built again.
+    again = _replicas([1, 1])
+    calls: list[int] = []
+    table.index(again)
+    table.sync(fam, 0, _build(1, calls), {"vec": 1})
+    table.index([])
+    table.index(_replicas([1], start=5))
+    table.sync(fam, 0, _build(1, calls), {"vec": 1})
+    assert calls == [1, 1]
+
+
+def test_token_move_rebuilds_once_per_content_and_counts_rows():
+    table = PodTable()
+    fam = table.family("f", _COLS)
+    pods = _replicas([1, 2, 1, 1, 2])
+    calls: list[int] = []
+    table.index(pods)
+    table.sync(fam, "old", _build(2, calls), {"vec": 2})
+    table.index(pods + _replicas([2, 8], start=5))
+    table.sync(fam, "new", _build(4, calls), {"vec": 4})
+    assert calls == [1, 2] + [1, 2, 8]
+    assert fam.take("vec").shape == (7, 4)
+    assert fam.take("vec")[:, 3].tolist() == [1, 2, 1, 1, 2, 2, 8]
+    assert table.rows_rebuilt == 5  # rows of pods the table held, as ever
+
+
+def test_unshared_family_builds_every_row_and_odd_manifests_share_with_nobody():
+    table = PodTable()
+    shared = table.family("s", _COLS)
+    own = table.family("o", (Column("name", object, None),))
+
+    class Odd:  # nothing marshal can take
+        pass
+
+    odd = Odd()
+    pods = _replicas([3, 3]) + [
+        {"metadata": {"name": "x"}, "n": 5, "odd": odd},
+        {"metadata": {"name": "y"}, "n": 5, "odd": odd},
+    ]
+    assert content_key(pods[2]) is None
+    calls: list[int] = []
+    table.index(pods)
+    table.sync(shared, 0, _build(1, calls), {"vec": 1})
+    table.sync(own, 0, lambda p: (p["metadata"]["name"],), shared=False)
+    assert calls == [3, 5, 5] and table.rows_copied == 1
+    assert own.take("name").tolist() == ["r0", "r1", "x", "y"]
+    table.index([])
+    assert table._cid_of == {} and not any(table._cid_rows)
+
+
+@pytest.mark.parametrize(
+    "field", ["name", "uid", "resourceVersion", "creationTimestamp", "generateName",
+              "selfLink", "managedFields"],
+)
+def test_content_key_leaves_out_identity_and_nothing_else(field):
+    base = {"metadata": {"namespace": "d", "labels": {"a": "b"}}, "spec": {"x": 1}}
+    one = copy.deepcopy(base)
+    one["metadata"][field] = "v1"
+    two = copy.deepcopy(base)
+    two["metadata"][field] = "v2"
+    assert content_key(one) == content_key(two) == content_key(base)
+    # The same word anywhere else is content.
+    one["spec"][field] = "v1"
+    two["spec"][field] = "v2"
+    assert content_key(one) != content_key(two)
+    # A field nobody listed tells two pods apart; so does a value's type.
+    assert content_key({**base, "other": 1}) != content_key(base)
+    assert content_key({"spec": {"x": 1}}) != content_key({"spec": {"x": True}})
+    assert content_key({"spec": {"x": 1}}) != content_key({"spec": {"x": 1.0}})
+    # Equal values in distinct string objects are the same content.
+    assert content_key({"a": "".join(["x", "y"])}) == content_key({"a": "xy"})
 
 
 def test_interner_is_append_only_until_its_valve():

@@ -149,7 +149,7 @@ def test_gather_guard_survivors_cost_no_per_pod_python(monkeypatch):
         held[id(self)] = set(self._row_of)
         return orig_index(self, pods, on_release)
 
-    def sync(self, fam, token, build, widths=None):
+    def sync(self, fam, token, build, widths=None, **kw):
         before = held[id(self)]
 
         def counted(pod):
@@ -157,7 +157,7 @@ def test_gather_guard_survivors_cost_no_per_pod_python(monkeypatch):
                 survivor_builds[id(self)] = survivor_builds.get(id(self), 0) + 1
             return build(pod)
 
-        return orig_sync(self, fam, token, counted, widths)
+        return orig_sync(self, fam, token, counted, widths, **kw)
 
     # What a family token is made of, read after each driver lowering.
     vocab_sizes: list[tuple] = []
