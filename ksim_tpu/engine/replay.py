@@ -2021,6 +2021,10 @@ class ReplayDriver:
         self.sampled_attempts = 0  # guarded-by: main-thread
         self.nodes_visited = 0  # guarded-by: main-thread
         self.nodes_scored = 0  # guarded-by: main-thread
+        # Whole-node-axis tables the featurizer's encoders built afresh
+        # (``Memo.seq_builds``), summed over the lowerings: about one a
+        # family in a cold call, none while the node objects stay.
+        self.featurize_node_builds = 0  # guarded-by: main-thread
         # Streaming ingest overlap (round 22, traces/stream.py): a
         # runner-provided NONBLOCKING drain of the trace-ingest queue,
         # called on the main thread while the dispatch worker owns the
@@ -2179,6 +2183,7 @@ class ReplayDriver:
             "featurize_rebuilt": feat.pod_rows_rebuilt if feat is not None else 0,
             "featurize_copied": feat.pod_rows_copied if feat is not None else 0,
             "featurize_passes": feat.featurize_passes if feat is not None else 0,
+            "featurize_node_builds": self.featurize_node_builds,
             "prelower": {
                 "windows": self.prelower_windows,
                 "consumed": self.prelower_consumed,
@@ -3179,6 +3184,10 @@ class ReplayDriver:
             created_nodes = sorted(created_nodes, key=name_of)
         universe_nodes = list(cur_nodes) + created_nodes
         bound_pods = store.pods_with_node()
+        from ksim_tpu.state import objcache
+
+        memo = objcache.current()
+        node_builds0 = memo.seq_builds
         feats = self._featurizer.featurize(
             universe_nodes,
             (),
@@ -3186,6 +3195,10 @@ class ReplayDriver:
             bound_pods=bound_pods,
             namespaces=store.list("namespaces", copy_objs=False),
         )
+        node_builds = memo.seq_builds - node_builds0
+        self.featurize_node_builds += node_builds
+        # What is left of the lap after the call, as a stage of its own.
+        TRACE.stage("replay.lower.featurize.program")
         if not feats.exact:
             raise _Unsupported("inexact_units")
         slot_of = dict(self._featurizer._slots.slot_of)
@@ -3556,7 +3569,6 @@ class ReplayDriver:
                 pod_eligible_to_preempt,
                 start_time,
             )
-            from ksim_tpu.state import objcache
 
             # Per-pod statics memoized on object identity (the cached
             # universe keeps survivors' objects alive across segments,
@@ -3687,6 +3699,7 @@ class ReplayDriver:
             "rows_reused": feat.pod_rows_reused - rows0[1],
             "rows_rebuilt": feat.pod_rows_rebuilt - rows0[2],
             "rows_copied": feat.pod_rows_copied - rows0[3],
+            "node_builds": node_builds,
             "cache_hit": use_cache,
             "tp": tp,
             "full_bytes_per_shard": int(full_bytes_shard),

@@ -14,6 +14,7 @@ from ksim_tpu.jobs.manager import (
     JobLimitExceeded,
     JobManager,
     JobThrottled,
+    SubmitClock,
     parse_job_faults,
 )
 from ksim_tpu.jobs.queue import JobQueue, JobQueueFull
@@ -32,5 +33,6 @@ __all__ = [
     "JobThrottled",
     "JournalTailer",
     "LeasePlane",
+    "SubmitClock",
     "parse_job_faults",
 ]

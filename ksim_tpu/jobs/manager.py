@@ -123,6 +123,7 @@ __all__ = [
     "JobManager",
     "JobQueueFull",
     "JobThrottled",
+    "SubmitClock",
     "parse_job_faults",
 ]
 
@@ -385,6 +386,46 @@ def _old_gen_job_ends() -> None:
             gc.set_threshold(young, middle, _old_gen_idle_threshold)
 
 
+class SubmitClock:
+    """The clock readings of one ``POST /api/v1/jobs``, taken by the
+    handler itself whatever the trace plane's state (the global plane,
+    where the ``jobs.submit`` span and its stages live, is off in an
+    untraced run, and a result document must not depend on that):
+    arrival, body read, document parsed, ops built
+    (``JobManager.submit`` takes that one), response written.  The job
+    keeps the clock and its result document carries the differences
+    as ``submit``."""
+
+    __slots__ = ("_marks", "_closed")
+
+    def __init__(self) -> None:
+        self._marks = [time.perf_counter()]
+        self._closed = threading.Event()
+
+    def mark(self) -> None:
+        self._marks.append(time.perf_counter())
+
+    def close(self) -> None:
+        """The last reading: the response is out."""
+        self.mark()
+        self._closed.set()
+
+    def seconds(self) -> "dict | None":
+        """``{read_s, parse_s, build_s, enqueue_s, total_s}``.  The job
+        is in the queue before its 202 is written, so a worker may ask
+        while the handler still writes: it waits for ``close`` (None if
+        that never comes)."""
+        if not self._closed.wait(5.0) or len(self._marks) != 5:
+            return None
+        m = self._marks
+        doc = {
+            f"{name}_s": round(b - a, 6)
+            for name, a, b in zip(("read", "parse", "build", "enqueue"), m, m[1:])
+        }
+        doc["total_s"] = round(m[4] - m[0], 6)
+        return doc
+
+
 class Job:
     """One tenant job: spec + isolation planes + the event log the SSE
     stream replays.  Mutable state lives under ``_cond`` (the SSE
@@ -404,6 +445,7 @@ class Job:
         faults: "FaultPlane | None",
         tenant: str = "default",
         runtime0: "dict | None" = None,
+        submit_clock: "SubmitClock | None" = None,
     ) -> None:
         self.id = job_id
         self.ordinal = ordinal
@@ -418,6 +460,9 @@ class Job:
         # arrived: the result's ``runtime`` block is their growth from
         # here, so a full collection during the submit is in it.
         self.runtime0 = runtime0 if runtime0 is not None else runtime_totals()
+        # The POST's own clock (None for a job no handler submitted):
+        # the result's ``submit`` block.
+        self.submit_clock = submit_clock
         self.steps_total = len({op.step for op in ops})
         # The job's PRIVATE trace plane: ring + histograms, every record
         # tagged with the job id; the sink feeds the SSE event log.
@@ -1299,6 +1344,7 @@ class JobManager:
         priority: "int | None" = None,
         tenant: "str | None" = None,
         runtime0: "dict | None" = None,
+        submit_clock: "SubmitClock | None" = None,
     ) -> Job:
         """Validate + enqueue one tenant job document.  Raises
         ``ScenarioSpecError`` on a bad spec (HTTP 400),
@@ -1313,7 +1359,11 @@ class JobManager:
         ``runtime0`` is the ``obs.runtime_totals()`` reading that opens
         the job's ``runtime`` window: the HTTP layer takes it when the
         POST arrives, before it reads the body; absent, it is taken
-        here, before the spec is parsed.
+        here, before the spec is parsed.  ``submit_clock`` is the same
+        handler's ``SubmitClock``, inside its ``jobs.submit`` span:
+        once the ops are built it takes one reading here, the span's
+        ``build`` stage gives way to ``enqueue``, and the job keeps the
+        clock for its result's ``submit`` block.
 
         The submission ordinal (the ``KSIM_JOBS_FAULTS`` key) commits
         only on a SUCCESSFUL enqueue: a refused submission must not
@@ -1348,6 +1398,9 @@ class JobManager:
                 f"over the per-job bound of {e.limit} ({env}); ingest "
                 "stopped early"
             ) from None
+        if submit_clock is not None:
+            submit_clock.mark()
+            TRACE.stage("jobs.submit.enqueue")
         if priority is None:
             priority = spec_priority
         if tenant is None:
@@ -1419,6 +1472,7 @@ class JobManager:
                 faults=faults,
                 tenant=tenant,
                 runtime0=runtime0,
+                submit_clock=submit_clock,
             )
             # The queued event lands BEFORE the queue hand-off: once
             # put() returns, a worker may claim (and emit "running")
@@ -1580,6 +1634,9 @@ class JobManager:
             # Full collections and XLA compiles / cache loads the PROCESS
             # saw since this job's POST arrived (other jobs' included).
             result["runtime"] = runtime_growth(job.runtime0)
+            submit = job.submit_clock.seconds() if job.submit_clock else None
+            if submit is not None:
+                result["submit"] = submit
             # WAL: result + terminal record become durable BEFORE the
             # in-memory success — a success the journal cannot vouch
             # for must not be reported (it would vanish on restart).

@@ -14,7 +14,10 @@ This module is the single answer surface:
   one per pipeline phase (segment lower / dispatch / reconcile, the
   per-pass host step, write-back pushes, kubeapi requests).  Every span
   lands its duration in a fixed-bucket log-spaced latency histogram and
-  (ring mode) a structured record in the event ring.
+  (ring mode) a structured record in the event ring.  A timed **stage**
+  (``TRACE.stage``) is the sequential child that lands in the histogram
+  (and the profiler bridge) ONLY: what is inside a span whose self time
+  is itself a metric.
 - **Events** — instants (``TRACE.event``): fallback reasons with the
   segment context, pass outcomes, fault-plane fires, breaker state
   changes, store-transaction commit/rollback.
@@ -219,6 +222,49 @@ SPAN_NAMES: tuple[str, ...] = (
     #                   (ksim_tpu/traces/stream.py; args carry
     #                   format/windows/ops — overlaps the replay it
     #                   feeds by construction)
+    # -- timed STAGES (``TracePlane.stage``): histograms and the
+    # profiler bridge only, never the ring or the sink, so the parents'
+    # self times (the ``job_span_self`` metrics) stay what they were --
+    "replay.reconcile.apply",  # stage of replay.reconcile: one step's
+    #                            own operations into the store
+    #                            (_apply_batch); entered once a step
+    "replay.reconcile.write",  # stage of replay.reconcile: one step's
+    #                            placements patched into the store (the
+    #                            replay.reconcile.evict ring span nests
+    #                            inside its interval); once a step
+    "replay.reconcile.verify",  # stage of replay.reconcile: the device-
+    #                             vs-store parity check (verify_segment)
+    "replay.reconcile.commit",  # stage of replay.reconcile: the
+    #                             transaction's exit, every buffered
+    #                             watch event delivered
+    "replay.reconcile.effects",  # stage with no ring parent, right
+    #                              after replay.reconcile: eviction
+    #                              listeners, slot advance, service
+    #                              sync, result accounting
+    "service.featurize.index",  # stage of Featurizer.featurize: node
+    #                             slots, bound map and diff, the row
+    #                             table's identity lookup and content
+    #                             keys, the static and identity families
+    "service.featurize.resources",  # stage: value sets, resource axis
+    #                                 and units, node arrays, request
+    #                                 aggregate and rows, pod tensors
+    "service.featurize.affinity",  # stage: encode_affinity +
+    #                                encode_taints
+    "service.featurize.spread",  # stage: encode_topology_spread
+    "service.featurize.interpod",  # stage: encode_inter_pod
+    "service.featurize.extras",  # stage: node name, ports, image
+    #                              locality, volumes, extra encoders,
+    #                              the snapshot's assembly
+    "replay.lower.featurize.program",  # stage of replay.lower.featurize
+    #                                    after the featurizer call: the
+    #                                    plugin factory, the host-hook
+    #                                    screen, _Program(...)
+    "jobs.submit.read",  # stage of jobs.submit: the body off the socket
+    "jobs.submit.parse",  # stage of jobs.submit: json.loads / YAML
+    "jobs.submit.build",  # stage of jobs.submit: _parse_job_spec
+    #                       (validation, one Operation an operation)
+    "jobs.submit.enqueue",  # stage of jobs.submit: reserve, journal,
+    #                         queue, the 202
 )
 
 #: Instant event names.
@@ -564,6 +610,24 @@ def _jax_annotation_exit(ctx) -> None:
         pass
 
 
+class _StageEnd:
+    """What ``TracePlane.stage`` returns: ``with TRACE.stage(name):``
+    ends the stage with the block (a stretch that has no open span to
+    close it); a bare call ignores it.  One per plane, no state."""
+
+    __slots__ = ("_plane",)
+
+    def __init__(self, plane: "TracePlane") -> None:
+        self._plane = plane
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        self._plane.stage_end()
+        return False
+
+
 class _Span:
     """One live span.  Records at EXIT: a span that never exits (a
     wedged dispatch abandoned with its watchdog worker) simply leaves
@@ -628,23 +692,27 @@ class _Span:
             return
         self._lap = None
         name, t0, args, ctx = lap
-        if ctx is not None:
-            _jax_annotation_exit(ctx)
         tl = self._plane._tls
         depth = getattr(tl, "depth", 1)
+        if getattr(tl, "stages", None):
+            self._plane._close_stages(tl.stages, depth, now)
+        if ctx is not None:
+            _jax_annotation_exit(ctx)
         tl.depth = depth - 1
         self._plane._record_span(name, t0, now, depth - 1, args)
 
     def __exit__(self, exc_type, exc, tb):
         t1 = time.perf_counter_ns()
         self._end_lap(t1)
+        plane = self._plane
+        tl = plane._tls
+        depth = getattr(tl, "depth", 1)
+        if getattr(tl, "stages", None):
+            plane._close_stages(tl.stages, depth, t1)
         if self._jax_ctx is not None:
             _jax_annotation_exit(self._jax_ctx)
         if self._observe is not None:
             self._observe(self._timer, (t1 - self._t0) / 1e9)
-        plane = self._plane
-        tl = plane._tls
-        depth = getattr(tl, "depth", 1)
         tl.depth = depth - 1
         if exc_type is not None:
             self.args["error"] = exc_type.__name__
@@ -739,6 +807,7 @@ class TracePlane:
         # one made by THIS thread while it holds ``_lock``.  It appends
         # here (atomic, lock-free) and every locked section drains first.
         self._deferred: deque = deque()
+        self._stage_end = _StageEnd(self)
 
     # -- configuration ---------------------------------------------------
 
@@ -894,6 +963,68 @@ class TracePlane:
             sp._timer = timer
         return sp
 
+    def stage(self, name: str):
+        """Open a timed STAGE on this thread: the next sequential child
+        of whatever span is open (or of none), closed by the next
+        ``stage`` at the same level, by ``stage_end``, or by the exit of
+        the span (or lap) it was opened in — an exception anywhere
+        still closes it there.  Unlike ``lap`` the record goes to the
+        per-name histograms (``phase_totals``, a job's ``phases`` and
+        ``latency``) and, bridge on, to a ``TraceAnnotation``, but NOT
+        to the ring and NOT to the sink: a stage never shows in
+        ``ring_records`` / ``GET .../trace``, so the self time of its
+        parent — what the benchmark's ``job_span_self`` metrics read —
+        stays the parent's whole duration, and a job ring holds no more
+        records for it.  A ring span may open and close inside a stage
+        (it leaves the stage open).  The open stage is the thread's, so
+        a callee (the featurizer) stages its caller's span without
+        being handed it.  Returns a context manager that ends the stage
+        with its block, for a stretch with no span around it; plane
+        off: the no-op singleton.  ``name`` must be a string literal
+        (registry-literals lint, like ``span``)."""
+        ov = getattr(self._tls, "scope", None)
+        if ov is not None:
+            return ov.stage(name)
+        if not self._active:
+            return _NOOP
+        now = time.perf_counter_ns()
+        tl = self._tls
+        depth = getattr(tl, "depth", 0)
+        stages = getattr(tl, "stages", None)
+        if stages is None:
+            stages = tl.stages = []
+        elif stages:
+            self._close_stages(stages, depth, now)
+        ctx = _jax_annotation(name) if self._jax_bridge else None
+        stages.append((name, now, depth, ctx))
+        return self._stage_end
+
+    def stage_end(self) -> None:
+        """Close this thread's open stage of the current level, if any
+        (the explicit end of ``stage``)."""
+        ov = getattr(self._tls, "scope", None)
+        if ov is not None:
+            ov.stage_end()
+            return
+        tl = self._tls
+        stages = getattr(tl, "stages", None)
+        if stages:
+            self._close_stages(
+                stages, getattr(tl, "depth", 0), time.perf_counter_ns()
+            )
+
+    def _close_stages(self, stages: list, depth: int, now: int) -> None:
+        """Close the calling thread's stages opened at ``depth`` or
+        deeper (``depth`` = the open spans and laps around the caller),
+        innermost first: the timing layer only."""
+        while stages and stages[-1][2] >= depth:
+            name, t0, _depth, ctx = stages.pop()
+            if ctx is not None:
+                _jax_annotation_exit(ctx)
+            with self._lock:
+                self._drain_deferred()
+                self._time_span(name, t0, now)
+
     def event(self, name: str, **args) -> None:
         """Record one instant event (counted always; stored when the
         ring is on)."""
@@ -941,14 +1072,19 @@ class TracePlane:
             except Exception:  # a broken sink must not break the plane
                 pass
 
-    def _store_span(  # ksimlint: lock-held(_lock)
-        self, name: str, t0: int, t1: int, tid: int, depth: int, args: dict,
-        want_rec: bool, tname: "str | None" = None,
-    ) -> "dict | None":
+    def _time_span(self, name: str, t0: int, t1: int) -> None:  # ksimlint: lock-held(_lock)
+        """The timing layer alone: one observation in ``name``'s
+        histogram (all a stage leaves)."""
         hist = self._hist.get(name)
         if hist is None:
             hist = self._hist[name] = LatencyHistogram()
         hist.observe((t1 - t0) / 1e9)
+
+    def _store_span(  # ksimlint: lock-held(_lock)
+        self, name: str, t0: int, t1: int, tid: int, depth: int, args: dict,
+        want_rec: bool, tname: "str | None" = None,
+    ) -> "dict | None":
+        self._time_span(name, t0, t1)
         if not (self._ring_on or want_rec):
             return None
         rec = {

@@ -59,7 +59,9 @@ class ScenarioResult:
     # plane (obs.SPAN_NAMES keys): device path = replay.lower /
     # replay.dispatch / replay.reconcile; per-pass host path =
     # runner.step (which NESTS its service.schedule span — the two are
-    # reported side by side, not additive).
+    # reported side by side, not additive).  A parent's timed stages
+    # (replay.reconcile.*, service.featurize.*, ...) are keys of their
+    # own: per-run sums, each no greater than its parent's entry.
     phase_seconds: dict[str, float] = field(default_factory=dict)
     # Fleet replay (engine/fleet.py): the per-lane ScenarioResults, in
     # lane order.  The top-level counts/steps are then AGGREGATES over
@@ -478,15 +480,20 @@ class ScenarioRunner:
         victims' evictions, in the exact per-pass order.  Runs inside
         the segment transaction: store-only, no service/result effects
         (victim eviction listeners defer into ``eviction_sink`` and fire
-        after commit)."""
+        after commit).  Two timed stages of ``replay.reconcile`` a step:
+        ``apply`` (the step's own operations) and ``write`` (its
+        placements, up to the next step's ``apply``)."""
+        TRACE.stage("replay.reconcile.apply")
         self._apply_batch(batch)
+        TRACE.stage("replay.reconcile.write")
         if outcome.attempts is not None:
             # The write-back of the step's preemptions (nominations, their
             # victims' evictions, the nominations they clear) under ONE
             # child span, from the first preemptor's attempt to the last
             # one's: a job that evicts 15,000 pods must not hide that cost
             # in the parent, and a span a preemption would push everything
-            # else out of the job's ring.
+            # else out of the job's ring.  It is a ring span and nests
+            # inside the ``write`` stage's interval, which stays open.
             atts = outcome.attempts
             hits = [
                 i for i, a in enumerate(atts) if a.victims or a.nominated or a.gave_up
@@ -647,7 +654,11 @@ class ScenarioRunner:
                         if outcome.eligible > 0
                         else None
                     )
+                TRACE.stage("replay.reconcile.verify")
                 driver.verify_segment(seg)
+                # The transaction's exit (every buffered watch event
+                # delivered), up to the span's own.
+                TRACE.stage("replay.reconcile.commit")
         except InjectedFault as e:
             driver.note_reconcile_fault()
             logger.warning(
@@ -657,12 +668,15 @@ class ScenarioRunner:
                 type(e).__name__, e,
             )
             return False
-        self.service._notify_evictions(evictions)
-        driver.advance_service_slots(step_nodes)
-        driver.sync_service(seg)
-        driver.note_segment_committed(seg, step_nodes)
-        for step, batch, outcome in zip(seg_keys, batches, seg.steps):
-            self._record_device_step(step, batch, outcome, result)
+        # What has no rollback story runs after the span; a stage with no
+        # ring parent times it.
+        with TRACE.stage("replay.reconcile.effects"):
+            self.service._notify_evictions(evictions)
+            driver.advance_service_slots(step_nodes)
+            driver.sync_service(seg)
+            driver.note_segment_committed(seg, step_nodes)
+            for step, batch, outcome in zip(seg_keys, batches, seg.steps):
+                self._record_device_step(step, batch, outcome, result)
         return True
 
     def run(

@@ -151,8 +151,13 @@ class _Handler(BaseHTTPRequestHandler):
         (its Monaco editors edit resources/config as YAML,
         web/components/ResourceBar/YamlEditor.vue), so pasted manifests
         round-trip without client-side conversion."""
+        return self._parse_body(self._read_body())
+
+    def _read_body(self) -> bytes:
         length = int(self.headers.get("Content-Length") or 0)
-        raw = self.rfile.read(length) if length else b""
+        return self.rfile.read(length) if length else b""
+
+    def _parse_body(self, raw: bytes):
         if not raw:
             return {}
         if "yaml" in (self.headers.get("Content-Type") or ""):
@@ -495,18 +500,36 @@ class _Handler(BaseHTTPRequestHandler):
         quota/rate — the throttle response carries a ``Retry-After``
         header with the token bucket's computed wait.  ``do_POST`` wraps
         the whole request — body read, parse, validation, enqueue,
-        response — in one ``jobs.submit`` span on the global plane: a
-        15,000-operation body spends 0.2-0.6 s here before the job's own
-        ring exists."""
-        from ksim_tpu.jobs import JobLimitExceeded, JobQueueFull, JobThrottled
+        response — in one ``jobs.submit`` span on the global plane,
+        before the job's own ring exists, and its four timed stages
+        (``jobs.submit.read`` / ``.parse`` / ``.build`` / ``.enqueue``)
+        show in a traced slice.  That plane is off in an untraced
+        server, so the same boundaries are read here on a
+        ``SubmitClock`` the job keeps: its result's ``submit`` block
+        (the benchmark's ``submit_s_per_job``).  A refused submission
+        has no job and leaves the span alone."""
+        from ksim_tpu.jobs import (
+            JobLimitExceeded,
+            JobQueueFull,
+            JobThrottled,
+            SubmitClock,
+        )
         from ksim_tpu.scenario.spec import ScenarioSpecError
 
         # The job's ``runtime`` window opens here, with the POST: a full
         # collection while the body is read and parsed is one its
         # client waits on.
         runtime0 = runtime_totals()
+        clock = SubmitClock()
         try:
-            doc = self._body()
+            TRACE.stage("jobs.submit.read")
+            raw = self._read_body()
+            clock.mark()
+            TRACE.stage("jobs.submit.parse")
+            doc = self._parse_body(raw)
+            del raw
+            clock.mark()
+            TRACE.stage("jobs.submit.build")
         except Exception:
             self._json(400, {"message": "Bad Request"})
             return
@@ -521,7 +544,10 @@ class _Handler(BaseHTTPRequestHandler):
             return
         try:
             job = jm.submit(
-                doc, tenant=self.headers.get("X-Ksim-Tenant"), runtime0=runtime0
+                doc,
+                tenant=self.headers.get("X-Ksim-Tenant"),
+                runtime0=runtime0,
+                submit_clock=clock,
             )
         except ScenarioSpecError as e:
             self._json(400, {"message": str(e)})
@@ -547,7 +573,10 @@ class _Handler(BaseHTTPRequestHandler):
             logger.exception("job submission failed")
             self._json(500, {"message": "Internal Server Error"})
             return
-        self._json(202, job.status())
+        try:
+            self._json(202, job.status())
+        finally:
+            clock.close()
 
     def _job_parts(self, path: str) -> "tuple[str, str] | None":
         parts = [p for p in path.split("/") if p]  # api v1 jobs [id [sub]]

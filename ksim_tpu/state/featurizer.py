@@ -32,6 +32,7 @@ from typing import Any, Iterable, Sequence
 
 import numpy as np
 
+from ksim_tpu.obs import TRACE
 from ksim_tpu.state import objcache
 from ksim_tpu.state.boundagg import NodeSlots, sync_family
 from ksim_tpu.state.podtable import ROW, Column, PodTable
@@ -323,7 +324,16 @@ class Featurizer:
         Queue pods are recognised by object identity across calls (the
         row table, state/podtable.py): a pod object handed in again is
         served its stored rows, so — as for every objcache memo — an
-        object must not be edited in place after it was featurized."""
+        object must not be edited in place after it was featurized.
+
+        The call is six timed stages of whatever span the caller has
+        open (``service.featurize.index`` / ``.resources`` /
+        ``.affinity`` / ``.spread`` / ``.interpod`` / ``.extras``:
+        obs.py ``TracePlane.stage``), the sequential seams of the body;
+        every encoder does its own node side, pod rows and bound
+        aggregate, and the node-side tables built afresh are counted by
+        ``Memo.seq_builds``."""
+        TRACE.stage("service.featurize.index")
         # Safe point for memo-table size enforcement: no memo key is in
         # flight here (see objcache.maybe_flush).
         objcache.maybe_flush()
@@ -415,6 +425,7 @@ class Featurizer:
         ident = table.family("identity", _IDENTITY_COLUMNS)
         table.sync(ident, None, lambda p: (namespaced_key(p),), shared=False)
 
+        TRACE.stage("service.featurize.resources")
         # Bound pods' raw request values as an incrementally-maintained
         # multiset per resource: the resource axis and exact gcd units
         # need every value that enters math, without an O(bound) walk.
@@ -613,35 +624,38 @@ class Featurizer:
         from ksim_tpu.state.interpod import encode_inter_pod
         from ksim_tpu.state.volumes import encode_volumes
 
-        aux = {
-            "affinity": encode_affinity(
-                nodes, table, NP, PP, added_affinity=self._added_affinity
-            ),
-            "taints": encode_taints(nodes, table, NP, PP),
-            "spread": encode_topology_spread(
-                nodes, table, NP, PP,
-                agg=self._agg, bound_map=bound_map,
-                changed_slots=changed_slots, slot_of=node_index,
-                default_constraints=self._spread_defaults,
-            ),
-            "interpod": encode_inter_pod(
-                nodes, table, namespaces, NP, PP,
-                hard_weight=self._interpod_hard_weight,
-                agg=self._agg, bound_map=bound_map,
-                changed_slots=changed_slots, slot_of=node_index,
-            ),
-            "nodename": encode_node_name(nodes, table, PP),
-            "nodeports": encode_node_ports(nodes, table, bound_pods, NP, PP),
-            "imagelocality": encode_image_locality(nodes, table, NP, PP),
-            "volumes": encode_volumes(
-                nodes, table, bound_pods, pvs, pvcs, storage_classes, NP, PP,
-                bound_volume_free=self._bound_vol_count == 0,
-            ),
-        }
+        aux = {}
+        TRACE.stage("service.featurize.affinity")
+        aux["affinity"] = encode_affinity(
+            nodes, table, NP, PP, added_affinity=self._added_affinity
+        )
+        aux["taints"] = encode_taints(nodes, table, NP, PP)
+        TRACE.stage("service.featurize.spread")
+        aux["spread"] = encode_topology_spread(
+            nodes, table, NP, PP,
+            agg=self._agg, bound_map=bound_map,
+            changed_slots=changed_slots, slot_of=node_index,
+            default_constraints=self._spread_defaults,
+        )
+        TRACE.stage("service.featurize.interpod")
+        aux["interpod"] = encode_inter_pod(
+            nodes, table, namespaces, NP, PP,
+            hard_weight=self._interpod_hard_weight,
+            agg=self._agg, bound_map=bound_map,
+            changed_slots=changed_slots, slot_of=node_index,
+        )
+        TRACE.stage("service.featurize.extras")
+        aux["nodename"] = encode_node_name(nodes, table, PP)
+        aux["nodeports"] = encode_node_ports(nodes, table, bound_pods, NP, PP)
+        aux["imagelocality"] = encode_image_locality(nodes, table, NP, PP)
+        aux["volumes"] = encode_volumes(
+            nodes, table, bound_pods, pvs, pvcs, storage_classes, NP, PP,
+            bound_volume_free=self._bound_vol_count == 0,
+        )
         for key, encoder in self._extra_encoders.items():
             aux[key] = encoder(nodes, sched_pods, NP, PP)
 
-        return FeaturizedSnapshot(
+        snapshot = FeaturizedSnapshot(
             resources=resources,
             units=units,
             exact=exact,
@@ -666,3 +680,5 @@ class Featurizer:
                 index=np.arange(PP, dtype=np.int32),
             ),
         )
+        TRACE.stage_end()
+        return snapshot

@@ -78,7 +78,10 @@ class Memo:
     """One owner's tables: the per-object memo with its pins, the
     family (whole-sequence) LRU and the token intern table."""
 
-    __slots__ = ("_data", "_refs", "_gen", "_limit", "_seq", "_intern", "_intern_next")
+    __slots__ = (
+        "_data", "_refs", "_gen", "_limit", "_seq", "_intern", "_intern_next",
+        "seq_builds",
+    )
 
     def __init__(self) -> None:
         # key -> [value, last_access_generation]; key[1] is the pinned id.
@@ -89,6 +92,10 @@ class Memo:
         self._seq: "collections.OrderedDict[Any, Any]" = collections.OrderedDict()
         self._intern: dict[Any, int] = {}
         self._intern_next = 0
+        # ``cached_seq`` misses: whole-sequence (node-axis) tables built
+        # afresh.  Never reset: the replay driver reads its growth
+        # across a lowering.
+        self.seq_builds = 0
 
     def ref_id(self, obj: Any) -> int:
         """id(obj), pinned: the object stays alive while the memo does."""
@@ -171,6 +178,7 @@ class Memo:
         if hit is not None:
             table.move_to_end(key)
             return hit[0]
+        self.seq_builds += 1
         value = fn()
         table[key] = (value, seq)
         if len(table) > _SEQ_LIMIT:
@@ -215,6 +223,7 @@ class Memo:
             "refs": len(self._refs),
             "generation": self._gen,
             "seq_entries": len(self._seq),
+            "seq_builds": self.seq_builds,
             "interned": len(self._intern),
         }
 

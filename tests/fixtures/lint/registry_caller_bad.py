@@ -40,4 +40,5 @@ def run(name):
     TRACE.phase("rogue.phase", None, "t")  # finding: not in SPAN_NAMES
     sp = TRACE.span("wired.site")
     sp.lap("rogue.lap")  # finding: not in SPAN_NAMES
+    TRACE.stage("rogue.stage")  # finding: not in SPAN_NAMES
     sp.lap(name)  # finding: non-literal name

@@ -179,6 +179,7 @@ def test_registry_literals_fires_on_seeded_violations():
     # The helpers that open spans by another spelling are scanned too.
     assert "'rogue.phase' is not in obs.SPAN_NAMES" in joined
     assert "'rogue.lap' is not in obs.SPAN_NAMES" in joined
+    assert "'rogue.stage' is not in obs.SPAN_NAMES" in joined
     assert joined.count("TRACE.span with a non-literal name") == 1
     assert "'rogue.event' is not in obs.EVENT_NAMES" in joined
     assert "non-literal name" in joined

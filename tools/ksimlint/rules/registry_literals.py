@@ -16,7 +16,9 @@ scans every call site in the tree and checks BOTH directions:
   span/event taxonomy — and so is every name given to the two helpers
   that open spans by another spelling: ``TRACE.phase("...", ...)`` (a
   span that also feeds a Metrics timer) and ``<span>.lap("...")`` (the
-  next sequential child of an open span, on any receiver);
+  next sequential child of an open span, on any receiver) — and to
+  ``TRACE.stage("...")`` (a timed stage: histograms and the profiler
+  bridge, never the ring);
 - every ``_expo_family("...")`` Prometheus exposition family declared
   in obs.py resolves into ``obs.METRIC_NAMES`` (and every registry
   entry is declared somewhere — a family in the registry with no
@@ -55,7 +57,7 @@ class RegistryConfig:
     replay_module: str = "ksim_tpu/engine/replay.py"
     faults_object: str = "FAULTS"  # <obj>.check(site)
     trace_object: str = "TRACE"  # <obj>.span(name) / <obj>.event(name)
-    span_methods: tuple = ("span", "phase")  # <obj>.<method>(name, ...)
+    span_methods: tuple = ("span", "phase", "stage")  # <obj>.<method>(name, ...)
     lap_method: str = "lap"  # <any span>.lap(name): a child span
     metric_helper: str = "_expo_family"  # <helper>(family, kind, help)
 
