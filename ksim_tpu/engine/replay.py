@@ -2025,6 +2025,13 @@ class ReplayDriver:
         # (``Memo.seq_builds``), summed over the lowerings: about one a
         # family in a cold call, none while the node objects stay.
         self.featurize_node_builds = 0  # guarded-by: main-thread
+        # Store writes of the committed segments' reconciles that
+        # replaced an object that was there: by a shallow re-wrap that
+        # shares the frozen manifest (every placement, nomination and
+        # requeue) and through the deep-copying ``patch`` (thousands =
+        # placements have gone back through it).
+        self.reconcile_writes_shared = 0  # guarded-by: main-thread
+        self.reconcile_writes_copied = 0  # guarded-by: main-thread
         # Streaming ingest overlap (round 22, traces/stream.py): a
         # runner-provided NONBLOCKING drain of the trace-ingest queue,
         # called on the main thread while the dispatch worker owns the
@@ -2148,6 +2155,8 @@ class ReplayDriver:
             "steps_padded": self.steps_padded,
             "pairs_evaluated": self.pairs_evaluated,
             "queue_slots_run": self.queue_slots_run,
+            "reconcile_writes_shared": self.reconcile_writes_shared,
+            "reconcile_writes_copied": self.reconcile_writes_copied,
             "preempt_searches": self.preempt_searches,
             "preempt_table_builds": self.preempt_table_builds,
             "preempt_candidates": self.preempt_candidates,
@@ -3708,6 +3717,8 @@ class ReplayDriver:
             # Filled in when the segment commits:
             "pairs_evaluated": 0,
             "slots_run": 0,
+            "writes_shared": 0,
+            "writes_copied": 0,
             "preempt_searches": 0,
             "preempt_table_builds": 0,
             "preempt_candidates": 0,
@@ -4131,7 +4142,10 @@ class ReplayDriver:
                 feat.advance_slots(nodes)
 
     def note_segment_committed(
-        self, seg: SegmentOutcome, step_nodes: "Sequence[Any]"
+        self,
+        seg: SegmentOutcome,
+        step_nodes: "Sequence[Any]",
+        writes: "tuple[int, int]" = (0, 0),
     ) -> None:
         """Post-commit accounting of one device segment: its steps, and
         the pod x node pairs its passes evaluated — every attempt of a
@@ -4140,8 +4154,11 @@ class ReplayDriver:
         the pass never ran, and then nothing was attempted) — and the
         queue slots the device's pod loops ran for them
         (``queue_slots_run``: the attempts, each step's rounded up to a
-        whole block of the loop)."""
+        whole block of the loop).  ``writes``: the reconcile's own count
+        of the objects it replaced by re-wrap and through ``patch``."""
         self.device_steps += len(seg.steps)
+        self.reconcile_writes_shared += writes[0]
+        self.reconcile_writes_copied += writes[1]
         pairs = sum(
             (o.scheduled + o.unschedulable) * len(nodes)
             for o, nodes in zip(seg.steps, step_nodes)
@@ -4172,6 +4189,7 @@ class ReplayDriver:
         if plan is not None and plan.log_entry is not None:
             plan.log_entry["pairs_evaluated"] = pairs
             plan.log_entry["slots_run"] = slots
+            plan.log_entry["writes_shared"], plan.log_entry["writes_copied"] = writes
             plan.log_entry.update(preempt | sampling)
             plan.log_entry["sampling_start"] = seg.sample_start
 

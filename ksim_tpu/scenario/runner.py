@@ -14,6 +14,7 @@ import time
 from dataclasses import dataclass, field
 from typing import Any, Iterable, Sequence
 
+from ksim_tpu.engine.annotations import apply_results_to_pod
 from ksim_tpu.obs import TRACE
 from ksim_tpu.scheduler.service import SchedulerService
 from ksim_tpu.state import objcache
@@ -87,6 +88,67 @@ def _phase_split(phase0: dict) -> dict[str, float]:
         if count > prev_count:
             split[name] = round(total - prev_total, 6)
     return split
+
+
+def placed_pod(
+    obj: JSON,
+    *,
+    anno: "dict[str, str] | None" = None,
+    node: "str | None" = None,
+    nominated: "str | None" = None,
+    gave_up: bool = False,
+) -> JSON:
+    """The pod as the segment reconciler leaves it after one attempt: a
+    ``ClusterStore.rewrap`` builder.  Result annotations merged into a
+    NEW ``metadata.annotations``; then the bind (``spec.nodeName``,
+    phase Running, any nomination dropped), or the nomination, or the
+    nomination given up.  Only the dicts on the way to a changed key
+    are new; everything else (containers, affinity and spread terms,
+    resource maps) is shared with ``obj``, which is frozen and is not
+    touched.
+
+    Key for key the object the deep-copying ``patch`` closure stored
+    here before: annotations only when the attempt recorded some,
+    ``status`` created by a bind or a nomination but not by a give-up.
+    That is NOT ``SchedulerService._bind_results``' rebuild, which
+    always writes ``metadata.annotations`` (possibly ``{}``), ``spec``
+    and ``status``: the two stay apart because their objects differ."""
+    new = dict(obj)
+    if anno:
+        md = dict(obj.get("metadata") or {})
+        md["annotations"] = apply_results_to_pod(
+            dict(md.get("annotations") or {}), anno
+        )
+        new["metadata"] = md
+    if node:
+        new["spec"] = dict(obj.get("spec") or {}, nodeName=node)
+        status = dict(obj.get("status") or {}, phase="Running")
+        status.pop("nominatedNodeName", None)
+        new["status"] = status
+    elif nominated:
+        new["status"] = dict(obj.get("status") or {}, nominatedNodeName=nominated)
+    elif gave_up:
+        _drop(new, "status", "nominatedNodeName")
+    return new
+
+
+def requeued_pod(obj: JSON) -> JSON:
+    """A pod of a drained node back in the queue (``rewrap`` builder):
+    ``spec.nodeName`` and ``status.phase`` gone, the rest shared."""
+    new = dict(obj)
+    _drop(new, "spec", "nodeName")
+    _drop(new, "status", "phase")
+    return new
+
+
+def _drop(new: JSON, section: str, key: str) -> None:
+    """Replace ``new[section]`` by a copy without ``key`` — where it has
+    the key; the section is shared with a frozen object otherwise."""
+    sub = new.get(section)
+    if sub and key in sub:
+        sub = dict(sub)
+        del sub[key]
+        new[section] = sub
 
 
 class _StreamFeeder:
@@ -290,6 +352,13 @@ class ScenarioRunner:
         )
         self._requeue = requeue_on_node_delete
         self._drained_nodes: set[str] = set()
+        # Store writes of objects that were there, since the segment
+        # reconcile in hand began staging: by re-wrap (``placed_pod``,
+        # ``requeued_pod``, the nominations a preemptor clears) and
+        # through the deep-copying ``patch`` (a patchOperation).  The
+        # driver sums them at commit (``replay.reconcile_writes_*``).
+        self._writes_shared = 0
+        self._writes_copied = 0
         self._device_replay = device_replay
         self._device_segment_steps = device_segment_steps
         self._fleet = fleet
@@ -382,6 +451,7 @@ class ScenarioRunner:
             self.store.patch(
                 op.kind, op.name, op.namespace, apply_merge, copy_ret=False
             )
+            self._writes_copied += 1
         elif op.op == "delete":
             if op.kind == "nodes" and self._requeue:
                 # Deferred: run() re-queues all drained nodes' pods in ONE
@@ -398,22 +468,20 @@ class ScenarioRunner:
         if not node_names:
             return
 
-        def clear(obj: JSON) -> None:
-            obj["spec"].pop("nodeName", None)
-            obj.get("status", {}).pop("phase", None)
-
         # The store's nodeName bucket index bounds the walk to pods ON
         # the drained nodes (the earlier bound-side walk still scanned
         # every bound pod per drain — ~10s of the 50k replay); the
         # matches sort by (name, "ns/name") — exactly list("pods")'s
-        # (name, key) order — so patches apply (and consume
+        # (name, key) order — so the writes apply (and consume
         # resourceVersions) in the same order the full walk produced.
+        # Each is a re-wrap that shares the manifest (``requeued_pod``).
         hit = [
             (name_of(p), f"{namespace_of(p) or 'default'}/{name_of(p)}", namespace_of(p))
             for p in self.store.pods_on_nodes(node_names)
         ]
         for name, _key, ns in sorted(hit):
-            self.store.patch("pods", name, ns, clear, copy_ret=False)
+            self.store.rewrap("pods", name, ns, requeued_pod)
+        self._writes_shared += len(hit)
 
     # -- replay -------------------------------------------------------------
 
@@ -480,7 +548,8 @@ class ScenarioRunner:
         victims' evictions, in the exact per-pass order.  Runs inside
         the segment transaction: store-only, no service/result effects
         (victim eviction listeners defer into ``eviction_sink`` and fire
-        after commit).  Two timed stages of ``replay.reconcile`` a step:
+        after commit).  A placement replaces the pod by ``placed_pod``'s
+        re-wrap, never by a deep copy.  Two timed stages of ``replay.reconcile`` a step:
         ``apply`` (the step's own operations) and ``write`` (its
         placements, up to the next step's ``apply``)."""
         TRACE.stage("replay.reconcile.apply")
@@ -512,44 +581,30 @@ class ScenarioRunner:
             for att in atts[end:]:
                 self._stage_attempt(att, eviction_sink)
         else:
+            rewrap = self.store.rewrap
             for ns, name, node in outcome.binds:
-
-                def bind(obj: JSON) -> None:
-                    obj.setdefault("spec", {})["nodeName"] = node
-                    obj.setdefault("status", {})["phase"] = "Running"
-                    obj.get("status", {}).pop("nominatedNodeName", None)
-
-                self.store.patch("pods", name, ns, bind, copy_ret=False)
+                rewrap("pods", name, ns, lambda obj, node=node: placed_pod(obj, node=node))
+            self._writes_shared += len(outcome.binds)
 
     def _stage_attempt(self, att, eviction_sink: list[tuple[str, str]]) -> None:
         """One attempt's store writes, mirroring the per-pass rebuild:
         result annotations, the bind or the nomination (or the
         nomination given up), then the victims' evictions and the
         lower-priority nominations the preemptor's node loses."""
-        from ksim_tpu.engine.annotations import apply_results_to_pod
-
         if att.anno or att.node or att.nominated or att.gave_up:
-
-            def mutate(obj: JSON) -> None:
-                if att.anno:
-                    annos = obj.setdefault("metadata", {}).setdefault(
-                        "annotations", {}
-                    )
-                    apply_results_to_pod(annos, att.anno)
-                if att.node:
-                    obj.setdefault("spec", {})["nodeName"] = att.node
-                    obj.setdefault("status", {})["phase"] = "Running"
-                    obj.get("status", {}).pop("nominatedNodeName", None)
-                elif att.nominated:
-                    obj.setdefault("status", {})[
-                        "nominatedNodeName"
-                    ] = att.nominated
-                elif att.gave_up:
-                    obj.get("status", {}).pop("nominatedNodeName", None)
-
-            self.store.patch(
-                "pods", att.name, att.namespace, mutate, copy_ret=False
+            self.store.rewrap(
+                "pods",
+                att.name,
+                att.namespace,
+                lambda obj: placed_pod(
+                    obj,
+                    anno=att.anno,
+                    node=att.node,
+                    nominated=att.nominated,
+                    gave_up=att.gave_up,
+                ),
             )
+            self._writes_shared += 1
         # Victim evictions go through the service so delete
         # semantics match the per-pass path; listener callbacks
         # defer to post-commit (a rolled-back segment must never
@@ -562,7 +617,7 @@ class ScenarioRunner:
         if att.cleared:
             from ksim_tpu.state.priorities import build_priority_resolver
 
-            self.service._clear_lower_nominations(
+            self._writes_shared += self.service._clear_lower_nominations(
                 att.nominated,
                 self.store.get("pods", att.name, att.namespace),
                 priority_of=build_priority_resolver(
@@ -592,8 +647,10 @@ class ScenarioRunner:
     ) -> bool:
         """Reconcile one device-computed segment ALL-OR-NOTHING.
 
-        Every store write of the segment — event ops, requeue patches,
-        bind/nomination/annotation patches, victim evictions — stages
+        Every store write of the segment — event ops, requeues,
+        binds/nominations/annotations (shallow re-wraps that share the
+        frozen manifest: ``placed_pod`` / ``requeued_pod`` through
+        ``ClusterStore.rewrap``), victim evictions — stages
         inside one store transaction, the device-vs-store parity check
         runs against the staged state, and only then does the batch
         commit (watch events deliver at commit, in write order).  The
@@ -619,6 +676,7 @@ class ScenarioRunner:
 
         evictions: list[tuple[str, str]] = []
         step_nodes: list = []
+        self._writes_shared = self._writes_copied = 0
         tags = {} if self._lane is None else {"lane": self._lane}
         try:
             with TRACE.span(
@@ -674,7 +732,9 @@ class ScenarioRunner:
             self.service._notify_evictions(evictions)
             driver.advance_service_slots(step_nodes)
             driver.sync_service(seg)
-            driver.note_segment_committed(seg, step_nodes)
+            driver.note_segment_committed(
+                seg, step_nodes, writes=(self._writes_shared, self._writes_copied)
+            )
             for step, batch, outcome in zip(seg_keys, batches, seg.steps):
                 self._record_device_step(step, batch, outcome, result)
         return True

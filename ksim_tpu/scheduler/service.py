@@ -1133,15 +1133,17 @@ class SchedulerService:
 
     def _clear_lower_nominations(
         self, node_name: str, pod: JSON, priority_of=None
-    ) -> None:
+    ) -> int:
         """prepareCandidate: pods of a lower priority nominated to the
-        node ``pod`` just took have to look again.  ``priority_of``
-        defaults to the running pass's resolver (the device replay's
-        reconcile, which runs no pass, brings its own)."""
+        node ``pod`` just took have to look again; returns how many
+        were.  ``priority_of`` defaults to the running pass's resolver
+        (the device replay's reconcile, which runs no pass, brings its
+        own)."""
         from ksim_tpu.scheduler.preemption import nominated_node_of
 
         priority_of = priority_of or self._priority_of
         prio = priority_of(pod)
+        cleared = 0
         for q in self._store.pods_without_node():
             if nominated_node_of(q) != node_name or priority_of(q) >= prio:
                 continue
@@ -1157,8 +1159,10 @@ class SchedulerService:
                 updated = self._store.rewrap("pods", name_of(q), namespace_of(q), clear)
             except NotFoundError:
                 continue
+            cleared += 1
             with self._own_rvs_lock:
                 self._own_rvs.add(updated["metadata"]["resourceVersion"])
+        return cleared
 
     def _bind_results(
         self, queue, feats, plugins, res, placements, prof=None, nominees=None

@@ -464,9 +464,11 @@ class ClusterStore:
         every attempt dominated the record="full" product path.
 
         Contract: ``build`` must not mutate ``current`` or any shared
-        substructure, must return a fresh ``metadata`` dict (it gets the
-        new resourceVersion), and the returned object is stored AND
-        shared with watch events — the caller must treat it as frozen.
+        substructure; its ``metadata`` may be the old one (it is copied
+        here, and the copy gets the new resourceVersion); the returned
+        object is stored AND shared with watch events — the caller must
+        treat it as frozen.  The segment reconciler's placements and
+        requeues come through here too (scenario/runner.py).
         """
         self._check_kind(kind)
         with self._lock:

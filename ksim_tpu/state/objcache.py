@@ -3,14 +3,35 @@
 Churn replay featurizes the whole cluster every scheduling pass, but most
 objects are unchanged between passes: the cluster store hands out the
 SAME dict object for an unchanged resource (``list(copy_objs=False)``)
-and a brand-new dict on every write (create/update/patch all deepcopy
-before storing, state/cluster.py).  ``id(obj)`` therefore identifies a
-frozen snapshot of an object's content for as long as that object is
-alive — and the memo keeps a strong reference to every key object so its
-id cannot be recycled while an entry exists.
+and a brand-new TOP-LEVEL dict on every write (state/cluster.py: writes
+replace, never mutate; nothing edits a stored object in place).
+``id(obj)`` therefore identifies a frozen snapshot of an object's content
+for as long as that object is alive — and the memo keeps a strong
+reference to every key object so its id cannot be recycled while an entry
+exists.
 
-Sub-objects inherit the property: a pod's ``spec.affinity`` term dicts
-are replaced together with the pod, so they are valid memo keys too.
+What a write does NOT renew is what lies below the top level.
+``create`` / ``update`` / ``patch`` deep-copy, but ``ClusterStore.rewrap``
+— the scheduler's bind, the segment reconciler's placements, nominations
+and requeues (``scenario/runner.py`` ``placed_pod`` / ``requeued_pod``) —
+and the runner's ``_own`` hand-over share every unmodified substructure
+with the object they replace (or were handed): a bound pod's
+``spec.containers``, its ``spec.affinity`` term dicts and its
+``topologySpreadConstraints`` are the SAME objects before and after the
+bind, and the operation's own object shares them too.  Sub-objects are
+frozen like their owners, so they are valid memo keys — for an entry
+whose value is a function of the keyed sub-object (and of the key's
+``extra`` parts) ALONE.  Such an entry outlives the write and is hit
+again, which is the point.  An entry keyed by a sub-object must never
+hold what the write can change around it: the pod's name or uid, its
+node, its phase, its annotations, its resourceVersion.  Whatever reads
+those is keyed by the pod itself, the top-level dict that every write
+renews: ``preq``, ``affpod``, ``ipterms``, ``ipparsed``, ``hostports``,
+``has_vols``, ``replay_*`` — every entry but one today.  The one keyed
+below the top level is ``ipctx`` (an affinity term's pod-matching part,
+the owner's namespace in its key: state/interpod.py), and it reads the
+term alone.  ``tests/test_objcache.py`` holds a warm memo to a cold one
+across re-wrapped binds and requeues.
 
 Eviction is generational, not clear-all: entries touched recently
 survive, entries untouched for a few generations are swept and their key
@@ -35,8 +56,8 @@ hands out the keyed objects: a ``SchedulerService`` owns one for its
 store (``SchedulerService.memo``) and installs it on the calling thread
 for every pass (``scope``); ``ScenarioRunner.run`` installs its
 service's for the whole replay, device lowering included.  The keys are
-ids of ONE store's own deep copies, so one store's entries can never
-serve another's lookups: a job's memo dies with the job's service, by
+ids of objects ONE store handed out and each owner has its own tables,
+so one store's entries can never serve another's lookups: a job's memo dies with the job's service, by
 reference count, and pins nothing of a finished job.  The sweep below
 is therefore the guard of a long-lived store only (the interactive
 server's).  The module-level functions act on the memo installed on

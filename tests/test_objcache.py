@@ -103,3 +103,78 @@ def test_maybe_flush_sweeps_only_stale_entries(monkeypatch):
     # Another owner's table saw none of it.
     assert other.stats()["entries"] == 1
     assert other.cached("slot", bystander, lambda: "recomputed") == "untouched"
+
+
+def _terms_pod(name, app, **kw):
+    """Required anti-affinity, a preferred affinity term and a spread
+    constraint: every kind of sub-object the encoders parse."""
+    sel = {"matchLabels": {"app": app}}
+    return make_pod(
+        name, cpu="500m", labels={"app": app},
+        affinity={
+            "podAntiAffinity": {"requiredDuringSchedulingIgnoredDuringExecution": [
+                {"labelSelector": sel, "topologyKey": "kubernetes.io/hostname"}]},
+            "podAffinity": {"preferredDuringSchedulingIgnoredDuringExecution": [
+                {"weight": 7, "podAffinityTerm": {
+                    "labelSelector": {"matchLabels": {"app": "web"}}, "topologyKey": "zone"}}]},
+        },
+        topology_spread_constraints=[{
+            "maxSkew": 1, "topologyKey": "zone", "whenUnsatisfiable": "DoNotSchedule",
+            "labelSelector": sel}],
+        **kw,
+    )
+
+
+def test_entries_keyed_by_shared_subobjects_survive_a_rewrap_and_stay_true():
+    """A pod written by re-wrap (``ClusterStore.rewrap``: the per-pass bind,
+    the segment reconciler's placements and requeues) keeps its containers,
+    affinity terms and spread constraints BY IDENTITY, so a memo entry keyed
+    by such a sub-object outlives the write.  That is sound because every
+    such entry is a function of the keyed sub-object (and the key's extras)
+    alone: after binds and requeues the warm memo's tensors are a cold
+    memo's, byte for byte."""
+    from ksim_tpu.scenario.runner import placed_pod, requeued_pod
+    from tests.test_featurizer import _assert_equal
+
+    store = ClusterStore()
+    for i in range(4):
+        node = make_node(f"n{i}", cpu="4")
+        node["metadata"]["labels"]["zone"] = f"z{i % 2}"
+        store.create("nodes", node)
+    for i in range(6):
+        store.create("pods", _terms_pod(f"p{i}", "web" if i % 2 else "db"))
+    store.create("pods", _terms_pod("held", "web", node_name="n3", phase="Running"))
+
+    def featurize():
+        return Featurizer().featurize(
+            store.list("nodes", copy_objs=False), (),
+            queue_pods=store.pods_without_node(), bound_pods=store.pods_with_node(),
+            namespaces=store.list("namespaces", copy_objs=False),
+        )
+
+    def term_of(name):
+        pod = next(p for p in store.list("pods", copy_objs=False) if p["metadata"]["name"] == name)
+        anti = pod["spec"]["affinity"]["podAntiAffinity"]
+        return pod, anti["requiredDuringSchedulingIgnoredDuringExecution"][0]
+
+    warm = objcache.Memo()
+    with objcache.scope(warm):
+        featurize()
+        old_pod, term = term_of("p0")
+        assert warm.get(("ipctx", id(term), "default")) is not objcache.MISS
+        for i, node in enumerate(("n0", "n1", "n2")):
+            store.rewrap("pods", f"p{i}", "default", lambda o, node=node: placed_pod(o, node=node))
+        store.rewrap("pods", "p3", "default", lambda o: placed_pod(o, nominated="n1"))
+        store.rewrap("pods", "held", "default", requeued_pod)
+        new_pod, new_term = term_of("p0")
+        # The pod is new, its parse entries with it; the term is the same
+        # object, and its entry is still there to be hit.
+        assert new_pod is not old_pod and new_term is term
+        assert warm.get(("ipparsed", id(new_pod))) is objcache.MISS
+        assert warm.get(("ipparsed", id(old_pod))) is not objcache.MISS
+        after_warm = featurize()
+        assert warm.get(("ipparsed", id(new_pod))) is not objcache.MISS
+    with objcache.scope(objcache.Memo()):
+        after_cold = featurize()
+    assert after_warm.pods.keys == ["default/held", "default/p3", "default/p4", "default/p5"]
+    _assert_equal(after_warm, after_cold)

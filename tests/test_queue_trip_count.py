@@ -104,9 +104,9 @@ def committed_steps(monkeypatch):
     seen = []
     committed = replay.ReplayDriver.note_segment_committed
 
-    def spy(self, seg, step_nodes):
+    def spy(self, seg, step_nodes, **kw):
         seen.extend(seg.steps)
-        return committed(self, seg, step_nodes)
+        return committed(self, seg, step_nodes, **kw)
 
     monkeypatch.setattr(replay.ReplayDriver, "note_segment_committed", spy)
     return seen
