@@ -510,18 +510,21 @@ class _Program:
         self,
         plugins: tuple[ScoredPlugin, ...],
         record: str,
-        sampled: bool = False,
+        sampled: "bool | int" = False,
     ) -> None:
         self.plugins = plugins
         self.record = record
         # percentageOfNodesToScore emulation: find-K-feasible sampling in
         # the sequential scan (upstream numFeasibleNodesToFind,
-        # schedule_one.go).  Only WHETHER the scan samples is static: K
+        # schedule_one.go).  Only WHETHER the scan samples, and whether
+        # in slot order (1) or by a visit-order operand (2), is static: K
         # is an operand of ``_schedule_sampled_fn``.
-        self.sampled = sampled
+        self.sampled = int(sampled)
         self._sig = (
             record,
-            sampled,
+            # False / True as before the ordered walk (the AOT tokens of
+            # the segment programs hold this signature), 2 for it.
+            self.sampled if self.sampled == 2 else bool(self.sampled),
             tuple(
                 (
                     _plugin_sig(sp.plugin),
@@ -713,13 +716,22 @@ class _Program:
     @partial(jax.jit, static_argnums=0)
     @device_kernel(static=("self",))
     def _schedule_sampled_fn(
-        self, state, pods: PodBatch, aux: dict, carries: dict, start, n_real, k
+        self, state, pods: PodBatch, aux: dict, carries: dict, start, n_real, k,
+        order=None,
     ):
         """The sequential-commit scan with percentageOfNodesToScore
         emulation: filter everywhere (the mask is needed to FIND the
         K feasible), then score/normalize/select over the sampled
         feasible set only, with the rotating start index carried across
-        pods exactly like upstream's sched.nextStartNodeIndex."""
+        pods exactly like upstream's sched.nextStartNodeIndex.
+
+        ``order`` (``self.sampled`` 2; else None: slot order) is each
+        slot's place in the visit order, i32 over the node axis, padding
+        slots last: the walk then goes through two gathers of the node
+        axis a pod."""
+        ordered = self.sampled == 2
+        if ordered:
+            by_order = jnp.argsort(order).astype(jnp.int32)
 
         def body(carry, pb: PodBatch):
             node_state, plugin_carries, start = carry
@@ -732,9 +744,17 @@ class _Program:
             )
             ok, bits = self._eval_filters(node_state, pod, aux, plugin_carries)
             in_real = jnp.arange(ok.shape[0], dtype=jnp.int32) < n_real
-            visited, sample, new_start = sample_visited(
-                ok & in_real, in_real, start, n_real, k
-            )
+            if not ordered:
+                visited, sample, new_start = sample_visited(
+                    ok & in_real, in_real, start, n_real, k
+                )
+            else:
+                ok = ok & in_real
+                vis_o, _, new_start = sample_visited(
+                    ok[by_order] & in_real, in_real, start, n_real, k
+                )
+                visited = vis_o[order] & in_real
+                sample = ok & visited
             # Padding pods never ran a cycle upstream: no rotation.
             new_start = jnp.where(pb.valid, new_start, start)
             raw, final, total = self._eval_scores(
@@ -800,6 +820,7 @@ class Engine:
         *,
         record: str = "full",  # full | final | selection
         sampling_k: int | None = None,
+        sampling_order: "np.ndarray | None" = None,
         metrics=None,
         node_mask: "np.ndarray | None" = None,
     ) -> None:
@@ -812,6 +833,9 @@ class Engine:
         rotating start index and stops after finding K feasible — only
         visited nodes are scored/recorded, exactly upstream's adaptive
         sampling (scan-only; batch evaluation has no visit order).
+        ``sampling_order`` gives each real node slot's place in the
+        visit order (a permutation of 0 .. count - 1; the service's node
+        tree); None visits in slot order.
 
         ``metrics`` (a ``util.Metrics``; the scheduler service passes
         its own) receives the pass's device phases as timers
@@ -831,8 +855,18 @@ class Engine:
                 f"padded axis is {int(feats.nodes.valid.shape[0])})"
             )
         self._feats = feats
-        self._prog = _Program(tuple(plugins), record, sampled=sampling_k is not None)
         self._sampling_k = sampling_k
+        self._sampling_order = None
+        if sampling_k is not None and sampling_order is not None:
+            width = int(feats.nodes.valid.shape[0])
+            order = np.arange(width, dtype=np.int32)
+            order[: len(sampling_order)] = sampling_order
+            self._sampling_order = jnp.asarray(order)
+        self._prog = _Program(
+            tuple(plugins),
+            record,
+            sampled=0 if sampling_k is None else 1 if self._sampling_order is None else 2,
+        )
         n = feats.nodes
         p = feats.pods
         node_host = dict(
@@ -1007,7 +1041,8 @@ class Engine:
             with TRACE.phase("engine.exec", self._metrics, "engine_exec"):
                 if sampled:
                     state, carries, start, out = self._prog._schedule_sampled_fn(
-                        state, pods_c, self._aux, carries, start, n_real, k
+                        state, pods_c, self._aux, carries, start, n_real, k,
+                        self._sampling_order,
                     )
                 else:
                     state, carries, out = self._prog._schedule_fn(

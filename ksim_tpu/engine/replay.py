@@ -90,10 +90,14 @@ start index carried through the pod loop, the steps and the segment
 stops at the step's ``ev["sample_k"]`` feasible nodes, and scores,
 normalises and selects over those (``engine/core.py sample_visited``:
 a prefix count, no sort; k and the index are operands).  The walk
-goes in the service's node order: this table's slot order where the
-lowering saw them agree at every step, else by the rank tensor
-(``_SegmentStatics.sample``).  A universe that does not sample lowers
-the program it always lowered.
+goes in the order of the service's node tree (scheduler/nodetree.py:
+upstream's zone-interleaved node list): this table's slot order where
+the lowering saw them agree at every step — it lays a window's new
+slots out in the tree's order, so a stream without node churn does —
+else by the step's walk tensor (``_SegmentStatics.sample``).  The tree
+orders the walk and nothing else: equal totals still go by the rank
+tensor, the per-pass featurizer's slot order.  A universe that does
+not sample lowers the program it always lowered.
 
 Segments shorter than the compiled K (stream tails, mid-window
 vocabulary misses) are tail-padded with inactive no-op steps and reuse
@@ -496,9 +500,11 @@ class _SegmentStatics:
     # attempt scores every feasible node (the program every window lowered
     # before sampling reached this path, unchanged); 1 = an attempt walks
     # the node axis from the carried start index, in SLOT order, which the
-    # lowering saw to be the service's node order at every step; 2 = the
-    # walk goes by the step's rank tensor (two gathers of the node axis a
-    # slot: node churn has moved the two orders apart).
+    # lowering saw to be the order of the service's node tree at every
+    # step (it lays new slots out in that order); 2 = the walk goes by the
+    # step's walk tensor, each slot's place in the tree's list (two
+    # gathers of the node axis a slot: node churn has moved the two
+    # orders apart).
     sample: int = 0
     tp: int = 1  # node-axis mesh width (round 17 sharded replay)
     # Round 19: the vmap axis name the fleet program maps lanes over, or
@@ -1398,11 +1404,12 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
             sampling = k_step > 0
             k_walk = jnp.where(sampling, k_step, _I32_MAX)
             if st.sample == 2:
-                # Visit order is the service's node order (the rank
-                # tensor), not this table's slot order: position -> slot
+                # Visit order is the service's node tree (the step's walk
+                # tensor: each slot's place in the tree's list, big when
+                # dead), not this table's slot order: position -> slot
                 # once a step, then two gathers of the node axis a slot.
-                by_rank = jnp.argsort(rank).astype(jnp.int32)
-                rank_pos = jnp.minimum(rank, N - 1)
+                by_walk = jnp.argsort(ev_k["walk"]).astype(jnp.int32)
+                walk_at = jnp.minimum(ev_k["walk"], N - 1)
                 in_order = jnp.arange(N, dtype=jnp.int32) < n_live
 
         def sample_walk(ok, start, go):
@@ -1412,10 +1419,10 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
             node) walks nowhere: ``ok`` stands and the index stays."""
             if st.sample == 2:
                 vis_o, sam_o, nxt = sample_visited(
-                    ok[by_rank] & in_order, in_order, start, n_live, k_walk
+                    ok[by_walk] & in_order, in_order, start, n_live, k_walk
                 )
-                visited = vis_o[rank_pos] & s["valid"]
-                sample = sam_o[rank_pos] & ok
+                visited = vis_o[walk_at] & s["valid"]
+                sample = sam_o[walk_at] & ok
             else:
                 visited, sample, nxt = sample_visited(
                     ok, s["valid"], start, n_live, k_walk
@@ -1895,6 +1902,9 @@ class StepOutcome:
     sampled: int = 0
     visited: int = 0
     scored: int = 0
+    # Of ``sampled``, the attempts whose walk went by the walk tensor
+    # (``_SegmentStatics.sample`` 2: two gathers of the node axis each).
+    by_rank: int = 0
     # (namespace, name, node_name) in queue (commit) order.
     binds: list[tuple[str, str, str]] = field(default_factory=list)
     # Per-attempt detail (preemption / full-record segments); None means
@@ -1917,6 +1927,9 @@ class SegmentOutcome:
     # The sampling walk's start index as the segment leaves it (None: the
     # segment's program does not sample).
     sample_start: "int | None" = None
+    # The service's node tree as the segment's node events leave it
+    # (None: the service does not sample).
+    node_tree: Any = None
 
 
 def _cleaned_pending(pod: JSON) -> JSON:
@@ -2021,6 +2034,7 @@ class ReplayDriver:
         self.sampled_attempts = 0  # guarded-by: main-thread
         self.nodes_visited = 0  # guarded-by: main-thread
         self.nodes_scored = 0  # guarded-by: main-thread
+        self.sampled_by_rank = 0  # guarded-by: main-thread
         # Whole-node-axis tables the featurizer's encoders built afresh
         # (``Memo.seq_builds``), summed over the lowerings: about one a
         # family in a cold call, none while the node objects stay.
@@ -2166,8 +2180,15 @@ class ReplayDriver:
             "sampled_attempts": self.sampled_attempts,
             "nodes_visited": self.nodes_visited,
             "nodes_scored": self.nodes_scored,
+            # Nodes the walks passed that could not take the pod.
+            "nodes_skipped": self.nodes_visited - self.nodes_scored,
+            # Attempts whose walk went by the walk tensor, two gathers of
+            # the node axis each (0: the slot order was the walk order).
+            "sampled_by_rank": self.sampled_by_rank,
             # The index the walk stands at (either path moves it).
             "sampling_start": self.service._pnts_start.get(self._sched_name or "", 0),
+            # Zones of the service's node tree (0: it does not sample).
+            "sampling_zones": len(self.service._node_tree.zones),
             "ingest_prefetches": self.ingest_prefetches,
             "device_errors": self.device_errors,
             "watchdog_timeouts": self.watchdog_timeouts,
@@ -3185,13 +3206,24 @@ class ReplayDriver:
             feat.pod_rows_built, feat.pod_rows_reused, feat.pod_rows_rebuilt,
             feat.pod_rows_copied,
         )
-        if svc._node_sampling:
-            # The service's featurizer meets the nodes of a pass in name
-            # order; give this table's new slots the same order, so that
-            # a stream without node churn walks for its samples in slot
-            # order (``_SegmentStatics.sample`` 1: no gather in the slot).
-            created_nodes = sorted(created_nodes, key=name_of)
         universe_nodes = list(cur_nodes) + created_nodes
+        walk_tree = None
+        if svc._node_sampling:
+            # A sampling attempt walks the nodes in the order of the
+            # service's node tree (scheduler/nodetree.py: round-robin
+            # across zones).  Give this table's new slots that order, so
+            # that a stream without node churn walks for its samples in
+            # slot order (``_SegmentStatics.sample`` 1: no gather in the
+            # slot); slots the table already holds stay where they are.
+            with TRACE.stage("replay.lower.walk_order"):
+                walk_tree = svc._node_tree.copy()
+                walk_tree.sync(cur_nodes)
+                ahead = walk_tree
+                if created_nodes:
+                    ahead = walk_tree.copy()
+                    ahead.apply((), created_nodes)
+                place = {nm: i for i, nm in enumerate(ahead.list())}
+                universe_nodes.sort(key=lambda n: place[name_of(n)])
         bound_pods = store.pods_with_node()
         from ksim_tpu.state import objcache
 
@@ -3395,8 +3427,9 @@ class ReplayDriver:
         # percentageOfNodesToScore: each step's numFeasibleNodesToFind
         # from its live node count (0: no sampling at that size), and
         # whether the walk may go in slot order — at every step the live
-        # nodes fill slots 0 .. n - 1 in the service's own order (a
-        # stream without node churn) — or has to go by the rank tensor.
+        # nodes fill slots 0 .. n - 1 in the order of the service's node
+        # tree (a stream without node churn) — or has to go by the walk
+        # tensor.
         sampling = svc._node_sampling
         sample_prof = (
             svc._profiles.get(self._sched_name)
@@ -3405,6 +3438,25 @@ class ReplayDriver:
         )
         sample_k = np.zeros(K, np.int32)
         slot_order = True
+        walk_rows = None
+        if sampling:
+            # Each step's walk order: every slot's place in the list of
+            # the node tree as the step's node events leave it.  The tree
+            # follows the node events alone, whatever the passes did.
+            with TRACE.stage("replay.lower.walk_order"):
+                joining = {name_of(n): n for n in created_nodes}
+                walk_rows = np.empty((K, N), np.int32)
+                walk_row = None
+                for k in range(K):
+                    if step_node_event[k]:
+                        walk_tree.apply(
+                            step_node_deletes[k],
+                            [joining[nm] for nm in step_node_creates[k]],
+                        )
+                        walk_row = None
+                    if walk_row is None:
+                        walk_row = walk_tree.positions(slot_of, N, _I32_MAX)
+                    walk_rows[k] = walk_row
         live_row = valid0.copy()
         live_sorted: list[str] = sorted(node_names)
         live_slots = (
@@ -3439,14 +3491,13 @@ class ReplayDriver:
                 sample_k[k] = svc._sampling_k_for(sample_prof, n_live) or 0
                 live_row[[slot_of[nm] for nm in step_node_deletes[k]]] = False
                 live_row[[slot_of[nm] for nm in step_node_creates[k]]] = True
-                # A step that attempts a pod reads a rank row that is
-                # current: its own sync's, or the last one's with no node
-                # event since (``_decode_outputs`` discards the segment
-                # otherwise).  Only those rows have to agree.
+                # Only a step that attempts a pod walks: its own sync's
+                # step, or one with no node event since the last
+                # (``_decode_outputs`` discards the segment otherwise).
                 if pred_featurizes[k] or not any(step_node_event[: k + 1]):
                     slot_order = slot_order and bool(
                         live_row[:n_live].all()
-                        and (rank_row[:n_live] == np.arange(n_live)).all()
+                        and (walk_rows[k, :n_live] == np.arange(n_live)).all()
                     )
             if need_names:
                 want[k] = candidate_count(len(live_sorted))
@@ -3543,6 +3594,8 @@ class ReplayDriver:
         }
         if statics.sample:
             ev["sample_k"] = sample_k
+            if statics.sample == 2:
+                ev["walk"] = walk_rows
         U = len(universe_pods)
         # Nominations that stand (pending pods only, onto a node that is
         # live: the runner clears the others with the node).
@@ -3728,6 +3781,8 @@ class ReplayDriver:
             "sampled_attempts": 0,
             "nodes_visited": 0,
             "nodes_scored": 0,
+            "nodes_skipped": 0,
+            "sampled_by_rank": 0,
             "sampling_start": None,
         }
         self.lower_log.append(log_entry)
@@ -3747,6 +3802,7 @@ class ReplayDriver:
             step_live_slots=step_live_slots,
             step_live_names=step_live_names,
             step_node_event=step_node_event,
+            node_tree=walk_tree,
             lower_epoch=lower_epoch,
             sort_keys=uni_sort,
             clean_pods=uni_clean,
@@ -4059,6 +4115,7 @@ class ReplayDriver:
                     sampled=int(pulled["walks"][k]) if st.sample else 0,
                     visited=int(pulled["nvis"][k]) if st.sample else 0,
                     scored=int(pulled["nsc"][k]) if st.sample else 0,
+                    by_rank=int(pulled["walks"][k]) if st.sample == 2 else 0,
                     binds=binds,
                     attempts=attempts,
                 )
@@ -4124,6 +4181,7 @@ class ReplayDriver:
                 if st.sample
                 else None
             ),
+            node_tree=plan.node_tree,
         )
 
     # -- reconcile -----------------------------------------------------------
@@ -4182,6 +4240,7 @@ class ReplayDriver:
             "sampled_attempts": sum(o.sampled for o in seg.steps),
             "nodes_visited": sum(o.visited for o in seg.steps),
             "nodes_scored": sum(o.scored for o in seg.steps),
+            "sampled_by_rank": sum(o.by_rank for o in seg.steps),
         }
         for key, n in (preempt | sampling).items():
             setattr(self, key, getattr(self, key) + n)
@@ -4191,6 +4250,9 @@ class ReplayDriver:
             plan.log_entry["slots_run"] = slots
             plan.log_entry["writes_shared"], plan.log_entry["writes_copied"] = writes
             plan.log_entry.update(preempt | sampling)
+            plan.log_entry["nodes_skipped"] = (
+                sampling["nodes_visited"] - sampling["nodes_scored"]
+            )
             plan.log_entry["sampling_start"] = seg.sample_start
 
     def verify_segment(self, seg: SegmentOutcome) -> None:
@@ -4246,6 +4308,9 @@ class ReplayDriver:
         if seg.sample_start is not None:
             # A per-pass step after this segment continues the walk.
             svc._pnts_start[self._sched_name] = seg.sample_start
+        if seg.node_tree is not None:
+            # A copy: fleet lanes decode one shared plan.
+            svc._node_tree = seg.node_tree.copy()
         # A committed segment proves the whole device->store pipeline is
         # healthy: reset the reconcile side of the breaker window.
         self._consecutive_reconcile_faults = 0
@@ -4756,7 +4821,7 @@ def _plan_shard_specs(plan: "_SegmentPlan", transient, mesh):
     ev, state0 = transient
     ev_spec = {
         k: sharding.node_axis_sharding(mesh, np.ndim(v), 1)
-        if k in ("rank", "name_rank")
+        if k in ("rank", "name_rank", "walk")
         else repl(v)
         for k, v in ev.items()
     }
@@ -4958,6 +5023,9 @@ class _SegmentPlan:
     step_live_slots: list = field(default_factory=list)
     step_live_names: list = field(default_factory=list)
     step_node_event: list = field(default_factory=list)
+    # The service's node tree after the window's node events (sampling
+    # services only): installed on the service when the segment commits.
+    node_tree: Any = None
     # Lower-cache seed (ReplayDriver._advance_cache filters it to the
     # committed segment's survivors) + the store epoch the lowering read.
     lower_epoch: int = -1

@@ -259,6 +259,10 @@ SPAN_NAMES: tuple[str, ...] = (
     #                                    after the featurizer call: the
     #                                    plugin factory, the host-hook
     #                                    screen, _Program(...)
+    "replay.lower.walk_order",  # stage of replay.lower (sampling
+    #                             services only): the node tree's list
+    #                             as each step's node events leave it,
+    #                             and the new slots laid out in it
     "jobs.submit.read",  # stage of jobs.submit: the body off the socket
     "jobs.submit.parse",  # stage of jobs.submit: json.loads / YAML
     "jobs.submit.build",  # stage of jobs.submit: _parse_job_spec
@@ -1320,6 +1324,11 @@ _runtime_hists = {  # guarded-by: _runtime_lock
 _gc_done: deque = deque()
 _gc_open: "tuple | None" = None  # (t0_ns, plane, jax_ctx, scheduled) of the running full collection
 _gc_scheduled = False  # True while collect_scheduled() is inside gc.collect()
+#: One scheduled collection at a time: ``gc.collect()`` called while another
+#: thread is inside a collection (its callbacks and finalizers let the GIL
+#: go) returns at once, having collected nothing and called no hook — two
+#: jobs that end together would leave one of them without its collection.
+_gc_scheduled_lock = threading.Lock()
 
 
 def _gc_callback(phase: str, info: dict) -> None:
@@ -1368,11 +1377,12 @@ def collect_scheduled() -> int:
     (``gc_gen2``, the ``service.gc`` span) and once more under
     ``gc_scheduled``.  Returns what ``gc.collect`` returns."""
     global _gc_scheduled
-    _gc_scheduled = True
-    try:
-        return gc.collect()
-    finally:
-        _gc_scheduled = False
+    with _gc_scheduled_lock:
+        _gc_scheduled = True
+        try:
+            return gc.collect()
+        finally:
+            _gc_scheduled = False
 
 
 def _fold_gc() -> None:  # ksimlint: lock-held(_runtime_lock)

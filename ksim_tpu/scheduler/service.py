@@ -37,6 +37,7 @@ from ksim_tpu.engine import Engine
 from ksim_tpu.engine.annotations import RenderCtx, apply_results_to_pod, render_pod_results
 from ksim_tpu.engine.core import ScoredPlugin
 from ksim_tpu.faults import FAULTS
+from ksim_tpu.scheduler.nodetree import NodeTree
 from ksim_tpu.scheduler.profile import (
     DEFAULT_SCHEDULER_NAME,
     Builder,
@@ -229,8 +230,14 @@ class SchedulerService:
         # one per profile binary — carried for the life of the service
         # (and in ``checkpoint_carries``); the per-pass path and the
         # device replay (engine/replay.py) both continue it.
+        # The walk goes in the order of the scheduler cache's node tree
+        # (scheduler/nodetree.py: the nodes by zone, dealt round-robin
+        # across the zones), synced to the store's nodes at every pass
+        # and carried like the index; it orders the walk and nothing
+        # else, and a service that does not sample leaves it empty.
         self._node_sampling = bool(node_sampling)
         self._pnts_start: dict[str, int] = {}
+        self._node_tree = NodeTree()
 
     MAX_BACKOFF_PASSES = 16
     # An event-triggered flush caps the remaining wait instead of zeroing
@@ -328,6 +335,7 @@ class SchedulerService:
             "pass_count": self._pass_count,
             "backoff": backoff,
             "pnts_start": dict(self._pnts_start),
+            "node_tree": self._node_tree.to_carry(),
             "slots": {
                 name: f.slot_names() for name, f in self._featurizers.items()
             },
@@ -350,6 +358,7 @@ class SchedulerService:
         self._pnts_start = {
             str(k): int(v) for k, v in (carry.get("pnts_start") or {}).items()
         }
+        self._node_tree = NodeTree.from_carry(carry.get("node_tree"))
         for name, names in (carry.get("slots") or {}).items():
             self._featurizer_for(name).seed_slots([str(n) for n in names])
 
@@ -556,6 +565,8 @@ class SchedulerService:
         self._priority_of = build_priority_resolver(
             self._store.list("priorityclasses", copy_objs=False)
         )
+        if self._node_sampling:
+            self._node_tree.sync(nodes)
         if not nodes:
             return {}
         self._pass_count += 1
@@ -680,6 +691,7 @@ class SchedulerService:
                             plugins,
                             record=self._record,
                             sampling_k=sampling_k,
+                            sampling_order=self._walk_order(feats, sampling_k),
                             metrics=self.metrics,
                         )
                         if self._shard_mesh is not None:
@@ -969,6 +981,22 @@ class SchedulerService:
         k = max(n_nodes * pct // 100, self._MIN_FEASIBLE_NODES_TO_FIND)
         return None if k >= n_nodes else k
 
+    def _walk_order(self, feats, sampling_k: int | None):
+        """Per node slot of ``feats``, the node's place in the node
+        tree's list: the order a sampling attempt visits the nodes in
+        (``Engine(sampling_order=...)``).  None where nothing samples or
+        the slot order is that order already (a cluster of one zone
+        whose nodes never went)."""
+        if sampling_k is None:
+            return None
+        import numpy as np
+
+        n = int(feats.nodes.count)
+        names = feats.nodes.names
+        slot_of = {names[i]: i for i in range(n)}
+        pos = self._node_tree.positions(slot_of, n, n)
+        return None if (pos == np.arange(n)).all() else pos
+
     def add_eviction_listener(self, fn) -> None:
         """Register a (namespace, name) callback fired right after each
         preemption victim's SUCCESSFUL store delete (see __init__ note;
@@ -1111,6 +1139,7 @@ class SchedulerService:
             eng = Engine(
                 feats, plugins, record=self._record, metrics=self.metrics,
                 node_mask=node_mask, sampling_k=sampling_k,
+                sampling_order=self._walk_order(feats, sampling_k),
             )
             res = eng.schedule(
                 pull_state=False,
