@@ -36,6 +36,19 @@ batched EngineResult tensors:
 
 JSON is serialized with sorted keys and compact separators to byte-match
 Go's json.Marshal of map[string]string.
+
+How the text is assembled: the three per-node maps are written as JSON
+text directly, never as nested dicts handed to ``json.dumps``.  Names go
+through ``json.dumps`` once per atom (``RenderCtx``), so escaping stays
+Go-compatible.  The filter map joins one shared row string per node (a
+handful of distinct rows a pod).  The two score maps are one ``"".join``
+each over a flat list of fragments — per feasible node its ``"node":``
+prefix, then per plugin a constant separator and the value — where a
+value's decimal text comes from the pass's integer -> text table
+(``RenderCtx.int_text``), formatted once per distinct integer.  What does
+not depend on the pod (prefilter / prescore statuses, the small
+``{"VolumeBinding":"success"}``-style maps) is text held by the
+``RenderCtx``.
 """
 
 from __future__ import annotations
@@ -112,13 +125,36 @@ def _marshal(obj) -> str:
     return json.dumps(obj, sort_keys=True, separators=(",", ":"))
 
 
+class _IntText(dict):
+    """integer -> its decimal text, exactly ``"%d" % v``, filled on a miss."""
+
+    def __missing__(self, v) -> str:
+        text = self[v] = "%d" % v
+        return text
+
+
 class RenderCtx:
-    """Per-pass shared state for rendering many pods' results: sorted
-    node-name order, pre-JSON'd node/plugin names, the all-pass filter
-    row, and a cross-pod reason-bit decode memo.  Build once per
-    scheduling pass (the maps are assembled as JSON text directly — at
-    10k pods x 5k nodes the per-entry dict building + json.dumps of the
-    nested maps dominated the product path)."""
+    """Per-pass shared state for rendering many pods' results.  Build
+    once per scheduling pass; everything a pod's text is assembled from
+    that does not depend on the pod lives here:
+
+    - the sorted node-name order, the pre-JSON'd node / plugin names and
+      the constant fragments of a score row (``score_row``: the
+      per-plugin separators ``{"p1":"`` / ``","p2":"`` and the closing
+      ``"},``, a hole before each for the node prefix and the values);
+    - ``int_text``, the pass's integer -> decimal text table: a score
+      value is formatted the first time the pass meets it and looked up
+      ever after, whatever its range (``values_formatted`` against
+      ``values_written`` says how often);
+    - the all-pass filter row and the cross-pod memo of failing rows;
+    - the texts of the pod-independent maps (prefilter / prescore
+      statuses, VolumeBinding's reserve / prebind maps) and a memo by
+      content for the small per-pod ones.
+
+    The maps are assembled as JSON text directly — at 10k pods x 5k nodes
+    the per-entry dict building + json.dumps of the nested maps dominated
+    the product path, and per-element number formatting dominated what
+    was left."""
 
     def __init__(self, feats, plugins: Sequence[ScoredPlugin]) -> None:
         """``feats`` is a FeaturizedSnapshot, or a plain sequence of
@@ -146,23 +182,89 @@ class RenderCtx:
         self.passed_row = "{" + ",".join(
             f"{k}:{passed}" for k in sorted(self.fname_json)
         ) + "}"
-        # Inner score rows list plugin names sorted (Go map marshal order).
+        # Inner score rows list plugin names sorted (Go map marshal order);
+        # a column, so that [score_order, feasible] indexes [S, F] at once.
         sorder = sorted(range(len(self.score_plugins)),
                         key=lambda s: self.score_plugins[s].plugin.name)
-        self.score_order = sorder
-        self.sname_json = [json.dumps(self.score_plugins[s].plugin.name) for s in sorder]
-        # Vectorized-assembly pieces: '"node":' prefixes (full and in
-        # key-sorted node order) and the per-plugin score-row separators
-        # ('{"p1":"', '","p2":"', ...).
+        self.score_order = np.asarray(sorder, dtype=np.int64)[:, None]
+        # '"node":' prefixes: an object array in node order (indexed by
+        # the feasible set) and a list in key-sorted order (filter map).
         self.sorted_order_arr = np.asarray(order, dtype=np.int64)
-        self.node_json_prefix_arr = np.asarray([nj + ":" for nj in self.node_json])
+        self.node_json_prefix_arr = np.array(
+            [nj + ":" for nj in self.node_json], dtype=object
+        )
         self.node_json_sorted_prefix = [self.node_json[i] + ":" for i in order]
-        self.score_prefix = [
-            ("{" if s == 0 else '",') + self.sname_json[s] + ':"'
-            for s in range(len(sorder))
-        ]
+        # One score row's fragments: the node prefix, then per plugin its
+        # separator ('{"p1":"', '","p2":"', ...) and the value; the row
+        # closes '"},'.  The holes (None) sit at the even positions and
+        # are filled per pod.
+        self.score_row: list = []
+        for k, s in enumerate(sorder):
+            name = json.dumps(self.score_plugins[s].plugin.name)
+            self.score_row += [None, ("{" if k == 0 else '",') + name + ':"']
+        self.score_row += [None, '"},']
+        self.int_text = _IntText()
+        #: Score values written by this ctx (both maps, every pod).
+        self.values_written = 0
         # (fi, bits) -> rendered filter row JSON, shared across pods.
         self.fail_row_memo: dict[tuple[int, int], str] = {}
+        self.prefilter_status_json = _marshal({
+            sp.plugin.name: SUCCESS_MESSAGE
+            for sp in self.filter_plugins
+            if sp.plugin.name in UPSTREAM_PRE_FILTER
+        })
+        self.prescore_json = _marshal({
+            sp.plugin.name: SUCCESS_MESSAGE
+            for sp in self.score_plugins
+            if sp.plugin.name in UPSTREAM_PRE_SCORE
+        })
+        # VolumeBinding is the default profile's only Reserve/PreBind
+        # plugin; on a successful cycle upstream's wrappers record
+        # "success" for it (wrappedplugin.go:616-645 Reserve, :670-697
+        # PreBind).  Profiles can disable it at a single point
+        # (ScoredPlugin.reserve/prebind_enabled).
+        def volume_binding(flag: str) -> dict:
+            return {
+                sp.plugin.name: SUCCESS_MESSAGE
+                for sp in plugins
+                if sp.plugin.name == "VolumeBinding" and getattr(sp, flag, True)
+            }
+
+        self.reserve_map = volume_binding("reserve_enabled")
+        self.prebind_map = volume_binding("prebind_enabled")
+        self._map_text_memo: dict[tuple, str] = {}
+
+    @property
+    def values_formatted(self) -> int:
+        """Integers this ctx formatted afresh: the size of its table."""
+        return len(self.int_text)
+
+    def map_text(self, obj: dict) -> str:
+        """``_marshal`` of a flat string map, memoised by content: a pass
+        writes the same two or three small maps for every pod."""
+        if not obj:
+            return "{}"
+        key = tuple(obj.items())
+        text = self._map_text_memo.get(key)
+        if text is None:
+            text = self._map_text_memo[key] = _marshal(obj)
+        return text
+
+    def score_map(self, node_prefixes: list, vals) -> str:
+        """One score map as text: ``vals`` is [S, F] integers, plugin
+        rows in ``score_order``, columns matching ``node_prefixes``.
+        The values become text through ``int_text``; the map is one
+        join over the flat list of every row's fragments."""
+        S, F = vals.shape
+        texts = list(map(self.int_text.__getitem__, vals.ravel().tolist()))
+        width = len(self.score_row)
+        flat = self.score_row * F
+        flat[0::width] = node_prefixes
+        for s in range(S):
+            flat[2 * s + 2 :: width] = texts[s * F : (s + 1) * F]
+        flat[-1] = '"}}'
+        self.values_written += S * F
+        return "{" + "".join(flat)
 
     def fail_row(self, fi: int, bits: int) -> str:
         """Row for a node whose first filter failure is plugin ``fi``
@@ -221,8 +323,6 @@ def render_pod_results(
     if ctx is None:
         ctx = RenderCtx(feats, plugins)
     node_names = ctx.node_names
-    filter_plugins = ctx.filter_plugins
-    score_plugins = ctx.score_plugins
     N = len(node_names)
 
     bits_pi = np.asarray(res.reason_bits[pi])[:, :N]  # [F, N]
@@ -258,13 +358,12 @@ def render_pod_results(
             row_strs.append(ctx.fail_row(int(code >> 32), int(code & 0xFFFFFFFF)))
     prefixes = ctx.node_json_sorted_prefix
     if vis is None:
-        parts = [prefixes[k] + row_strs[i] for k, i in enumerate(inv)]
+        parts = [p + row_strs[i] for p, i in zip(prefixes, inv.tolist())]
     else:
-        vis_s = vis[so]
         parts = [
-            prefixes[k] + row_strs[i]
-            for k, i in enumerate(inv)
-            if vis_s[k]
+            p + row_strs[i]
+            for p, i, v in zip(prefixes, inv.tolist(), vis[so].tolist())
+            if v
         ]
     filter_json = "{" + ",".join(parts) + "}"
 
@@ -276,59 +375,19 @@ def render_pod_results(
     ran_scoring = len(feasible_nodes) > 1
     score_json = "{}"
     final_json = "{}"
-    if res.scores is not None and score_plugins and ran_scoring:
-        # Feasible nodes in key-sorted order; values stringified in bulk.
+    if res.scores is not None and ctx.score_plugins and ran_scoring:
+        # Feasible nodes in key-sorted order, shared by both maps.
         feas = feasible_nodes[np.argsort(ctx.rank[feasible_nodes], kind="stable")]
-        raw = np.char.mod("%d", np.asarray(res.scores[pi])[:, feas][ctx.score_order])
-        fin = np.char.mod("%d", np.asarray(res.final_scores[pi])[:, feas][ctx.score_order])
-
-        def rows_json(vals: np.ndarray) -> np.ndarray:
-            # '"p1":"V1","p2":"V2",...' assembled as S vectorized string
-            # concatenations over the feasible axis (python-level per-cell
-            # loops dominated the product path at 10k x 5k).
-            row = np.char.add(ctx.score_prefix[0], vals[0])
-            for s in range(1, vals.shape[0]):
-                row = np.char.add(row, ctx.score_prefix[s])
-                row = np.char.add(row, vals[s])
-            return np.char.add(row, '"}')
-
-        node_pre = ctx.node_json_prefix_arr[feas]
-        score_json = "{" + ",".join(np.char.add(node_pre, rows_json(raw)).tolist()) + "}"
-        final_json = "{" + ",".join(np.char.add(node_pre, rows_json(fin)).tolist()) + "}"
-
-    prefilter_status = {
-        sp.plugin.name: SUCCESS_MESSAGE
-        for sp in filter_plugins
-        if sp.plugin.name in UPSTREAM_PRE_FILTER
-    }
-    prescore = (
-        {
-            sp.plugin.name: SUCCESS_MESSAGE
-            for sp in score_plugins
-            if sp.plugin.name in UPSTREAM_PRE_SCORE
-        }
-        if ran_scoring
-        else {}
-    )
+        at = (ctx.score_order, feas)
+        node_pre = ctx.node_json_prefix_arr[feas].tolist()
+        score_json = ctx.score_map(node_pre, np.asarray(res.scores[pi])[at])
+        final_json = ctx.score_map(node_pre, np.asarray(res.final_scores[pi])[at])
 
     selected = int(res.selected[pi])
-    # VolumeBinding is the default profile's only Reserve/PreBind plugin;
-    # on a successful cycle upstream's wrappers record "success" for it
-    # (wrappedplugin.go:616-645 Reserve, :670-697 PreBind).  Profiles can
-    # disable it at a single point (ScoredPlugin.reserve/prebind_enabled).
-    def _point_map(flag: str, ran: bool = True) -> dict:
-        if selected < 0 or not ran:
-            return {}
-        return {
-            sp.plugin.name: SUCCESS_MESSAGE
-            for sp in plugins
-            if sp.plugin.name == "VolumeBinding" and getattr(sp, flag, True)
-        }
-
-    reserve_map = _point_map("reserve_enabled")
+    reserve_map = ctx.reserve_map if selected >= 0 else {}
     if reserve_extra and selected >= 0:
         reserve_map = {**reserve_map, **reserve_extra}
-    prebind_map = _point_map("prebind_enabled", ran=bound)
+    prebind_map = ctx.prebind_map if selected >= 0 and bound else {}
     if prebind_extra and selected >= 0:
         prebind_map = {**prebind_map, **prebind_extra}
     if bind_map is None:
@@ -336,18 +395,18 @@ def render_pod_results(
     elif selected < 0:
         bind_map = {}
     out = {
-        PRE_FILTER_RESULT_KEY: _marshal({}),
-        PRE_FILTER_STATUS_KEY: _marshal(prefilter_status),
+        PRE_FILTER_RESULT_KEY: "{}",
+        PRE_FILTER_STATUS_KEY: ctx.prefilter_status_json,
         FILTER_RESULT_KEY: filter_json,
-        POST_FILTER_RESULT_KEY: _marshal(postfilter or {}),
-        PRE_SCORE_RESULT_KEY: _marshal(prescore),
+        POST_FILTER_RESULT_KEY: _marshal(postfilter) if postfilter else "{}",
+        PRE_SCORE_RESULT_KEY: ctx.prescore_json if ran_scoring else "{}",
         SCORE_RESULT_KEY: score_json,
         FINAL_SCORE_RESULT_KEY: final_json,
-        RESERVE_RESULT_KEY: _marshal(reserve_map),
-        PERMIT_RESULT_KEY: _marshal(permit[0] if permit else {}),
-        PERMIT_TIMEOUT_RESULT_KEY: _marshal(permit[1] if permit else {}),
-        PRE_BIND_RESULT_KEY: _marshal(prebind_map),
-        BIND_RESULT_KEY: _marshal(bind_map),
+        RESERVE_RESULT_KEY: ctx.map_text(reserve_map),
+        PERMIT_RESULT_KEY: ctx.map_text(permit[0]) if permit else "{}",
+        PERMIT_TIMEOUT_RESULT_KEY: ctx.map_text(permit[1]) if permit else "{}",
+        PRE_BIND_RESULT_KEY: ctx.map_text(prebind_map),
+        BIND_RESULT_KEY: ctx.map_text(bind_map),
     }
     if selected >= 0:
         out[SELECTED_NODE_KEY] = node_names[selected]

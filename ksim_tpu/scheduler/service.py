@@ -710,7 +710,7 @@ class SchedulerService:
                             namespaces, volume_kw, prof, sched_name,
                         )
                 with TRACE.phase("service.bind", self.metrics, "bind") as ph:
-                    render_s, store_s, done = self._bind_results(
+                    render_s, store_s, done, written, formatted = self._bind_results(
                         run, feats, plugins, res, placements, prof=prof,
                         nominees=nominees,
                     )
@@ -719,6 +719,8 @@ class SchedulerService:
                 # recorded once per pass: never a span per pod.
                 self.metrics.observe("render", render_s)
                 self.metrics.observe("bind_store", store_s)
+                self.metrics.inc("render_values", written)
+                self.metrics.inc("render_values_formatted", formatted)
                 remaining = remaining[done:]
         # Bound _own_rvs growth for library use (schedule_pending without
         # the watch loop draining events).  The limit scales with the pass
@@ -1195,14 +1197,16 @@ class SchedulerService:
 
     def _bind_results(
         self, queue, feats, plugins, res, placements, prof=None, nominees=None
-    ) -> tuple[float, float, int]:
+    ) -> tuple[float, float, int, int, int]:
         """Decode, render and write back the pods of one engine run, up
         to and including the first whose preemption found victims (their
         going and its nomination change what every later pod sees: the
         pass evaluates the rest anew); returns the seconds summed over
         the pods inside ``render_pod_results`` and inside
         ``store.rewrap`` (the rest of the ``bind`` timer is the host hook
-        chains and the loop itself) and how many pods were written.
+        chains and the loop itself), how many pods were written, and from
+        the run's ``RenderCtx`` the score values written and the integers
+        formatted afresh for them.
         ``nominees``: the nominations that stand as the run begins
         (``_live_nominees``; none changes before the run ends), for the
         dry runs of its preemptors."""
@@ -1352,7 +1356,12 @@ class SchedulerService:
             if nominated and node_name is None:
                 self._clear_lower_nominations(nominated, pod)
                 break
-        return render_s, store_s, done
+        if render_ctx is None:
+            return render_s, store_s, done, 0, 0
+        return (
+            render_s, store_s, done,
+            render_ctx.values_written, render_ctx.values_formatted,
+        )
 
     # -- host extension points (PreEnqueue/PostFilter/PreBind/Bind/PostBind) -
 
