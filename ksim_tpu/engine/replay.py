@@ -2035,6 +2035,12 @@ class ReplayDriver:
         self.nodes_visited = 0  # guarded-by: main-thread
         self.nodes_scored = 0  # guarded-by: main-thread
         self.sampled_by_rank = 0  # guarded-by: main-thread
+        # What the walk tensor took: steps of the committed segments that
+        # sorted it and walked by it (the device's ``outs["walks"]``), and
+        # walk rows the lowerings computed (``NodeTree.positions`` calls:
+        # one a window and one more a step with a node event).
+        self.sampled_by_rank_steps = 0  # guarded-by: main-thread
+        self.walk_rows_built = 0  # guarded-by: main-thread
         # Whole-node-axis tables the featurizer's encoders built afresh
         # (``Memo.seq_builds``), summed over the lowerings: about one a
         # family in a cold call, none while the node objects stay.
@@ -2185,6 +2191,10 @@ class ReplayDriver:
             # Attempts whose walk went by the walk tensor, two gathers of
             # the node axis each (0: the slot order was the walk order).
             "sampled_by_rank": self.sampled_by_rank,
+            # Steps that sorted the walk tensor (one ``argsort`` of the
+            # node axis each) and the walk rows the lowerings computed.
+            "sampled_by_rank_steps": self.sampled_by_rank_steps,
+            "walk_rows_built": self.walk_rows_built,
             # The index the walk stands at (either path moves it).
             "sampling_start": self.service._pnts_start.get(self._sched_name or "", 0),
             # Zones of the service's node tree (0: it does not sample).
@@ -3439,6 +3449,7 @@ class ReplayDriver:
         sample_k = np.zeros(K, np.int32)
         slot_order = True
         walk_rows = None
+        walk_rows_built = 0
         if sampling:
             # Each step's walk order: every slot's place in the list of
             # the node tree as the step's node events leave it.  The tree
@@ -3456,7 +3467,9 @@ class ReplayDriver:
                         walk_row = None
                     if walk_row is None:
                         walk_row = walk_tree.positions(slot_of, N, _I32_MAX)
+                        walk_rows_built += 1
                     walk_rows[k] = walk_row
+                self.walk_rows_built += walk_rows_built
         live_row = valid0.copy()
         live_sorted: list[str] = sorted(node_names)
         live_slots = (
@@ -3783,6 +3796,8 @@ class ReplayDriver:
             "nodes_scored": 0,
             "nodes_skipped": 0,
             "sampled_by_rank": 0,
+            "sampled_by_rank_steps": 0,
+            "walk_rows_built": walk_rows_built,
             "sampling_start": None,
         }
         self.lower_log.append(log_entry)
@@ -4241,6 +4256,8 @@ class ReplayDriver:
             "nodes_visited": sum(o.visited for o in seg.steps),
             "nodes_scored": sum(o.scored for o in seg.steps),
             "sampled_by_rank": sum(o.by_rank for o in seg.steps),
+            # Steps that sorted their walk tensor and walked by it.
+            "sampled_by_rank_steps": sum(o.by_rank > 0 for o in seg.steps),
         }
         for key, n in (preempt | sampling).items():
             setattr(self, key, getattr(self, key) + n)
