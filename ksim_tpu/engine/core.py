@@ -500,6 +500,37 @@ def sample_visited(feasible, real, start, n_real, k):
     return visited, feasible & visited, (start + n_visited) % nr
 
 
+@device_kernel()
+def sample_visited_at(feasible, real, pos, start, n_real, k):
+    """``sample_visited`` with the masks IN SLOT ORDER and the visit
+    order as an operand: ``pos`` is each slot's place in the visit
+    order, i32 over the node axis, the real slots a permutation of
+    0 .. n_real - 1 (what it holds elsewhere is not read).  Same
+    contract, same triple; ``feasible`` need not be confined to
+    ``real``.
+
+    In visit order the visited set is one cyclic interval from
+    ``start``, so no mask is carried into visit order and back: a
+    slot's distance from the start is ``(pos - start) mod n_real``, the
+    walk stops behind the ``k``-th smallest distance among the feasible
+    slots (after all ``n_real`` where fewer than ``k`` are feasible),
+    and a slot is visited exactly when its distance is below that
+    count.  The one thing the node axis is sorted for is that scalar: a
+    single-operand sort read at ``k - 1``."""
+    n = feasible.shape[0]
+    nr = jnp.maximum(n_real, 1).astype(jnp.int32)
+    start = (start % nr).astype(jnp.int32)
+    away = (pos.astype(jnp.int32) - start) % nr
+    none = jnp.iinfo(jnp.int32).max  # what an infeasible slot sorts as
+    found = jnp.sort(jnp.where(feasible & real, away, none), stable=False)
+    kth = found[jnp.clip(k - 1, 0, n - 1)]  # ``none``: fewer than k feasible
+    n_visited = jnp.where(
+        k <= 0, 0, jnp.where(kth == none, n_real, kth + 1)
+    ).astype(jnp.int32)
+    visited = real & (away < n_visited)
+    return visited, feasible & visited, (start + n_visited) % nr
+
+
 class _Program:
     """The static half of an Engine: plugin set + record mode, hashable by
     signature.  jax.jit keys its cache on this object (static argnum 0),
@@ -727,11 +758,9 @@ class _Program:
 
         ``order`` (``self.sampled`` 2; else None: slot order) is each
         slot's place in the visit order, i32 over the node axis, padding
-        slots last: the walk then goes through two gathers of the node
-        axis a pod."""
-        ordered = self.sampled == 2
-        if ordered:
-            by_order = jnp.argsort(order).astype(jnp.int32)
+        slots last: the walk then stays in slot order and finds where it
+        stops by one sort of the node axis a pod
+        (``sample_visited_at``)."""
 
         def body(carry, pb: PodBatch):
             node_state, plugin_carries, start = carry
@@ -744,17 +773,14 @@ class _Program:
             )
             ok, bits = self._eval_filters(node_state, pod, aux, plugin_carries)
             in_real = jnp.arange(ok.shape[0], dtype=jnp.int32) < n_real
-            if not ordered:
+            if self.sampled == 2:
+                visited, sample, new_start = sample_visited_at(
+                    ok, in_real, order, start, n_real, k
+                )
+            else:
                 visited, sample, new_start = sample_visited(
                     ok & in_real, in_real, start, n_real, k
                 )
-            else:
-                ok = ok & in_real
-                vis_o, _, new_start = sample_visited(
-                    ok[by_order] & in_real, in_real, start, n_real, k
-                )
-                visited = vis_o[order] & in_real
-                sample = ok & visited
             # Padding pods never ran a cycle upstream: no rotation.
             new_start = jnp.where(pb.valid, new_start, start)
             raw, final, total = self._eval_scores(

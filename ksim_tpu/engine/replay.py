@@ -94,7 +94,9 @@ goes in the order of the service's node tree (scheduler/nodetree.py:
 upstream's zone-interleaved node list): this table's slot order where
 the lowering saw them agree at every step — it lays a window's new
 slots out in the tree's order, so a stream without node churn does —
-else by the step's walk tensor (``_SegmentStatics.sample``).  The tree
+else by the step's walk tensor (``_SegmentStatics.sample`` 2;
+``sample_visited_at``: the masks stay in slot order and one sort of
+the node axis a slot finds where the walk stops).  The tree
 orders the walk and nothing else: equal totals still go by the rank
 tensor, the per-pass featurizer's slot order.  A universe that does
 not sample lowers the program it always lowered.
@@ -502,9 +504,9 @@ class _SegmentStatics:
     # the node axis from the carried start index, in SLOT order, which the
     # lowering saw to be the order of the service's node tree at every
     # step (it lays new slots out in that order); 2 = the walk goes by the
-    # step's walk tensor, each slot's place in the tree's list (two
-    # gathers of the node axis a slot: node churn has moved the two
-    # orders apart).
+    # step's walk tensor, each slot's place in the tree's list (one sort
+    # of the node axis a slot, ``sample_visited_at``: node churn has
+    # moved the two orders apart).
     sample: int = 0
     tp: int = 1  # node-axis mesh width (round 17 sharded replay)
     # Round 19: the vmap axis name the fleet program maps lanes over, or
@@ -581,7 +583,7 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
     import jax.numpy as jnp
 
     from ksim_tpu.plugins.base import NodeStateView, PodBatch
-    from ksim_tpu.engine.core import SCAN_UNROLL, sample_visited
+    from ksim_tpu.engine.core import SCAN_UNROLL, sample_visited, sample_visited_at
 
     # Slots per trip of the pod loops (``run_slots`` in ``_run_step``):
     # the widest block <= SCAN_UNROLL that divides the queue, so a block
@@ -1397,20 +1399,13 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
         if st.sample:
             # percentageOfNodesToScore: the step's numFeasibleNodesToFind
             # (0: this many nodes are not sampled) and the visit order.
-            # The walk never sorts: ``sample_visited`` counts feasible
-            # nodes by prefix sum, with k and the start index as operands.
+            # In slot order the walk never sorts: ``sample_visited``
+            # counts feasible nodes by prefix sum, with k and the start
+            # index as operands.
             n_live = jnp.sum(s["valid"], dtype=jnp.int32)
             k_step = ev_k["sample_k"]
             sampling = k_step > 0
             k_walk = jnp.where(sampling, k_step, _I32_MAX)
-            if st.sample == 2:
-                # Visit order is the service's node tree (the step's walk
-                # tensor: each slot's place in the tree's list, big when
-                # dead), not this table's slot order: position -> slot
-                # once a step, then two gathers of the node axis a slot.
-                by_walk = jnp.argsort(ev_k["walk"]).astype(jnp.int32)
-                walk_at = jnp.minimum(ev_k["walk"], N - 1)
-                in_order = jnp.arange(N, dtype=jnp.int32) < n_live
 
         def sample_walk(ok, start, go):
             """One attempt's walk for a sample (``st.sample``): what is
@@ -1418,11 +1413,12 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
             ``go`` False (a padding slot, a pod that takes its nominated
             node) walks nowhere: ``ok`` stands and the index stays."""
             if st.sample == 2:
-                vis_o, sam_o, nxt = sample_visited(
-                    ok[by_walk] & in_order, in_order, start, n_live, k_walk
+                # Visit order is the service's node tree (the step's walk
+                # tensor: each slot's place in the tree's list, big when
+                # dead), not this table's slot order.
+                visited, sample, nxt = sample_visited_at(
+                    ok, s["valid"], ev_k["walk"], start, n_live, k_walk
                 )
-                visited = vis_o[walk_at] & s["valid"]
-                sample = sam_o[walk_at] & ok
             else:
                 visited, sample, nxt = sample_visited(
                     ok, s["valid"], start, n_live, k_walk
@@ -1903,7 +1899,7 @@ class StepOutcome:
     visited: int = 0
     scored: int = 0
     # Of ``sampled``, the attempts whose walk went by the walk tensor
-    # (``_SegmentStatics.sample`` 2: two gathers of the node axis each).
+    # (``_SegmentStatics.sample`` 2: one sort of the node axis each).
     by_rank: int = 0
     # (namespace, name, node_name) in queue (commit) order.
     binds: list[tuple[str, str, str]] = field(default_factory=list)
@@ -2035,8 +2031,8 @@ class ReplayDriver:
         self.nodes_visited = 0  # guarded-by: main-thread
         self.nodes_scored = 0  # guarded-by: main-thread
         self.sampled_by_rank = 0  # guarded-by: main-thread
-        # What the walk tensor took: steps of the committed segments that
-        # sorted it and walked by it (the device's ``outs["walks"]``), and
+        # What the walk tensor took: steps of the committed segments
+        # whose walks went by it (the device's ``outs["walks"]``), and
         # walk rows the lowerings computed (``NodeTree.positions`` calls:
         # one a window and one more a step with a node event).
         self.sampled_by_rank_steps = 0  # guarded-by: main-thread
@@ -2188,11 +2184,11 @@ class ReplayDriver:
             "nodes_scored": self.nodes_scored,
             # Nodes the walks passed that could not take the pod.
             "nodes_skipped": self.nodes_visited - self.nodes_scored,
-            # Attempts whose walk went by the walk tensor, two gathers of
+            # Attempts whose walk went by the walk tensor, one sort of
             # the node axis each (0: the slot order was the walk order).
             "sampled_by_rank": self.sampled_by_rank,
-            # Steps that sorted the walk tensor (one ``argsort`` of the
-            # node axis each) and the walk rows the lowerings computed.
+            # Steps with such an attempt (nothing is sorted a step) and
+            # the walk rows the lowerings computed.
             "sampled_by_rank_steps": self.sampled_by_rank_steps,
             "walk_rows_built": self.walk_rows_built,
             # The index the walk stands at (either path moves it).
@@ -4256,7 +4252,7 @@ class ReplayDriver:
             "nodes_visited": sum(o.visited for o in seg.steps),
             "nodes_scored": sum(o.scored for o in seg.steps),
             "sampled_by_rank": sum(o.by_rank for o in seg.steps),
-            # Steps that sorted their walk tensor and walked by it.
+            # Steps whose walks went by their walk tensor.
             "sampled_by_rank_steps": sum(o.by_rank > 0 for o in seg.steps),
         }
         for key, n in (preempt | sampling).items():

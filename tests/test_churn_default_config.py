@@ -117,8 +117,8 @@ def test_the_segment_path_equals_the_plain_reference_all_by_the_walk_tensor(
         assert stats[key] == want[key], key
     # Node churn in every step: no attempt went in slot order.
     assert stats["sampled_by_rank"] == stats["sampled_attempts"]
-    # What the stream implies: a step that attempted a pod sorted its walk
-    # tensor once; the lowering computed one walk row for every step with a
+    # What the stream implies: a step that attempted a pod walked by its
+    # walk tensor; the lowering computed one walk row for every step with a
     # node event (here: all of them; a window whose first step had none
     # would add one).
     attempted = [k_ for k_, (placed, failed) in enumerate(want["steps"]) if placed + failed]
