@@ -51,8 +51,9 @@ Two former fallback classes are now lowered instead (round 7):
   every pod of a pass is evaluated against the state as its
   predecessors left it — their binds, their victims gone, their
   nominations — with the nominated pods of its priority or above
-  counted into a second filter run, and its own nominated node tried
-  first; a pod that fits nowhere searches for victims on the spot.  The
+  counted in (upstream's second filter run; ONE run decides both in a
+  node-local window, ``_preempt_search``), and its own nominated node
+  tried first; a pod that fits nowhere searches for victims on the spot.  The
   search evaluates the preemptor's candidates TOGETHER where every
   filter verdict of the window is node-local (no DoNotSchedule spread
   constraint and no required pod (anti-)affinity in the universe: the
@@ -62,7 +63,7 @@ Two former fallback classes are now lowered instead (round 7):
   chain runs over the whole node axis for "everything lower gone" and
   once per reprieve rank, and pickOneNode is one lexicographic argmin
   over the first ``candidate_count(live nodes)`` candidates in name
-  order.  The table is built once a PASS AND PRIORITY LEVEL, by the
+  order (kept by one sort of the node axis in slot space).  The table is built once a PASS AND PRIORITY LEVEL, by the
   first search of the level, and carried through the pass's pod loop:
   the universe axis is in queue order (priority descending), so a pod
   that binds in the pass is never of a lower priority than a
@@ -912,17 +913,20 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
             ),
         )
 
-    def _victims_over_nodes(nstate, pcarries, live, pod, cnt, vreq, vact, examine, over, has_nom):
+    def _victims_over_nodes(nstate, pcarries, live, pod, cnt, vreq, vact, examine, over):
         """selectVictimsOnNode for EVERY node at once, for a window whose
         filter verdicts are node-local (st.local): a node's verdict reads
         that node's own load, so one evaluation of the chain over the
         node axis, on a state where every node's lower-priority pods are
         gone, answers each node's own question; then the pods come back
         rank by rank (each node's k-th most important at step k) and stay
-        where the chain still passes.  Both filter runs where nominated
-        pods count.  ``vreq``: the victims' requests, one [V, N] array a
-        resource; ``vact`` [V, N].  Returns (fits with all lower gone
-        [N], victim mask [V, N])."""
+        where the chain still passes.  Each hypothetical state goes
+        through the chain ONCE, with the nominated pods of ``over``
+        counted in: in a node-local window that run decides both of
+        upstream's (``_preempt_search`` has the argument).  ``vreq``: the
+        victims' requests, one [V, N] array a resource; ``vact`` [V, N].
+        Returns (fits with all lower gone [N], victim mask [V, N], whole
+        chain evaluations run)."""
         req_dt, cnt_dt = nstate.requested.dtype, nstate.pod_count.dtype
         base_req = nstate.requested - jnp.stack(
             [jnp.sum(f, axis=0) for f in vreq], axis=1
@@ -931,13 +935,7 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
 
         def fit(req, pc):
             view = nstate._replace(requested=req, pod_count=pc)
-            ok_a = prog._eval_filters(view, pod, aux, pcarries)[0]
-            ok_b = jax.lax.cond(
-                has_nom,
-                lambda: _filters_with(view, pcarries, live, pod, over),
-                lambda: jnp.ones(N, bool),
-            )
-            return ok_a & ok_b
+            return _filters_with(view, pcarries, live, pod, over)
 
         fit0 = fit(base_req, base_cnt) & examine
 
@@ -968,7 +966,7 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
             reprieve,
             (base_req, base_cnt, jnp.zeros((v_eff, N), bool)),
         )
-        return fit0, vic & fit0[None, :]
+        return fit0, vic & fit0[None, :], 1 + ranks
 
     def _victims_by_walk(nstate, pcarries, live, pod, cnt, vrow, vact, examine, over, has_nom, rank_names, want_k):
         """selectVictimsOnNode node by node, for a window that holds a
@@ -977,8 +975,10 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
         live name order, one exact hypothetical state a check — that
         node's lower-priority pods gone, the spread and inter-pod carries
         re-derived from the modified locals — through the whole compiled
-        chain, both filter runs, until ``want_k`` candidates are found.
-        Returns (candidate [N], victim mask [V, N])."""
+        chain, both filter runs (a nominee can satisfy an affinity term
+        here, so neither run decides the other), until ``want_k``
+        candidates are found.  Returns (candidate [N], victim mask
+        [V, N], whole chain evaluations run)."""
         order = jnp.argsort(jnp.where(examine, rank_names, _I32_MAX)).astype(jnp.int32)
         n_exam = jnp.sum(examine.astype(jnp.int32)).astype(jnp.int32)
 
@@ -1015,8 +1015,11 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
         def wanted(i, found):
             return (i < n_exam) & (found < want_k)
 
+        # What one ``eval_fit`` runs of the chain.
+        per_fit = 1 + has_nom.astype(jnp.int32)
+
         def walk(c):
-            i, found, is_c, vic = c
+            i, found, is_c, vic, runs = c
             # In the fleet program a lane that is done idles here while
             # another still walks (its trips write nothing).
             more = wanted(i, found)
@@ -1031,13 +1034,9 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
                 removed = jnp.where(act[v] & ok_v, test, removed)
                 return removed, vc.at[v].set(act[v] & ~ok_v)
 
+            trips = jnp.where(fit0, jnp.minimum(cnt[n_i], v_eff), 0).astype(jnp.int32)
             _removed, vc = jax.lax.fori_loop(
-                0,
-                _lane_max(
-                    jnp.where(fit0, jnp.minimum(cnt[n_i], v_eff), 0).astype(jnp.int32)
-                ),
-                reprieve,
-                (act, jnp.zeros(v_eff, bool)),
+                0, _lane_max(trips), reprieve, (act, jnp.zeros(v_eff, bool))
             )
             cand = more & fit0 & jnp.any(vc)
             at = jnp.where(more, n_i, N)
@@ -1046,6 +1045,7 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
                 found + cand.astype(jnp.int32),
                 is_c.at[at].set(cand, mode="drop"),
                 vic.at[:, at].set(vc & cand, mode="drop"),
+                runs + jnp.where(more, (1 + trips) * per_fit, 0),
             )
 
         def any_lane_wants(c):
@@ -1054,7 +1054,7 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
                 return go
             return jax.lax.psum(go.astype(jnp.int32), st.lane_axis) > 0
 
-        _i, _found, is_c, vic = jax.lax.while_loop(
+        _i, _found, is_c, vic, runs = jax.lax.while_loop(
             any_lane_wants,
             walk,
             (
@@ -1062,9 +1062,10 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
                 jnp.int32(0),
                 jnp.zeros(N, bool),
                 jnp.zeros((v_eff, N), bool),
+                jnp.int32(0),
             ),
         )
-        return is_c, vic
+        return is_c, vic, runs
 
     def _preempt_search(s, nstate, pcarries, live, pod, lvl, bits_mat, ev_k, table):
         """DefaultPreemption's victim search for one unschedulable pod,
@@ -1085,14 +1086,41 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
           gates on the profile's filter set matching the oracle fit
           chain (preemption.py ORACLE_FIT_FILTER_NAMES);
         - the first ``want`` candidates in live name order are kept
-          (upstream's candidate_count; it stops looking there);
+          (upstream's candidate_count; it stops looking there): those
+          whose name rank is at most the ``want``-th smallest among the
+          candidates', found by ONE sort of the node axis in slot space
+          (``name_rank`` is a permutation of the live nodes);
         - pickOneNodeForPreemption is the lexicographic min over (max
           victim prio, sum of prio + 2**31 as two 16-bit limbs, count,
           -latest earliest-top-start, name order) — the host's
           narrowing cascade;
         Returns the verdict — the nominated slot (-1: none), the victim
-        rows in reprieve order, the overflow flag, the candidates kept —
-        and changes nothing (``_apply_preemption`` does)."""
+        rows in reprieve order, the overflow flag, the candidates kept,
+        the whole-chain filter evaluations it ran — and changes nothing
+        (``_apply_preemption`` does).
+
+        ONE CHAIN EVALUATION A HYPOTHETICAL STATE IN A NODE-LOCAL WINDOW
+        (``st.local``; ``_victims_over_nodes``, and the attempt itself in
+        ``pod_body_preempt``).  Upstream runs the chain twice
+        (RunFilterPluginsWithNominatedPods: with the nominees counted
+        in, then as the node stands) and a node has to pass both.  Here
+        the first run alone decides: (1) ``st.local`` says no pod of the
+        window carries a DoNotSchedule spread constraint or a required
+        pod (anti-)affinity, so ``_filters_with`` adds the nominees'
+        ``nm_req`` / ``nm_cnt`` to ``requested`` / ``pod_count`` and
+        touches nothing else; (2) of the chain the lowering admits —
+        ``ORACLE_FIT_FILTER_NAMES`` with or without the volume filters,
+        anything else raises ``preemption_filter_set`` and the window
+        goes to the host — only NodeResourcesFit reads those two, and it
+        is monotone in both: more load never makes a node pass; (3)
+        ``nm_req`` / ``nm_cnt`` are sums of requests and head counts,
+        never negative.  So a node that passes with the nominees counted
+        in passes as it stands (``ok_with & ok_plain == ok_with``), and
+        where nobody is nominated the addend is zero and the two runs
+        are one.  The gate fails closed: a filter outside that set never
+        reaches this program, and a window that is not node-local takes
+        ``_victims_by_walk``, where a nominee can SATISFY an affinity
+        term and both runs stay."""
         valid_now = s["valid"]
         if st.record == "full":
             fail = bits_mat != 0  # [F, N]
@@ -1110,22 +1138,28 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
         examine = (cnt > 0) & valid_now & resolvable
         over_v = jnp.any(examine & (cnt > v_eff))
         over = _nom_at(live, lvl)
-        has_nom = jnp.any(over["nm_cnt"] > 0)
         if st.local:
-            is_c, vic = _victims_over_nodes(
-                nstate, pcarries, live, pod, cnt, vreq, vact, examine, over, has_nom,
+            # One run of the chain a state: a filter outside
+            # ORACLE_FIT_FILTER_NAMES and the volume filters has sent the
+            # window to the host already (the docstring has the argument).
+            is_c, vic, runs = _victims_over_nodes(
+                nstate, pcarries, live, pod, cnt, vreq, vact, examine, over
             )
             is_c = is_c & jnp.any(vic, axis=0)
         else:
-            is_c, vic = _victims_by_walk(
+            has_nom = jnp.any(over["nm_cnt"] > 0)
+            is_c, vic, runs = _victims_by_walk(
                 nstate, pcarries, live, pod, cnt, vrow, vact, examine, over,
                 has_nom, rank_names, want_k,
             )
         # Upstream stops after `want` candidates (discovery = name
-        # order); a node whose pods were all reprieved is none.
-        in_name_order = is_c[ev_k["name_order"]]
-        pos = (jnp.cumsum(in_name_order.astype(jnp.int32)) - 1)[ev_k["name_pos"]]
-        keep = is_c & (pos < want_k)
+        # order); a node whose pods were all reprieved is none.  The
+        # want-th smallest name rank among the candidates bounds them
+        # (_I32_MAX where there are fewer: all stay); single operand,
+        # unstable, as ``sample_visited_at`` sorts.
+        by_name = jnp.sort(jnp.where(is_c, rank_names, _I32_MAX), stable=False)
+        last = by_name[jnp.clip(want_k - 1, 0, N - 1)]
+        keep = is_c & (rank_names <= last) & (want_k > 0)
         any_c = jnp.any(keep)
         maxp = jnp.max(jnp.where(vic, vprio, _I32_MIN), axis=0)
         # Sum of (priority + 2**31) over the victims, exactly, in 32-bit
@@ -1161,6 +1195,7 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
             "vic": jnp.where(any_c & vic[:, chosen], vrow[:, chosen], -1),
             "over": over_v,
             "cands": jnp.sum(keep.astype(jnp.int32)).astype(jnp.int32),
+            "runs": runs.astype(jnp.int32),
         }
 
     def _apply_preemption(nstate, pcarries, live, pod, lvl, found):
@@ -1256,6 +1291,7 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
                 z["searches"] = jnp.zeros((), jnp.int32)
                 z["cands"] = jnp.zeros((), jnp.int32)
                 z["builds"] = jnp.zeros((), jnp.int32)
+                z["fruns"] = jnp.zeros((), jnp.int32)
             if st.sample:
                 z["walks"] = jnp.zeros((), jnp.int32)
                 z["nvis"] = jnp.zeros((), jnp.int32)
@@ -1485,6 +1521,7 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
             "vic": jnp.full(v_eff, -1, jnp.int32),
             "over": jnp.zeros((), bool),
             "cands": jnp.int32(0),
+            "runs": jnp.int32(0),
         }
 
         def pod_body_preempt(pcarry, pb):
@@ -1511,17 +1548,28 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
             n0 = jnp.where(pb.valid, live["nom_node"][j], -1)
             live = _nom_apply_one(live, n0, j, -1)
             live["nom_node"] = _row_set(live["nom_node"], jnp.where(n0 >= 0, j, P), -1)
-            ok, _bits = prog._eval_filters(nstate, pod, aux, pcarries)
             # RunFilterPluginsWithNominatedPods: a node has to pass as it
             # stands and with the nominated pods of this priority or
-            # above counted in (skipped where none is).
+            # above counted in.
             over = _nom_at(live, lvl)
-            has_nom = jnp.any(over["nm_cnt"] > 0)
-            ok = ok & _lane_cond(
-                has_nom,
-                lambda: _filters_with(nstate, pcarries, live, pod, over),
-                lambda: jnp.ones(N, bool),
-            )
+            if st.local and st.record == "selection":
+                # Node-local window: the run with the nominees counted in
+                # decides both (``_preempt_search`` has the argument and
+                # the gate it rests on); nothing reads the reasons.
+                ok, _bits = _filters_with(nstate, pcarries, live, pod, over), None
+                runs = jnp.int32(1)
+            else:
+                # The as-it-stands run gives the recorded reasons (full
+                # record: the search reads them for ``resolvable``); the
+                # other is skipped where nobody is nominated.
+                ok, _bits = prog._eval_filters(nstate, pod, aux, pcarries)
+                has_nom = jnp.any(over["nm_cnt"] > 0)
+                ok = ok & _lane_cond(
+                    has_nom,
+                    lambda: _filters_with(nstate, pcarries, live, pod, over),
+                    lambda: jnp.ones(N, bool),
+                )
+                runs = 1 + has_nom.astype(jnp.int32)
             # evaluateNominatedNode: the nominated node, if it passes,
             # is the whole feasible set.
             own = (n0 >= 0) & ok[jnp.clip(n0, 0, N - 1)]
@@ -1579,6 +1627,9 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
             out_pod["vic"] = found["vic"]
             out_pod["over"] = found["over"]
             out_pod["cands"] = found["cands"]
+            # (A fleet lane counts its own: not the padding slots and the
+            # idle trips it shares with a longer lane.)
+            out_pod["runs"] = jnp.where(pb.valid, runs, 0) + found["runs"]
             out_pod["clr"] = cleared
             out_pod["searched"] = pred
             out_pod["built"] = build
@@ -1606,14 +1657,6 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
                 clr=jnp.int32(0),
                 searched=jnp.zeros((), bool),
                 built=jnp.zeros((), bool),
-            )
-            # Name order of the live nodes, once a step: position ->
-            # slot and back (dead nodes rank last).
-            name_order = jnp.argsort(ev_k["name_rank"]).astype(jnp.int32)
-            ev_k = dict(
-                ev_k,
-                name_order=name_order,
-                name_pos=jnp.argsort(name_order).astype(jnp.int32),
             )
             live0 = {
                 k: s[k]
@@ -1723,6 +1766,9 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
             out["searches"] = jnp.sum(pod_outs["searched"].astype(jnp.int32)).astype(jnp.int32)
             out["cands"] = jnp.sum(pod_outs["cands"]).astype(jnp.int32)
             out["builds"] = jnp.sum(pod_outs["built"].astype(jnp.int32)).astype(jnp.int32)
+            # Whole-chain filter evaluations the pod loop ran, attempts
+            # and searches (replay.preempt_filter_runs).
+            out["fruns"] = jnp.sum(pod_outs["runs"]).astype(jnp.int32)
         if st.sample:
             # Summed here, on the device, and pulled with the outputs.
             out["walks"] = jnp.sum(pod_outs["walked"], dtype=jnp.int32)
@@ -1893,6 +1939,7 @@ class StepOutcome:
     searches: int = 0  # victim searches the step ran on the device
     candidates: int = 0  # candidates those searches kept, summed
     table_builds: int = 0  # victim tables the step built (once a pass and level)
+    filter_runs: int = 0  # whole-chain filter evaluations of a preempt window's pod loop
     # percentageOfNodesToScore: attempts that walked for a sample, the
     # nodes they visited and the nodes they scored, summed on the device.
     sampled: int = 0
@@ -2016,10 +2063,14 @@ class ReplayDriver:
         # The on-device victim search, over the committed segments:
         # searches run, victim tables built for them (one a pass and
         # priority level: near the searches = the carried table is not
-        # engaging), candidates they kept, victims evicted, nominations
-        # made; and the segments discarded for VMAX.
+        # engaging), whole-chain filter evaluations the pod loops of the
+        # windows that search ran (attempts and searches: one a state in
+        # a node-local window, upstream's two elsewhere), candidates the
+        # searches kept, victims evicted, nominations made; and the
+        # segments discarded for VMAX.
         self.preempt_searches = 0  # guarded-by: main-thread
         self.preempt_table_builds = 0  # guarded-by: main-thread
+        self.preempt_filter_runs = 0  # guarded-by: main-thread
         self.preempt_candidates = 0  # guarded-by: main-thread
         self.preempt_victims = 0  # guarded-by: main-thread
         self.preempt_nominations = 0  # guarded-by: main-thread
@@ -2175,6 +2226,7 @@ class ReplayDriver:
             "reconcile_writes_copied": self.reconcile_writes_copied,
             "preempt_searches": self.preempt_searches,
             "preempt_table_builds": self.preempt_table_builds,
+            "preempt_filter_runs": self.preempt_filter_runs,
             "preempt_candidates": self.preempt_candidates,
             "preempt_victims": self.preempt_victims,
             "preempt_nominations": self.preempt_nominations,
@@ -3783,6 +3835,7 @@ class ReplayDriver:
             "writes_copied": 0,
             "preempt_searches": 0,
             "preempt_table_builds": 0,
+            "preempt_filter_runs": 0,
             "preempt_candidates": 0,
             "preempt_victims": 0,
             "preempt_nominations": 0,
@@ -4123,6 +4176,7 @@ class ReplayDriver:
                     searches=int(pulled["searches"][k]) if st.preempt else 0,
                     candidates=int(pulled["cands"][k]) if st.preempt else 0,
                     table_builds=int(pulled["builds"][k]) if st.preempt else 0,
+                    filter_runs=int(pulled["fruns"][k]) if st.preempt else 0,
                     sampled=int(pulled["walks"][k]) if st.sample else 0,
                     visited=int(pulled["nvis"][k]) if st.sample else 0,
                     scored=int(pulled["nsc"][k]) if st.sample else 0,
@@ -4239,6 +4293,7 @@ class ReplayDriver:
         preempt = {
             "preempt_searches": sum(o.searches for o in seg.steps),
             "preempt_table_builds": sum(o.table_builds for o in seg.steps),
+            "preempt_filter_runs": sum(o.filter_runs for o in seg.steps),
             "preempt_candidates": sum(o.candidates for o in seg.steps),
             "preempt_victims": sum(
                 len(a.victims) for o in seg.steps for a in o.attempts or ()

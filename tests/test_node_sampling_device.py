@@ -360,10 +360,13 @@ def test_the_served_job_equals_the_plain_reference_on_scheduling_basic_500_nodes
 #: taken from the tree BEFORE sampling reached the segment path (PR 33,
 #: 68d8aaa) with this jax.  They are what every accepted benchmark cell runs:
 #: a change to one of them recompiles those cells and may move them, and has
-#: to say so (refresh the pin from the parent of such a change).
+#: to say so (refresh the pin from the parent of such a change).  The two
+#: flat ones still are 68d8aaa's; ``preempt`` is PR 44's, which meant to move
+#: it (one filter run a state in a node-local window, the candidate cut by
+#: one sort, the count of filter runs: 3c57f32e...4789c before).
 UNSAMPLED_PROGRAMS = {
     "flat": "84623a633e9446c484804e23965f7a428d626251e3ce58fac89b0a1e5af518b0",
-    "preempt": "3c57f32e3ceafe3148babb65b83bc7d3076ca23cba775e9585fce8276824789c",
+    "preempt": "57f0a26cb7514a03b14d3a3e801e72498d6b6f2cee20d77ff39e5b20376c1af6",
     "flat-full": "3d36b065ddc4ebbb0d3cb8fec6d50f2882d054551c0c5a943b7709eac3d7ecd6",
 }
 

@@ -465,7 +465,8 @@ def test_a_search_with_a_current_table_sorts_and_searches_nothing():
     axis by (node, importance) and ONE ``searchsorted``, both inside the
     build's branch, whose conditional hands back the table; the search's
     conditional (it hands back the verdict: slot, victim rows, overflow,
-    candidates) holds neither in any branch."""
+    candidates, filter runs) holds neither in any branch — its one sort is
+    the candidate cut's: a single operand, the node axis (PR 44)."""
     plan, stats = _plan_of(5)
     assert stats["preempt_searches"] == 1 and stats["preempt_table_builds"] == 1
     program = _traced(plan).jaxpr
@@ -508,13 +509,22 @@ def test_a_search_with_a_current_table_sorts_and_searches_nothing():
     assert ((v, n_nodes), "bool") in shapes(builds[0])  # vact
     searches = [
         e for e in conds(program)
-        if shapes(e) == sorted([((), "int32"), ((), "int32"), ((), "bool"), ((v,), "int32")])
+        if shapes(e) == sorted(
+            [((), "int32"), ((), "int32"), ((), "int32"), ((), "bool"), ((v,), "int32")]
+        )
     ]
     assert len(searches) == 1
-    assert sum(
-        count(b.jaxpr, lambda e: table_work(e) or e.primitive.name == "sort")
-        for b in searches[0].params["branches"]
-    ) == 0
+    branches = [b.jaxpr for b in searches[0].params["branches"]]
+    assert sum(count(b, table_work) for b in branches) == 0
+
+    def cut(eqn):
+        return (
+            eqn.primitive.name == "sort"
+            and [v.aval.shape for v in eqn.invars] == [(n_nodes,)]
+        )
+
+    assert sum(count(b, cut) for b in branches) == 1
+    assert sum(count(b, lambda e: e.primitive.name == "sort") for b in branches) == 1
 
 
 def test_the_write_back_of_a_steps_preemptions_is_one_child_span():
