@@ -42,7 +42,7 @@ shard-check: lint
 
 # The 2-D mesh suite (round 19, docs/scaling.md "2-D mesh"): the tp x dp
 # fleet parity test with its mesh evidence (lanes match solo, the
-# (2, 4) grid, per-shard bytes, dev_const zero-resharding hits) + the
+# (2, 4) grid, per-shard bytes) + the
 # donated-carry byte-identity test, the mesh fleet under a dead device,
 # and the slow tp=4 x dp=2 6k fleet lock leg — every lane 2524/471
 # stepwise against the solo unsharded run.  Gated on lint like

@@ -36,9 +36,6 @@ frees its device arrays.
 from __future__ import annotations
 
 import dataclasses
-import hashlib
-import os
-from collections import OrderedDict
 from dataclasses import dataclass
 from functools import partial
 from typing import Any, Sequence
@@ -186,42 +183,12 @@ class EngineResult:
     sampling_next_start: int | None = None
 
 
-# Content-addressed host->device transfer cache.  Engines rebuilt for an
-# UNCHANGED snapshot skip re-transferring byte-identical arrays.  Keyed
-# on content + dtype/shape + the x64 flag (jnp.asarray downcasts
-# int64/float64 when x64 is off).  No jitted path donates its inputs,
-# so cached buffers stay alive.
-#
-# DISABLED by default (limit 0): the cost it trades against — hundreds
-# of extra live device buffers vs the transfers saved — is unmeasured
-# on a directly attached chip (ROADMAP Queue 1 item 7 settles the
-# default with a chip cell).  Set KSIM_H2D_CACHE to a positive entry
-# count to enable.
-_H2D: "OrderedDict[tuple, jnp.ndarray]" = OrderedDict()
-_H2D_LIMIT = int(os.environ.get("KSIM_H2D_CACHE", "0"))
-
 # lax.scan unroll factor for the sequential-commit loop (see
 # _Program._schedule_fn), and the slots per trip of the segment
 # program's pod loops (engine/replay.py).  A constant since PR 29: on
 # the chip 8 and 16 bought nothing against 4 and round more slots up
 # (PERF.md section 6, PR 28).
 SCAN_UNROLL = 4
-
-
-def _to_device(a) -> jnp.ndarray:
-    if not _H2D_LIMIT or not isinstance(a, np.ndarray) or a.nbytes > (64 << 20):
-        return jnp.asarray(a)
-    digest = hashlib.blake2b(a.tobytes(), digest_size=16).digest()
-    key = (a.dtype.str, a.shape, digest, bool(jax.config.jax_enable_x64))
-    hit = _H2D.get(key)
-    if hit is not None:
-        _H2D.move_to_end(key)
-        return hit
-    v = jnp.asarray(a)
-    _H2D[key] = v
-    if len(_H2D) > _H2D_LIMIT:
-        _H2D.popitem(last=False)
-    return v
 
 
 def _aux_host(aux: dict) -> tuple[dict, dict]:
@@ -368,7 +335,7 @@ def _pack_tree_to_device(tree):
     ]
     if len(pack_idx) < 4:
         return jax.tree_util.tree_unflatten(
-            treedef, [_to_device(a) for a in leaves]
+            treedef, [jnp.asarray(a) for a in leaves]
         )
     x64 = bool(jax.config.jax_enable_x64)
     chunks = []
@@ -413,7 +380,7 @@ def _pack_tree_to_device(tree):
         out[i] = unpacked[pos]
     for i, a in enumerate(out):
         if i not in pack_idx and not isinstance(a, jnp.ndarray):
-            out[i] = _to_device(a)
+            out[i] = jnp.asarray(a)
     return jax.tree_util.tree_unflatten(treedef, out)
 
 

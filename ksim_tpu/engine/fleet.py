@@ -207,7 +207,7 @@ class FleetDriver:
             if len(cohort) == 1:
                 # A cohort of one gains nothing from the group path;
                 # hand the lane the richer solo pipeline (prelower
-                # overlap, dev-const reuse) for the rest of the run.
+                # overlap) for the rest of the run.
                 cohort[0].convergent = False
                 solos.append(cohort[0])
                 cohort = []
@@ -451,8 +451,8 @@ class FleetDriver:
                     )
                 else:
                     # Dedupe: the leader's solo segment program (same
-                    # compile, same dev-const reuse); its outputs ARE
-                    # every cohort lane's outputs.
+                    # compile); its outputs ARE every cohort lane's
+                    # outputs.
                     box["out"] = lead.driver._device_exec(plan)
             except BaseException as e:  # classified below, on the main thread
                 box["err"] = e
@@ -517,7 +517,7 @@ class FleetDriver:
                 self._per_pass_head(ln)
             return None
         for ln in ready:
-            ln.driver.note_dispatch_healthy(plan, adopt=(ln is lead))
+            ln.driver.note_dispatch_healthy(plan)
         self.group_dispatches += 1
         return box["out"]
 

@@ -184,14 +184,14 @@ FALLBACK_REASON_PREFIXES: tuple[str, ...] = ("op:", "host_hook:")
 # with K; lowering/reconcile host work amortizes over it.  8-32 is the
 # useful range (beyond that the universe grows stale and the first
 # fallback forces a re-lower anyway).
-SEGMENT_STEPS = int(os.environ.get("KSIM_REPLAY_K", "16"))
+SEGMENT_STEPS = 16
 
 # record="full" segments stack per-step [Q, F|S, N] result tensors on
 # device, so they run at a SHORTER fixed K (one extra compiled shape)
 # and are rejected outright when even that would exceed the byte bound
 # below ("full_record_bytes" fallback).
-FULL_SEGMENT_STEPS = int(os.environ.get("KSIM_REPLAY_FULL_K", "4"))
-FULL_RECORD_BYTES = int(os.environ.get("KSIM_REPLAY_FULL_BYTES", str(1 << 30)))
+FULL_SEGMENT_STEPS = 4
+FULL_RECORD_BYTES = 1 << 30
 
 # On-device preemption: the victims-per-node bound (a static shape of the
 # victim table).  A search that meets a node holding more pods of a lower
@@ -199,7 +199,7 @@ FULL_RECORD_BYTES = int(os.environ.get("KSIM_REPLAY_FULL_BYTES", str(1 << 30)))
 # before any store effect ("preemption_overflow" fallback) —
 # bounded-exact, never approximate.  The candidate bound is upstream's
 # own, ``candidate_count(live nodes)``, derived per step by the lowering.
-PREEMPT_VICTIMS = int(os.environ.get("KSIM_REPLAY_VMAX", "8"))
+PREEMPT_VICTIMS = 8
 
 # Distinct pod priorities a window's universe may hold with the victim
 # search on: the nominated pods' per-node load is carried per level
@@ -231,8 +231,8 @@ def _replay_tp() -> int:
     """``KSIM_REPLAY_TP``: lay every node-axis tensor of the segment
     program over a ``make_mesh(tp, dp=1)`` node mesh (round 17).  1 (the
     default) keeps the single-device layout.  The byte bound
-    (``KSIM_REPLAY_FULL_BYTES``) and the preemption victim bound
-    (``KSIM_REPLAY_VMAX``) are PER-SHARD budgets —
+    (``FULL_RECORD_BYTES``) and the preemption victim bound
+    (``PREEMPT_VICTIMS``) are PER-SHARD budgets —
     record="full" and bounded-exact preemption scale with the mesh.
     Read at ReplayDriver construction; an explicit service ``shard_mesh``
     takes precedence over the env knob."""
@@ -245,18 +245,6 @@ def _replay_tp() -> int:
 #: comment in _lower, docs/churn_floor.md, and the standalone
 #: jax-only repro in tools/shard_repro.py).
 _MIN_SHARD_NODES = 4
-
-
-#: ``KSIM_REPLAY_DONATE`` (default on): donate the scan-carried cluster
-#: state (``state0``) to the segment programs.  The carry is transferred
-#: fresh every dispatch and never enters the id-keyed dev-const reuse
-#: map, so XLA may alias its input buffer into the output instead of
-#: holding TWO copies of cluster state per chip for the dispatch's
-#: lifetime — on a fleet mesh that halves the per-chip carry footprint
-#: (docs/scaling.md "2-D mesh (round 19)").  ``0`` is the escape hatch
-#: for backends whose runtime mishandles input-output aliasing.  Read
-#: at import (the jit wrappers are built once, at module load).
-_REPLAY_DONATE = os.environ.get("KSIM_REPLAY_DONATE", "1") != "0"
 
 
 #: Half-open cooldown doubling is bounded here: a backend that stays
@@ -1788,15 +1776,11 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
 
 
 #: Donation (round 19): argument 4 is the carried cluster state.  Both
-#: executors transfer it FRESH every dispatch — the id-keyed device
-#: reuse map covers the CONST leaves only (_pack_plan_buffers /
-#: _shard_plan_buffers put ``(ev, state0)`` in the per-dispatch batch
-#: unconditionally) — so donating it can never hand XLA a buffer a
-#: later dispatch still needs, and the output carry reuses the input's
-#: allocation instead of holding two copies of ``[N]``/``[N, R]``
-#: cluster state per chip (SNIPPETS.md scan-carry donation idiom; the
-#: fleet's dominant per-lane footprint).  ``KSIM_REPLAY_DONATE=0``
-#: restores the copying program.
+#: executors transfer it FRESH every dispatch, so donating it can never
+#: hand XLA a buffer a later dispatch still needs, and the output carry
+#: reuses the input's allocation instead of holding two copies of
+#: ``[N]``/``[N, R]`` cluster state per chip (SNIPPETS.md scan-carry
+#: donation idiom; the fleet's dominant per-lane footprint).
 #:
 #: MESH dispatches never donate (the ``_nodonate`` twins below): on the
 #: forced-8-virtual-device CPU backend, donating the carry of a
@@ -1811,7 +1795,7 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
 #: allocator, so per-device "exclusive" donated buffers can alias in
 #: ways real per-chip HBM cannot; re-evaluate on silicon before
 #: donating mesh carries.
-_DONATE_ARGNUMS = (4,) if _REPLAY_DONATE else ()
+_DONATE_ARGNUMS = (4,)
 
 
 @partial(jax.jit, static_argnums=(0, 1), donate_argnums=_DONATE_ARGNUMS)
@@ -2133,18 +2117,11 @@ class ReplayDriver:
         self._segment_seq = 0
         # Incremental-lowering state (docs/churn_floor.md round 10): the
         # persistent lowered-universe cache, the speculative next-window
-        # spec from the double-buffered executor, the committed plan the
-        # cache advances from, and the device-resident constant-buffer
-        # reuse map ({id(host array): (host ref, device array)} from the
-        # previous dispatch; the host ref pins the id).
+        # spec from the double-buffered executor and the committed plan
+        # the cache advances from.
         self._cache = _LowerCache()  # guarded-by: main-thread
         self._spec: "tuple[tuple[int, ...], _WindowSpec] | None" = None  # guarded-by: main-thread
         self._last_plan: "_SegmentPlan | None" = None  # guarded-by: main-thread
-        self._dev_consts: dict[int, tuple[Any, Any]] = {}  # guarded-by: main-thread
-        self._dev_consts_x64: "bool | None" = None  # guarded-by: main-thread
-        # Layout token the adopted buffers were committed under (round
-        # 19): ("pack",) or ("mesh", dp, tp) — see _SegmentPlan.
-        self._dev_consts_layout: Any = None  # guarded-by: main-thread
         # Sharded replay (round 17): the requested node-mesh width.  An
         # explicit service shard_mesh (validated in service_supported)
         # wins over the env knob.  Fleet lanes honor the knob too since
@@ -2155,18 +2132,6 @@ class ReplayDriver:
         self._tp_env = _replay_tp()
         self._tp_req = self._tp_env  # guarded-by: main-thread
         self._shard_mesh_obj: Any = None  # guarded-by: main-thread
-        # Default: ON where re-transfer is the only cost (cpu backend),
-        # OFF on every other backend — what pinning the extra live
-        # device buffers costs there is unmeasured on a directly
-        # attached chip (same question as KSIM_H2D_CACHE, engine/core.py;
-        # ROADMAP Queue 1 item 7 settles both with a chip cell).
-        # KSIM_REPLAY_DEV_CACHE=1/0 overrides either way.  Unset env ->
-        # None: the backend probe is DEFERRED to after the first healthy
-        # dispatch — jax.default_backend() initializes the XLA client,
-        # which must only ever happen on the watchdogged worker (a
-        # hung backend would hang an unguarded main-thread init here).
-        _dc = os.environ.get("KSIM_REPLAY_DEV_CACHE")
-        self._dev_cache_on: "bool | None" = _dc != "0" if _dc is not None else None
         self._prio_gen = 0
         # Pipeline / O(delta) evidence counters (job result, lock-check
         # guard).  ``lower_log`` records one entry per successful lower:
@@ -2176,8 +2141,6 @@ class ReplayDriver:
         self.prelower_consumed = 0  # guarded-by: main-thread
         self.prelower_discarded = 0  # guarded-by: main-thread
         self.prelower_faults = 0  # guarded-by: main-thread
-        self.dev_const_hits = 0  # guarded-by: main-thread
-        self.dev_const_misses = 0  # guarded-by: main-thread
         self.lower_log: list[dict] = []  # guarded-by: main-thread
         # Last _reject reason — the fleet coordinator mirrors a shared
         # (cohort-leader) rejection onto every follower lane's histogram
@@ -2277,10 +2240,6 @@ class ReplayDriver:
                 "consumed": self.prelower_consumed,
                 "discarded": self.prelower_discarded,
                 "faults": self.prelower_faults,
-            },
-            "dev_const": {
-                "hits": self.dev_const_hits,
-                "misses": self.dev_const_misses,
             },
             # PROCESS-WIDE (shared by every driver/tenant in the
             # process): the compiled-executable cache's rung counters —
@@ -2494,14 +2453,13 @@ class ReplayDriver:
 
     def _flush_incremental(self, reason: str) -> None:
         """Strictly drop ALL incremental lowering state — the cache, the
-        speculative prefix, the retained plan, and the device-resident
-        constant buffers — ahead of a path the incremental bookkeeping
-        cannot track.  One helper so no future invalidation site can
-        flush the cache but leave a stale plan/buffer map behind it."""
+        speculative prefix and the retained plan — ahead of a path the
+        incremental bookkeeping cannot track.  One helper so no future
+        invalidation site can flush the cache but leave a stale plan
+        behind it."""
         self._cache.invalidate(reason)
         self._discard_spec()
         self._last_plan = None
-        self._dev_consts = {}
 
     def _take_spec(self, batches: list[list[Any]]) -> "_WindowSpec | None":
         """Consume the speculative prefix if it predicted exactly this
@@ -2674,8 +2632,8 @@ class ReplayDriver:
     ) -> "_SegmentPlan | None":
         """The lowering half of ``try_segment``: breaker / support / op
         screens plus the classified lowering taxonomy, ending in a
-        dispatch-ready ``_SegmentPlan`` (device-const reuse attached) or
-        None with the reason recorded.  Split from the dispatch half so
+        dispatch-ready ``_SegmentPlan`` or None with the reason
+        recorded.  Split from the dispatch half so
         the fleet coordinator (engine/fleet.py) can lower a shared
         window ONCE on the cohort leader and dispatch all lanes in one
         program.  The fleet passes ``check_lane_faults=False``: it gates
@@ -2735,21 +2693,6 @@ class ReplayDriver:
             )
             self._reject("lowering_fault")
             return None
-        if plan is None:
-            return None
-        if (
-            self._dev_cache_on
-            and self._dev_consts_x64 == bool(jax.config.jax_enable_x64)
-        ):
-            # Round 17/19: the reuse map holds buffers committed to ONE
-            # device layout.  The map rides with its layout TOKEN and
-            # the executor compares at use-site (a solo-vs-fleet or
-            # mesh-shape change silently misses and re-transfers;
-            # changed host arrays still miss by id individually) — the
-            # driver can't predict here whether the fleet will dispatch
-            # this plan on its (dp, tp) mesh.
-            plan.dev_reuse = self._dev_consts
-            plan.dev_reuse_layout = self._dev_consts_layout
         return plan
 
     def dispatch_segment(self, plan: "_SegmentPlan", batches: list[list[Any]]):
@@ -2789,12 +2732,10 @@ class ReplayDriver:
         self._last_plan = plan
         return res
 
-    def note_dispatch_healthy(self, plan: "_SegmentPlan", *, adopt: bool = True) -> None:
+    def note_dispatch_healthy(self, plan: "_SegmentPlan") -> None:
         """Main-thread accounting for one healthy dispatch join: breaker
-        window reset, round-trip count, device-const buffer adoption.
-        Shared by the solo path above and the fleet's group dispatch
-        (where every lane's driver gets the reset but only the plan
-        OWNER — the cohort leader — adopts the buffers, ``adopt``)."""
+        window reset, round-trip count.  Shared by the solo path above
+        and the fleet's group dispatch (every lane's driver gets it)."""
         self._consecutive_device_errors = 0
         if self._breaker_probe:
             # The half-open probe segment came back healthy: the
@@ -2806,18 +2747,6 @@ class ReplayDriver:
         self.queue_width_max = max(self.queue_width_max, plan.statics.q)
         self.steps_padded += plan.statics.k - plan.n_steps
         note_backend()
-        if self._dev_cache_on is None:
-            # Safe to probe now: the dispatch initialized the backend on
-            # the watchdogged worker, so this is an instant lookup.
-            self._dev_cache_on = jax.default_backend() == "cpu"
-        if adopt and self._dev_cache_on and plan.dev_map_out is not None:
-            # Adopt this dispatch's device buffers for id-keyed reuse by
-            # the next one (main thread: _run never mutates the driver).
-            self._dev_consts = plan.dev_map_out
-            self._dev_consts_x64 = bool(jax.config.jax_enable_x64)
-            self._dev_consts_layout = plan.dev_layout
-            self.dev_const_hits += plan.dev_hits
-            self.dev_const_misses += plan.dev_misses
 
     def _run_watchdogged(self, plan: "_SegmentPlan", future: list[list[Any]]):
         """Run ``_run`` on a worker thread bounded by the watchdog, and
@@ -3873,7 +3802,6 @@ class ReplayDriver:
             priority_of=priority_of,
             prio_gen=prio_gen,
             sched_names=sched_names,
-            dev_collect=bool(self._dev_cache_on),
             mesh=self._shard_mesh_obj,
             log_entry=log_entry,
         )
@@ -4005,11 +3933,10 @@ class ReplayDriver:
             return self._decode_outputs(plan, pulled_state, pulled)
 
     def _device_exec(self, plan: "_SegmentPlan"):  # ksimlint: worker-thread
-        """The device half of a dispatch: pack constants (id-keyed
-        buffer reuse), execute the compiled segment program, pull the
-        carried state + per-step outputs back to host numpy.  Worker
-        thread; side-effect-free on the driver (packing evidence rides
-        on the plan).  The dispatch goes through the process-wide
+        """The device half of a dispatch: pack the buffers, execute the
+        compiled segment program, pull the carried state + per-step
+        outputs back to host numpy.  Worker thread; side-effect-free on
+        the driver.  The dispatch goes through the process-wide
         compile-once gate (engine/compilecache.py): the first caller of
         a shape rung compiles, concurrent same-rung callers — other
         tenant jobs on the same bucketed shapes — wait and reuse."""
@@ -4669,15 +4596,13 @@ def prewarm_aot_cache(*, speculative: bool = False) -> int:  # ksimlint: thread-
 
 def prewarm_rescan_loop(
     stop: "threading.Event | None" = None,
-    interval_s: "float | None" = None,
+    interval_s: float = 30.0,
 ) -> None:  # ksimlint: thread-role(service-loop)
     """``KSIM_AOT_PREWARM=2`` (cmd/simulator.py): the startup prewarm
-    pass, then a speculative rescan every ``KSIM_AOT_PREWARM_RESCAN_S``
-    seconds (default 30) picking up executables OTHER fleet workers
-    stored since the last scan.  Runs forever on its daemon thread;
-    ``stop`` is the tests' exit handle."""
-    if interval_s is None:
-        interval_s = float(os.environ.get("KSIM_AOT_PREWARM_RESCAN_S", "30"))
+    pass, then a speculative rescan every 30 seconds — how stale another
+    fleet worker's freshly stored executable can be before this process
+    has it warm.  Runs forever on its daemon thread; ``stop`` and
+    ``interval_s`` are the tests' handles."""
     interval_s = max(float(interval_s), 0.05)
     if stop is None:
         stop = threading.Event()
@@ -4701,8 +4626,8 @@ def prewarm_rescan_loop(
 def _plan_const_parts(plan: "_SegmentPlan"):
     """The plan's universe-constant trees in canonical order (node
     statics, pod rows, the optional preemption extras, the packed aux
-    host tree) — the id-keyed-reuse "cacheable" half of a dispatch's
-    inputs, shared by the solo and fleet executors."""
+    host tree) — the half of a dispatch's inputs that does not change
+    from step to step, shared by the solo and fleet executors."""
     from ksim_tpu.engine.core import _aux_host
 
     aux_host, _axes = _aux_host(plan.aux)
@@ -4711,91 +4636,26 @@ def _plan_const_parts(plan: "_SegmentPlan"):
     return (const["node"], const["pods"], extra, aux_host)
 
 
-def _const_dev_dict(cacheable_dev) -> dict:
-    node_dev, pods_dev, extra_dev, aux_dev = cacheable_dev
+def _const_dev_dict(parts_dev) -> dict:
+    node_dev, pods_dev, extra_dev, aux_dev = parts_dev
     return {"node": node_dev, "pods": pods_dev, "aux": aux_dev, **extra_dev}
 
 
-def _reuse_scan(reuse, c_leaves):
-    """Split const leaves into device-buffer reuse hits and transfer
-    misses — the shared first half of both executors' packers.  Two
-    rungs: the id-keyed fast path (the featurizer kept the host array
-    OBJECT alive since last window), then a positional VALUE rung —
-    ``_plan_const_parts`` flattens in canonical order and the reuse
-    map preserves insertion order, so leaf ``i`` aligns with last
-    window's leaf ``i``.  The value rung is what makes steady-state
-    reuse real on churn streams: the featurizer restacks its tensors
-    every lower (fresh array ids even on a lowered-universe cache
-    hit) while the steady-state VALUES are unchanged, so an id-only
-    map misses wholesale forever.  Byte-equality is the full safety
-    condition (the cached device buffer holds exactly the bytes the
-    transfer would produce); positional alignment only affects the
-    hit rate, never correctness.  A changed leaf pays one short-
-    circuiting memcmp before it transfers — cheap against the H2D
-    round trip it replaces."""
-    prev = list(reuse.values()) if reuse else None
-    dev_c: "list[Any]" = [None] * len(c_leaves)
-    miss_idx: "list[int]" = []
-    for i, a in enumerate(c_leaves):
-        ent = reuse.get(id(a)) if reuse else None
-        if ent is not None and ent[0] is a:
-            dev_c[i] = ent[1]
-            continue
-        if prev is not None and i < len(prev):
-            pa, pd = prev[i]
-            if (
-                isinstance(a, np.ndarray)
-                and isinstance(pa, np.ndarray)
-                and pa.shape == a.shape
-                and pa.dtype == a.dtype
-                and np.array_equal(pa, a)
-            ):
-                dev_c[i] = pd
-                continue
-        miss_idx.append(i)
-    return dev_c, miss_idx
-
-
 def _pack_plan_buffers(plan: "_SegmentPlan", transient):
-    """ONE transfer protocol for both executors: constant buffers (node
-    statics, pod rows, aux tables) that are the SAME host arrays as the
-    previous dispatch — the featurizer family caches and the
-    lowered-universe cache keep them identity-stable when the
-    underlying objects survived — reuse their device buffers instead of
-    re-transferring; everything else (the caller's per-segment
-    ``transient`` tree: event streams + the solo or lane-stacked carry)
-    packs into the usual single byte-buffer transfer.  The id-keyed map
-    pins its host arrays, so a recycled id can never alias a fresh
-    array; identity is the fast path and positional byte-equality the
-    second rung (``_reuse_scan`` — the featurizer restacks tensors
-    every lower, so steady-state reuse is a VALUE property, not an id
-    one).  Reuse evidence (dev_hits/dev_misses) and the next window's
-    reuse map (dev_map_out, only when the driver will adopt it — with
-    the cache off, retaining it would pin a full segment's constant
-    buffers across the next window: the KSIM_H2D_CACHE pinning
-    pathology, engine/core.py) ride on the plan.
+    """The single-device transfer: the plan's constant buffers (node
+    statics, pod rows, aux tables) and the caller's per-segment
+    ``transient`` tree (event streams + the solo or lane-stacked carry)
+    go up in ONE packed byte-buffer transfer.
 
     Returns ``(const_dev, transient_dev)``."""
     from ksim_tpu.engine.core import _pack_tree_to_device
 
-    cacheable = _plan_const_parts(plan)
-    c_leaves, c_def = jax.tree_util.tree_flatten(cacheable)
+    c_leaves, c_def = jax.tree_util.tree_flatten(_plan_const_parts(plan))
     t_leaves, t_def = jax.tree_util.tree_flatten(transient)
-    plan.dev_layout = ("pack",)
-    reuse = plan.dev_reuse if plan.dev_reuse_layout == ("pack",) else None
-    dev_c, miss_idx = _reuse_scan(reuse, c_leaves)
-    packed = _pack_tree_to_device([c_leaves[i] for i in miss_idx] + t_leaves)
-    for pos, i in enumerate(miss_idx):
-        dev_c[i] = packed[pos]
-    plan.dev_hits = len(c_leaves) - len(miss_idx)
-    plan.dev_misses = len(miss_idx)
-    plan.dev_map_out = (
-        {id(a): (a, d) for a, d in zip(c_leaves, dev_c)}
-        if plan.dev_collect
-        else None
-    )
-    const_dev = _const_dev_dict(jax.tree_util.tree_unflatten(c_def, dev_c))
-    transient_dev = jax.tree_util.tree_unflatten(t_def, packed[len(miss_idx):])
+    packed = _pack_tree_to_device(c_leaves + t_leaves)
+    n_c = len(c_leaves)
+    const_dev = _const_dev_dict(jax.tree_util.tree_unflatten(c_def, packed[:n_c]))
+    transient_dev = jax.tree_util.tree_unflatten(t_def, packed[n_c:])
     return const_dev, transient_dev
 
 
@@ -4925,26 +4785,19 @@ def _fleet_shard_specs(plan: "_SegmentPlan", transient, mesh):
 
 
 def _shard_plan_buffers(plan: "_SegmentPlan", transient, mesh, *, specs=None):
-    """The mesh mirror of ``_pack_plan_buffers``: the same id-keyed
-    constant-buffer reuse protocol, but every transferred leaf goes up
-    COMMITTED to its NamedSharding (one batched ``jax.device_put`` over
-    the miss + transient leaves — jit then respects the input layouts
-    without in_shardings and GSPMD propagates them through the scan).
-    Reuse hits return buffers already laid out for THIS mesh: the
-    layout token (``("mesh", dp, tp)``) rides with the reuse map and a
-    mismatch misses wholesale (a mesh change re-shards everything)
-    while an unchanged-universe redispatch re-shards only changed host
-    arrays.  ``specs`` overrides the solo spec trees — the fleet passes
-    ``_fleet_shard_specs`` so its lane-stacked carry lays lanes over dp
-    and node axes over tp.
+    """The mesh mirror of ``_pack_plan_buffers``: every leaf goes up
+    COMMITTED to its NamedSharding in one batched ``jax.device_put`` —
+    jit then respects the input layouts without in_shardings and GSPMD
+    propagates them through the scan.  ``specs`` overrides the solo spec
+    trees — the fleet passes ``_fleet_shard_specs`` so its lane-stacked
+    carry lays lanes over dp and node axes over tp.
 
     Returns ``(const_dev, transient_dev)`` exactly like the packed
     path."""
     c_spec, t_spec = (
         specs if specs is not None else _plan_shard_specs(plan, transient, mesh)
     )
-    cacheable = _plan_const_parts(plan)
-    c_leaves, c_def = jax.tree_util.tree_flatten(cacheable)
+    c_leaves, c_def = jax.tree_util.tree_flatten(_plan_const_parts(plan))
     cs_leaves = jax.tree_util.tree_leaves(c_spec)
     t_leaves, t_def = jax.tree_util.tree_flatten(transient)
     ts_leaves = jax.tree_util.tree_leaves(t_spec)
@@ -4964,24 +4817,13 @@ def _shard_plan_buffers(plan: "_SegmentPlan", transient, mesh, *, specs=None):
             if not x64 and a.dtype.itemsize == 8 and a.dtype.kind in "iuf":
                 a = a.astype(np.dtype(f"{a.dtype.kind}4"))
         return a
-    plan.dev_layout = ("mesh",) + tuple(int(d) for d in mesh.devices.shape)
-    reuse = plan.dev_reuse if plan.dev_reuse_layout == plan.dev_layout else None
-    dev_c, miss_idx = _reuse_scan(reuse, c_leaves)
+
     put = jax.device_put(
-        [_canon(c_leaves[i]) for i in miss_idx] + [_canon(a) for a in t_leaves],
-        [cs_leaves[i] for i in miss_idx] + ts_leaves,
+        [_canon(a) for a in c_leaves + t_leaves], cs_leaves + ts_leaves
     )
-    for pos, i in enumerate(miss_idx):
-        dev_c[i] = put[pos]
-    plan.dev_hits = len(c_leaves) - len(miss_idx)
-    plan.dev_misses = len(miss_idx)
-    plan.dev_map_out = (
-        {id(a): (a, d) for a, d in zip(c_leaves, dev_c)}
-        if plan.dev_collect
-        else None
-    )
-    const_dev = _const_dev_dict(jax.tree_util.tree_unflatten(c_def, dev_c))
-    transient_dev = jax.tree_util.tree_unflatten(t_def, put[len(miss_idx):])
+    n_c = len(c_leaves)
+    const_dev = _const_dev_dict(jax.tree_util.tree_unflatten(c_def, put[:n_c]))
+    transient_dev = jax.tree_util.tree_unflatten(t_def, put[n_c:])
     return const_dev, transient_dev
 
 
@@ -4998,16 +4840,12 @@ def _fleet_exec(plan: "_SegmentPlan", lanes_state0, mesh=None):
     broadcasting ``ev`` is load-bearing under vmap).  With ``mesh`` (a
     ``(dp, tp)`` fleet mesh), every leaf goes up COMMITTED to its
     NamedSharding via the sharded packer: lanes lay over ``dp``, node
-    tensors over ``tp`` (round 19 — ``_fleet_shard_specs``), and the
-    id-keyed device-buffer reuse map applies exactly as on the solo
-    path (layout-token gated), so steady-state segments re-transfer
-    only the event streams and the carry.
+    tensors over ``tp`` (round 19 — ``_fleet_shard_specs``).
 
     Returns ``(pulled_state, pulled)`` exactly as a solo dispatch would,
     with a leading lane axis on every leaf; the caller decodes each
     lane's slice through ``ReplayDriver._decode_outputs``.  Module
-    function, side-effect-free on every driver (packing evidence rides
-    on the plan, applied by the fleet on the main thread)."""
+    function, side-effect-free on every driver."""
     FAULTS.check("replay.dispatch")
     with TRACE.span("replay.pack", lanes=len(lanes_state0)):
         st_s = jax.tree_util.tree_map(lambda *xs: np.stack(xs), *lanes_state0)
@@ -5102,29 +4940,8 @@ class _SegmentPlan:
     priority_of: Any = None
     prio_gen: int = 0
     sched_names: Any = None  # profile set the lowering screened against
-    # Device-resident constant-buffer reuse: ``dev_reuse`` is consumed by
-    # _run (id(host array) -> (host ref, device array) from the previous
-    # dispatch); ``dev_map_out``/hits/misses are produced by _run and
-    # adopted by the driver on the MAIN thread after a healthy join
-    # (_run itself stays side-effect-free on the driver).
-    dev_reuse: dict = field(default_factory=dict)
-    dev_collect: bool = False  # build dev_map_out (driver cache enabled)
-    dev_map_out: "dict | None" = None
-    dev_hits: int = 0
-    dev_misses: int = 0
     exec_s: float = 0.0  # wall of this dispatch's replay.exec (worker)
     log_entry: "dict | None" = None  # this lowering's lower_log entry
-    # Round 19: device-buffer LAYOUT tokens — ``dev_reuse_layout`` is
-    # the token the attached reuse map's buffers were committed under
-    # (("pack",) for the single-device packed transfer, ("mesh", dp, tp)
-    # for a sharded one); ``dev_layout`` is the token this dispatch's
-    # executor actually used (adopted by note_dispatch_healthy).  The
-    # executors compare tokens at USE-SITE and silently miss on a
-    # mismatch: prepare_segment cannot know whether the plan will be
-    # dispatched solo or on the fleet's (dp, tp) mesh, and reusing a
-    # buffer laid out for a different device set corrupts the program.
-    dev_reuse_layout: Any = None
-    dev_layout: Any = None
     # Round 17: the EXPLICIT service shard_mesh this plan was lowered
     # for (None for env-knob sharding — _device_exec builds that mesh
     # lazily on the worker — and for tp=1 plans).

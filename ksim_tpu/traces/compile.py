@@ -27,7 +27,7 @@ streams priority-DIVERSE — unlike the synthetic churn, windows are not
 priority-flat, which is exactly the workload property the ROADMAP item
 wanted on record.  (Trace replay runs with preemption disabled by
 default: a preemption-armed trace replay is bounded by
-``KSIM_REPLAY_VMAX`` (pods of a lower priority a node) and may
+``replay.PREEMPT_VICTIMS`` (pods of a lower priority a node) and may
 legitimately discard segments —
 docs/scenario.md.)
 

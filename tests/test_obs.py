@@ -387,6 +387,45 @@ def test_fallback_reasons_match_replay_source():
     assert "replay.fallback" in EVENT_NAMES
 
 
+#: Every environment variable the engine layer names in a string (an
+#: environ read, a docstring, an error message): the analyzer's own
+#: scan, held to this tuple.  A knob is added here in the PR that brings
+#: the cell, test or deployment file that sets it.
+ENGINE_ENV_KNOBS = (
+    "KSIM_AOT_CACHE",
+    "KSIM_AOT_PREWARM",
+    "KSIM_FAULTS",
+    "KSIM_FLEET_DP",
+    "KSIM_FLEET_FAULTS",
+    "KSIM_FLEET_VMAP",
+    "KSIM_JOBS_DIR",
+    "KSIM_REPLAY_BREAKER_COOLDOWN_S",
+    "KSIM_REPLAY_BREAKER_N",
+    "KSIM_REPLAY_TP",
+    "KSIM_REPLAY_WATCHDOG_S",
+)
+
+
+def test_engine_env_knobs_are_the_listed_ones():
+    """The segment program's compile shapes and the transfer path are
+    constants of the source: ksim_tpu/engine/ names no environment
+    variable beyond the ones above."""
+    from tools.ksimlint.core import Project
+    from tools.ksimlint.rules import env_contract as ec
+
+    project = _lint_project()
+    engine = Project(
+        root=project.root,
+        files={
+            rel: sf
+            for rel, sf in project.files.items()
+            if rel.startswith("ksim_tpu/engine/")
+        },
+    )
+    assert engine.files
+    assert sorted(ec.scan_env_literals(engine)) == sorted(ENGINE_ENV_KNOBS)
+
+
 def test_fault_fire_emits_trace_event():
     """The fault plane lands fault.fired on the global plane; exercised
     through a private enable/restore cycle of the global TRACE."""

@@ -718,14 +718,11 @@ def test_fleet_tp_dp_mesh_lanes_match_single_device(monkeypatch):
     )
     assert all(e["full_bytes_per_shard"] > 0 for e in leader.lower_log)
     assert fd.stats()["group_dispatches"] >= 1
-    # Zero resharding: once the ("mesh", 2, 4) layout is adopted, a
-    # steady-state window finds its constants on the device.
-    assert leader.stats()["dev_const"]["hits"] > 0, leader.stats()["dev_const"]
 
 
 def test_replay_donation_engages_and_stays_byte_identical():
-    """The segment programs donate the scan carry (KSIM_REPLAY_DONATE
-    default-on, engine/replay.py _DONATE_ARGNUMS): a donated dispatch
+    """The single-device segment programs donate the scan carry
+    (engine/replay.py _DONATE_ARGNUMS): a donated dispatch
     must raise no jax donation warnings on CPU — XLA either consumed
     the buffers or would warn "Some donated buffers were not usable" —
     and the donated path's per-step outcomes stay byte-identical to
@@ -736,7 +733,7 @@ def test_replay_donation_engages_and_stays_byte_identical():
 
     from ksim_tpu.engine import replay as rmod
 
-    assert rmod._REPLAY_DONATE and rmod._DONATE_ARGNUMS == (4,)
+    assert rmod._DONATE_ARGNUMS == (4,)
     jax.config.update("jax_enable_x64", False)
     with warnings.catch_warnings():
         warnings.filterwarnings(
