@@ -2076,6 +2076,12 @@ class ReplayDriver:
         # (``Memo.seq_builds``), summed over the lowerings: about one a
         # family in a cold call, none while the node objects stay.
         self.featurize_node_builds = 0  # guarded-by: main-thread
+        # Bound-pod records the featurizer's additive families built
+        # (``boundagg.records_built``), summed over the lowerings:
+        # families x bound pods in a job's first call, then families x
+        # the pods bound since; a multiple of the first = a family
+        # walked the whole bound population again.
+        self.featurize_bound_records = 0  # guarded-by: main-thread
         # Store writes of the committed segments' reconciles that
         # replaced an object that was there: by a shallow re-wrap that
         # shares the frozen manifest (every placement, nomination and
@@ -2235,6 +2241,7 @@ class ReplayDriver:
             "featurize_copied": feat.pod_rows_copied if feat is not None else 0,
             "featurize_passes": feat.featurize_passes if feat is not None else 0,
             "featurize_node_builds": self.featurize_node_builds,
+            "featurize_bound_records": self.featurize_bound_records,
             "prelower": {
                 "windows": self.prelower_windows,
                 "consumed": self.prelower_consumed,
@@ -3216,6 +3223,7 @@ class ReplayDriver:
 
         memo = objcache.current()
         node_builds0 = memo.seq_builds
+        bound_records0 = self._featurizer.bound_records_built
         feats = self._featurizer.featurize(
             universe_nodes,
             (),
@@ -3225,6 +3233,8 @@ class ReplayDriver:
         )
         node_builds = memo.seq_builds - node_builds0
         self.featurize_node_builds += node_builds
+        bound_records = self._featurizer.bound_records_built - bound_records0
+        self.featurize_bound_records += bound_records
         # What is left of the lap after the call, as a stage of its own.
         TRACE.stage("replay.lower.featurize.program")
         if not feats.exact:
@@ -3752,6 +3762,7 @@ class ReplayDriver:
             "rows_rebuilt": feat.pod_rows_rebuilt - rows0[2],
             "rows_copied": feat.pod_rows_copied - rows0[3],
             "node_builds": node_builds,
+            "bound_records": bound_records,
             "cache_hit": use_cache,
             "tp": tp,
             "full_bytes_per_shard": int(full_bytes_shard),

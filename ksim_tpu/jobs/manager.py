@@ -386,6 +386,24 @@ def _old_gen_job_ends() -> None:
             gc.set_threshold(young, middle, _old_gen_idle_threshold)
 
 
+def _snapshot_counts(snapshot: dict, *, restored: bool) -> dict:
+    """The ``snapshot`` block of a job's result: what the spec's
+    ``initialSnapshot`` document holds.  A job resumed from a checkpoint
+    says ``restored`` and carries no ``load_s`` (its store came back
+    with the snapshot's objects in it)."""
+    pods = snapshot.get("pods") or []
+    bound = sum(1 for p in pods if (p.get("spec") or {}).get("nodeName"))
+    doc = {
+        "nodes": len(snapshot.get("nodes") or []),
+        "pods": len(pods),
+        "bound_pods": bound,
+        "pending_pods": len(pods) - bound,
+    }
+    if restored:
+        doc["restored"] = True
+    return doc
+
+
 class SubmitClock:
     """The clock readings of one ``POST /api/v1/jobs``, taken by the
     handler itself whatever the trace plane's state (the global plane,
@@ -510,6 +528,9 @@ class Job:
         self.checkpoint_segment: "int | None" = None  # guarded-by: _cond
         self.resumed_from: "int | None" = None  # guarded-by: _cond
         self._resume_info: "dict | None" = None  # worker-thread only
+        # The result's ``snapshot`` block: set by the worker when the
+        # spec carries an ``initialSnapshot`` (``_snapshot_counts``).
+        self._snapshot_info: "dict | None" = None  # worker-thread only
         # Fleet ownership (docs/jobs.md "Multi-worker fleet"): which
         # worker process holds the job's lease, folded from the lease
         # file by the front door's poller (or set locally on adoption).
@@ -1707,12 +1728,24 @@ class JobManager:
             restored = self._restore_checkpoint(job, sim)
             if restored is not None:
                 store, service, resume_cursor, resume_result = restored
+        snapshot = sim.get("initialSnapshot")
+        if snapshot:
+            # What the job starts from, for its result document: counts
+            # of the document, and how long its load took by this
+            # worker's own clock.  A restored store already holds the
+            # snapshot's objects: nothing is loaded again.
+            job._snapshot_info = _snapshot_counts(snapshot, restored=store is not None)
         if store is None:
             store = ClusterStore()
-            if sim.get("initialSnapshot"):
+            if snapshot:
                 from ksim_tpu.state.snapshot import SnapshotService
 
-                SnapshotService(store).load(sim["initialSnapshot"])
+                t0 = time.perf_counter()
+                # A stage, not a ring child: jobs.run's self time stays
+                # its whole duration (docs/observability.md).
+                with TRACE.stage("jobs.run.snapshot"):
+                    SnapshotService(store).load(snapshot)
+                job._snapshot_info["load_s"] = round(time.perf_counter() - t0, 6)
             service = SchedulerService(
                 store,
                 config=sim.get("schedulerConfig"),
@@ -1961,6 +1994,8 @@ class JobManager:
                 "cursor": info["cursor"],
                 "eventsReplayed": res.events_applied - info["carried_events"],
             }
+        if job._snapshot_info is not None:
+            doc["snapshot"] = job._snapshot_info
         drv = getattr(runner, "replay_driver", None)
         if drv is not None:
             doc["replay"] = drv.stats()  # includes the shared compile_cache

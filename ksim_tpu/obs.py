@@ -269,6 +269,9 @@ SPAN_NAMES: tuple[str, ...] = (
     #                       (validation, one Operation an operation)
     "jobs.submit.enqueue",  # stage of jobs.submit: reserve, journal,
     #                         queue, the 202
+    "jobs.run.snapshot",  # stage of jobs.run: the spec's
+    #                       initialSnapshot loaded into the job's store
+    #                       (SnapshotService.load), before the runner
 )
 
 #: Instant event names.

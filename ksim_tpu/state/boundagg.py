@@ -36,7 +36,21 @@ from typing import Any, Callable, Sequence
 
 from ksim_tpu.state.resources import JSON, name_of
 
-__all__ = ["NodeSlots", "sync_family"]
+__all__ = ["NodeSlots", "records_built", "sync_family"]
+
+#: The state dict's count of bound-pod records built (``record_of``
+#: calls), over every family it holds.
+_BUILT = "__built__"
+
+
+def records_built(state: dict) -> int:
+    """Bound-pod records ``sync_family`` built for the families of
+    ``state``: a family's from-scratch walk counts its whole bound
+    population, an incremental sync its arrivals and slot repairs.
+    Families x bound pods = every family walked the population once; a
+    multiple of that = a family's token moved and it walked them all
+    again."""
+    return state.get(_BUILT, 0)
 
 
 class NodeSlots:
@@ -151,6 +165,7 @@ def sync_family(
             else:
                 apply(arrays, rec, +1)
                 by_slot.setdefault(rec[0], set()).add(pid)
+        state[_BUILT] = state.get(_BUILT, 0) + len(bound_map)
         state[name] = {
             "token": token,
             "records": records,
@@ -178,8 +193,12 @@ def sync_family(
                 if not peers:
                     del by_slot[rec[0]]
 
+    built = 0
+
     def _add(pid: int, p: JSON) -> None:
+        nonlocal built
         rec = record_of(p)
+        built += 1
         records[pid] = (p, rec)
         if rec is None:
             nones.add(pid)
@@ -230,4 +249,6 @@ def sync_family(
                 _add(pid, p)
     if diff is not None:
         fam["gen"] = diff["gen"]
+    if built:
+        state[_BUILT] = state.get(_BUILT, 0) + built
     return arrays

@@ -34,7 +34,7 @@ import numpy as np
 
 from ksim_tpu.obs import TRACE
 from ksim_tpu.state import objcache
-from ksim_tpu.state.boundagg import NodeSlots, sync_family
+from ksim_tpu.state.boundagg import NodeSlots, records_built, sync_family
 from ksim_tpu.state.podtable import ROW, Column, PodTable
 from ksim_tpu.state.resources import (
     BASE_RESOURCES,
@@ -271,6 +271,12 @@ class Featurizer:
         live pod's but for its identity: their rows were copied, no
         builder ran (state/podtable.py)."""
         return self._table.rows_copied
+
+    @property
+    def bound_records_built(self) -> int:
+        """Bound-pod records the additive families built, summed over
+        the families (state/boundagg.py ``records_built``)."""
+        return records_built(self._agg)
 
     def slot_names(self) -> list[str]:
         """The current node-slot order, lowest slot first — the carry a
