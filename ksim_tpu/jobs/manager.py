@@ -389,8 +389,9 @@ def _old_gen_job_ends() -> None:
 def _snapshot_counts(snapshot: dict, *, restored: bool) -> dict:
     """The ``snapshot`` block of a job's result: what the spec's
     ``initialSnapshot`` document holds.  A job resumed from a checkpoint
-    says ``restored`` and carries no ``load_s`` (its store came back
-    with the snapshot's objects in it)."""
+    says ``restored`` and carries neither ``load_s`` nor
+    ``batched_objects`` (its store came back with the snapshot's objects
+    in it; the load adds those two)."""
     pods = snapshot.get("pods") or []
     bound = sum(1 for p in pods if (p.get("spec") or {}).get("nodeName"))
     doc = {
@@ -1744,8 +1745,12 @@ class JobManager:
                 # A stage, not a ring child: jobs.run's self time stays
                 # its whole duration (docs/observability.md).
                 with TRACE.stage("jobs.run.snapshot"):
-                    SnapshotService(store).load(snapshot)
+                    batched = SnapshotService(store).load(snapshot)
                 job._snapshot_info["load_s"] = round(time.perf_counter() - t0, 6)
+                # The objects that went into the store a batch a kind
+                # (``ClusterStore.apply_many``): the document's, less
+                # the system priority classes and ``kube-`` namespaces.
+                job._snapshot_info["batched_objects"] = batched
             service = SchedulerService(
                 store,
                 config=sim.get("schedulerConfig"),

@@ -19,7 +19,7 @@ class ReplicateService(Protocol):
     def snap(self, label_selector: JSON | None = None) -> JSON: ...
 
     def load(self, resources: JSON, *, ignore_err: bool = False,
-             ignore_scheduler_configuration: bool = False) -> None: ...
+             ignore_scheduler_configuration: bool = False) -> int: ...
 
 
 class OneShotImporter:

@@ -22,9 +22,9 @@ import os
 import queue
 import threading
 from dataclasses import dataclass, field
-from typing import Any, Callable, Iterator
+from typing import Any, Callable, Iterable, Iterator
 
-from ksim_tpu.errors import ConflictError, ExpiredError, NotFoundError
+from ksim_tpu.errors import ConflictError, ExpiredError, NotFoundError, SimulatorError
 from ksim_tpu.obs import TRACE
 from ksim_tpu.state.resources import JSON, name_of, namespace_of
 
@@ -48,6 +48,12 @@ DELETED = "DELETED"
 
 #: pre-image marker for keys a transaction CREATED (nothing to restore).
 _MISSING = object()
+
+#: ``apply_many``: up to this many new keys go into a kind's sorted list
+#: one ``insort`` each.  An insort moves half the list and one sort
+#: compares all of it once, so where the two meet depends on the batch
+#: alone: 64 entries at 1,000, 10,000 and 150,000 keys (CPU sandbox).
+_INSORT_MAX = 64
 
 
 @dataclass
@@ -520,6 +526,82 @@ class ClusterStore:
             if key in self._objects[kind]:
                 return self.update(kind, obj)
             return self.create(kind, obj)
+
+    def apply_many(
+        self,
+        kind: str,
+        objs: Iterable[JSON],
+        *,
+        on_refused: Callable[[JSON, SimulatorError], None] | None = None,
+    ) -> int:
+        """Create-or-update a batch of one kind under ONE hold of the
+        store lock (``SnapshotService.load`` hands over a kind of the
+        document at a time: two deepcopies and one ``insort`` an object
+        were 12.4 s of a 31.8-s job at 155,000 objects).  Every object
+        gets, in the order given, what ``apply`` gives it — pre-image,
+        namespace default, the next resourceVersion, a defaulted uid
+        (an update keeps the current one), the pod index, one ADDED or
+        MODIFIED event — except the copies: same ownership-transfer
+        contract as ``create(copy_obj=False)``, the caller hands the
+        dicts over and must not mutate them afterwards.  The name-sorted
+        key list is repaired once a batch, before the lock is let go, so
+        no reader sees it disagree with the object table.
+
+        An object the store refuses (a SimulatorError) ends the batch
+        and propagates — what was applied before it stays — unless
+        ``on_refused`` is given: it is then called with the object and
+        the error, and the batch goes on.  Returns the objects applied."""
+        self._check_kind(kind)
+        with self._lock:
+            table = self._objects[kind]
+            namespaced = kind in NAMESPACED_KINDS
+            new_keys: list[tuple[str, str]] = []
+            applied = 0
+            try:
+                for obj in objs:
+                    try:
+                        key = _key(kind, obj)
+                        current = table.get(key)
+                        self._touch(kind, key)
+                        md = obj.setdefault("metadata", {})
+                        if namespaced:
+                            md.setdefault("namespace", "default")
+                        rv = md["resourceVersion"] = str(next(self._rv))
+                        table[key] = obj
+                        if current is None:
+                            md.setdefault("uid", f"uid-{kind}-{rv}")
+                            new_keys.append((name_of(obj), key))
+                            event_type = ADDED
+                        else:
+                            md["uid"] = current["metadata"].get("uid")
+                            event_type = MODIFIED
+                        if kind == "pods":
+                            self._index_pod(key, obj)
+                        self._notify(WatchEvent(kind, event_type, obj))
+                    except SimulatorError as e:
+                        if on_refused is None:
+                            raise
+                        on_refused(obj, e)
+                    else:
+                        applied += 1
+            finally:
+                self._add_sorted_keys(kind, new_keys)
+            return applied
+
+    def _add_sorted_keys(self, kind: str, new_keys: list[tuple[str, str]]) -> None:  # ksimlint: lock-held(_lock)
+        """Merge a batch's new (name, key) entries into the kind's
+        sorted list.  A few entries against a larger population go in
+        by ``insort``; otherwise append and sort once — Timsort takes
+        the sorted prefix as one run.  Entries are unique (the key
+        embeds the name), so both give the same list."""
+        self._assert_owned()
+        sk = self._sorted_keys[kind]
+        if len(new_keys) <= _INSORT_MAX and len(new_keys) < len(sk):
+            for entry in new_keys:
+                bisect.insort(sk, entry)
+        else:
+            sk.extend(new_keys)
+            sk.sort()
 
     # -- watch --------------------------------------------------------------
 

@@ -61,6 +61,11 @@ def is_ignored_namespace(name: str) -> bool:
     return name.startswith("kube-")
 
 
+def _log_refused(obj: JSON, err: SimulatorError) -> None:
+    """IgnoreErr: an object the store refuses is logged, the load goes on."""
+    logger.error("failed to apply %s: %s", name_of(obj), err)
+
+
 class SnapshotService:
     """Snap/Load against a ClusterStore (reference snapshot.Service)."""
 
@@ -91,28 +96,40 @@ class SnapshotService:
         *,
         ignore_err: bool = False,
         ignore_scheduler_configuration: bool = False,
-    ) -> None:
+    ) -> int:
+        """Apply the document to the store, a kind at a time in
+        dependency order, each kind as ONE batch
+        (``ClusterStore.apply_many``: a kind's objects become visible
+        together).  Returns the number of objects the batches applied.
+
+        The store takes ownership of what it is given: afterwards its
+        objects share ``spec``, ``status``, ``labels`` and every deeper
+        value with ``resources``.  The document's top-level objects and
+        their ``metadata`` are not touched (the store gets shallow
+        copies of both) and the store never mutates a stored object, so
+        the document stays valid to READ — but the caller must not
+        mutate it after the load."""
+        batched = 0
         for field, kind in _LOAD_ORDER:
+            batch = []
             for obj in resources.get(field) or []:
                 if field == "priorityClasses" and is_system_priority_class(name_of(obj)):
                     continue
                 if field == "namespaces" and is_ignored_namespace(name_of(obj)):
                     continue
-                try:
-                    obj = dict(obj)
-                    md = dict(obj.get("metadata") or {})
-                    # Apply semantics: never carry a foreign UID in
-                    # (snapshot.go applyPcs: pc.UID = nil).
-                    md.pop("uid", None)
-                    md.pop("resourceVersion", None)
-                    obj["metadata"] = md
-                    if field == "pvs":
-                        obj = self._fix_claim_ref(obj)
-                    self._store.apply(kind, obj)
-                except SimulatorError:
-                    if not ignore_err:
-                        raise
-                    logger.error("failed to apply %s %s", kind, name_of(obj))
+                obj = dict(obj)
+                md = dict(obj.get("metadata") or {})
+                # Apply semantics: never carry a foreign UID in
+                # (snapshot.go applyPcs: pc.UID = nil).
+                md.pop("uid", None)
+                md.pop("resourceVersion", None)
+                obj["metadata"] = md
+                if field == "pvs":
+                    obj = self._fix_claim_ref(obj)
+                batch.append(obj)
+            batched += self._store.apply_many(
+                kind, batch, on_refused=_log_refused if ignore_err else None
+            )
         cfg = resources.get("schedulerConfig")
         if (
             cfg is not None
@@ -123,6 +140,7 @@ class SnapshotService:
             # swap with rollback (reference snapshot.go:202-219 calls
             # RestartScheduler after load).
             self._scheduler_service.apply_scheduler_config(cfg)
+        return batched
 
     def _fix_claim_ref(self, pv: JSON) -> JSON:
         """Re-resolve a Bound PV's claimRef UID to the freshly-loaded PVC —
@@ -153,5 +171,6 @@ class SnapshotService:
     def export_json(self, label_selector: JSON | None = None) -> str:
         return json.dumps(self.snap(label_selector), separators=(",", ":"))
 
-    def import_json(self, data: str | bytes, **kwargs: Any) -> None:
-        self.load(json.loads(data), **kwargs)
+    def import_json(self, data: str | bytes, **kwargs: Any) -> int:
+        """``load`` of a parsed document that nobody else holds."""
+        return self.load(json.loads(data), **kwargs)

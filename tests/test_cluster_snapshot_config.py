@@ -111,10 +111,16 @@ def test_a_scheduler_that_sees_an_empty_cluster_is_another_scheduler(bench, rehe
 
 def test_the_result_says_what_the_job_started_from(rehearsal, served):
     block = served["snapshot"]
-    assert set(block) == {"load_s", "nodes", "pods", "bound_pods", "pending_pods"}
+    assert set(block) == {"load_s", "batched_objects", "nodes", "pods", "bound_pods",
+                          "pending_pods"}
     assert (block["nodes"], block["pods"], block["bound_pods"], block["pending_pods"]) == (
         500, 15000, 15000, 0)
     assert block["load_s"] > 0
+    # Every object of the document went into the store through a kind's
+    # batch (``ClusterStore.apply_many``): nodes + pods + the one namespace.
+    document = json.loads(rehearsal["inputs"]["body"])["spec"]["simulator"]["initialSnapshot"]
+    assert block["batched_objects"] == 15501 == sum(
+        len(document[field]) for field in ("nodes", "pods", "namespaces"))
     # The load is a timed stage of the job's own plane, never a ring child.
     assert served["latency"]["jobs.run.snapshot"]["count"] == 1
     assert "jobs.run.snapshot" not in served["phases"]
@@ -134,7 +140,7 @@ def test_a_job_resumed_from_a_checkpoint_loads_nothing_again(bench, rehearsal, t
     """Twenty steps of one pod (two windows) on the small snapshot, a
     checkpoint after the first window; the journal cut there and resumed: the
     restored store holds the snapshot's objects, so the block says ``restored``
-    and no ``load_s``, and the job ends on the uninterrupted run's digest."""
+    and neither ``load_s`` nor ``batched_objects``, and the job ends on the uninterrupted run's digest."""
     snapshot, scenario = small_cluster(bench, rehearsal)
     ops = [dict(op, step=i) for i, op in enumerate(scenario[:20])]
     body = {"spec": {"simulator": {"deviceReplay": True, "nodeSampling": True, "podBucketMin": 128,
@@ -150,7 +156,9 @@ def test_a_job_resumed_from_a_checkpoint_loads_nothing_again(bench, rehearsal, t
 
     first = JobManager(workers=1, jobs_dir=str(tmp_path), checkpoint_every=1)
     whole = finished(first, first.submit(body))
-    assert set(whole["snapshot"]) == {"load_s", "nodes", "pods", "bound_pods", "pending_pods"}
+    assert set(whole["snapshot"]) == {"load_s", "batched_objects", "nodes", "pods", "bound_pods",
+                                      "pending_pods"}
+    assert whole["snapshot"]["batched_objects"] == 120 + 1200 + 1
     path = os.path.join(str(tmp_path), JOURNAL_NAME)
     records = JobJournal(path).replay()
     cut = next(i for i, r in enumerate(records) if r["t"] == "checkpoint")

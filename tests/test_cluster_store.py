@@ -444,3 +444,63 @@ def test_strict_mode_default_comes_from_env(monkeypatch):
     # Explicit argument beats the environment either way.
     monkeypatch.setenv("KSIM_STORE_STRICT", "1")
     assert not ClusterStore(strict=False)._strict
+
+
+def test_apply_many_takes_ownership_and_counts_what_it_applied():
+    store = ClusterStore(strict=True)
+    store.create("pods", make_pod("b"))
+    given = [make_pod("c", node_name="n0"), make_pod("a"), make_pod("b", node_name="n1")]
+    assert store.apply_many("pods", given) == 3
+    live = store.list("pods", copy_objs=False)
+    # Stored as handed over (no copy), in name order, one rv an object in
+    # the order given; the key that was there keeps its uid.
+    assert [o is g for o, g in zip(live, (given[1], given[2], given[0]))] == [True] * 3
+    assert [o["metadata"]["resourceVersion"] for o in given] == ["2", "3", "4"]
+    assert [o["metadata"]["uid"] for o in given] == ["uid-pods-2", "uid-pods-3", "uid-pods-1"]
+    assert store.apply_many("pods", []) == 0
+    with pytest.raises(NotFoundError):
+        store.apply_many("widgets", [make_pod("x")])
+    with pytest.raises(AssertionError, match="KSIM_STORE_STRICT"):
+        store._add_sorted_keys("pods", [("x", "default/x")])
+
+
+def test_apply_many_is_one_lock_hold_for_readers():
+    """A kind's batch becomes visible together: a reader in another
+    thread sees none of it or all of it, and never a key list that
+    disagrees with the object table (``list`` would raise KeyError)."""
+    import sys
+    import threading
+
+    store = ClusterStore()
+    batch = [make_pod(f"p-{i}", node_name="n0" if i % 2 else "") for i in range(4000)]
+    seen, errors, started = set(), [], threading.Event()
+    done = threading.Event()
+
+    def reader():
+        try:
+            while not done.is_set():
+                seen.add(len(store.list("pods", copy_objs=False)))
+                seen.add(2 * len(store.pods_with_node()))
+                seen.add(2 * len(store.pods_without_node()))
+                started.set()
+        except Exception as e:  # noqa: BLE001 — reported by the assert below
+            errors.append(e)
+            started.set()
+
+    threads = [threading.Thread(target=reader) for _ in range(4)]
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        for t in threads:
+            t.start()
+        assert started.wait(10)
+        assert store.apply_many("pods", batch) == 4000
+        store.list("pods", copy_objs=False)
+    finally:
+        done.set()
+        for t in threads:
+            t.join(10)
+        sys.setswitchinterval(interval)
+    assert not any(t.is_alive() for t in threads)
+    assert not errors, errors
+    assert seen <= {0, 4000}, sorted(seen)
