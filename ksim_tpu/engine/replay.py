@@ -2076,12 +2076,16 @@ class ReplayDriver:
         # (``Memo.seq_builds``), summed over the lowerings: about one a
         # family in a cold call, none while the node objects stay.
         self.featurize_node_builds = 0  # guarded-by: main-thread
-        # Bound-pod records the featurizer's additive families built
-        # (``boundagg.records_built``), summed over the lowerings:
-        # families x bound pods in a job's first call, then families x
-        # the pods bound since; a multiple of the first = a family
-        # walked the whole bound population again.
+        # Bound-pod records of the featurizer's additive families,
+        # summed over the lowerings: those that RAN a contribution
+        # builder (``boundagg.records_built``: one a distinct manifest a
+        # family) and those that took their content's contribution from
+        # the family's table (``records_shared``).  Together: families x
+        # bound pods in a job's first call, then families x the pods
+        # bound since; a multiple of the first = a family walked the
+        # whole bound population again.
         self.featurize_bound_records = 0  # guarded-by: main-thread
+        self.featurize_bound_shared = 0  # guarded-by: main-thread
         # Store writes of the committed segments' reconciles that
         # replaced an object that was there: by a shallow re-wrap that
         # shares the frozen manifest (every placement, nomination and
@@ -2242,6 +2246,7 @@ class ReplayDriver:
             "featurize_passes": feat.featurize_passes if feat is not None else 0,
             "featurize_node_builds": self.featurize_node_builds,
             "featurize_bound_records": self.featurize_bound_records,
+            "featurize_bound_shared": self.featurize_bound_shared,
             "prelower": {
                 "windows": self.prelower_windows,
                 "consumed": self.prelower_consumed,
@@ -3224,6 +3229,7 @@ class ReplayDriver:
         memo = objcache.current()
         node_builds0 = memo.seq_builds
         bound_records0 = self._featurizer.bound_records_built
+        bound_shared0 = self._featurizer.bound_records_shared
         feats = self._featurizer.featurize(
             universe_nodes,
             (),
@@ -3235,6 +3241,8 @@ class ReplayDriver:
         self.featurize_node_builds += node_builds
         bound_records = self._featurizer.bound_records_built - bound_records0
         self.featurize_bound_records += bound_records
+        bound_shared = self._featurizer.bound_records_shared - bound_shared0
+        self.featurize_bound_shared += bound_shared
         # What is left of the lap after the call, as a stage of its own.
         TRACE.stage("replay.lower.featurize.program")
         if not feats.exact:
@@ -3763,6 +3771,7 @@ class ReplayDriver:
             "rows_copied": feat.pod_rows_copied - rows0[3],
             "node_builds": node_builds,
             "bound_records": bound_records,
+            "bound_shared": bound_shared,
             "cache_hit": use_cache,
             "tp": tp,
             "full_bytes_per_shard": int(full_bytes_shard),

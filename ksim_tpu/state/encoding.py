@@ -24,7 +24,7 @@ from __future__ import annotations
 
 import json
 from dataclasses import dataclass
-from typing import Any, Sequence
+from typing import Any, Callable, Sequence
 
 import numpy as np
 
@@ -668,14 +668,15 @@ def encode_topology_spread(
     agg: dict,
     bound_map: "dict[int, JSON]",
     changed_slots: "set[int]",
-    slot_of: "dict[str, int]",
+    slot_of: "Callable[[JSON], int | None]",
     default_constraints: tuple | None = None,
 ) -> SpreadTensors:
     """``agg``/``bound_map``/``changed_slots``/``slot_of`` are the
     Featurizer's persistent state (state/boundagg.py): the selector
     vocabulary persists append-only across calls and the per-node
-    selector-match counts over BOUND pods update by delta.  A one-shot
-    Featurizer is the same code with empty state."""
+    selector-match counts over BOUND pods update by delta, a pod's match
+    row looked up by its content.  A one-shot Featurizer is the same
+    code with empty state."""
     dom_vocab: dict[tuple[int, str], int] = {}
     sels = agg.setdefault("spread_sels", {"vocab": {}, "list": []})
     if len(sels["list"]) > 4096:
@@ -776,21 +777,14 @@ def encode_topology_spread(
             count=S0,
         )
 
-    node_index = slot_of
-    N0 = len(nodes)
+    def _init_row(bp: JSON) -> "np.ndarray | None":
+        """The selectors a bound pod counts under (their ids); None for
+        a pod no selector of the vocabulary matches."""
+        hits = np.flatnonzero(sel_row(bp))
+        return hits if hits.size else None
 
-    def _init_record(bp: JSON):
-        ni = node_index.get(bp.get("spec", {}).get("nodeName", ""))
-        if ni is None or ni >= N0:
-            return None
-        return (ni, sel_row(bp))
-
-    def _init_apply(arr, rec, sign: int) -> None:
-        ni, row = rec
-        if sign > 0:
-            arr[ni, : row.shape[0]] += row
-        else:
-            arr[ni, : row.shape[0]] -= row
+    def _init_apply(arr, ni: int, hits: np.ndarray, sign: int) -> None:
+        arr[ni, hits] += sign
 
     init_counts = sync_family(
         agg,
@@ -799,7 +793,8 @@ def encode_topology_spread(
         bound_map,
         changed_slots,
         make_arrays=lambda: np.zeros((n_padded, S), dtype=np.int32),
-        record_of=_init_record,
+        slot_of=slot_of,
+        contribution=_init_row,
         apply=_init_apply,
     ).copy()
 

@@ -34,7 +34,13 @@ import numpy as np
 
 from ksim_tpu.obs import TRACE
 from ksim_tpu.state import objcache
-from ksim_tpu.state.boundagg import NodeSlots, records_built, sync_family
+from ksim_tpu.state.boundagg import (
+    BoundContents,
+    NodeSlots,
+    records_built,
+    records_shared,
+    sync_family,
+)
 from ksim_tpu.state.podtable import ROW, Column, PodTable
 from ksim_tpu.state.resources import (
     BASE_RESOURCES,
@@ -230,14 +236,18 @@ class Featurizer:
         self._pending_changed: set[int] = set()
         self._agg: dict[str, Any] = {}
         # Shared per-pass bound-set diff (see boundagg.sync_family): one
-        # O(bound) comparison per pass instead of one per family.
+        # O(bound) comparison per pass instead of one per family — and
+        # the bound pods' content ids, maintained from it: what the
+        # families' contribution tables are keyed by.
         self._prev_bound: dict[int, JSON] = {}
         self._bound_gen = 0
+        self._contents = self._agg["__contents__"] = BoundContents()
         # Bound pods carrying volumes, maintained from the diff — the
         # volumes fast path needs "is ANY bound pod using volumes", and
         # re-scanning 15k+ bound pods per pass was the single largest
-        # steady-state featurize cost.
+        # steady-state featurize cost.  Asked once a content id.
         self._bound_vol_count = 0
+        self._vol_of: dict[int, bool] = {}
         # The persistent per-pod row table (state/podtable.py): every
         # pod-axis output is a gather from it, so a call's per-pod Python
         # runs only for pods it has not seen — and the raw request values
@@ -274,9 +284,16 @@ class Featurizer:
 
     @property
     def bound_records_built(self) -> int:
-        """Bound-pod records the additive families built, summed over
-        the families (state/boundagg.py ``records_built``)."""
+        """Bound-pod records for which an additive family ran its
+        contribution builder, summed over the families (state/boundagg.py
+        ``records_built``)."""
         return records_built(self._agg)
+
+    @property
+    def bound_records_shared(self) -> int:
+        """Bound-pod records that took their content's contribution
+        from a family's table, no builder run (``records_shared``)."""
+        return records_shared(self._agg)
 
     def slot_names(self) -> list[str]:
         """The current node-slot order, lowest slot first — the carry a
@@ -370,17 +387,25 @@ class Featurizer:
         self._bound_gen += 1
         added = [pid for pid in bound_map if pid not in prev]
         removed = [pid for pid in prev if pid not in bound_map]
+        from ksim_tpu.state.volumes import _pod_has_volumes
+
+        cid_of, vol_of = self._contents.of, self._vol_of
+        for pid in removed:
+            self._bound_vol_count -= vol_of[cid_of[pid]]
+        released = self._contents.sync(bound_map, added, removed)
+        for c in released:
+            del vol_of[c]
+        for pid in added:
+            c = cid_of[pid]
+            if c not in vol_of:
+                vol_of[c] = _pod_has_volumes(bound_map[pid])
+            self._bound_vol_count += vol_of[c]
         self._agg["__diff__"] = {
             "gen": self._bound_gen,
             "added": added,
             "removed": removed,
+            "released": released,
         }
-        from ksim_tpu.state.volumes import _pod_has_volumes
-
-        for pid in added:
-            self._bound_vol_count += _pod_has_volumes(bound_map[pid])
-        for pid in removed:
-            self._bound_vol_count -= _pod_has_volumes(prev[pid])
         self._prev_bound = bound_map
 
         # The pod axis: one identity lookup per pod, shared by every
@@ -435,13 +460,13 @@ class Featurizer:
         # Bound pods' raw request values as an incrementally-maintained
         # multiset per resource: the resource axis and exact gcd units
         # need every value that enters math, without an O(bound) walk.
-        def _resvals_record(p: JSON):
+        def _resvals(p: JSON):
             pairs = []
             for non_zero in (False, True):
                 for r, v in pod_requests(p, non_zero=non_zero).items():
                     if v:
                         pairs.append((r, v))
-            return (-1, tuple(pairs))
+            return tuple(pairs) or None
 
         bound_vals: dict[str, dict[int, int]] = sync_family(
             self._agg,
@@ -450,8 +475,9 @@ class Featurizer:
             bound_map,
             set(),  # node-independent
             make_arrays=dict,
-            record_of=_resvals_record,
-            apply=lambda counters, rec, sign: _vals_apply(counters, rec[1], sign),
+            slot_of=None,
+            contribution=_resvals,
+            apply=lambda counters, _slot, pairs, sign: _vals_apply(counters, pairs, sign),
         )
 
         def build_node_vals():
@@ -541,25 +567,24 @@ class Featurizer:
         # Masters accumulate in int64: per-value bounds don't bound the
         # SUM over bound pods; clamp (and drop exactness) on the copies
         # only if a sum overflows.
-        def _req_record(p: JSON):
+        def bound_slot(p: JSON) -> "int | None":
+            """The slot of a bound pod's node; None while the node is
+            not on the axis.  The same for every family with a slot."""
             ni = node_index.get(pod_node_name(p))
-            if ni is None or ni >= N:
-                return None
-            return (
-                ni,
-                (lower(pod_requests(p)), lower(pod_requests(p, non_zero=True))),
+            return None if ni is None or ni >= N else ni
+
+        # One master row a node — requests | non-zero requests | pods —
+        # so that a pod comes and goes by ONE row operation.
+        def _req_row(p: JSON) -> np.ndarray:
+            return np.concatenate(
+                (lower(pod_requests(p)), lower(pod_requests(p, non_zero=True)), (1,))
             )
 
-        def _req_apply(arrays, rec, sign: int) -> None:
-            ni, (row, nzrow) = rec
+        def _req_apply(master: np.ndarray, ni: int, row: np.ndarray, sign: int) -> None:
             if sign > 0:
-                arrays["req"][ni] += row
-                arrays["nz"][ni] += nzrow
-                arrays["cnt"][ni] += 1
+                master[ni] += row
             else:
-                arrays["req"][ni] -= row
-                arrays["nz"][ni] -= nzrow
-                arrays["cnt"][ni] -= 1
+                master[ni] -= row
 
         reqagg = sync_family(
             self._agg,
@@ -567,17 +592,14 @@ class Featurizer:
             (units_token, NP),
             bound_map,
             changed_slots,
-            make_arrays=lambda: {
-                "req": np.zeros((NP, R), dtype=np.int64),
-                "nz": np.zeros((NP, R), dtype=np.int64),
-                "cnt": np.zeros(NP, dtype=np.int32),
-            },
-            record_of=_req_record,
+            make_arrays=lambda: np.zeros((NP, 2 * R + 1), dtype=np.int64),
+            slot_of=bound_slot,
+            contribution=_req_row,
             apply=_req_apply,
         )
-        requested = reqagg["req"].copy()
-        nz_requested = reqagg["nz"].copy()
-        pod_count = reqagg["cnt"].copy()
+        requested = reqagg[:, :R].copy()
+        nz_requested = reqagg[:, R : 2 * R].copy()
+        pod_count = reqagg[:, 2 * R].astype(np.int32)
 
         if requested.max(initial=0) > MAX_EXACT_SCALED or nz_requested.max(initial=0) > MAX_EXACT_SCALED:
             exact = False
@@ -640,7 +662,7 @@ class Featurizer:
         aux["spread"] = encode_topology_spread(
             nodes, table, NP, PP,
             agg=self._agg, bound_map=bound_map,
-            changed_slots=changed_slots, slot_of=node_index,
+            changed_slots=changed_slots, slot_of=bound_slot,
             default_constraints=self._spread_defaults,
         )
         TRACE.stage("service.featurize.interpod")
@@ -648,7 +670,7 @@ class Featurizer:
             nodes, table, namespaces, NP, PP,
             hard_weight=self._interpod_hard_weight,
             agg=self._agg, bound_map=bound_map,
-            changed_slots=changed_slots, slot_of=node_index,
+            changed_slots=changed_slots, slot_of=bound_slot,
         )
         TRACE.stage("service.featurize.extras")
         aux["nodename"] = encode_node_name(nodes, table, PP)

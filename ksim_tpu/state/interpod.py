@@ -38,7 +38,7 @@ from __future__ import annotations
 
 import json
 from dataclasses import dataclass
-from typing import Any, Sequence
+from typing import Any, Callable, Sequence
 
 import numpy as np
 
@@ -252,13 +252,15 @@ def encode_inter_pod(
     agg: dict,
     bound_map: "dict[int, JSON]",
     changed_slots: "set[int]",
-    slot_of: "dict[str, int]",
+    slot_of: "Callable[[JSON], int | None]",
 ) -> InterPodTensors:
     """``agg`` is the Featurizer's persistent state (state/boundagg.py):
     the context/term/domain vocabularies persist append-only across
     calls — ids stay stable — and the existing-pod domain aggregates
     (match counts, required-anti counts, signed score weights) update by
-    delta over the bound population.  The match aggregate rebuilds when
+    delta over the bound population, what a pod's CONTENT adds (its
+    matched contexts, its mapped terms) looked up by content id and
+    joined with its node's domains.  The match aggregate rebuilds when
     the context vocabulary or namespace labels change (a new context can
     match pods that did not themselves change); the term aggregates only
     depend on each pod's own terms, so they survive vocabulary growth.
@@ -313,14 +315,12 @@ def encode_inter_pod(
     # ``ip_seen`` is the bound-set generation registered so far: on the
     # pass right after it only the arrivals of the featurizer's shared
     # diff are new; any gap (first call, a vocabulary reset) registers
-    # the whole bound set.
+    # the whole bound set.  One pod a content among them, the first: the
+    # others would register what it did.
     diff = agg["__diff__"]
-    if agg.get("ip_seen") == diff["gen"] - 1:
-        arrivals = [bound_map[pid] for pid in diff["added"]]
-    else:
-        arrivals = bound_map.values()
-    for p in arrivals:
-        terms_of(p)
+    arrivals = diff["added"] if agg.get("ip_seen") == diff["gen"] - 1 else bound_map
+    for pid in agg["__contents__"].firsts(arrivals):
+        terms_of(bound_map[pid])
     agg["ip_seen"] = diff["gen"]
 
     # Padded terms are inert: term_u/term_tk 0 with all-zero pod columns.
@@ -368,9 +368,6 @@ def encode_inter_pod(
         n_padded,
     )
 
-    node_index = slot_of
-    N0 = len(nodes)
-
     # Per-pod context-match rows span the final ctx vocab and depend on
     # the namespace labels: both are the table family's token below.
     U0 = len(vocab.ctxs)
@@ -389,19 +386,22 @@ def encode_inter_pod(
     # node missing the key contributes nowhere (no topologyPair exists —
     # upstream filtering.go only counts nodes that carry the key).
 
-    def _match_record(bp: JSON):
-        ni = node_index.get(bp.get("spec", {}).get("nodeName", ""))
-        if ni is None or ni >= N0:
-            return None
-        doms = [int(d) for d in node_dom[ni] if d >= 0]
-        row = match_row(bp)
-        uis = [int(ui) for ui in np.nonzero(row)[0]]
-        if not doms or not uis:
-            return (ni, ())
-        return (ni, tuple((d, ui) for ui in uis for d in doms))
+    # A contribution is what the pod's content says (the contexts it
+    # matches; its own terms); ``place`` joins the node's domains in, and
+    # the pod's record keeps the joined entries — a departure takes away
+    # what the arrival added, whatever the node's labels have become.
+    # A content that matches no context / has no term adds nothing on
+    # any node and never reads ``node_dom``.
 
-    def _match_apply(arr, rec, sign: int) -> None:
-        for d, ui in rec[1]:
+    def _match_ctxs(bp: JSON) -> "tuple[int, ...] | None":
+        return tuple(np.flatnonzero(match_row(bp)).tolist()) or None
+
+    def _match_place(ni: int, uis: "tuple[int, ...]"):
+        doms = [int(d) for d in node_dom[ni] if d >= 0]
+        return tuple((d, ui) for ui in uis for d in doms) or None
+
+    def _match_apply(arr, _ni: int, entries, sign: int) -> None:
+        for d, ui in entries:
             arr[d, ui] += sign
 
     match_dom = sync_family(
@@ -411,38 +411,30 @@ def encode_inter_pod(
         bound_map,
         changed_slots,
         make_arrays=lambda: np.zeros((D, U), dtype=np.int32),
-        record_of=_match_record,
+        slot_of=slot_of,
+        contribution=_match_ctxs,
+        place=_match_place,
         apply=_match_apply,
     )
 
-    def _terms_record(bp: JSON):
-        ni = node_index.get(bp.get("spec", {}).get("nodeName", ""))
-        if ni is None or ni >= N0:
-            return None
+    def _own_terms(bp: JSON):
+        """(topology-key id, term, ranti delta, ew delta) a term."""
         terms = terms_of(bp)
-        doms = node_dom[ni]
-        entries = []  # (d, t, ranti_delta, ew_delta)
-        for t, _u, _w in terms["req_anti"]:
-            d = doms[term_tk[t]]
-            if d >= 0:
-                entries.append((int(d), t, 1, 0))
-        for t, _u, _w in terms["req_aff"]:
-            d = doms[term_tk[t]]
-            if d >= 0:
-                entries.append((int(d), t, 0, hard_weight))
-        for t, _u, w in terms["pref_aff"]:
-            d = doms[term_tk[t]]
-            if d >= 0:
-                entries.append((int(d), t, 0, w))
-        for t, _u, w in terms["pref_anti"]:
-            d = doms[term_tk[t]]
-            if d >= 0:
-                entries.append((int(d), t, 0, -w))
-        return (ni, tuple(entries))
+        own = [(t, 1, 0) for t, _u, _w in terms["req_anti"]]
+        own += [(t, 0, hard_weight) for t, _u, _w in terms["req_aff"]]
+        own += [(t, 0, w) for t, _u, w in terms["pref_aff"]]
+        own += [(t, 0, -w) for t, _u, w in terms["pref_anti"]]
+        return tuple((int(term_tk[t]), t, dr, dw) for t, dr, dw in own) or None
 
-    def _terms_apply(arrays, rec, sign: int) -> None:
+    def _terms_place(ni: int, own):
+        doms = node_dom[ni]
+        return tuple(
+            (int(doms[tk]), t, dr, dw) for tk, t, dr, dw in own if doms[tk] >= 0
+        ) or None
+
+    def _terms_apply(arrays, _ni: int, entries, sign: int) -> None:
         ranti, ew = arrays
-        for d, t, dr, dw in rec[1]:
+        for d, t, dr, dw in entries:
             if dr:
                 ranti[d, t] += sign * dr
             if dw:
@@ -458,7 +450,9 @@ def encode_inter_pod(
             np.zeros((D, T), dtype=np.int32),
             np.zeros((D, T), dtype=np.int32),
         ),
-        record_of=_terms_record,
+        slot_of=slot_of,
+        contribution=_own_terms,
+        place=_terms_place,
         apply=_terms_apply,
     )
 

@@ -391,3 +391,311 @@ def test_persistent_featurizer_matches_fresh_per_family(replayed_segments, famil
                     # Node indices: through the slot permutation.
                     vb = np.where(vb >= 0, perm[np.maximum(vb, 0)], vb).astype(vb.dtype)
                 _assert_same_arrays(f"{where}.{f.name}", va, vb, perm, n, axis)
+
+
+# -- the five additive families against a per-pod loop ------------------------
+#
+# What a bound pod adds to an aggregate is (slot of its node, contribution of
+# its content); ``sync_family`` builds the contribution once a distinct
+# manifest and looks it up for every other pod that shares it.  The oracle
+# below is the loop the families were before that: one record a pod, parsed
+# from THAT pod, applied to arrays made afresh — against the vocabularies and
+# the node order the featurizer under test ended its call with.
+
+BOUND_FAMILIES = ("resvals", "requested", "spread_init", "ip_match", "ip_terms")
+_ZONES = ("az-1", "az-2", "az-3")
+
+
+def _zone_node(name: str, zone: str) -> dict:
+    node = make_node(name, cpu="16", memory="32Gi")
+    node["metadata"]["labels"] = {"zone": zone, "kubernetes.io/hostname": name}
+    return node
+
+
+def _spread(app: str) -> list:
+    return [{"maxSkew": 1, "topologyKey": "zone", "whenUnsatisfiable": "DoNotSchedule",
+             "labelSelector": {"matchLabels": {"app": app}}}]
+
+
+def _anti(app: str, key: str = "kubernetes.io/hostname") -> dict:
+    return {"podAntiAffinity": {"requiredDuringSchedulingIgnoredDuringExecution": [
+        {"topologyKey": key, "labelSelector": {"matchLabels": {"app": app}}}]}}
+
+
+def _prefer(app: str, weight: int) -> dict:
+    return {"podAffinity": {"preferredDuringSchedulingIgnoredDuringExecution": [
+        {"weight": weight, "podAffinityTerm": {
+            "topologyKey": "zone", "namespaces": ["default", "batch"],
+            "labelSelector": {"matchLabels": {"app": app}}}}]}}
+
+
+#: template -> the keyword arguments of its replicas (``make_pod``).
+_TEMPLATES = {
+    "web": dict(cpu="250m", memory="256Mi", labels={"app": "web"},
+                topology_spread_constraints=_spread("web")),
+    "db": dict(cpu="1", memory="1Gi", labels={"app": "db"}, affinity=_anti("db")),
+    "cache": dict(cpu="100m", memory="64Mi", labels={"app": "cache"},
+                  affinity=_prefer("web", 30)),
+    "plain": dict(cpu="500m", memory="128Mi", labels={"app": "plain"}),
+    "batch-web": dict(cpu="250m", memory="256Mi", labels={"app": "web"}, namespace="batch"),
+}
+
+
+def _replica(template: str, i: int, node: str) -> dict:
+    """Replica ``i`` of a template on ``node``: name, uid, node and status
+    are its own, everything else is the template's."""
+    pod = make_pod(f"{template}-{i}", node_name=node, phase="Running", **_TEMPLATES[template])
+    pod["metadata"]["uid"] = f"uid-{template}-{i}"
+    pod["status"]["podIP"] = f"10.0.{len(template)}.{i}"
+    pod["status"]["startTime"] = f"2024-01-01T00:00:{i:02d}Z"
+    return pod
+
+
+def _cluster(seed: int):
+    rng = random.Random(seed)
+    nodes = [_zone_node(f"n{i}", _ZONES[i % 3]) for i in range(7)]
+    bound = []
+    for template in _TEMPLATES:
+        for i in range(rng.randint(3, 6)):
+            bound.append(_replica(template, i, f"n{rng.randrange(7)}"))
+    # Manifests nobody shares, one of them with an extended resource ...
+    for i in range(4):
+        bound.append(make_pod(
+            f"solo-{i}", cpu=f"{150 + i}m", memory="96Mi", node_name=f"n{rng.randrange(7)}",
+            labels={"app": rng.choice(["web", "db"]), "solo": str(i)}, phase="Running",
+            extra_requests={"example.com/gpu": "2"} if i == 0 else None))
+    # ... and two replicas on a node that does not exist.
+    bound += [_replica("web", 90, "ghost"), _replica("db", 91, "ghost")]
+    rng.shuffle(bound)
+    queue = [
+        make_pod("q-web", labels={"app": "web"}, topology_spread_constraints=_spread("web")),
+        make_pod("q-db", labels={"app": "db"}, affinity=_anti("db")),
+        make_pod("q-cache", labels={"app": "cache"}, affinity=_prefer("web", 30)),
+        make_pod("q-batch", labels={"app": "web"}, namespace="batch",
+                 topology_spread_constraints=_spread("web"), affinity=_anti("web", "zone")),
+    ]
+    namespaces = [
+        {"metadata": {"name": "default", "labels": {"team": "a"}}},
+        {"metadata": {"name": "batch", "labels": {"team": "b"}}},
+    ]
+    return nodes, bound, queue, namespaces
+
+
+def _per_pod_arrays(family: str, f: Featurizer, feats, bound: list, namespaces: list):
+    """The family's arrays by the loop that was there before: a record a pod."""
+    from ksim_tpu.state.interpod import context_matches, parsed_terms
+    from ksim_tpu.state.resources import labels_of, namespace_of, pod_requests
+    from ksim_tpu.state.selectors import match_label_selector
+
+    agg = f._agg
+    slot_of = {nm: i for i, nm in enumerate(feats.nodes.names)}
+    shape_of = lambda a: (a.shape, a.dtype)
+
+    def on_axis(pod) -> "int | None":
+        return slot_of.get(pod["spec"].get("nodeName", ""))
+
+    if family == "resvals":
+        counters: dict = {}
+        for pod in bound:
+            for non_zero in (False, True):
+                for r, v in pod_requests(pod, non_zero=non_zero).items():
+                    if v:
+                        c = counters.setdefault(r, {})
+                        c[v] = c.get(v, 0) + 1
+        return counters
+
+    if family == "requested":
+        # One master row a node: requests | non-zero requests | pods.
+        out = np.zeros(*shape_of(agg["requested"]["arrays"]))
+        ridx = {r: i for i, r in enumerate(feats.resources)}
+        R = len(ridx)
+        assert out.shape[1] == 2 * R + 1
+
+        def lower(d):
+            row = np.zeros(len(ridx), dtype=np.int64)
+            for r, v in d.items():
+                if r in ridx:
+                    u = feats.units[r]
+                    row[ridx[r]] = v // u if v % u == 0 else -(-v // u)
+            return row
+
+        for pod in bound:
+            ni = on_axis(pod)
+            if ni is not None:
+                out[ni, :R] += lower(pod_requests(pod))
+                out[ni, R : 2 * R] += lower(pod_requests(pod, non_zero=True))
+                out[ni, 2 * R] += 1
+        return out
+
+    if family == "spread_init":
+        out = np.zeros(*shape_of(agg["spread_init"]["arrays"]))
+        for pod in bound:
+            ni = on_axis(pod)
+            if ni is None:
+                continue
+            for s, (ns, sel) in enumerate(agg["spread_sels"]["list"]):
+                out[ni, s] += (namespace_of(pod) or "default") == ns and match_label_selector(
+                    sel, labels_of(pod))
+        return out
+
+    vocab = agg["ip_vocab"]
+    node_dom = feats.aux["interpod"].node_dom
+    ns_labels = {ns["metadata"]["name"]: ns["metadata"]["labels"] for ns in namespaces}
+    if family == "ip_match":
+        out = np.zeros(*shape_of(agg["ip_match"]["arrays"]))
+        for pod in bound:
+            ni = on_axis(pod)
+            if ni is None:
+                continue
+            for ui, ctx in enumerate(vocab.ctxs):
+                if context_matches(ctx, pod, ns_labels):
+                    for d in node_dom[ni]:
+                        if d >= 0:
+                            out[d, ui] += 1
+        return out
+
+    assert family == "ip_terms"
+    ranti, ew = (np.zeros(*shape_of(a)) for a in agg["ip_terms"]["arrays"])
+    signed = {"req_anti": (1, 0, 0), "req_aff": (0, f._interpod_hard_weight, 0),
+              "pref_aff": (0, 0, 1), "pref_anti": (0, 0, -1)}
+    for pod in bound:
+        ni = on_axis(pod)
+        if ni is None:
+            continue
+        for fam, items in parsed_terms(pod).items():
+            dr, hard, sign = signed[fam]
+            for _ctx, ck, tk, w in items:
+                tki = vocab.tk_ids[tk]
+                t = vocab.term_ids[(vocab.ctx_ids[ck], tki)]
+                d = node_dom[ni][tki]
+                if d >= 0:
+                    ranti[d, t] += dr
+                    ew[d, t] += hard + sign * w
+    return ranti, ew
+
+
+def _assert_family(family: str, f: Featurizer, feats, bound, namespaces, where: str):
+    got = f._agg[family]["arrays"]
+    want = _per_pod_arrays(family, f, feats, bound, namespaces)
+    if family == "resvals":
+        assert got == want, where
+        return
+    if isinstance(got, np.ndarray):
+        got, want = [got], [want]
+    assert any(a.any() for a in want), f"{where}: the oracle adds nothing, the case is vacuous"
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(a, b, err_msg=f"{where} {family}")
+
+
+@pytest.mark.parametrize("family", BOUND_FAMILIES)
+def test_bound_family_by_content_equals_the_per_pod_loop(family):
+    nodes, bound, queue, namespaces = _cluster(11)
+    f = Featurizer()
+    state = {}
+
+    def call(where: str):
+        feats = f.featurize(list(nodes), (), queue_pods=list(queue), bound_pods=list(bound),
+                            namespaces=namespaces)
+        _assert_family(family, f, feats, bound, namespaces, where)
+        state["tokens"] = {fam: f._agg[fam]["token"] for fam in BOUND_FAMILIES}
+        return feats
+
+    call("cold")
+    # From scratch, a builder ran once a distinct manifest and family: the
+    # replicas (the two on the ghost node too) took their template's.
+    contents = len(f._contents)
+    assert contents == len(_TEMPLATES) + 4
+    assert f.bound_records_built <= len(BOUND_FAMILIES) * contents
+    assert f.bound_records_built + f.bound_records_shared == len(BOUND_FAMILIES) * len(bound)
+
+    # Departures of pods whose contribution was shared; every replica of
+    # ``plain`` goes, so its content id goes back ...
+    gone = [p for p in bound if p["metadata"]["name"].startswith("plain-")]
+    gone += [p for p in bound if p["metadata"]["name"] in ("web-0", "db-1", "cache-0", "solo-2")]
+    plain_id = f._contents.of[id(gone[0])]
+    bound = [p for p in bound if not any(p is g for g in gone)]
+    call("departures")
+    assert plain_id not in f._contents.of.values()
+    # ... and the next new content holds it: the tables must not hand the
+    # arrival what ``plain`` added.
+    fresh = make_pod("fresh-0", cpu="2", memory="3Gi", node_name="n1", phase="Running",
+                     labels={"app": "db"}, affinity=_anti("db", "zone"),
+                     topology_spread_constraints=_spread("db"))
+    bound = bound + [fresh, _replica("web", 7, "n2"), _replica("plain", 8, "n2")]
+    built0 = f.bound_records_built
+    call("arrivals")
+    assert f._contents.of[id(fresh)] == plain_id
+    # web-7 shares with the live web replicas; fresh-0 and the returning
+    # ``plain`` are new contents: at most two builders a family ran.
+    assert f.bound_records_built - built0 <= 2 * len(BOUND_FAMILIES)
+
+    # A slot repair: a node replaced by one in another zone (and the ghost
+    # node appears, so the pods that waited for it start to count).
+    nodes[2] = _zone_node("n2", "az-9")
+    nodes.append(_zone_node("ghost", "az-1"))
+    tokens = dict(state["tokens"])
+    call("slot repair")
+    assert state["tokens"]["requested"] == tokens["requested"]
+
+    # Token moves: a selector and a context the vocabularies have not seen
+    # (``spread_init`` / ``ip_match``), relabelled namespaces (``ip_match``),
+    # an extended resource on a new pod (``requested``).
+    queue.append(make_pod("q-new", labels={"app": "cache"}, namespace="batch",
+                          topology_spread_constraints=_spread("cache"),
+                          affinity=_anti("cache", "zone")))
+    namespaces[1] = {"metadata": {"name": "batch", "labels": {"team": "c"}}}
+    bound = bound + [make_pod("fpga-0", cpu="1", memory="1Gi", node_name="n0", phase="Running",
+                              labels={"app": "web"}, extra_requests={"example.com/fpga": "1"})]
+    call("token move")
+    moved = {fam for fam in BOUND_FAMILIES if state["tokens"][fam] != tokens[fam]}
+    assert moved >= {"requested", "spread_init", "ip_match"}
+    # The content ids outlive every token.
+    assert len(f._contents) == len(set(f._contents.of.values()))
+    call("steady")
+
+
+def test_content_id_is_shared_by_replicas_and_by_nothing_else():
+    from ksim_tpu.state.boundagg import BoundContents, content_key
+
+    base = _replica("db", 0, "n0")
+    twin = _replica("db", 1, "n4")
+    twin["metadata"]["resourceVersion"] = "77"
+    twin["status"]["phase"] = "Pending"
+    assert base["metadata"]["name"] != twin["metadata"]["name"]
+    assert base["metadata"]["uid"] != twin["metadata"]["uid"]
+    assert base["spec"]["nodeName"] != twin["spec"]["nodeName"]
+    assert base["status"] != twin["status"]
+    apart = {}
+    for field in ("label", "request", "namespace", "term", "annotation"):
+        pod = apart[field] = copy.deepcopy(base)
+        if field == "label":
+            pod["metadata"]["labels"]["tier"] = "x"
+        elif field == "request":
+            pod["spec"]["containers"][0]["resources"]["requests"]["cpu"] = "1001m"
+        elif field == "namespace":
+            pod["metadata"]["namespace"] = "batch"
+        elif field == "term":
+            term = pod["spec"]["affinity"]["podAntiAffinity"]
+            term["requiredDuringSchedulingIgnoredDuringExecution"][0]["topologyKey"] = "zone"
+        else:  # a field nothing reads: a deny-list shares only what it names
+            pod["metadata"]["annotations"] = {"note": "x"}
+    pods = [base, twin, *apart.values()]
+    contents = BoundContents()
+    bound_map = {id(p): p for p in pods}
+    assert contents.sync(bound_map, list(bound_map), []) == []
+    ids = [contents.of[id(p)] for p in pods]
+    assert ids[0] == ids[1]
+    assert len(set(ids)) == 1 + len(apart) == len(contents)
+    assert content_key(base) == content_key(twin)
+    # The inputs keep what the key leaves out.
+    assert base["spec"]["nodeName"] == "n0" and "status" in base and "name" in base["metadata"]
+    # One replica leaves: the id stays with the other; both gone: it goes
+    # back, and the next content takes it.
+    assert contents.sync({id(twin): twin}, [], [id(p) for p in pods if p is not twin]) == sorted(
+        set(ids) - {ids[0]}, key=ids.index)
+    assert contents.of == {id(twin): ids[0]} and len(contents) == 1
+    assert contents.sync({}, [], [id(twin)]) == [ids[0]]
+    other = apart["label"]
+    contents.sync({id(other): other}, [id(other)], [])
+    assert contents.of[id(other)] in ids and len(contents) == 1
+    assert contents.firsts([id(other)]) == [id(other)]

@@ -9,7 +9,8 @@ The document is the benchmark's own generator kind
 15,000 bound pods, 500 arrivals: k = 230 of 500); the witness is the benchmark's
 plain reference ``benchmark/references/snapshot_zoned.py``, which imports
 nothing of the program.  Pinned here besides: the result's ``snapshot`` block,
-and ``replay.featurize_bound_records`` (families x bound pods, once).
+and ``replay.featurize_bound_records`` + ``replay.featurize_bound_shared``
+(together families x bound pods, once; builders run for the distinct manifests).
 """
 
 from __future__ import annotations
@@ -98,6 +99,15 @@ def test_the_job_equals_the_plain_reference_started_from_the_snapshot(bench, reh
     assert {k: replay[k] for k in equals} == equals
     # The walks pass full nodes and the zones the constraint rules out.
     assert want["nodes_visited"] > want["nodes_scored"] and want["sampling_zones"] == 3
+    # The rehearsal's digest, as it was before the bound families went by
+    # content; and the one cold lowering met every bound pod in every family,
+    # running a builder for the distinct manifests only.
+    digest = replay["placements_digest"]
+    assert (digest[:8], digest[-4:]) == ("c0ec54b0", "3575")
+    document = json.loads(inputs["body"])["spec"]["simulator"]["initialSnapshot"]
+    assert replay["featurize_bound_records"] + replay["featurize_bound_shared"] == FAMILIES * 15000
+    assert 0 < replay["featurize_bound_records"] <= FAMILIES * distinct_contents(document["pods"])
+    assert replay["featurize_bound_shared"] >= FAMILIES * 15000 * 0.8
 
 
 def test_a_scheduler_that_sees_an_empty_cluster_is_another_scheduler(bench, rehearsal, served):
@@ -175,6 +185,19 @@ def test_a_job_resumed_from_a_checkpoint_loads_nothing_again(bench, rehearsal, t
     assert resumed["result"]["podsScheduled"] == whole["result"]["podsScheduled"] == 20
 
 
+def distinct_contents(pods: list) -> int:
+    """Manifests that differ once who the pod is (name, uid), where it runs
+    (``spec.nodeName``) and its ``status`` are taken out: derived here from the
+    generator's pods, not from the program's key."""
+    seen = set()
+    for pod in pods:
+        meta = {k: v for k, v in pod["metadata"].items() if k not in ("name", "uid")}
+        spec = {k: v for k, v in pod["spec"].items() if k != "nodeName"}
+        rest = {k: v for k, v in pod.items() if k not in ("metadata", "spec", "status")}
+        seen.add(json.dumps([meta, spec, rest], sort_keys=True))
+    return len(seen)
+
+
 def small_cluster(bench, rehearsal) -> "tuple[dict, list]":
     """120 nodes x 8-12 pods (1,200 bound, 90 of them earlier replicas) and a
     rollout of 40, from the kind's own code."""
@@ -186,9 +209,11 @@ def small_cluster(bench, rehearsal) -> "tuple[dict, list]":
 
 
 def test_bound_records_are_families_times_bound_pods_once(bench, rehearsal):
-    """Windows of one step: the first lowering builds one record a bound pod
-    and family; the second meets the 40 pods the first window bound; the third
-    and fourth meet nothing new (their pods fit nowhere), and build nothing."""
+    """Windows of one step: the first lowering meets every bound pod once a
+    family; the second meets the 40 pods the first window bound; the third and
+    fourth meet nothing new (their pods fit nowhere).  A record either RAN its
+    family's builder (``bound_records``: at most once a distinct manifest and
+    family) or took its content's contribution (``bound_shared``)."""
     snapshot, scenario = small_cluster(bench, rehearsal)
     huge = lambda step, name: {"step": step, "createOperation": {"object": {
         "apiVersion": "v1", "kind": "Pod", "metadata": {"name": name, "namespace": "default"},
@@ -202,6 +227,16 @@ def test_bound_records_are_families_times_bound_pods_once(bench, rehearsal):
     driver = runner.replay_driver
     assert driver.fallback_steps == 0 and not driver.unsupported, driver.unsupported
     assert (result.pods_scheduled, len(result.steps)) == (40, 4)
-    log = [entry["bound_records"] for entry in driver.lower_log]
-    assert log == [FAMILIES * 1200, FAMILIES * 40, 0, 0]
-    assert driver.stats()["featurize_bound_records"] == sum(log) == FAMILIES * 1240
+    built = [entry["bound_records"] for entry in driver.lower_log]
+    shared = [entry["bound_shared"] for entry in driver.lower_log]
+    assert [b + s for b, s in zip(built, shared)] == [FAMILIES * 1200, FAMILIES * 40, 0, 0]
+    stats = driver.stats()
+    assert stats["featurize_bound_records"] == sum(built)
+    assert stats["featurize_bound_shared"] == sum(shared)
+    assert sum(built) + sum(shared) == FAMILIES * 1240
+    # The 40 arrivals are replicas of the scaled Deployment, whose earlier
+    # replicas the snapshot holds: the second window runs no builder at all.
+    contents = distinct_contents(
+        snapshot["pods"] + [op["createOperation"]["object"] for op in scenario])
+    assert contents < 1200 and 0 < built[0] <= FAMILIES * contents
+    assert built[1:] == [0, 0, 0] and shared[1] == FAMILIES * 40

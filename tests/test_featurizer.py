@@ -335,3 +335,65 @@ def test_identity_apart_shares_every_row_and_keeps_its_own_key():
     assert out.pods.keys == ["team-a/a", "team-a/b"]
     want = Featurizer().featurize(nodes, (), queue_pods=[_full_pod("a"), _full_pod("b")])
     _assert_equal(out, want)
+
+
+def test_cold_call_over_bound_replicas_registers_the_interpod_vocabulary_in_walk_order():
+    """The bound side registers one pod a content (the first); the contexts,
+    terms and topology keys must still get the ids a walk over EVERY bound pod
+    gives them — a later replica registers nothing its template's first did
+    not.  Three templates whose replicas interleave, the second one's first
+    replica behind a replica of the third."""
+    from ksim_tpu.state.interpod import parsed_terms
+
+    def term(app: str, key: str) -> dict:
+        return {"topologyKey": key, "labelSelector": {"matchLabels": {"app": app}}}
+
+    templates = {
+        "a": {"podAntiAffinity": {"requiredDuringSchedulingIgnoredDuringExecution": [
+            term("a", "kubernetes.io/hostname"), term("b", "zone")]}},
+        "b": {"podAffinity": {"preferredDuringSchedulingIgnoredDuringExecution": [
+            {"weight": 5, "podAffinityTerm": term("a", "zone")}]}},
+        "c": {"podAffinity": {"requiredDuringSchedulingIgnoredDuringExecution": [
+            term("c", "rack"), term("a", "kubernetes.io/hostname")]}},
+    }
+    order = ["c", "a", "c", "b", "a", "b", "c", "a"]
+    nodes = [make_node(f"n{i}", labels={"zone": f"z{i % 2}", "rack": f"r{i}",
+                                        "kubernetes.io/hostname": f"n{i}"}) for i in range(3)]
+    bound = [make_pod(f"{t}-{i}", labels={"app": t}, affinity=templates[t],
+                      node_name=f"n{i % 3}", phase="Running") for i, t in enumerate(order)]
+    feat = Featurizer()
+    out = feat.featurize(nodes, (), queue_pods=[make_pod("q", labels={"app": "a"})],
+                         bound_pods=bound)
+    assert len(feat._contents) == 3
+
+    # The per-pod walk, written out: first appearance over every pod's terms.
+    ctxs, tks, terms = [], [], []
+    for pod in bound:
+        for items in parsed_terms(pod).values():
+            for _ctx, ck, tk, _w in items:
+                if ck not in ctxs:
+                    ctxs.append(ck)
+                if tk not in tks:
+                    tks.append(tk)
+                if (ctxs.index(ck), tks.index(tk)) not in terms:
+                    terms.append((ctxs.index(ck), tks.index(tk)))
+    vocab = feat._agg["ip_vocab"]
+    assert list(vocab.ctx_ids) == ctxs and list(vocab.tk_ids) == tks and vocab.terms == terms
+    # Pinned: c's two terms first, then a's second (its first is c's second), then b's.
+    assert tks == ["rack", "kubernetes.io/hostname", "zone"]
+    assert terms == [(0, 0), (1, 1), (2, 2), (1, 2)]
+    ip = out.aux["interpod"]
+    assert ip.term_u[:4].tolist() == [0, 1, 2, 1] and ip.term_tk[:4].tolist() == [0, 1, 2, 2]
+    # What the eight pods add, by hand: required anti-affinity counts (a's two
+    # terms; its three replicas all stand on n1, the one node of zone z1) ...
+    n = len(nodes)
+    assert ip.ecnt_node[:n, 1].tolist() == [0, 3, 0]
+    assert ip.ecnt_node[:n, 2].tolist() == [0, 3, 0]
+    # ... and signed weights: c's required terms at the hard weight 1 (pods on
+    # n0, n2, n0: racks and hostnames apart), b's preference 5 a replica (n0
+    # and n2: both zone z0).
+    assert ip.ew_node[:n, 0].tolist() == [2, 0, 1]
+    assert ip.ew_node[:n, 1].tolist() == [2, 0, 1]
+    assert ip.ew_node[:n, 3].tolist() == [10, 0, 10]
+    _assert_equal(out, Featurizer().featurize(
+        nodes, (), queue_pods=[make_pod("q", labels={"app": "a"})], bound_pods=copy.deepcopy(bound)))
