@@ -102,11 +102,26 @@ orders the walk and nothing else: equal totals still go by the rank
 tensor, the per-pass featurizer's slot order.  A universe that does
 not sample lowers the program it always lowered.
 
+A fourth since PR 49: **volumes**.  A pod whose claims are bound
+(claim -> ``spec.volumeName`` -> PV) or that names a disk directly runs
+the four volume filters inside its slot (plugins/volumes.py over the
+encoding of state/volumes.py: PV rows by class, a pool count for a
+volume one pod alone uses, a column only for what two pods share), and
+the plugins' counted carries ride the window's state beside
+``requested`` (``_SegmentStatics.volumes``; state keys ``vc.<plugin>.
+<leaf>``): a bind adds the pod's ``carry_rows``, a pod delete takes them
+off, a node that goes takes its rows along.  PersistentVolumes, claims
+and StorageClasses may stand in the store or be created by a step,
+before the pods of that step that name them.  A universe none of whose
+pods reads a volume — whatever ``emptyDir`` / ``configMap`` /
+``projected`` volumes they carry — lowers the program it always lowered.
+
 Segments shorter than the compiled K (stream tails, mid-window
 vocabulary misses) are tail-padded with inactive no-op steps and reuse
 the existing compile.  Anything outside the remaining vocabulary
-(patch/update ops, pods with host ports / volumes / scheduling gates,
-extenders, multiple profiles, node images, inexact unit scaling, ...)
+(patch/update ops, pods with host ports or scheduling gates, claims
+the scheduler itself would have to bind, extenders, multiple profiles,
+node images, inexact unit scaling, ...)
 makes ``lower()`` return None and the segment falls back to the
 per-pass path, so coverage can grow incrementally without risking the
 locks.
@@ -159,8 +174,11 @@ FALLBACK_REASONS: frozenset[str] = frozenset(
         "queue_hooks", "permit_waiters", "plugin_extender",
         # object vocabulary misses
         "scheduling_gates", "foreign_scheduler", "terminal_phase",
-        "host_ports", "volumes", "volume_objects", "node_images",
+        "host_ports", "node_images",
         "create_bound_pod", "bound_to_unknown_node", "inexact_units",
+        # volumes the segment program does not carry (each names its case)
+        "ephemeral_volume_claim", "unbound_wffc_claim",
+        "volume_victim_search", "volume_object_order", "volume_name_reuse",
         # stream-shape misses
         "pod_name_reuse", "backoff_name_reuse", "node_name_reuse",
         "delete_unknown_pod", "delete_unknown_node",
@@ -179,6 +197,13 @@ FALLBACK_REASONS: frozenset[str] = frozenset(
 #: Dynamic reason families (``op:<op>/<kind>``, ``host_hook:<attr>``) —
 #: prefix-matched by the registry-sync test.
 FALLBACK_REASON_PREFIXES: tuple[str, ...] = ("op:", "host_hook:")
+
+#: The three volume kinds, by the featurizer argument each feeds.
+_VOLUME_ARGS = (
+    ("pvs", "persistentvolumes"),
+    ("pvcs", "persistentvolumeclaims"),
+    ("storage_classes", "storageclasses"),
+)
 
 # Steps batched per device dispatch.  The dispatch-latency win scales
 # with K; lowering/reconcile host work amortizes over it.  8-32 is the
@@ -396,10 +421,14 @@ class _WindowSpec:
     err_reason: str | None = None
     steps: list[_StepParse] = field(default_factory=list)
     # (step, kind, key) store-membership checks, in op order; kind in
-    # {"create_pod", "delete_pod", "create_node", "delete_node"}.
+    # {"create_pod", "delete_pod", "create_node", "delete_node",
+    # "create_volume" (key "<kind>/<name>")}.
     checks: list[tuple[int, str, str]] = field(default_factory=list)
     created_pods: list[tuple[int, str, JSON]] = field(default_factory=list)
     created_nodes: list[tuple[int, JSON]] = field(default_factory=list)
+    # (step, kind, "namespace/name" or name, object) of the PersistentVolumes,
+    # claims and StorageClasses the window creates.
+    created_volumes: list[tuple[int, str, str, JSON]] = field(default_factory=list)
 
 
 # ---------------------------------------------------------------------------
@@ -497,6 +526,12 @@ class _SegmentStatics:
     # of the node axis a slot, ``sample_visited_at``: node churn has
     # moved the two orders apart).
     sample: int = 0
+    # Whether a pod of the universe reads a volume: the volume plugins'
+    # counted carries (``carry_rows``) then ride the window's state
+    # (keys ``vc.<plugin>.<leaf>``) through pod deletes and node events,
+    # and the steps report ``vatt`` / ``vrej`` / ``vheld`` / ``vhead``.
+    # False lowers the program every volume-free window lowered.
+    volumes: bool = False
     tp: int = 1  # node-axis mesh width (round 17 sharded replay)
     # Round 19: the vmap axis name the fleet program maps lanes over, or
     # None for a solo program.  With it set, the preemption-search gate
@@ -622,6 +657,35 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
         nm_keys += ("nm_sel", "nm_qm", "nm_eat", "nm_vw")
         nm_rows.update(nm_sel=sel_rows, nm_qm=qm_rows, nm_eat=eat_rows, nm_vw=vw_rows)
 
+    # Volumes (st.volumes): the plugins whose carry is LINEAR in the pods
+    # bound to a node (``carry_rows``: NodeVolumeLimits, VolumeRestrictions
+    # and the one-pool legacy names) — per plugin its per-pod rows, each
+    # leaf carried through the window as ``vc.<plugin>.<leaf>`` — and which
+    # of the filter chain's verdicts are a volume filter's.
+    vol_rows = {}
+    vol_filters = ()
+    vol_summary = None
+    if st.volumes:
+        vol_rows = {
+            sp.plugin.name: sp.plugin.carry_rows(aux)
+            for sp in prog.plugins
+            if hasattr(sp.plugin, "carry_rows")
+        }
+        enabled = [sp.plugin for sp in prog.plugins if sp.filter_enabled]
+        vol_filters = tuple(
+            i for i, pl in enumerate(enabled) if getattr(pl, "volume_family", False)
+        )
+        vol_summary = next(
+            (sp.plugin for sp in prog.plugins if hasattr(sp.plugin, "attach_summary")),
+            None,
+        )
+
+    def _vol_leaves():
+        """(state key, the plugin's per-pod rows [P, X]) per carried leaf."""
+        for pn, rows in vol_rows.items():
+            for leaf, table in rows.items():
+                yield f"vc.{pn}.{leaf}", table
+
     if st.preempt:
         # What a search reads of a node's lower-priority pods, each a
         # column of the pod axis: its requests (one a resource), its
@@ -729,6 +793,11 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
         )
         s["ip_eat"] = s["ip_eat"].at[tgt].add(-eat_rows[safe], mode="drop")
         s["ip_vw"] = s["ip_vw"].at[tgt].add(-vw_rows[safe], mode="drop")
+        for key, table in _vol_leaves():
+            # A deleted pod detaches: its rows come off its node's.
+            s[key] = s[key].at[tgt].add(
+                -table[safe].astype(s[key].dtype), mode="drop"
+            )
         gone = jnp.where(v, pdel, P)
         s["alive"] = s["alive"].at[gone].set(False, mode="drop")
         s["bound"] = s["bound"].at[gone].set(-1, mode="drop")
@@ -753,6 +822,10 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
         s["ip_cnt"] = jnp.where(keep[:, None], s["ip_cnt"], 0)
         s["ip_eat"] = jnp.where(keep[:, None], s["ip_eat"], 0)
         s["ip_vw"] = jnp.where(keep[:, None], s["ip_vw"], 0)
+        for key, _table in _vol_leaves():
+            # A node that goes takes its attachments along (a node that
+            # comes has a slot of its own and starts with none).
+            s[key] = jnp.where(keep[:, None], s[key], 0)
         if st.preempt:
             # A node that goes takes the nominations onto it along (the
             # store keeps the dead name on the pod; it counts for
@@ -1256,6 +1329,22 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
         )
         return nstate, pcarries, live, jnp.sum(cleared.astype(jnp.int32)).astype(jnp.int32)
 
+    def _vol_state_summary(s):
+        """``vheld`` / ``vhead`` of a step's outputs: the attachments the
+        live nodes hold and the smallest limit-less-attached, read from
+        the carried state (``NodeVolumeLimits.attach_summary``)."""
+        if vol_summary is None:
+            return {"vheld": jnp.int32(0), "vhead": jnp.int32(_I32_MAX)}
+        carry = {
+            leaf: s[f"vc.{vol_summary.name}.{leaf}"]
+            for leaf in vol_rows[vol_summary.name]
+        }
+        got = vol_summary.attach_summary(carry, aux, s["valid"])
+        return {
+            "vheld": got["attached"].astype(jnp.int32),
+            "vhead": got["headroom"].astype(jnp.int32),
+        }
+
     def step(carry, ev_k):
         def run_step(s):
             return _run_step(s, ev_k)
@@ -1284,6 +1373,10 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
                 z["walks"] = jnp.zeros((), jnp.int32)
                 z["nvis"] = jnp.zeros((), jnp.int32)
                 z["nsc"] = jnp.zeros((), jnp.int32)
+            if st.volumes:
+                z["vatt"] = jnp.zeros((), jnp.int32)
+                z["vrej"] = jnp.zeros((), jnp.int32)
+                z.update(_vol_state_summary(s))
             if st.record == "full":
                 z["bits"] = jnp.zeros((st.q, n_filters, N), bits_dtype)
                 z["raw"] = jnp.zeros((st.q, n_scores, N), raw_dtype)
@@ -1408,6 +1501,9 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
         carries["InterPodAffinity"] = _derive_interpod(
             {"cnt": s["ip_cnt"], "eat": s["ip_eat"], "vw": s["ip_vw"]}, ipa, st
         )
+        for pn, rows in vol_rows.items():
+            # The window's own counts, not the encoder's start.
+            carries[pn] = {leaf: s[f"vc.{pn}.{leaf}"] for leaf in rows}
         rank = ev_k["rank"]  # i32 [N], canonical slot, big when dead
 
         def select_host(ok, total, valid):
@@ -1455,6 +1551,8 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
             }
             if st.record == "full":
                 out["visited"] = visited
+            if st.volumes:
+                out["seen"] = jnp.where(walked, visited, s["valid"])
             return jnp.where(go, sample, ok), jnp.where(go, nxt, start), out
 
         def record_rows(_bits, _raw, _final):
@@ -1481,12 +1579,14 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
                 has_requests=pb.has_requests,
                 index=pb.index,
             )
+            seen = nstate.valid
             if st.sample:
                 # Filter everywhere (the mask FINDS the sample), then
                 # score, normalise and choose over the sample only.
                 ok, _bits = prog._eval_filters(nstate, pod, aux, pcarries)
                 ok, start, walk_out = sample_walk(ok, walk[0], pb.valid)
                 walk = [start]
+                seen = walk_out.pop("seen", seen)
                 _raw, _final, total = prog._eval_scores(
                     nstate, pod, aux, pcarries, ok
                 )
@@ -1502,6 +1602,16 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
                 out_pod.update(record_rows(_bits, _raw, _final))
             if st.sample:
                 out_pod.update(walk_out)
+            if st.volumes:
+                # An attempt of a pod with a plugin-read volume, and the
+                # nodes it visited that a volume filter turned down.
+                turned = jnp.zeros(N, bool)
+                for i in vol_filters:
+                    turned = turned | (_bits[i] != 0)
+                out_pod["vatt"] = pb.valid & prow["vol_reads"][pb.index]
+                out_pod["vrej"] = jnp.where(
+                    pb.valid, jnp.sum(turned & seen, dtype=jnp.int32), 0
+                )
             return (nstate, pcarries, *walk), out_pod
 
         invalid_search = {
@@ -1631,6 +1741,8 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
             invalid_pod["raw"] = jnp.zeros((n_scores, N), raw_dtype)
             invalid_pod["final"] = jnp.zeros((n_scores, N), final_dtype)
         walk0 = ()
+        if st.volumes:
+            invalid_pod.update(vatt=jnp.zeros((), bool), vrej=jnp.int32(0))
         if st.sample:
             invalid_pod.update(
                 walked=jnp.zeros((), bool), nvis=jnp.int32(0), nsc=jnp.int32(0)
@@ -1682,6 +1794,9 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
         s["pod_count"] = node_state.pod_count
         # The committed spread carry is node-local — carry it forward.
         s["spread"] = carries["PodTopologySpread"]
+        for pn, rows in vol_rows.items():
+            for leaf in rows:
+                s[f"vc.{pn}.{leaf}"] = carries[pn][leaf]
         if st.preempt:
             # live already holds binds, victim removals and nominations:
             # it IS the post-step state.
@@ -1757,6 +1872,10 @@ def _segment_body(st: _SegmentStatics, prog, const: dict, ev: dict, state0: dict
             # Whole-chain filter evaluations the pod loop ran, attempts
             # and searches (replay.preempt_filter_runs).
             out["fruns"] = jnp.sum(pod_outs["runs"]).astype(jnp.int32)
+        if st.volumes:
+            out["vatt"] = jnp.sum(pod_outs["vatt"], dtype=jnp.int32)
+            out["vrej"] = jnp.sum(pod_outs["vrej"], dtype=jnp.int32)
+            out.update(_vol_state_summary(s))
         if st.sample:
             # Summed here, on the device, and pulled with the outputs.
             out["walks"] = jnp.sum(pod_outs["walked"], dtype=jnp.int32)
@@ -1932,6 +2051,14 @@ class StepOutcome:
     # Of ``sampled``, the attempts whose walk went by the walk tensor
     # (``_SegmentStatics.sample`` 2: one sort of the node axis each).
     by_rank: int = 0
+    # Volumes (``_SegmentStatics.volumes``): attempts of a pod with a
+    # plugin-read volume, visited nodes a volume filter turned down, and
+    # from the state the step leaves: attachments held and the smallest
+    # limit-less-attached (None: nothing limited).
+    vol_attempts: int = 0
+    vol_rejections: int = 0
+    vol_attached: "int | None" = None
+    vol_headroom: "int | None" = None
     # (namespace, name, node_name) in queue (commit) order.
     binds: list[tuple[str, str, str]] = field(default_factory=list)
     # Per-attempt detail (preemption / full-record segments); None means
@@ -1957,6 +2084,11 @@ class SegmentOutcome:
     # The service's node tree as the segment's node events leave it
     # (None: the service does not sample).
     node_tree: Any = None
+
+
+def _headroom(raw) -> "int | None":
+    """A step's ``vhead`` output: int32 max says nothing is limited."""
+    return None if int(raw) >= _I32_MAX else int(raw)
 
 
 def _cleaned_pending(pod: JSON) -> JSON:
@@ -2072,6 +2204,21 @@ class ReplayDriver:
         # one a window and one more a step with a node event).
         self.sampled_by_rank_steps = 0  # guarded-by: main-thread
         self.walk_rows_built = 0  # guarded-by: main-thread
+        # Volumes (PR 49).  Summed on the device over the committed
+        # steps: attempts of a pod with a plugin-read volume, and node
+        # verdicts a volume filter turned down among the nodes those
+        # attempts visited.  From the last committed step's carried
+        # state: attachments held, and the smallest limit-less-attached
+        # (None: nothing limited, or no window carried volume state).
+        # From the lowerings, the largest any window read or built:
+        # volume objects, distinct PV rows, columns for shared volumes.
+        self.volume_attempts = 0  # guarded-by: main-thread
+        self.volume_rejections = 0  # guarded-by: main-thread
+        self.volume_attached = 0  # guarded-by: main-thread
+        self.volume_headroom_min: "int | None" = None  # guarded-by: main-thread
+        self.volume_objects = 0  # guarded-by: main-thread
+        self.volume_classes = 0  # guarded-by: main-thread
+        self.volume_shared = 0  # guarded-by: main-thread
         # Whole-node-axis tables the featurizer's encoders built afresh
         # (``Memo.seq_builds``), summed over the lowerings: about one a
         # family in a cold call, none while the node objects stay.
@@ -2220,6 +2367,16 @@ class ReplayDriver:
             "sampling_start": self.service._pnts_start.get(self._sched_name or "", 0),
             # Zones of the service's node tree (0: it does not sample).
             "sampling_zones": len(self.service._node_tree.zones),
+            # Volumes on the segment path (docs/jobs.md): device sums,
+            # the carried state at the last committed step, and what the
+            # largest lowering read and built.
+            "volume_attempts": self.volume_attempts,
+            "volume_rejections": self.volume_rejections,
+            "volume_attached": self.volume_attached,
+            "volume_headroom_min": self.volume_headroom_min,
+            "volume_objects": self.volume_objects,
+            "volume_classes": self.volume_classes,
+            "volume_shared": self.volume_shared,
             "ingest_prefetches": self.ingest_prefetches,
             "device_errors": self.device_errors,
             "watchdog_timeouts": self.watchdog_timeouts,
@@ -2339,6 +2496,15 @@ class ReplayDriver:
         return True
 
     _OP_KINDS = frozenset({"pods", "nodes"})
+    #: Kinds a step may CREATE besides (a delete or an update of one inside
+    #: a stream ends the window as ``op:delete/persistentvolumes`` etc.).
+    _VOLUME_KINDS = frozenset(kind for _arg, kind in _VOLUME_ARGS)
+
+    @classmethod
+    def _op_in_vocabulary(cls, op) -> bool:
+        if op.kind in cls._OP_KINDS:
+            return op.op in ("create", "delete")
+        return op.kind in cls._VOLUME_KINDS and op.op == "create"
 
     def _window_len(self) -> int:
         """Steps one lowered window may consume (record mode dependent;
@@ -2368,25 +2534,34 @@ class ReplayDriver:
         win_node_seen: set[str] = set()
         win_node_live: set[str] = set()
         ext_del_nodes: set[str] = set()
+        win_vol_seen: set[tuple[str, str]] = set()
         try:
             for k, batch in enumerate(batches):
                 for op in batch:
-                    if op.kind not in self._OP_KINDS or op.op not in (
-                        "create",
-                        "delete",
-                    ):
+                    if not self._op_in_vocabulary(op):
                         if k == 0:
                             spec.head_reason = f"op:{op.op}/{op.kind}"
                         return spec  # op-screen prefix ends here
+                # (A volume object's arrival flushes the backoff like a
+                # node's: scenario/runner.py ``_run_step_traced``.)
                 st = _StepParse(
                     flush=any(
-                        op.kind == "nodes"
-                        or (op.op == "delete" and op.kind == "pods")
-                        for op in batch
+                        op.kind != "pods" or op.op == "delete" for op in batch
                     )
                 )
                 for op in batch:
-                    if op.kind == "pods":
+                    if op.kind in self._VOLUME_KINDS:
+                        key = (
+                            name_of(op.obj)
+                            if op.kind != "persistentvolumeclaims"
+                            else f"{namespace_of(op.obj) or 'default'}/{name_of(op.obj)}"
+                        )
+                        if (op.kind, key) in win_vol_seen:
+                            raise _Unsupported("volume_name_reuse")
+                        win_vol_seen.add((op.kind, key))
+                        spec.checks.append((k, "create_volume", f"{op.kind}/{key}"))
+                        spec.created_volumes.append((k, op.kind, key, op.obj))
+                    elif op.kind == "pods":
                         if op.op == "create":
                             key = _pod_key(op.obj)
                             if key in win_pod_seen or key in ext_del_pods:
@@ -2565,7 +2740,7 @@ class ReplayDriver:
         access).  ``record`` counts the reject reason — only the batch
         that actually forces a fallback (the segment head) should."""
         for op in batch:
-            if op.kind not in self._OP_KINDS or op.op not in ("create", "delete"):
+            if not self._op_in_vocabulary(op):
                 if record:
                     self._reject(f"op:{op.op}/{op.kind}")
                 return False
@@ -2576,7 +2751,7 @@ class ReplayDriver:
         """None when the pod fits the tensor vocabulary, else the reason."""
         from ksim_tpu.scheduler.profile import DEFAULT_SCHEDULER_NAME
         from ksim_tpu.state.extras import _host_ports
-        from ksim_tpu.state.volumes import _pod_has_volumes
+        from ksim_tpu.state.volumes import _has_ephemeral_claim
 
         spec = pod.get("spec", {})
         if spec.get("schedulingGates"):
@@ -2588,8 +2763,10 @@ class ReplayDriver:
             return "terminal_phase"
         if _host_ports(pod):
             return "host_ports"
-        if _pod_has_volumes(pod):
-            return "volumes"
+        if _has_ephemeral_claim(pod):
+            # A generic ephemeral volume's claim is made by a controller
+            # after the pod: the stream does not hold it.
+            return "ephemeral_volume_claim"
         return None
 
     # -- lowering ------------------------------------------------------------
@@ -2996,10 +3173,6 @@ class ReplayDriver:
         span.lap("replay.lower.universe")
         svc = self.service
         store = self.store
-        for kind in ("persistentvolumes", "persistentvolumeclaims", "storageclasses"):
-            if store.list(kind, copy_objs=False):
-                raise _Unsupported("volume_objects")
-
         m_steps = len(batches)
         lower_epoch = store.mutation_epoch
         cur_pods = store.list("pods", copy_objs=False)
@@ -3057,6 +3230,11 @@ class ReplayDriver:
             elif check == "create_node":
                 if key in node_names:
                     raise _Unsupported("node_name_reuse")
+            elif check == "create_volume":
+                kind, _, rest = key.partition("/")
+                ns, _, nm = rest.rpartition("/")
+                if store.contains(kind, nm, ns):
+                    raise _Unsupported("volume_name_reuse")
             else:  # delete_node
                 if key not in node_names:
                     raise _Unsupported("delete_unknown_node")
@@ -3076,6 +3254,14 @@ class ReplayDriver:
         step_flush = [s.flush for s in steps]
         created_pod_entries = [e for e in spec.created_pods if e[0] < m_steps]
         created_nodes = [obj for stp, obj in spec.created_nodes if stp < m_steps]
+        # The volume objects the window's verdicts are read from: the
+        # store's and the window's own (a step creates them before its
+        # pods; ``volume_object_order`` below holds the window to that).
+        volume_kw = {
+            arg: store.list(kind, copy_objs=False)
+            + [o for stp, kd, _key, o in spec.created_volumes if stp < m_steps and kd == kind]
+            for arg, kind in _VOLUME_ARGS
+        }
 
         # Tail padding: segments shorter than the compiled K (the stream
         # tail, a mid-window vocabulary miss, or full-record's shorter
@@ -3236,6 +3422,7 @@ class ReplayDriver:
             queue_pods=universe_pods,
             bound_pods=bound_pods,
             namespaces=store.list("namespaces", copy_objs=False),
+            **volume_kw,
         )
         node_builds = memo.seq_builds - node_builds0
         self.featurize_node_builds += node_builds
@@ -3270,6 +3457,36 @@ class ReplayDriver:
                 if hasattr(sp.plugin, attr):
                     raise _Unsupported(f"host_hook:{attr}")
         prog = _Program(plugins, self._record_mode)
+
+        # Volumes: whether any pod of the universe reads one (then the
+        # plugins' counted carries ride the window's state), and the
+        # cases the segment program does not carry.
+        vt = feats.aux["volumes"]
+        volumes_plan = bool(vt.live)
+        if volumes_plan:
+            if vt.pod_wffc.any():
+                # Upstream's PreBind chooses a PV for an unbound
+                # WaitForFirstConsumer claim and writes the binding.
+                raise _Unsupported("unbound_wffc_claim")
+            if preempt_plan:
+                # The victim search's hypothetical states take pods off
+                # a node by their request rows alone.
+                raise _Unsupported("volume_victim_search")
+            born = {
+                (kd, key): stp
+                for stp, kd, key, _o in spec.created_volumes
+                if stp < m_steps
+            }
+            if born:
+                # A pod's volume rows are one verdict for the window: the
+                # objects they were read from have to stand by the pod's
+                # first attempt (its own step; step 0 for a pod of the
+                # store).
+                first = {row_of[key]: stp for stp, key, _o in created_pod_entries}
+                for j, refs in vt.pod_refs.items():
+                    at = first.get(j, 0)
+                    if any(born.get(ref, -1) > at for ref in refs):
+                        raise _Unsupported("volume_object_order")
 
         if preempt_plan:
             from ksim_tpu.scheduler.preemption import (
@@ -3576,6 +3793,7 @@ class ReplayDriver:
             ip_terms=ip_terms,
             sample=0 if not sample_k.any() else 1 if slot_order else 2,
             tp=tp,
+            volumes=volumes_plan,
         )
         const = {
             "node": dict(
@@ -3751,6 +3969,18 @@ class ReplayDriver:
                 state0["nm_vw"] = by_level(ipa.pod_vw, (T,), np.int32)
         else:
             state0["nominated"] = np.zeros(P, bool)
+        if volumes_plan:
+            # The volume plugins' counted carries, from the bound
+            # population as the encoder counted it (``carry_init`` picks
+            # arrays, so the host tree serves).
+            from ksim_tpu.engine.core import _aux_host
+
+            const["pods"]["vol_reads"] = vt.pod_reads
+            aux_host = _aux_host(feats.aux)[0]
+            for sp in plugins:
+                if hasattr(sp.plugin, "carry_rows"):
+                    for leaf, arr in sp.plugin.carry_init(aux_host).items():
+                        state0[f"vc.{sp.plugin.name}.{leaf}"] = arr
         if statics.sample:
             # The walk continues where the service's last attempt, on
             # either path, left it.
@@ -3797,7 +4027,18 @@ class ReplayDriver:
             "sampled_by_rank_steps": 0,
             "walk_rows_built": walk_rows_built,
             "sampling_start": None,
+            # What the volume encoding read and built (0s: no pod of the
+            # universe reads a volume), and the device's sums.
+            "volume_objects": (
+                sum(len(v) for v in volume_kw.values()) if volumes_plan else 0
+            ),
+            "volume_classes": vt.n_classes,
+            "volume_shared": vt.n_shared,
+            "volume_attempts": 0,
+            "volume_rejections": 0,
         }
+        for key in ("volume_objects", "volume_classes", "volume_shared"):
+            setattr(self, key, max(getattr(self, key), log_entry[key]))
         self.lower_log.append(log_entry)
         return _SegmentPlan(
             statics=statics,
@@ -3824,6 +4065,9 @@ class ReplayDriver:
             sched_names=sched_names,
             mesh=self._shard_mesh_obj,
             log_entry=log_entry,
+            volume_objects=(
+                tuple(len(v) for v in volume_kw.values()) if volumes_plan else None
+            ),
         )
 
     @staticmethod
@@ -4128,6 +4372,10 @@ class ReplayDriver:
                     visited=int(pulled["nvis"][k]) if st.sample else 0,
                     scored=int(pulled["nsc"][k]) if st.sample else 0,
                     by_rank=int(pulled["walks"][k]) if st.sample == 2 else 0,
+                    vol_attempts=int(pulled["vatt"][k]) if st.volumes else 0,
+                    vol_rejections=int(pulled["vrej"][k]) if st.volumes else 0,
+                    vol_attached=int(pulled["vheld"][k]) if st.volumes else None,
+                    vol_headroom=_headroom(pulled["vhead"][k]) if st.volumes else None,
                     binds=binds,
                     attempts=attempts,
                 )
@@ -4257,14 +4505,21 @@ class ReplayDriver:
             # Steps whose walks went by their walk tensor.
             "sampled_by_rank_steps": sum(o.by_rank > 0 for o in seg.steps),
         }
-        for key, n in (preempt | sampling).items():
+        volumes = {
+            "volume_attempts": sum(o.vol_attempts for o in seg.steps),
+            "volume_rejections": sum(o.vol_rejections for o in seg.steps),
+        }
+        for key, n in (preempt | sampling | volumes).items():
             setattr(self, key, getattr(self, key) + n)
+        if seg.steps and seg.steps[-1].vol_attached is not None:
+            self.volume_attached = seg.steps[-1].vol_attached
+            self.volume_headroom_min = seg.steps[-1].vol_headroom
         plan = self._last_plan  # None on a fleet follower: it lowered nothing
         if plan is not None and plan.log_entry is not None:
             plan.log_entry["pairs_evaluated"] = pairs
             plan.log_entry["slots_run"] = slots
             plan.log_entry["writes_shared"], plan.log_entry["writes_copied"] = writes
-            plan.log_entry.update(preempt | sampling)
+            plan.log_entry.update(preempt | sampling | volumes)
             plan.log_entry["nodes_skipped"] = (
                 sampling["nodes_visited"] - sampling["nodes_scored"]
             )
@@ -4291,6 +4546,20 @@ class ReplayDriver:
                 f"{len(seg.bound_view)}, pending {len(store_pending)} vs "
                 f"{len(seg.pending_view)}"
             )
+        plan = self._last_plan
+        if plan is not None and plan.volume_objects is not None:
+            # The volume objects the verdicts were read from are the ones
+            # the staged store holds: the window's creates all landed and
+            # nothing else came or went.
+            have = tuple(
+                len(self.store.list(kind, copy_objs=False)) for _arg, kind in _VOLUME_ARGS
+            )
+            if have != plan.volume_objects:
+                raise ReplayParityError(
+                    "device-resident replay diverged from the store after "
+                    f"reconcile: volume objects {have} in the store, "
+                    f"{plan.volume_objects} lowered"
+                )
         if seg.nominated_view is not None:
             # Nominations that stand: a name the store still holds for a
             # node that is gone counts on neither side.
@@ -4720,6 +4989,12 @@ _NODE_STATE_KEYS = frozenset(
 )
 
 
+def _node_state_key(key: str) -> bool:
+    """``_NODE_STATE_KEYS``, and the volume plugins' carries (``vc.
+    <plugin>.<leaf>``: every one [N, X])."""
+    return key in _NODE_STATE_KEYS or key.startswith("vc.")
+
+
 def _plan_shard_specs(plan: "_SegmentPlan", transient, mesh):
     """NamedSharding spec trees mirroring ``_plan_const_parts(plan)``
     and the ``(ev, state0)`` transient tree, structure-identical so the
@@ -4774,7 +5049,7 @@ def _plan_shard_specs(plan: "_SegmentPlan", transient, mesh):
         for k, v in ev.items()
     }
     state_spec = {
-        k: node_lead(v) if k in _NODE_STATE_KEYS else repl(v)
+        k: node_lead(v) if _node_state_key(k) else repl(v)
         for k, v in state0.items()
     }
     return (node_spec, pods_spec, extra_spec, aux_spec), (ev_spec, state_spec)
@@ -4797,7 +5072,7 @@ def _fleet_shard_specs(plan: "_SegmentPlan", transient, mesh):
     )
     state_spec = {
         k: sharding.lane_node_sharding(mesh, np.ndim(v))
-        if k in _NODE_STATE_KEYS
+        if _node_state_key(k)
         else sharding.lane_sharding(mesh, np.ndim(v))
         for k, v in st_s.items()
     }
@@ -4966,6 +5241,9 @@ class _SegmentPlan:
     # for (None for env-knob sharding — _device_exec builds that mesh
     # lazily on the worker — and for tp=1 plans).
     mesh: Any = None
+    # PersistentVolumes / claims / StorageClasses the lowering read (store
+    # and window), for ``verify_segment``; None: no volume state carried.
+    volume_objects: "tuple[int, int, int] | None" = None
 
 
 class _Unsupported(ReplayFallback):

@@ -252,9 +252,11 @@ SPAN_NAMES: tuple[str, ...] = (
     #                                encode_taints
     "service.featurize.spread",  # stage: encode_topology_spread
     "service.featurize.interpod",  # stage: encode_inter_pod
+    "service.featurize.volumes",  # stage: encode_volumes (the four
+    #                               volume plugins' tensors)
     "service.featurize.extras",  # stage: node name, ports, image
-    #                              locality, volumes, extra encoders,
-    #                              the snapshot's assembly
+    #                              locality, extra encoders, the
+    #                              snapshot's assembly
     "replay.lower.featurize.program",  # stage of replay.lower.featurize
     #                                    after the featurizer call: the
     #                                    plugin factory, the host-hook

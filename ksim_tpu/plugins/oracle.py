@@ -1067,6 +1067,9 @@ def node_volume_limits_filter(
     for pool, vids in want.items():
         if pools is not None and pool not in pools:
             continue
-        if pool in limits and len(attached.get(pool, set()) | vids) > limits[pool]:
+        # csi.go: the pool is checked for the volumes the pod would ADD
+        # to the node; with none to add it passes.
+        have = attached.get(pool, set())
+        if pool in limits and vids - have and len(have | vids) > limits[pool]:
             return [ERR_MAX_VOLUME_COUNT]
     return []

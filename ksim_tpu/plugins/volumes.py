@@ -8,7 +8,10 @@ state/volumes.py).  All four are filter-only in the default profile
 Every per-pod check is a ``[N, X] x [X]`` matvec over the factored
 volume tensors; the attach/usage state mutated by scheduling rides the
 scan carries with the same elementwise outer-product commit as the other
-carried plugins.
+carried plugins.  One definition for both paths: the per-pass engine
+re-reads the carries' start from the encoder every pass, the segment
+program (engine/replay.py) carries them through a window's steps — a
+bind adds a pod's ``carry_rows``, a delete takes them off.
 """
 
 from __future__ import annotations
@@ -59,6 +62,7 @@ class VolumeBinding:
     # filter plugin's bits fit a narrower dtype (engine/core.py).
     reason_bit_width = 4
     name = VOLUME_BINDING
+    volume_family = True
 
     def __init__(self, vt: VolumeTensors) -> None:
         del vt
@@ -108,6 +112,7 @@ class VolumeZone:
     # filter plugin's bits fit a narrower dtype (engine/core.py).
     reason_bit_width = 1
     name = VOLUME_ZONE
+    volume_family = True
 
     def __init__(self, vt: VolumeTensors) -> None:
         del vt
@@ -138,11 +143,19 @@ class NodeVolumeLimits:
     EBSLimits, GCEPDLimits, AzureDiskLimits, CinderLimits (upstream
     nodevolumelimits/non_csi.go, carried by the reference's exported
     default config, simulator/snapshot/snapshot_test.go:1415) — are
-    instances restricted to their one pool via ``pools``."""
+    instances restricted to their one pool via ``pools``.
+
+    Where a pod of the call reads a volume (``vt.live``) the carry is
+    COUNTED: ``att`` [N, V] users of each shared volume on the node and
+    ``excl`` [N, K] exclusive attachments a pool, both linear in the
+    pods bound (``carry_rows``), so the segment program can take a
+    deleted pod's row off again.  Where none does, the carry and the
+    program are what a volume-free pass always compiled."""
 
     # Static reason-bit width: result tensors downcast when every
     # filter plugin's bits fit a narrower dtype (engine/core.py).
     reason_bit_width = 1
+    volume_family = True
 
     def __init__(
         self,
@@ -152,6 +165,7 @@ class NodeVolumeLimits:
         pools: tuple[str, ...] | None = None,
     ) -> None:
         self.name = name
+        self._live = vt.live
         self._pool_ids = tuple(
             k
             for k, pool in enumerate(vt.pool_names[: int(vt.n_pools)])
@@ -159,26 +173,46 @@ class NodeVolumeLimits:
         )
 
     def static_sig(self) -> tuple:
-        return (NODE_VOLUME_LIMITS, self.name, self._pool_ids)
+        return (NODE_VOLUME_LIMITS, self.name, self._pool_ids) + (
+            ("live",) if self._live else ()
+        )
 
     def failure_unresolvable(self, bits: int) -> bool:
         return False  # evicting pods detaches volumes
 
-    def carry_init(self, aux) -> jnp.ndarray:
-        return aux["volumes"]["attached_init"]  # i32 [N, V]
+    def carry_init(self, aux):
+        a = aux["volumes"]
+        if not self._live:
+            return a["attached_init"]  # i32 [N, V]
+        return {"att": a["attached_init"], "excl": a["excl_init"]}
 
-    def carry_commit(self, carry, aux, pod: PodView, best) -> jnp.ndarray:
-        uses = aux["volumes"]["pod_vol"][pod.index].astype(carry.dtype)  # [V]
-        onehot = ((jnp.arange(carry.shape[0]) == best) & (best >= 0)).astype(
-            carry.dtype
-        )
-        # Attachment is unique per (volume, node): saturate at 1.
-        return jnp.maximum(carry, onehot[:, None] * uses[None, :])
+    def carry_rows(self, aux) -> dict:
+        """Per pod, what its binding adds to the node's row of each
+        carried array (live only)."""
+        a = aux["volumes"]
+        return {"att": a["pod_vol"], "excl": a["pod_excl"]}
+
+    def carry_commit(self, carry, aux, pod: PodView, best):
+        a = aux["volumes"]
+        if not self._live:
+            uses = a["pod_vol"][pod.index].astype(carry.dtype)  # [V]
+            onehot = ((jnp.arange(carry.shape[0]) == best) & (best >= 0)).astype(
+                carry.dtype
+            )
+            # Attachment is unique per (volume, node): saturate at 1.
+            return jnp.maximum(carry, onehot[:, None] * uses[None, :])
+        onehot = (
+            (jnp.arange(carry["att"].shape[0]) == best) & (best >= 0)
+        ).astype(jnp.int32)[:, None]
+        return {
+            key: carry[key] + onehot * rows[pod.index].astype(jnp.int32)[None, :]
+            for key, rows in self.carry_rows(aux).items()
+        }
 
     def filter(self, state: NodeStateView, pod: PodView, aux, carry) -> FilterOutput:
         a = aux["volumes"]
         j = pod.index
-        attached = carry > 0  # [N, V]
+        attached = (carry["att"] if self._live else carry) > 0  # [N, V]
         pod_vol = a["pod_vol"][j]  # [V]
         over = jnp.zeros(state.valid.shape[0], dtype=bool)
         for k in self._pool_ids:  # static unroll over this plugin's pools
@@ -186,10 +220,36 @@ class NodeVolumeLimits:
             used = _dot_bool(attached, in_pool)  # [N]
             new = _dot_bool(~attached, pod_vol & in_pool)  # [N] dedup'd
             limit = a["limits"][:, k]
-            over = over | ((limit >= 0) & (used + new > limit))
+            if self._live:
+                # csi.go: a pool is checked for the volumes the pod would
+                # ADD to the node; with none to add it passes.
+                used = used + carry["excl"][:, k]
+                new = new + a["pod_excl"][j, k]
+                over = over | ((limit >= 0) & (new > 0) & (used + new > limit))
+            else:
+                over = over | ((limit >= 0) & (used + new > limit))
         return FilterOutput(
             ok=~over, reason_bits=jnp.where(over, 1, 0).astype(jnp.int32)
         )
+
+    def attach_summary(self, carry, aux, valid) -> dict:
+        """From the carried state (live only): the attachments the valid
+        nodes hold, and the smallest limit-less-attached over the valid
+        nodes and this plugin's limited pools (int32 max where nothing is
+        limited)."""
+        a = aux["volumes"]
+        attached = carry["att"] > 0
+        big = jnp.iinfo(jnp.int32).max
+        total = jnp.int32(0)
+        head = jnp.int32(big)
+        for k in self._pool_ids:
+            used = _dot_bool(attached, a["vol_key"] == k) + carry["excl"][:, k]
+            limit = a["limits"][:, k]
+            total = total + jnp.sum(jnp.where(valid, used, 0), dtype=jnp.int32)
+            head = jnp.minimum(
+                head, jnp.min(jnp.where(valid & (limit >= 0), limit - used, big))
+            )
+        return {"attached": total, "headroom": head.astype(jnp.int32)}
 
     def decode_reasons(self, bits: int) -> list[str]:
         return [ERR_MAX_VOLUME_COUNT] if bits else []
@@ -200,6 +260,7 @@ class VolumeRestrictions:
     # filter plugin's bits fit a narrower dtype (engine/core.py).
     reason_bit_width = 2
     name = VOLUME_RESTRICTIONS
+    volume_family = True
 
     def __init__(self, vt: VolumeTensors) -> None:
         del vt
@@ -216,6 +277,16 @@ class VolumeRestrictions:
             "rwop": a["rwop_init"],
             "disk_any": a["disk_any_init"],
             "disk_rw": a["disk_rw_init"],
+        }
+
+    def carry_rows(self, aux) -> dict:
+        """Per pod, what its binding adds to the node's row of each
+        carried array (``carry_commit`` below, row by row)."""
+        a = aux["volumes"]
+        return {
+            "rwop": a["pod_rwop"],
+            "disk_any": a["pod_disk_any"],
+            "disk_rw": a["pod_disk_rw"],
         }
 
     def carry_commit(self, carry, aux, pod: PodView, best) -> dict:

@@ -242,8 +242,9 @@ class Featurizer:
         self._prev_bound: dict[int, JSON] = {}
         self._bound_gen = 0
         self._contents = self._agg["__contents__"] = BoundContents()
-        # Bound pods carrying volumes, maintained from the diff — the
-        # volumes fast path needs "is ANY bound pod using volumes", and
+        # Bound pods carrying a volume that a plugin reads, maintained
+        # from the diff — the volumes fast path needs "does ANY bound pod
+        # read a volume", and
         # re-scanning 15k+ bound pods per pass was the single largest
         # steady-state featurize cost.  Asked once a content id.
         self._bound_vol_count = 0
@@ -349,9 +350,10 @@ class Featurizer:
         served its stored rows, so — as for every objcache memo — an
         object must not be edited in place after it was featurized.
 
-        The call is six timed stages of whatever span the caller has
+        The call is seven timed stages of whatever span the caller has
         open (``service.featurize.index`` / ``.resources`` /
-        ``.affinity`` / ``.spread`` / ``.interpod`` / ``.extras``:
+        ``.affinity`` / ``.spread`` / ``.interpod`` / ``.volumes`` /
+        ``.extras``:
         obs.py ``TracePlane.stage``), the sequential seams of the body;
         every encoder does its own node side, pod rows and bound
         aggregate, and the node-side tables built afresh are counted by
@@ -387,7 +389,7 @@ class Featurizer:
         self._bound_gen += 1
         added = [pid for pid in bound_map if pid not in prev]
         removed = [pid for pid in prev if pid not in bound_map]
-        from ksim_tpu.state.volumes import _pod_has_volumes
+        from ksim_tpu.state.volumes import _pod_reads_volumes
 
         cid_of, vol_of = self._contents.of, self._vol_of
         for pid in removed:
@@ -398,7 +400,7 @@ class Featurizer:
         for pid in added:
             c = cid_of[pid]
             if c not in vol_of:
-                vol_of[c] = _pod_has_volumes(bound_map[pid])
+                vol_of[c] = _pod_reads_volumes(bound_map[pid])
             self._bound_vol_count += vol_of[c]
         self._agg["__diff__"] = {
             "gen": self._bound_gen,
@@ -672,14 +674,16 @@ class Featurizer:
             agg=self._agg, bound_map=bound_map,
             changed_slots=changed_slots, slot_of=bound_slot,
         )
+        TRACE.stage("service.featurize.volumes")
+        volumes = encode_volumes(
+            nodes, table, bound_pods, pvs, pvcs, storage_classes, NP, PP,
+            bound_volume_free=self._bound_vol_count == 0,
+        )
         TRACE.stage("service.featurize.extras")
         aux["nodename"] = encode_node_name(nodes, table, PP)
         aux["nodeports"] = encode_node_ports(nodes, table, bound_pods, NP, PP)
         aux["imagelocality"] = encode_image_locality(nodes, table, NP, PP)
-        aux["volumes"] = encode_volumes(
-            nodes, table, bound_pods, pvs, pvcs, storage_classes, NP, PP,
-            bound_volume_free=self._bound_vol_count == 0,
-        )
+        aux["volumes"] = volumes
         for key, encoder in self._extra_encoders.items():
             aux[key] = encoder(nodes, sched_pods, NP, PP)
 

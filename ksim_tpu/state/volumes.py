@@ -9,27 +9,48 @@ simulator/snapshot/snapshot.go:33-42; CSINode objects don't exist in
 either snapshot model, so attach limits read the node's
 ``attachable-volumes-*`` allocatable keys, the pre-CSINode mechanism).
 
-Factored host/device split (nothing [P, N]-sized is materialized):
+Factored host/device split (nothing [P, N]-sized is materialized, and
+nothing sized by the number of volumes unless two pods share one):
 
-- **VolumeBinding / VolumeZone**: every PV referenced by a queue pod's
-  bound PVCs gets a row in ``pv_node_ok`` / ``pv_zone_ok`` [NPV, N]
-  (node-affinity and zone-label matching evaluated host-side in exact
-  Python); a pod's per-node verdict is then a ``[NPV] x [NPV, N]`` dot.
-  Unbound WaitForFirstConsumer PVCs get candidate-PV node masks
-  ``pvc_cand_ok`` [C, N] + a node-independent ``provisionable`` flag.
+- **VolumeBinding / VolumeZone**: the bound PVs of the queue pods'
+  claims are grouped by CLASS — what the two plugins read of a PV: its
+  required node affinity and its zone / region labels — and a class
+  gets ONE row in ``pv_node_ok`` / ``pv_zone_ok`` [C, N] (node-affinity
+  and zone-label matching evaluated host-side in exact Python, once a
+  class and node; a class that admits every node costs no walk: 7,000
+  PVs of one template are one row).  A pod's per-node verdict is a
+  ``[C] x [C, N]`` dot over the classes of its PVs.  Unbound
+  WaitForFirstConsumer PVCs get candidate-PV node masks
+  ``pvc_cand_ok`` [W, N] (OR of the candidates' affinity rows, one a
+  distinct affinity) + a node-independent ``provisionable`` flag.
   Pod-level failures (unbound Immediate PVC, missing PVC) fail every
   node with a dedicated bit, like upstream's PreFilter
   UnschedulableAndUnresolvable abort.
-- **NodeVolumeLimits**: volume vocabulary V (distinct PVC-backed volume
-  ids) with a key id per volume (which ``attachable-volumes-<k>`` pool
-  it consumes, from the PV source or the StorageClass provisioner);
-  per-node attached [N, V] counts (the scan carry) + per-node limits
-  [N, K]; new-attachment counting dedups volumes already attached to
-  the node, exactly like upstream's unique-volume counting.
-- **VolumeRestrictions**: ReadWriteOncePod PVC vocabulary R and direct
-  disk-source vocabulary D (GCE PD / AWS EBS / ISCSI / RBD ids):
-  per-node use counts (any/rw) as carries; GCE/ISCSI/RBD allow
+- **NodeVolumeLimits**: every attachable volume has a pool (which
+  ``attachable-volumes-<k>`` key it consumes, from the PV source, the
+  PV's CSI driver or the StorageClass provisioner).  A volume that ONE
+  pod alone of the call (queue and bound) uses can never be attached
+  twice to a node, so it is only counted: ``pod_excl`` [P, K] adds to
+  the node's ``excl`` [N, K] carry as a request adds to ``requested``.
+  Only the volumes that two pods SHARE keep upstream's unique-per-node
+  bookkeeping: a column each in ``attached`` [N, V] (users on the node;
+  attached = users > 0), ``pod_vol`` [P, V], ``vol_key`` [V].  Limits
+  are ``limits`` [N, K].
+- **VolumeRestrictions**: a ReadWriteOncePod claim or a direct disk
+  (GCE PD / AWS EBS / ISCSI / RBD) that one pod alone uses conflicts
+  with nothing and gets no column; the shared ones keep per-node use
+  counts (R claims; D disks, any / rw) as carries; GCE/ISCSI/RBD allow
   read-only sharing, EBS never shares (upstream isVolumeConflict).
+
+Every carry is LINEAR in the pods bound to a node (a bind adds the
+pod's row, a delete takes it off, a node that goes takes its rows
+along), which is what lets the segment program of engine/replay.py
+carry it through the steps of a window beside ``requested``.  A call no
+pod of which reads a volume — ``emptyDir``, ``configMap``, ``secret``,
+``projected``, ``downwardAPI``, ``hostPath``, inline ``csi`` concern
+none of the four plugins — gets the trivial tensors (no counted state,
+the programs such a call always compiled), whatever volume objects the
+store holds.
 
 Documented simplifications: ephemeral volume claims use the upstream
 ``<pod>-<volume>`` naming but ownership is not verified; dynamic
@@ -40,6 +61,7 @@ tracking (upstream needs CSIStorageCapacity objects the snapshot lacks).
 
 from __future__ import annotations
 
+import json
 from dataclasses import dataclass
 from typing import Sequence
 
@@ -49,7 +71,7 @@ from ksim_tpu.state import objcache
 from ksim_tpu.state.featurizer import vocab_pad
 from ksim_tpu.state.podtable import Column, PodTable
 from ksim_tpu.state.quantity import parse_quantity
-from ksim_tpu.state.resources import JSON, labels_of, name_of, namespace_of
+from ksim_tpu.state.resources import JSON, labels_of, name_of, namespace_of, namespaced_key
 from ksim_tpu.state.selectors import match_node_selector_terms
 
 # Zone/region label keys upstream volume_zone.go consults.
@@ -92,7 +114,7 @@ SOURCE_POOL = {
 @dataclass
 class VolumeTensors:
     AXES = {
-        "pv_node_ok": None,  # [NPV, N] — N is the MINOR axis here
+        "pv_node_ok": None,  # [C, N] — N is the MINOR axis here
         "pv_zone_ok": None,
         "pvc_cand_ok": None,
         "pvc_provisionable": None,
@@ -100,9 +122,12 @@ class VolumeTensors:
         "pod_wffc": "pod",
         "pod_fail": "pod",
         "attached_init": "node",
+        "excl_init": "node",
         "limits": "node",
         "vol_key": None,
         "pod_vol": "pod",
+        "pod_excl": "pod",
+        "pod_reads": "pod",
         "rwop_init": "node",
         "pod_rwop": "pod",
         "disk_any_init": "node",
@@ -113,22 +138,22 @@ class VolumeTensors:
     }
 
     # VolumeBinding + VolumeZone
-    pv_node_ok: np.ndarray  # bool [NPV, N] PV node-affinity admits node
-    pv_zone_ok: np.ndarray  # bool [NPV, N] PV zone labels admit node
-    pvc_cand_ok: np.ndarray  # bool [C, N] some available PV binds on node
-    pvc_provisionable: np.ndarray  # bool [C] SC can dynamically provision
-    pod_pv: np.ndarray  # bool [P, NPV] pod's bound PVCs' PVs
-    pod_wffc: np.ndarray  # bool [P, C] pod's unbound WFFC PVCs
+    pv_node_ok: np.ndarray  # bool [C, N] PV class's node-affinity admits node
+    pv_zone_ok: np.ndarray  # bool [C, N] PV class's zone labels admit node
+    pvc_cand_ok: np.ndarray  # bool [W, N] some available PV binds on node
+    pvc_provisionable: np.ndarray  # bool [W] SC can dynamically provision
+    pod_pv: np.ndarray  # bool [P, C] the classes of the pod's bound PVCs' PVs
+    pod_wffc: np.ndarray  # bool [P, W] pod's unbound WFFC PVCs
     pod_fail: np.ndarray  # i32 [P] bitmask: 1 unbound-immediate | 2 pvc-missing
     # NodeVolumeLimits
-    attached_init: np.ndarray  # i32 [N, V] volume attached to node (carry)
+    attached_init: np.ndarray  # i32 [N, V] users of SHARED volume on node (carry)
     limits: np.ndarray  # i32 [N, K] pool limits (-1 = unlimited)
-    vol_key: np.ndarray  # i32 [V] volume -> pool id (-1 = uncounted)
-    pod_vol: np.ndarray  # bool [P, V] pod uses volume
+    vol_key: np.ndarray  # i32 [V] shared volume -> pool id (-1 = uncounted)
+    pod_vol: np.ndarray  # bool [P, V] pod uses shared volume
     # VolumeRestrictions
-    rwop_init: np.ndarray  # i32 [N, R] RWOP-claim users on node (carry)
+    rwop_init: np.ndarray  # i32 [N, R] users of a SHARED RWOP claim on node (carry)
     pod_rwop: np.ndarray  # bool [P, R]
-    disk_any_init: np.ndarray  # i32 [N, D] any-mode users (carry)
+    disk_any_init: np.ndarray  # i32 [N, D] any-mode users of a SHARED disk (carry)
     disk_rw_init: np.ndarray  # i32 [N, D] rw users (carry)
     pod_disk_any: np.ndarray  # bool [P, D] pod uses disk (any mode)
     pod_disk_rw: np.ndarray  # bool [P, D] pod uses disk read-write
@@ -138,21 +163,35 @@ class VolumeTensors:
     # legacy per-type plugins (EBSLimits et al.) restrict their check to
     # one pool while NodeVolumeLimits covers all of them.
     pool_names: tuple[str, ...] = ()
+    # The EXCLUSIVE volumes — one pod alone of the call's pods uses each —
+    # as pool counts, and which pods carry a plugin-read volume: None in
+    # the trivial tensors of a call no pod of which reads a volume (not an
+    # array, so not in the device tree: such a call's programs are the ones
+    # it always had).
+    excl_init: "np.ndarray | None" = None  # i32 [N, K] exclusive attachments (carry)
+    pod_excl: "np.ndarray | None" = None  # i32 [P, K] pod's exclusive volumes a pool
+    pod_reads: "np.ndarray | None" = None  # bool [P] pod has a plugin-read volume
+    # Host-only evidence of one encode (engine/replay.py reads it):
+    # distinct PV rows, columns built for shared volumes / claims /
+    # disks, and per volume-reading queue row the (kind, key) of every
+    # volume object its verdicts were read from.
+    n_classes: int = 0
+    n_shared: int = 0
+    pod_refs: "dict[int, tuple] | None" = None
+
+    @property
+    def live(self) -> bool:
+        """Whether any pod of the call reads a volume: the plugins carry
+        counted state then (plugins/volumes.py)."""
+        return self.excl_init is not None
 
 
-_EMPTY_ROW = {"pv": (), "wffc": (), "vol": (), "rwop": (), "disk": (), "fail": 0}
+# (source key, id field) of every direct source a plugin reads.
+_SOURCE_IDS = tuple((src, f) for src, f, _ro in DISK_SOURCES) + LIMIT_ONLY_SOURCES
 
 
 def _pod_volumes(pod: JSON) -> list[JSON]:
     return pod.get("spec", {}).get("volumes") or []
-
-
-def _pod_has_volumes(pod: JSON) -> bool:
-    """Memoized per pod object: churn replay re-checks every bound pod
-    each pass, and the common case is volume-free pods."""
-    return objcache.cached(
-        "has_vols", pod, lambda: bool(_pod_volumes(pod))
-    )
 
 
 def _node_has_attach_pools(node: JSON) -> bool:
@@ -166,15 +205,34 @@ def _node_has_attach_pools(node: JSON) -> bool:
     return objcache.cached("attach_pools", node, build)
 
 
-def _any_node_has_attach_pools(nodes) -> bool:
-    """Family-memoized over the exact node list: the volumes fast path
-    asks this every pass, and walking 2k per-node memos was a measurable
-    slice of churn featurize time."""
-    return objcache.cached_seq(
-        "any_attach_pools",
-        nodes,
-        lambda: any(_node_has_attach_pools(n) for n in nodes),
+def _reads(vol: JSON) -> bool:
+    """Whether one of the four plugins reads this volume: a claim
+    (``persistentVolumeClaim``, generic ``ephemeral``) or a direct disk
+    source with a conflict rule or an attach pool.  ``emptyDir``,
+    ``configMap``, ``secret``, ``projected``, ``downwardAPI``, ``hostPath``,
+    ``nfs``, inline ``csi`` and the rest concern none of them (upstream
+    nodevolumelimits/csi.go counts an inline volume only where it is an
+    in-tree source under CSI migration: the sources below)."""
+    if (vol.get("persistentVolumeClaim") or {}).get("claimName") or vol.get("ephemeral"):
+        return True
+    return any(
+        (vol.get(src) or {}).get(id_field)
+        for src, id_field in _SOURCE_IDS
     )
+
+
+def _pod_reads_volumes(pod: JSON) -> bool:
+    """Memoized per pod object: does any of the four volume plugins read
+    one of the pod's volumes?  Churn replay re-checks every bound pod
+    each pass, and the common case is pods with none (or with the token,
+    configMap and emptyDir volumes every real pod carries)."""
+    return objcache.cached(
+        "reads_vols", pod, lambda: any(_reads(v) for v in _pod_volumes(pod))
+    )
+
+
+def _has_ephemeral_claim(pod: JSON) -> bool:
+    return any(v.get("ephemeral") for v in _pod_volumes(pod))
 
 
 # Trivial no-volume tensors per (n_padded, p_padded): identical arrays
@@ -251,6 +309,22 @@ def _pv_affinity_admits(pv: JSON, node: JSON) -> bool:
     )
 
 
+def _pv_class(pv: JSON) -> tuple[str, tuple]:
+    """What VolumeBinding and VolumeZone read of a bound PV: its required
+    node affinity and its zone / region labels.  PVs that agree in both
+    admit the same nodes and share one row (memoized per PV object)."""
+
+    def build():
+        req = ((pv.get("spec") or {}).get("nodeAffinity") or {}).get("required")
+        labels = labels_of(pv)
+        return (
+            json.dumps(req, sort_keys=True) if req else "",
+            tuple((k, str(labels[k])) for k in ZONE_KEYS if k in labels),
+        )
+
+    return objcache.cached("pv_class", pv, build)
+
+
 def _pv_matches_claim(pv: JSON, pvc: JSON) -> bool:
     """Static binding match (upstream pv_controller findMatchingVolume,
     reduced): class, access modes, capacity, phase Available, no claimRef."""
@@ -278,6 +352,20 @@ def _pv_matches_claim(pv: JSON, pvc: JSON) -> bool:
 _VOLUME_COLUMNS = (Column("has", bool, False),)
 
 
+@dataclass
+class _PodUse:
+    """What one pod's plugin-read volumes come to, by name: nothing here
+    is an index yet (the census below decides which names get a column)."""
+
+    pvs: list  # bound PV names (VolumeBinding / VolumeZone)
+    wffc: list  # unbound WaitForFirstConsumer claim keys
+    vols: dict  # attachable volume id -> pool name or None
+    rwop: set  # ReadWriteOncePod claim keys
+    disks: dict  # (source, id) -> used read-write
+    fail: int = 0
+    refs: tuple = ()  # (kind, key) of every volume object looked up
+
+
 def encode_volumes(
     nodes: Sequence[JSON],
     table: PodTable,
@@ -290,22 +378,17 @@ def encode_volumes(
     *,
     bound_volume_free: bool,
 ) -> VolumeTensors:
-    # Fast path — the common churn case: no volume API objects, no pod
-    # declares volumes, no node exposes attach pools.  A bound-pod scan
-    # would be the expensive precondition at churn scale: the Featurizer
-    # passes ``bound_volume_free`` from its incrementally maintained
-    # count, and the table knows which queue pods carry volumes.
+    # Fast path — the common case: no pod of the call, queued or bound,
+    # carries a volume that a plugin reads (whatever volume objects the
+    # store holds and whatever pools the nodes expose: with no reader
+    # all four filters pass everywhere).  A bound-pod scan would be the
+    # expensive precondition at churn scale: the Featurizer passes
+    # ``bound_volume_free`` from its incrementally maintained count, and
+    # the table knows which queue pods read volumes.
     fam = table.family("volumes", _VOLUME_COLUMNS)
-    table.sync(fam, None, lambda p: (_pod_has_volumes(p),))
+    table.sync(fam, None, lambda p: (_pod_reads_volumes(p),))
     with_volumes = np.nonzero(fam.take("has"))[0]
-    if (
-        not pvs
-        and not pvcs
-        and not storage_classes
-        and not with_volumes.size
-        and bound_volume_free
-        and not _any_node_has_attach_pools(nodes)
-    ):
+    if not with_volumes.size and bound_volume_free:
         return _trivial_volume_tensors(n_padded, p_padded)
 
     pvc_by_key = {f"{namespace_of(c)}/{name_of(c)}": c for c in pvcs}
@@ -315,125 +398,81 @@ def encode_volumes(
     def sc_of(pvc: JSON) -> JSON | None:
         return sc_by_name.get((pvc.get("spec") or {}).get("storageClassName") or "")
 
-    def binding_mode(pvc: JSON) -> str:
-        sc = sc_of(pvc)
-        if sc is None:
-            return "Immediate"
-        return sc.get("volumeBindingMode") or "Immediate"
-
-    def provisionable(pvc: JSON) -> bool:
-        sc = sc_of(pvc)
-        return bool(sc and (sc.get("provisioner") or "") not in ("", NO_PROVISIONER))
-
-    # Vocabularies built from the QUEUE pods' volume usage.
-    pv_vocab: dict[str, int] = {}  # PV name -> row
-    wffc_vocab: dict[str, int] = {}  # pvc key -> row
-    vol_vocab: dict[str, int] = {}  # attachable volume id -> row
-    vol_key_of: dict[str, str] = {}  # volume id -> pool key
-    rwop_vocab: dict[str, int] = {}  # RWOP pvc key -> row
-    disk_vocab: dict[tuple[str, str], int] = {}  # (source, id) -> row
-
-    pod_fail = np.zeros(p_padded, dtype=np.int32)
-    pod_rows: list[tuple[int, dict]] = []  # volume-carrying pods only
-
-    def classify_pod(pod: JSON, register: bool):
-        """Walk a pod's volumes; returns per-pod row dict (queue pods).
-        Pods without volumes (the common churn case) share one frozen
-        empty row — consumers only iterate the rows."""
-        vols = _pod_volumes(pod)
-        if not vols:
-            return _EMPTY_ROW
+    def use_of(pod: JSON) -> _PodUse:
+        """Walk a pod's volumes against the volume objects."""
         ns = namespace_of(pod) or "default"
-        row = {"pv": [], "wffc": [], "vol": [], "rwop": [], "disk": []}
-        fail = 0
-        for vol in vols:
+        use = _PodUse(pvs=[], wffc=[], vols={}, rwop=set(), disks={})
+        refs = []
+        for vol in _pod_volumes(pod):
             claim = _pvc_name(pod, vol)
             if claim is not None:
-                pvc = pvc_by_key.get(f"{ns}/{claim}")
+                key = f"{ns}/{claim}"
+                refs.append(("persistentvolumeclaims", key))
+                pvc = pvc_by_key.get(key)
                 if pvc is None:
-                    fail |= 2  # pvc not found
+                    use.fail |= 2  # pvc not found
                     continue
-                modes = set((pvc.get("spec") or {}).get("accessModes") or [])
-                if "ReadWriteOncePod" in modes:
-                    key = f"{ns}/{claim}"
-                    if register:
-                        rwop_vocab.setdefault(key, len(rwop_vocab))
-                    if key in rwop_vocab:
-                        row["rwop"].append(rwop_vocab[key])
-                bound_pv = (pvc.get("spec") or {}).get("volumeName") or ""
+                spec = pvc.get("spec") or {}
+                if "ReadWriteOncePod" in (spec.get("accessModes") or ()):
+                    use.rwop.add(key)
+                if spec.get("storageClassName"):
+                    refs.append(("storageclasses", spec["storageClassName"]))
+                sc = sc_of(pvc)
+                bound_pv = spec.get("volumeName") or ""
                 if bound_pv:
+                    refs.append(("persistentvolumes", bound_pv))
                     pv = pv_by_name.get(bound_pv)
                     if pv is None:
-                        fail |= 2
+                        use.fail |= 2
                         continue
-                    if register:
-                        pv_vocab.setdefault(bound_pv, len(pv_vocab))
-                    if bound_pv in pv_vocab:
-                        row["pv"].append(pv_vocab[bound_pv])
+                    use.pvs.append(bound_pv)
                     # Attach-limit accounting for the PV's source.
-                    src, vid = _pv_source_id(pv)
-                    if src is not None:
-                        pool = SOURCE_POOL.get(src) or _csi_pool(pv, sc_of(pvc))
-                        _register_vol(
-                            vol_vocab, vol_key_of, f"pv:{bound_pv}", pool, register
-                        )
-                        if f"pv:{bound_pv}" in vol_vocab:
-                            row["vol"].append(vol_vocab[f"pv:{bound_pv}"])
-                    else:
-                        pool = _csi_pool(pv, sc_of(pvc))
-                        _register_vol(
-                            vol_vocab, vol_key_of, f"pv:{bound_pv}", pool, register
-                        )
-                        if f"pv:{bound_pv}" in vol_vocab:
-                            row["vol"].append(vol_vocab[f"pv:{bound_pv}"])
-                elif binding_mode(pvc) == "Immediate":
-                    fail |= 1  # unbound immediate claim
+                    src, _vid = _pv_source_id(pv)
+                    pool = (SOURCE_POOL.get(src) if src else None) or _csi_pool(pv, sc)
+                    use.vols[f"pv:{bound_pv}"] = pool
+                elif ((sc or {}).get("volumeBindingMode") or "Immediate") == "Immediate":
+                    use.fail |= 1  # unbound immediate claim
                 else:  # WaitForFirstConsumer
-                    key = f"{ns}/{claim}"
-                    if register:
-                        wffc_vocab.setdefault(key, len(wffc_vocab))
-                    if key in wffc_vocab:
-                        row["wffc"].append(wffc_vocab[key])
+                    use.wffc.append(key)
                 continue
             for src, id_field, _ro in DISK_SOURCES:
                 s = vol.get(src)
                 if s and s.get(id_field):
                     dk = (src, str(s[id_field]))
-                    if register:
-                        disk_vocab.setdefault(dk, len(disk_vocab))
-                    if dk in disk_vocab:
-                        row["disk"].append(
-                            (disk_vocab[dk], not bool(s.get("readOnly")))
-                        )
-                    pool = SOURCE_POOL.get(src)
-                    _register_vol(
-                        vol_vocab, vol_key_of, f"{src}:{s[id_field]}", pool, register
-                    )
-                    if f"{src}:{s[id_field]}" in vol_vocab:
-                        row["vol"].append(vol_vocab[f"{src}:{s[id_field]}"])
+                    use.disks[dk] = use.disks.get(dk, False) or not s.get("readOnly")
+                    use.vols[f"{src}:{s[id_field]}"] = SOURCE_POOL.get(src)
             for src, id_field in LIMIT_ONLY_SOURCES:
                 s = vol.get(src)
                 if s and s.get(id_field):
-                    pool = SOURCE_POOL.get(src)
-                    _register_vol(
-                        vol_vocab, vol_key_of, f"{src}:{s[id_field]}", pool, register
-                    )
-                    if f"{src}:{s[id_field]}" in vol_vocab:
-                        row["vol"].append(vol_vocab[f"{src}:{s[id_field]}"])
-        row["fail"] = fail
-        return row
+                    use.vols[f"{src}:{s[id_field]}"] = SOURCE_POOL.get(src)
+        use.refs = tuple(refs)
+        return use
 
-    # A pod without volumes has the empty row everywhere; the walk
-    # visits the others, in queue order.
-    for j in with_volumes.tolist():
-        row = classify_pod(table.pod(j), register=True)
-        pod_rows.append((j, row))
-        pod_fail[j] = row["fail"]
+    # The call's pods that read a volume, each once: the queue's, and the
+    # bound ones the queue does not hold already (the replay's universe
+    # lists every live pod, the bound ones too).
+    queue_uses = [(j, use_of(table.pod(j))) for j in with_volumes.tolist()]
+    bound_uses = (
+        []
+        if bound_volume_free
+        else [(bp, use_of(bp)) for bp in bound_pods if _pod_reads_volumes(bp)]
+    )
+    # The census: a volume, a ReadWriteOncePod claim or a disk that ONE
+    # pod alone uses can neither be attached twice to a node nor conflict
+    # with anything, so it needs no column: an attachable one adds to its
+    # pool's count on the node, as a request adds to ``requested``.  Only
+    # what two pods share keeps upstream's unique-per-node bookkeeping.
+    users: dict = {}
+    queue_keys = {namespaced_key(table.pod(j)) for j, _use in queue_uses}
+    census = [use for _j, use in queue_uses] + [
+        use for bp, use in bound_uses if namespaced_key(bp) not in queue_keys
+    ]
+    for use in census:
+        for name in (*use.vols, *(("rwop", k) for k in use.rwop), *use.disks):
+            users[name] = users.get(name, 0) + 1
 
-    # Bound pods register too: their attached volumes / disk uses / RWOP
-    # claims must exist in the vocabularies for the per-node counts even
-    # when no queue pod shares them (attach limits count ALL attachments).
-    bound_rows = [classify_pod(bp, register=True) for bp in bound_pods]
+    def shared(name) -> bool:
+        return users.get(name, 0) > 1
 
     # Pool-key vocabulary: every attachable-volumes-* key any node exposes
     # plus any pool a volume maps to.
@@ -442,90 +481,145 @@ def encode_volumes(
         for k in (n.get("status", {}).get("allocatable") or {}):
             if k.startswith("attachable-volumes-"):
                 pool_vocab.setdefault(k.removeprefix("attachable-volumes-"), len(pool_vocab))
-    for pool in set(vol_key_of.values()):
-        if pool:
-            pool_vocab.setdefault(pool, len(pool_vocab))
+    class_vocab: dict[tuple, int] = {}  # PV class -> row
+    class_pv: list[JSON] = []  # a PV of each class
+    wffc_vocab: dict[str, int] = {}  # pvc key -> row
+    vol_vocab: dict[str, int] = {}  # shared attachable volume id -> column
+    vol_pool: list = []
+    rwop_vocab: dict[str, int] = {}  # shared RWOP pvc key -> column
+    disk_vocab: dict[tuple[str, str], int] = {}  # shared (source, id) -> column
+    for use in census:
+        for vid, pool in use.vols.items():
+            if pool:
+                pool_vocab.setdefault(pool, len(pool_vocab))
+                if shared(vid) and vid not in vol_vocab:
+                    vol_vocab[vid] = len(vol_vocab)
+                    vol_pool.append(pool)
+        for key in use.rwop:
+            if shared(("rwop", key)):
+                rwop_vocab.setdefault(key, len(rwop_vocab))
+        for dk in use.disks:
+            if shared(dk):
+                disk_vocab.setdefault(dk, len(disk_vocab))
+    for _j, use in queue_uses:
+        for pv_name in use.pvs:
+            cls = _pv_class(pv_by_name[pv_name])
+            if cls not in class_vocab:
+                class_vocab[cls] = len(class_vocab)
+                class_pv.append(pv_by_name[pv_name])
+        for key in use.wffc:
+            wffc_vocab.setdefault(key, len(wffc_vocab))
 
-    NPV = vocab_pad(len(pv_vocab))
-    C = vocab_pad(len(wffc_vocab))
+    C = vocab_pad(len(class_vocab))
+    W = vocab_pad(len(wffc_vocab))
     V = vocab_pad(len(vol_vocab))
     R = vocab_pad(len(rwop_vocab))
     D = vocab_pad(len(disk_vocab))
     K = max(len(pool_vocab), 1)
 
-    node_labels = [dict(labels_of(n)) for n in nodes]
-    pv_node_ok = np.ones((NPV, n_padded), dtype=bool)
-    pv_zone_ok = np.ones((NPV, n_padded), dtype=bool)
-    for pv_name, vi in pv_vocab.items():
-        pv = pv_by_name[pv_name]
+    # One row a PV CLASS: the (class, node) pairs are evaluated in exact
+    # Python, the PVs of a class share the row.  A class that admits
+    # every node (no affinity, no zone label) costs no walk.
+    node_labels = None
+    pv_node_ok = np.ones((C, n_padded), dtype=bool)
+    pv_zone_ok = np.ones((C, n_padded), dtype=bool)
+    for (affinity, zones), ci in class_vocab.items():
+        if not affinity and not zones:
+            continue
+        if node_labels is None:
+            node_labels = [dict(labels_of(n)) for n in nodes]
+        pv = class_pv[ci]
         for ni, node in enumerate(nodes):
-            pv_node_ok[vi, ni] = _pv_affinity_admits(pv, node)
-            pv_zone_ok[vi, ni] = _pv_zone_admits(pv, node_labels[ni])
+            pv_node_ok[ci, ni] = not affinity or _pv_affinity_admits(pv, node)
+            pv_zone_ok[ci, ni] = not zones or _pv_zone_admits(pv, node_labels[ni])
 
-    pvc_cand_ok = np.zeros((C, n_padded), dtype=bool)
-    pvc_provisionable = np.zeros(C, dtype=bool)
-    for key, ci in wffc_vocab.items():
+    pvc_cand_ok = np.zeros((W, n_padded), dtype=bool)
+    pvc_provisionable = np.zeros(W, dtype=bool)
+    affinity_rows: dict[str, np.ndarray] = {}  # a candidate PV's affinity -> bool [N]
+    for key, wi in wffc_vocab.items():
         pvc = pvc_by_key[key]
-        pvc_provisionable[ci] = provisionable(pvc)
-        cands = [pv for pv in pvs if _pv_matches_claim(pv, pvc)]
-        for ni, node in enumerate(nodes):
-            pvc_cand_ok[ci, ni] = any(
-                _pv_affinity_admits(pv, node) for pv in cands
-            )
+        sc = sc_of(pvc)
+        pvc_provisionable[wi] = bool(
+            sc and (sc.get("provisioner") or "") not in ("", NO_PROVISIONER)
+        )
+        for pv in pvs:
+            if not _pv_matches_claim(pv, pvc):
+                continue
+            affinity = _pv_class(pv)[0]
+            row = affinity_rows.get(affinity)
+            if row is None:
+                row = affinity_rows[affinity] = np.fromiter(
+                    (_pv_affinity_admits(pv, node) for node in nodes), bool, len(nodes)
+                )
+            pvc_cand_ok[wi, : len(nodes)] |= row
 
-    pod_pv = np.zeros((p_padded, NPV), dtype=bool)
-    pod_wffc = np.zeros((p_padded, C), dtype=bool)
+    pod_pv = np.zeros((p_padded, C), dtype=bool)
+    pod_wffc = np.zeros((p_padded, W), dtype=bool)
+    pod_fail = np.zeros(p_padded, dtype=np.int32)
     pod_vol = np.zeros((p_padded, V), dtype=bool)
+    pod_excl = np.zeros((p_padded, K), dtype=np.int32)
+    pod_reads = np.zeros(p_padded, dtype=bool)
     pod_rwop = np.zeros((p_padded, R), dtype=bool)
     pod_disk_any = np.zeros((p_padded, D), dtype=bool)
     pod_disk_rw = np.zeros((p_padded, D), dtype=bool)
-    for j, row in pod_rows:
-        for vi in row["pv"]:
-            pod_pv[j, vi] = True
-        for ci in row["wffc"]:
-            pod_wffc[j, ci] = True
-        for vi in row["vol"]:
-            pod_vol[j, vi] = True
-        for ri in row["rwop"]:
-            pod_rwop[j, ri] = True
-        for di, rw in row["disk"]:
-            pod_disk_any[j, di] = True
-            if rw:
-                pod_disk_rw[j, di] = True
+    pod_refs: dict[int, tuple] = {}
+    for j, use in queue_uses:
+        pod_reads[j] = True
+        pod_fail[j] = use.fail
+        pod_refs[j] = use.refs
+        for pv_name in use.pvs:
+            pod_pv[j, class_vocab[_pv_class(pv_by_name[pv_name])]] = True
+        for key in use.wffc:
+            pod_wffc[j, wffc_vocab[key]] = True
+        for vid, pool in use.vols.items():
+            if vid in vol_vocab:
+                pod_vol[j, vol_vocab[vid]] = True
+            elif pool:
+                pod_excl[j, pool_vocab[pool]] += 1
+        for key in use.rwop:
+            if key in rwop_vocab:
+                pod_rwop[j, rwop_vocab[key]] = True
+        for dk, rw in use.disks.items():
+            if dk in disk_vocab:
+                pod_disk_any[j, disk_vocab[dk]] = True
+                pod_disk_rw[j, disk_vocab[dk]] = rw
 
-    # Per-node initial state from bound pods.
+    # Per-node initial state from bound pods: users of each shared
+    # volume / claim / disk, exclusive attachments a pool.
     attached = np.zeros((n_padded, V), dtype=np.int32)
+    excl = np.zeros((n_padded, K), dtype=np.int32)
     rwop_init = np.zeros((n_padded, R), dtype=np.int32)
     disk_any = np.zeros((n_padded, D), dtype=np.int32)
     disk_rw = np.zeros((n_padded, D), dtype=np.int32)
     node_index = {name_of(n): i for i, n in enumerate(nodes)}
-    for bp, row in zip(bound_pods, bound_rows):
+    for bp, use in bound_uses:
         ni = node_index.get(bp.get("spec", {}).get("nodeName", ""))
         if ni is None:
             continue
-        for vi in row["vol"]:
-            attached[ni, vi] = 1  # attachment is unique per (volume, node)
-        for ri in row["rwop"]:
-            rwop_init[ni, ri] += 1
-        for di, rw in row["disk"]:
-            disk_any[ni, di] += 1
-            if rw:
-                disk_rw[ni, di] += 1
+        for vid, pool in use.vols.items():
+            if vid in vol_vocab:
+                attached[ni, vol_vocab[vid]] += 1
+            elif pool:
+                excl[ni, pool_vocab[pool]] += 1
+        for key in use.rwop:
+            if key in rwop_vocab:
+                rwop_init[ni, rwop_vocab[key]] += 1
+        for dk, rw in use.disks.items():
+            if dk in disk_vocab:
+                disk_any[ni, disk_vocab[dk]] += 1
+                disk_rw[ni, disk_vocab[dk]] += rw
 
     limits = np.full((n_padded, K), -1, dtype=np.int32)
     for ni, node in enumerate(nodes):
-        alloc = node.get("status", {}).get("allocatable") or {}
-        for k, v in alloc.items():
+        if not _node_has_attach_pools(node):
+            continue
+        for k, v in (node.get("status", {}).get("allocatable") or {}).items():
             if k.startswith("attachable-volumes-"):
-                pool = k.removeprefix("attachable-volumes-")
-                if pool in pool_vocab:
-                    limits[ni, pool_vocab[pool]] = int(v)
+                limits[ni, pool_vocab[k.removeprefix("attachable-volumes-")]] = int(v)
 
     vol_key = np.full(V, -1, dtype=np.int32)
-    for vid, vi in vol_vocab.items():
-        pool = vol_key_of.get(vid)
-        if pool and pool in pool_vocab:
-            vol_key[vi] = pool_vocab[pool]
+    for vi, pool in enumerate(vol_pool):
+        vol_key[vi] = pool_vocab[pool]
 
     disk_ro_shareable = np.zeros(D, dtype=bool)
     ro_by_src = {src: ro for src, _f, ro in DISK_SOURCES}
@@ -555,14 +649,13 @@ def encode_volumes(
         pool_names=tuple(
             sorted(pool_vocab, key=pool_vocab.get) + [""] * (K - len(pool_vocab))
         ),
+        excl_init=excl,
+        pod_excl=pod_excl,
+        pod_reads=pod_reads,
+        n_classes=len(class_vocab),
+        n_shared=len(vol_vocab) + len(rwop_vocab) + len(disk_vocab),
+        pod_refs=pod_refs,
     )
-
-
-def _register_vol(vocab, key_of, vid: str, pool: str | None, register: bool) -> None:
-    if register:
-        vocab.setdefault(vid, len(vocab))
-        if pool:
-            key_of[vid] = pool
 
 
 def _pv_source_id(pv: JSON) -> tuple[str | None, str | None]:

@@ -254,7 +254,9 @@ def _full_pod(name: str = "base") -> dict:
     )
     pod["spec"]["containers"][0]["image"] = "registry.example/web:v1"
     pod["spec"]["containers"][0]["ports"] = [{"containerPort": 80, "hostPort": 8080}]
-    pod["spec"]["volumes"] = [{"name": "scratch", "emptyDir": {}}]
+    # (A volume a plugin reads: an ``emptyDir`` concerns none of them and
+    # the ``volumes`` family does not see it.)
+    pod["spec"]["volumes"] = [{"name": "data", "gcePersistentDisk": {"pdName": "disk-a"}}]
     return pod
 
 

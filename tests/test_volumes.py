@@ -275,11 +275,15 @@ def test_bound_volume_count_tracks_across_passes():
     queue = [make_pod("q0")]
 
     f = Featurizer()
-    # Pass 1: a bound volume user -> full encode (disk counts non-zero).
+    # Pass 1: a bound volume user -> full encode (its disk, which no other
+    # pod uses, is one exclusive attachment of its pool and no column).
     feats1 = f.featurize([node], [voluser, plain_bound], queue_pods=queue)
-    assert feats1.aux["volumes"].disk_any_init.sum() > 0
+    assert feats1.aux["volumes"].live
+    assert feats1.aux["volumes"].excl_init.sum() == 1
+    assert feats1.aux["volumes"].disk_any_init.sum() == 0
     # Pass 2: the volume user is gone -> trivial path, zero tensors.
     feats2 = f.featurize([node], [plain_bound], queue_pods=queue)
+    assert not feats2.aux["volumes"].live
     assert feats2.aux["volumes"].disk_any_init.sum() == 0
     # Fresh featurizer agrees with the persistent one, field by field.
     fresh = Featurizer().featurize([node], [plain_bound], queue_pods=queue)
@@ -293,4 +297,5 @@ def test_bound_volume_count_tracks_across_passes():
     volq = make_pod("volq")
     volq["spec"]["volumes"] = [{"name": "d", "gcePersistentDisk": {"pdName": "disk-2"}}]
     feats3 = f.featurize([node], [plain_bound], queue_pods=[volq])
-    assert feats3.aux["volumes"].pod_vol.sum() > 0
+    assert feats3.aux["volumes"].pod_excl.sum() == 1
+    assert feats3.aux["volumes"].pod_vol.sum() == 0
