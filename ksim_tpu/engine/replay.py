@@ -2233,6 +2233,15 @@ class ReplayDriver:
         # whole bound population again.
         self.featurize_bound_records = 0  # guarded-by: main-thread
         self.featurize_bound_shared = 0  # guarded-by: main-thread
+        # ``marshal.dumps`` calls made for a content key, the cold
+        # universe walks' and the featurizer's two tables' together: one
+        # a store pod of a cold lowering and one a created pod; two a
+        # store pod = the hand-over is not engaging (a cleaned copy
+        # whose ``status`` is not empty, a copy that is not the memoised
+        # one).  And the support screens the cold walks ran: one a
+        # distinct manifest, plus the pods without a key.
+        self.content_keys_built = 0  # guarded-by: main-thread
+        self.universe_screens = 0  # guarded-by: main-thread
         # Store writes of the committed segments' reconciles that
         # replaced an object that was there: by a shallow re-wrap that
         # shares the frozen manifest (every placement, nomination and
@@ -2404,6 +2413,8 @@ class ReplayDriver:
             "featurize_node_builds": self.featurize_node_builds,
             "featurize_bound_records": self.featurize_bound_records,
             "featurize_bound_shared": self.featurize_bound_shared,
+            "content_keys_built": self.content_keys_built,
+            "universe_screens": self.universe_screens,
             "prelower": {
                 "windows": self.prelower_windows,
                 "consumed": self.prelower_consumed,
@@ -2748,7 +2759,16 @@ class ReplayDriver:
 
     @staticmethod
     def _pod_supported(pod: JSON, sched_names: tuple[str, ...]) -> str | None:
-        """None when the pod fits the tensor vocabulary, else the reason."""
+        """None when the pod fits the tensor vocabulary, else the reason.
+
+        Of what ``boundagg.content_key`` leaves out of a manifest — the
+        identity fields of ``metadata`` (``podtable._IDENTITY``),
+        ``spec.nodeName`` and all of ``status`` — this reads
+        ``status.phase`` and nothing else: ``_cold_universe`` runs it
+        once a key and tests the phase of every other pod itself.  A
+        reason that reads another of those fields has to join that
+        per-pod half (tests/test_content_key_handover.py holds the walk
+        to this screen of every pod)."""
         from ksim_tpu.scheduler.profile import DEFAULT_SCHEDULER_NAME
         from ksim_tpu.state.extras import _host_ports
         from ksim_tpu.state.volumes import _has_ephemeral_claim
@@ -2768,6 +2788,58 @@ class ReplayDriver:
             # after the pod: the stream does not hold it.
             return "ephemeral_volume_claim"
         return None
+
+    def _cold_universe(
+        self, cur_pods: list[JSON], sched_names: tuple[str, ...], priority_of
+    ) -> "tuple[list[tuple], dict[int, bytes | None]]":
+        """The store's pods for a lowering that missed the cache, in one
+        walk: ``(queue_sort_key, pod key, cleaned pending copy)`` of each,
+        unsorted, and the content keys to hand to the featurizer,
+        ``id(object) -> boundagg.content_key`` (one bytes object a
+        distinct manifest): of each pod that has a cleaned copy, for the
+        bound contents, and of each queue object whose pod-table key
+        would cover the same fields, for the pod table.
+
+        Raises ``_Unsupported`` with the reason of the first offending
+        pod in store order: the first pod of a key is screened in full;
+        a later one can differ from it only in what the key leaves out,
+        of which ``_pod_supported`` reads the phase alone (its
+        docstring).  A pod without a key (a manifest ``marshal`` cannot
+        take) is screened itself."""
+        from ksim_tpu.scheduler.service import queue_sort_key
+        from ksim_tpu.state.boundagg import content_key
+
+        passed: dict[bytes, bytes] = {}
+        keys: "dict[int, bytes | None]" = {}
+        decorated = []
+        for p in cur_pods:
+            key = content_key(p)
+            self.content_keys_built += 1
+            known = None if key is None else passed.get(key)
+            if known is None:
+                self.universe_screens += 1
+                reason = self._pod_supported(p, sched_names)
+                if reason is not None:
+                    raise _Unsupported(reason)
+                if key is not None:
+                    passed[key] = key
+            else:
+                key = known
+                if p.get("status", {}).get("phase") in ("Succeeded", "Failed"):
+                    raise _Unsupported("terminal_phase")
+            clean = _cleaned_pending(p)
+            if clean is not p:
+                keys[id(p)] = key
+            # The pod table's own key (podtable.content_key) leaves out
+            # identity alone: this key, which leaves out spec.nodeName
+            # and status too, cuts the queue as that one would only
+            # where the queue object holds neither.  An empty status is
+            # taken for an absent one: no row builder can tell them
+            # apart (they read it through ``.get``, if at all).
+            if "nodeName" not in (clean.get("spec") or ()) and clean.get("status", {}) == {}:
+                keys[id(clean)] = key
+            decorated.append((queue_sort_key(p, priority_of), _pod_key(p), clean))
+        return decorated, keys
 
     # -- lowering ------------------------------------------------------------
 
@@ -3289,6 +3361,8 @@ class ReplayDriver:
         # binds/annotations only touch nodeName/phase/annotations/rv),
         # and identity-stable across segments, which is what keeps every
         # per-pod featurizer memo row alive (the O(delta) claim).
+        content_keys = None
+        keys_built0, screens0 = self.content_keys_built, self.universe_screens
         if use_cache:
             cache.hits += 1
             priority_of = cache.priority_of
@@ -3307,18 +3381,19 @@ class ReplayDriver:
             # cache-hit path were screened when they entered the
             # universe and cannot have changed: only segment-exempt
             # writes happened since, and those never touch the screened
-            # fields).
-            for p in cur_pods:
-                reason = self._pod_supported(p, sched_names)
-                if reason is not None:
-                    raise _Unsupported(reason)
+            # fields).  The screen runs once a DISTINCT MANIFEST: ONE
+            # walk keys each store pod by content, screens the first pod
+            # of a key in full and every other for what the key does not
+            # cover (its phase), and makes its cleaned copy, sort key and
+            # pod key; the keys go on to the featurizer, which takes
+            # them for its own two tables instead of keying again.
+            decorated, content_keys = self._cold_universe(
+                cur_pods, sched_names, priority_of
+            )
             for n in cur_nodes:
                 if n.get("status", {}).get("images"):
                     raise _Unsupported("node_images")
-            decorated = sorted(
-                (queue_sort_key(p, priority_of), _pod_key(p), _cleaned_pending(p))
-                for p in cur_pods
-            )
+            decorated.sort()
             uni_sort = [d[0] for d in decorated]
             uni_keys = [d[1] for d in decorated]
             uni_clean = [d[2] for d in decorated]
@@ -3416,14 +3491,17 @@ class ReplayDriver:
         node_builds0 = memo.seq_builds
         bound_records0 = self._featurizer.bound_records_built
         bound_shared0 = self._featurizer.bound_records_shared
+        feat_keys0 = self._featurizer.content_keys_built
         feats = self._featurizer.featurize(
             universe_nodes,
             (),
             queue_pods=universe_pods,
             bound_pods=bound_pods,
             namespaces=store.list("namespaces", copy_objs=False),
+            content_keys=content_keys,
             **volume_kw,
         )
+        self.content_keys_built += self._featurizer.content_keys_built - feat_keys0
         node_builds = memo.seq_builds - node_builds0
         self.featurize_node_builds += node_builds
         bound_records = self._featurizer.bound_records_built - bound_records0
@@ -4002,6 +4080,8 @@ class ReplayDriver:
             "node_builds": node_builds,
             "bound_records": bound_records,
             "bound_shared": bound_shared,
+            "keys_built": self.content_keys_built - keys_built0,
+            "screens": self.universe_screens - screens0,
             "cache_hit": use_cache,
             "tp": tp,
             "full_bytes_per_shard": int(full_bytes_shard),

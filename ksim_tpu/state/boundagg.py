@@ -26,7 +26,13 @@ across passes:
   never in what they add.  The ids are small ints, maintained once a
   call from the featurizer's bound-set diff and shared by the families;
   an id goes back with its last pod, so the key map is bounded by the
-  live bound set and holds bytes, never a manifest.
+  live bound set and holds bytes, never a manifest.  Who makes a key:
+  ``BoundContents.sync``, one ``content_key`` an arrival — unless the
+  pod's key is HANDED in (``Featurizer.featurize(content_keys=)``) by
+  a caller that has taken this module's ``content_key`` of the pod
+  already, for a purpose of its own; an arrival that comes without
+  one (a re-wrapped pod of a later window, every pod of the per-pass
+  path) is keyed here.
 - ``sync_family`` maintains one aggregate: a pod's contribution applied
   additively (+1 on arrival, -1 on departure), with per-slot repair
   when a slot's node changed (drained node, replaced object) and a full
@@ -123,19 +129,29 @@ class BoundContents:
         self._keys: "list[bytes | None]" = []
         self._left: list[int] = []
         self._free: list[int] = []
+        #: ``content_key`` calls made here: arrivals that came without a
+        #: handed key.
+        self.keys_built = 0
 
     def __len__(self) -> int:
         """Contents that have a live pod."""
         return len(self._keys) - len(self._free)
 
     def sync(
-        self, bound_map: "dict[int, JSON]", added: Iterable[int], removed: Iterable[int]
+        self,
+        bound_map: "dict[int, JSON]",
+        added: Iterable[int],
+        removed: Iterable[int],
+        handed: "dict[int, bytes | None] | None" = None,
     ) -> list[int]:
         """Follow one bound-set diff: departures give their ids back,
-        arrivals get theirs.  Returns the ids whose last pod left — a
-        table keyed by content id drops them before it reads an
-        arrival's, because an arrival of this very call may hold one
-        again for another content."""
+        arrivals get theirs — by the key that came with the pod
+        (``handed``: ``id(pod)`` -> its ``content_key``, taken by the
+        caller already), by ``content_key`` otherwise.  Returns the ids
+        whose last pod left — a table keyed by content id drops them
+        before it reads an arrival's, because an arrival of this very
+        call may hold one again for another content."""
+        handed = handed or {}
         of, ids, keys, left, free = self.of, self._ids, self._keys, self._left, self._free
         released = []
         for pid in removed:
@@ -149,7 +165,10 @@ class BoundContents:
                 released.append(c)
         free.extend(released)
         for pid in added:
-            key = content_key(bound_map[pid])
+            key = handed.get(pid, _MISS)
+            if key is _MISS:
+                key = content_key(bound_map[pid])
+                self.keys_built += 1
             c = None if key is None else ids.get(key)
             if c is not None:
                 left[c] += 1

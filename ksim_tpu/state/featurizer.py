@@ -284,6 +284,14 @@ class Featurizer:
         return self._table.rows_copied
 
     @property
+    def content_keys_built(self) -> int:
+        """``content_key`` calls (one ``marshal.dumps`` each) this
+        featurizer made: a new queue pod and a bound arrival cost one
+        each unless the caller handed its key in (``featurize``'s
+        ``content_keys``)."""
+        return self._table.keys_built + self._contents.keys_built
+
+    @property
     def bound_records_built(self) -> int:
         """Bound-pod records for which an additive family ran its
         contribution builder, summed over the families (state/boundagg.py
@@ -335,6 +343,7 @@ class Featurizer:
         pvs: Sequence[JSON] = (),
         pvcs: Sequence[JSON] = (),
         storage_classes: Sequence[JSON] = (),
+        content_keys: "dict[int, bytes | None] | None" = None,
     ) -> FeaturizedSnapshot:
         """``pods`` are existing cluster pods (bound ones charge their node);
         ``queue_pods`` are the pods to schedule (the pod axis P);
@@ -343,7 +352,15 @@ class Featurizer:
         ``store.pods_with_node()`` to skip the O(all pods) split —
         phase filtering still happens here);
         ``namespaces`` feed namespaceSelector matching (InterPodAffinity);
-        ``pvs``/``pvcs``/``storage_classes`` feed the volume plugins.
+        ``pvs``/``pvcs``/``storage_classes`` feed the volume plugins;
+        ``content_keys`` maps ``id(pod)`` to the pod's
+        ``boundagg.content_key`` for the pods whose key the caller has
+        taken already: a bound pod found there is not keyed again, nor
+        is a queue pod — which the caller lists only where that key
+        leaves out nothing the queue object holds (no ``spec.nodeName``,
+        an empty ``status`` or none), so that it cuts the queue as the
+        pod table's own key would; every other pod is keyed here as if
+        nothing had been handed in.
 
         Queue pods are recognised by object identity across calls (the
         row table, state/podtable.py): a pod object handed in again is
@@ -394,7 +411,7 @@ class Featurizer:
         cid_of, vol_of = self._contents.of, self._vol_of
         for pid in removed:
             self._bound_vol_count -= vol_of[cid_of[pid]]
-        released = self._contents.sync(bound_map, added, removed)
+        released = self._contents.sync(bound_map, added, removed, content_keys)
         for c in released:
             del vol_of[c]
         for pid in added:
@@ -430,7 +447,7 @@ class Featurizer:
         def forget_vals(rows: np.ndarray) -> None:
             count_vals(rows[static.valid[rows]], -1)
 
-        n_new = table.index(sched_pods, forget_vals)
+        n_new = table.index(sched_pods, forget_vals, content_keys)
         self.pod_rows_built += n_new
         self.pod_rows_reused += P - n_new
         self.featurize_passes += 1

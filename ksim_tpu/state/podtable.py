@@ -22,7 +22,12 @@ rows.  This table keeps the lowered rows instead:
   content and the family's token, never of who the pod is.  The id is
   a small int, recycled when its last row is released, so the key map
   is bounded by the live rows exactly as the table is and holds bytes,
-  never a manifest.
+  never a manifest.  Who makes a key: this table, ``content_key`` of
+  every pod new to it — unless the caller of ``index`` HANDS the pod's
+  key in (``handed``), having keyed the pod already for a purpose of
+  its own.  The table takes such a key as it is, and keys every other
+  pod itself, as if nothing had been handed; that a handed key cuts
+  the pods as ``content_key`` would is the caller's to see to.
 - A ``RowFamily`` is a set of named growable arrays with one row per
   table row, valid for a TOKEN (a vocabulary lineage, a resource axis,
   namespace labels).  ``sync`` makes the rows of this call valid under
@@ -261,6 +266,9 @@ class PodTable:
         # Pods new to the table whose content a live row (or an earlier
         # pod of the same call) already had.
         self.rows_copied = 0
+        # ``content_key`` calls made here: new pods that came without a
+        # handed key.
+        self.keys_built = 0
 
     def __len__(self) -> int:
         return len(self._pods)
@@ -289,10 +297,15 @@ class PodTable:
         self,
         pods: Sequence[JSON],
         on_release: "Callable[[np.ndarray], None] | None" = None,
+        handed: "dict[int, bytes | None] | None" = None,
     ) -> int:
         """Point the table at this call's pods (``self.idx``); returns
         how many of them were new.  ``on_release(rows)`` sees the rows
-        about to be released while their columns are still readable."""
+        about to be released while their columns are still readable.
+        ``handed`` maps ``id(pod)`` to the content key its caller has
+        taken already: bytes, equal for two pods only if their manifests
+        are equal outside ``_IDENTITY`` (an empty ``status`` may stand
+        for an absent one), or None for a pod that shares with nobody."""
         self._gen += 1
         row_of = self._row_of
         idx = np.array(list(map(row_of.get, map(id, pods), repeat(-1))), dtype=np.intp)
@@ -331,17 +344,24 @@ class PodTable:
             self._forget_content(gone)
         # After the release, so that "copied" means a row that stays.
         if n > n0:
-            self._cid[n0:n] = self._content_ids(self._pods[n0:n])
+            self._cid[n0:n] = self._content_ids(self._pods[n0:n], handed or {})
         if n > 2 * self._n_live:
             idx = self._compact(idx, n)
         self.idx = idx
         return n - n0
 
-    def _content_ids(self, pods: "list[JSON]") -> "list[int]":
-        """A content id for each of ``pods``, new to the table."""
+    def _content_ids(
+        self, pods: "list[JSON]", handed: "dict[int, bytes | None]"
+    ) -> "list[int]":
+        """A content id for each of ``pods``, new to the table: by the
+        key that came with the pod, by ``content_key`` otherwise."""
         ids, keys, left, free = self._cid_of, self._cid_key, self._cid_rows, self._cid_free
         out = []
-        for key in map(content_key, pods):
+        for p in pods:
+            key = handed.get(id(p), _UNSET)
+            if key is _UNSET:
+                key = content_key(p)
+                self.keys_built += 1
             c = None if key is None else ids.get(key)
             if c is not None:
                 left[c] += 1

@@ -145,9 +145,9 @@ def test_gather_guard_survivors_cost_no_per_pod_python(monkeypatch):
     survivor_builds: dict[int, int] = {}
     orig_index, orig_sync = PodTable.index, PodTable.sync
 
-    def index(self, pods, on_release=None):
+    def index(self, pods, *args):
         held[id(self)] = set(self._row_of)
-        return orig_index(self, pods, on_release)
+        return orig_index(self, pods, *args)
 
     def sync(self, fam, token, build, widths=None, **kw):
         before = held[id(self)]
