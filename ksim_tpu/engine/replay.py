@@ -3617,7 +3617,9 @@ class ReplayDriver:
         ipa = feats.aux["interpod"]
         spread = feats.aux["spread"]
 
-        # Initial dynamic state.
+        # Initial dynamic state.  (The lap's seams are stages: the span
+        # exit, or the next seam, closes each.)
+        TRACE.stage("replay.lower.tensors.state")
         valid0 = np.zeros(N, bool)
         for n in cur_nodes:
             valid0[slot_of[name_of(n)]] = True
@@ -3643,6 +3645,7 @@ class ReplayDriver:
 
         # Inter-pod local per-node accumulators from the bound population
         # (the linear pre-aggregation the segment re-derives each step).
+        TRACE.stage("replay.lower.tensors.interpod")
         T = ipa.pod_term_match.shape[1]
         ip_cnt0 = np.zeros((N, T), np.int32)
         ip_eat0 = np.zeros((N, T), np.int32)
@@ -3669,6 +3672,8 @@ class ReplayDriver:
         # Widths bucket like every other axis: an exact-max width would
         # hand the jit cache a fresh shape (= a multi-second compile)
         # nearly every segment.
+        TRACE.stage("replay.lower.tensors.ranks")
+
         def pad(lists: list[list[int]]) -> np.ndarray:
             width = vocab_pad(max((len(x) for x in lists), default=1))
             out = np.full((K, width), -1, np.int32)
@@ -3760,6 +3765,8 @@ class ReplayDriver:
                         walk_rows_built += 1
                     walk_rows[k] = walk_row
                 self.walk_rows_built += walk_rows_built
+            # The walk-order stage closed the seam's: open it again.
+            TRACE.stage("replay.lower.tensors.ranks")
         live_row = valid0.copy()
         live_sorted: list[str] = sorted(node_names)
         live_slots = (
@@ -3816,6 +3823,7 @@ class ReplayDriver:
         # Queue width: pending(now) + creates + requeue-able is an exact
         # upper bound on the pending population at any step, so eligible
         # can never exceed it (overflow-free by construction).
+        TRACE.stage("replay.lower.tensors.statics")
         pending_now = int(np.sum(alive0 & (bound0 < 0)))
         drained = set().union(*step_node_deletes) if step_node_deletes else set()
         drained_bound = sum(

@@ -11,10 +11,10 @@ from ksim_tpu.jobs.manager import (
     JOB_FAULT_SITES,
     TERMINAL_STATES,
     Job,
+    JobClock,
     JobLimitExceeded,
     JobManager,
     JobThrottled,
-    SubmitClock,
     parse_job_faults,
 )
 from ksim_tpu.jobs.queue import JobQueue, JobQueueFull
@@ -25,6 +25,7 @@ __all__ = [
     "FileLock",
     "FleetMember",
     "Job",
+    "JobClock",
     "JobJournal",
     "JobLimitExceeded",
     "JobManager",
@@ -33,6 +34,5 @@ __all__ = [
     "JobThrottled",
     "JournalTailer",
     "LeasePlane",
-    "SubmitClock",
     "parse_job_faults",
 ]

@@ -123,7 +123,7 @@ __all__ = [
     "JobManager",
     "JobQueueFull",
     "JobThrottled",
-    "SubmitClock",
+    "JobClock",
     "parse_job_faults",
 ]
 
@@ -405,35 +405,72 @@ def _snapshot_counts(snapshot: dict, *, restored: bool) -> dict:
     return doc
 
 
-class SubmitClock:
-    """The clock readings of one ``POST /api/v1/jobs``, taken by the
-    handler itself whatever the trace plane's state (the global plane,
+class JobClock:
+    """One job's clock: every reading its result document's ``submit``,
+    ``snapshot.load_s`` and ``account`` blocks are differences of.
+    Plain ``time.perf_counter()`` readings on the thread that does the
+    work, taken whatever the trace plane's state (the global plane,
     where the ``jobs.submit`` span and its stages live, is off in an
-    untraced run, and a result document must not depend on that):
-    arrival, body read, document parsed, ops built
-    (``JobManager.submit`` takes that one), response written.  The job
-    keeps the clock and its result document carries the differences
-    as ``submit``."""
+    untraced run, and a result document must not depend on that) — a
+    dozen a JOB, nothing per step, per segment or per pod.
 
-    __slots__ = ("_marks", "_closed")
+    The handler of a ``POST /api/v1/jobs`` makes it on arrival and takes
+    the next four in order (``mark`` / ``close``): body read, document
+    parsed, ops built (``JobManager.submit`` takes that one), response
+    written.  A job no handler submitted gets one in
+    ``JobManager.submit``.  From the queue on the readings go by name
+    (``at``): ``queued``, ``claim``, ``journal0`` / ``journal1``,
+    ``run0``, ``snapshot0`` / ``snapshot1``, ``build0``, ``built``,
+    ``run1``, ``digest``, ``document``, ``released``,
+    ``collected``, ``sealed``.  Beside them two CPU readings at each end
+    (``time.process_time()`` at arrival, ``time.thread_time()`` of the
+    worker at its claim) tell a job that was running from one that was
+    not scheduled."""
+
+    __slots__ = (
+        "_marks", "_closed", "_at", "_process_cpu0", "_worker_cpu0",
+        "run_children_s",
+    )
 
     def __init__(self) -> None:
         self._marks = [time.perf_counter()]
         self._closed = threading.Event()
+        self._at: dict[str, float] = {}
+        self._process_cpu0 = time.process_time()
+        self._worker_cpu0 = 0.0
+        # What the ``jobs.run`` span's direct children took
+        # (``obs._Span.watch``), for ``run_self_s``.
+        self.run_children_s = 0.0
 
     def mark(self) -> None:
         self._marks.append(time.perf_counter())
 
     def close(self) -> None:
-        """The last reading: the response is out."""
+        """The handler's last reading: the response is out."""
         self.mark()
         self._closed.set()
 
+    def at(self, name: str) -> None:
+        self._at[name] = time.perf_counter()
+
+    def claimed(self) -> None:
+        """The worker took the job: its wall and its own CPU clock."""
+        self._worker_cpu0 = time.thread_time()
+        self.at("claim")
+
+    def between(self, a: str, b: str) -> float:
+        """Seconds from reading ``a`` to reading ``b``; 0 where either
+        was not taken (no journal, no snapshot)."""
+        at = self._at
+        return at[b] - at[a] if a in at and b in at else 0.0
+
     def seconds(self) -> "dict | None":
-        """``{read_s, parse_s, build_s, enqueue_s, total_s}``.  The job
-        is in the queue before its 202 is written, so a worker may ask
-        while the handler still writes: it waits for ``close`` (None if
-        that never comes)."""
+        """``{read_s, parse_s, build_s, enqueue_s, total_s}``, or None
+        for a job no handler submitted.  The job is in the queue before
+        its 202 is written, so a worker may ask while the handler still
+        writes: it waits for ``close`` (None if that never comes)."""
+        if len(self._marks) < 4:
+            return None
         if not self._closed.wait(5.0) or len(self._marks) != 5:
             return None
         m = self._marks
@@ -443,6 +480,39 @@ class SubmitClock:
         }
         doc["total_s"] = round(m[4] - m[0], 6)
         return doc
+
+    def account(self) -> dict:
+        """The ``account`` block, on the worker, as the document is
+        sealed (takes the ``sealed`` reading itself): where the job's
+        wall went, in parts that share their end points, and what no
+        part names.  ``unnamed_s`` is the wall less the sequential
+        parts; ``build_s`` lies inside ``run_s``."""
+        worker_cpu = time.thread_time() - self._worker_cpu0
+        process_cpu = time.process_time() - self._process_cpu0
+        self.at("sealed")
+        at = self._at
+        arrival = self._marks[0]
+        queued = at.get("queued", arrival)
+        claim = at.get("claim", queued)
+        sequential = {
+            "submit_s": queued - arrival,
+            "queue_s": claim - queued,
+            "journal_s": self.between("journal0", "journal1"),
+            "run_s": self.between("run0", "run1"),
+            "digest_s": self.between("run1", "digest"),
+            "document_s": self.between("digest", "document"),
+            "release_s": self.between("document", "released"),
+            "collect_s": self.between("released", "collected"),
+        }
+        wall = at["sealed"] - arrival
+        doc = {"wall_s": wall, **sequential}
+        doc["build_s"] = self.between("build0", "built")
+        doc["unnamed_s"] = wall - sum(sequential.values())
+        doc["run_self_s"] = sequential["run_s"] - self.run_children_s
+        doc["off_cpu_s"] = at["sealed"] - claim - worker_cpu
+        doc["worker_cpu_s"] = worker_cpu
+        doc["process_cpu_s"] = process_cpu
+        return {k: round(v, 6) for k, v in doc.items()}
 
 
 class Job:
@@ -464,7 +534,7 @@ class Job:
         faults: "FaultPlane | None",
         tenant: str = "default",
         runtime0: "dict | None" = None,
-        submit_clock: "SubmitClock | None" = None,
+        clock: "JobClock | None" = None,
     ) -> None:
         self.id = job_id
         self.ordinal = ordinal
@@ -479,9 +549,9 @@ class Job:
         # arrived: the result's ``runtime`` block is their growth from
         # here, so a full collection during the submit is in it.
         self.runtime0 = runtime0 if runtime0 is not None else runtime_totals()
-        # The POST's own clock (None for a job no handler submitted):
-        # the result's ``submit`` block.
-        self.submit_clock = submit_clock
+        # The job's one clock, the POST's own where a handler made it:
+        # the result's ``submit`` and ``account`` blocks.
+        self.clock = clock if clock is not None else JobClock()
         self.steps_total = len({op.step for op in ops})
         # The job's PRIVATE trace plane: ring + histograms, every record
         # tagged with the job id; the sink feeds the SSE event log.
@@ -1276,6 +1346,7 @@ class JobManager:
                     job.checkpoint_segment = last.get("segment")
             job.emit({"event": "state", "state": "queued", "resumed": True},
                      vital=True)
+            job.clock.at("queued")
             self.queue.put(job, priority=priority, cost=len(ops))
             return job
         except Exception:
@@ -1348,6 +1419,7 @@ class JobManager:
         job.emit({"event": "state", "state": "queued", "resumed": True},
                  vital=True)
         # JobQueueFull propagates with no registry residue.
+        job.clock.at("queued")
         self.queue.put(job, priority=priority, cost=len(ops))
         with self._lock:
             self._seq = max(self._seq, ordinal + 1)
@@ -1366,7 +1438,7 @@ class JobManager:
         priority: "int | None" = None,
         tenant: "str | None" = None,
         runtime0: "dict | None" = None,
-        submit_clock: "SubmitClock | None" = None,
+        clock: "JobClock | None" = None,
     ) -> Job:
         """Validate + enqueue one tenant job document.  Raises
         ``ScenarioSpecError`` on a bad spec (HTTP 400),
@@ -1381,11 +1453,12 @@ class JobManager:
         ``runtime0`` is the ``obs.runtime_totals()`` reading that opens
         the job's ``runtime`` window: the HTTP layer takes it when the
         POST arrives, before it reads the body; absent, it is taken
-        here, before the spec is parsed.  ``submit_clock`` is the same
-        handler's ``SubmitClock``, inside its ``jobs.submit`` span:
-        once the ops are built it takes one reading here, the span's
+        here, before the spec is parsed.  ``clock`` is the same
+        handler's ``JobClock``, inside its ``jobs.submit`` span: once
+        the ops are built it takes one reading here, the span's
         ``build`` stage gives way to ``enqueue``, and the job keeps the
-        clock for its result's ``submit`` block.
+        clock for its result's ``submit`` and ``account`` blocks; absent,
+        the job's clock starts here.
 
         The submission ordinal (the ``KSIM_JOBS_FAULTS`` key) commits
         only on a SUCCESSFUL enqueue: a refused submission must not
@@ -1399,6 +1472,9 @@ class JobManager:
 
         if runtime0 is None:
             runtime0 = runtime_totals()
+        handler_clock = clock is not None
+        if clock is None:
+            clock = JobClock()
         try:
             ops, sim, spec_priority, fault_spec = _parse_job_spec(
                 doc,
@@ -1420,8 +1496,8 @@ class JobManager:
                 f"over the per-job bound of {e.limit} ({env}); ingest "
                 "stopped early"
             ) from None
-        if submit_clock is not None:
-            submit_clock.mark()
+        if handler_clock:
+            clock.mark()
             TRACE.stage("jobs.submit.enqueue")
         if priority is None:
             priority = spec_priority
@@ -1494,7 +1570,7 @@ class JobManager:
                 faults=faults,
                 tenant=tenant,
                 runtime0=runtime0,
-                submit_clock=submit_clock,
+                clock=clock,
             )
             # The queued event lands BEFORE the queue hand-off: once
             # put() returns, a worker may claim (and emit "running")
@@ -1508,6 +1584,8 @@ class JobManager:
             # claims it by lease; backpressure there is per-tenant
             # admission plus the workers' own queue capacity.
             if self.role != "frontdoor":
+                # Read BEFORE the hand-off: a worker may claim at once.
+                clock.at("queued")
                 self.queue.put(
                     job, priority=priority, cost=len(ops)
                 )  # JobQueueFull -> no ordinal
@@ -1608,6 +1686,7 @@ class JobManager:
             if not job.claim():
                 job = None
                 continue  # cancelled while queued
+            job.clock.claimed()
             with self._lock:
                 self._active += 1
             _old_gen_job_starts()
@@ -1641,14 +1720,22 @@ class JobManager:
         # that finds it (and no terminal record) knows the job died
         # mid-run and flags it ``interrupted``.  An unappendable
         # journal fails the job without running it.
+        clock = job.clock
+        if self._journal is not None:
+            clock.at("journal0")
         if not self._journal_state(job, "running"):
             job.finish("failed", error="journal append failed (running)")
             return
+        if self._journal is not None:
+            clock.at("journal1")
         try:
             state, result, error = self._attempt(job)
             with TRACE.scoped(job.trace):
-                job.let_go()
+                with TRACE.stage("jobs.finish.release"):
+                    job.let_go()
+                clock.at("released")
                 collect_scheduled()
+                clock.at("collected")
             if state != "succeeded":
                 job.finish(state, error=error)
                 self._journal_state(job, state, error=error)  # best-effort: terminal
@@ -1656,9 +1743,12 @@ class JobManager:
             # Full collections and XLA compiles / cache loads the PROCESS
             # saw since this job's POST arrived (other jobs' included).
             result["runtime"] = runtime_growth(job.runtime0)
-            submit = job.submit_clock.seconds() if job.submit_clock else None
+            submit = clock.seconds()
             if submit is not None:
                 result["submit"] = submit
+            # Where the job's wall went, by its own clock; the document
+            # is sealed at the reading this takes (docs/jobs.md).
+            result["account"] = clock.account()
             # WAL: result + terminal record become durable BEFORE the
             # in-memory success — a success the journal cannot vouch
             # for must not be reported (it would vanish on restart).
@@ -1678,14 +1768,21 @@ class JobManager:
         state, result, error)``.  Every way out of here leaves nothing
         of the job's graph on a frame or a traceback — the caller lets
         the graph go and collects before it publishes the state."""
+        clock = job.clock
         try:
             with TRACE.scoped(job.trace):
-                with TRACE.span("jobs.run", steps=job.steps_total):
+                clock.at("run0")
+                with TRACE.span("jobs.run", steps=job.steps_total) as run:
+                    # What no child span, lap or stage of jobs.run covers
+                    # is the account's ``run_self_s``.
+                    run.watch()
                     FAULTS.check("jobs.run")
                     if job.faults is not None:
                         job.faults.check("jobs.run")
                     res, runner = self._execute(job)
-            return "succeeded", self._result_doc(job, res, runner), None
+                clock.at("run1")
+                clock.run_children_s = run.children_ns / 1e9
+                return "succeeded", self._result_doc(job, res, runner), None
         except RunCancelled:
             logger.info("job %s cancelled", job.id)
             return "cancelled", None, None
@@ -1704,18 +1801,22 @@ class JobManager:
         from ksim_tpu.state.cluster import ClusterStore
 
         sim = job.sim
+        clock = job.clock
         fleet = sim.get("fleet")
         if fleet:
-            runner = ScenarioRunner(
-                record=sim.get("recordMode", "selection"),
-                preemption=bool(sim.get("preemption", False)),
-                node_sampling=bool(sim.get("nodeSampling", False)),
-                max_pods_per_pass=sim.get("maxPodsPerPass"),
-                pod_bucket_min=sim.get("podBucketMin"),
-                device_replay=True,
-                fleet=int(fleet),
-                cancel=job.cancel,
-            )
+            clock.at("build0")
+            with TRACE.stage("jobs.run.build"):
+                runner = ScenarioRunner(
+                    record=sim.get("recordMode", "selection"),
+                    preemption=bool(sim.get("preemption", False)),
+                    node_sampling=bool(sim.get("nodeSampling", False)),
+                    max_pods_per_pass=sim.get("maxPodsPerPass"),
+                    pod_bucket_min=sim.get("podBucketMin"),
+                    device_replay=True,
+                    fleet=int(fleet),
+                    cancel=job.cancel,
+                )
+            clock.at("built")
             job.runner = runner
             res = runner.run(job.ops)
             return res, runner
@@ -1741,36 +1842,43 @@ class JobManager:
             if snapshot:
                 from ksim_tpu.state.snapshot import SnapshotService
 
-                t0 = time.perf_counter()
+                clock.at("snapshot0")
                 # A stage, not a ring child: jobs.run's self time stays
                 # its whole duration (docs/observability.md).
                 with TRACE.stage("jobs.run.snapshot"):
                     batched = SnapshotService(store).load(snapshot)
-                job._snapshot_info["load_s"] = round(time.perf_counter() - t0, 6)
+                clock.at("snapshot1")
+                job._snapshot_info["load_s"] = round(
+                    clock.between("snapshot0", "snapshot1"), 6
+                )
                 # The objects that went into the store a batch a kind
                 # (``ClusterStore.apply_many``): the document's, less
                 # the system priority classes and ``kube-`` namespaces.
                 job._snapshot_info["batched_objects"] = batched
-            service = SchedulerService(
-                store,
-                config=sim.get("schedulerConfig"),
-                record=sim.get("recordMode", "selection"),
-                preemption=bool(sim.get("preemption", False)),
-                node_sampling=bool(sim.get("nodeSampling", False)),
-                max_pods_per_pass=sim.get("maxPodsPerPass"),
-                pod_bucket_min=sim.get("podBucketMin"),
+        clock.at("build0")
+        with TRACE.stage("jobs.run.build"):
+            if service is None:
+                service = SchedulerService(
+                    store,
+                    config=sim.get("schedulerConfig"),
+                    record=sim.get("recordMode", "selection"),
+                    preemption=bool(sim.get("preemption", False)),
+                    node_sampling=bool(sim.get("nodeSampling", False)),
+                    max_pods_per_pass=sim.get("maxPodsPerPass"),
+                    pod_bucket_min=sim.get("podBucketMin"),
+                )
+            hook = None
+            if self._journal is not None and self._checkpoint_every > 0:
+                hook = self._checkpoint_hook_for(job, store, service)
+            runner = ScenarioRunner(
+                store=store,
+                service=service,
+                device_replay=bool(sim.get("deviceReplay", False)),
+                cancel=job.cancel,
+                private_faults=job.faults,
+                checkpoint_hook=hook,
             )
-        hook = None
-        if self._journal is not None and self._checkpoint_every > 0:
-            hook = self._checkpoint_hook_for(job, store, service)
-        runner = ScenarioRunner(
-            store=store,
-            service=service,
-            device_replay=bool(sim.get("deviceReplay", False)),
-            cancel=job.cancel,
-            private_faults=job.faults,
-            checkpoint_hook=hook,
-        )
+        clock.at("built")
         job.store = store
         job.runner = runner
         res = runner.run(
@@ -1971,42 +2079,53 @@ class JobManager:
         return None
 
     def _result_doc(self, job: Job, res, runner) -> dict:
-        doc: dict = {
-            "phase": "Succeeded",
-            "done": res.succeeded,
-            "result": {
-                "eventsApplied": res.events_applied,
-                "podsScheduled": res.pods_scheduled,
-                "unschedulableAttempts": res.unschedulable_attempts,
-                "wallSeconds": round(res.wall_seconds, 3),
-                "steps": len(res.steps),
-            },
-            "phases": dict(res.phase_seconds),
-            # The job's OWN latency quantiles (its private histograms).
-            "latency": job.trace_summary()["histograms"],
-        }
-        if res.lanes is not None:
-            doc["lanes"] = [
-                [r.pods_scheduled, r.unschedulable_attempts] for r in res.lanes
-            ]
-        info = job._resume_info
-        if info is not None:
-            # eventsReplayed counts only THIS process's suffix — the
-            # restart-check evidence that an incremental resume
-            # did strictly less work than a from-scratch replay.
-            doc["resume"] = {
-                "fromSegment": info["fromSegment"],
-                "cursor": info["cursor"],
-                "eventsReplayed": res.events_applied - info["carried_events"],
-            }
-        if job._snapshot_info is not None:
-            doc["snapshot"] = job._snapshot_info
+        """The succeeded job's document, on the job's plane right after
+        its ``jobs.run`` span: the digest, then everything else, each
+        under its stage and between two readings of the job's clock."""
+        clock = job.clock
         drv = getattr(runner, "replay_driver", None)
-        if drv is not None:
-            doc["replay"] = drv.stats()  # includes the shared compile_cache
-            # Where every pod of the job's store stands at its end: the
-            # counts above do not show a pod that landed elsewhere.
-            doc["replay"]["placements_digest"] = runner.store.placements_digest()
+        digest = None
+        with TRACE.stage("jobs.finish.digest"):
+            if drv is not None:
+                # Where every pod of the job's store stands at its end:
+                # the counts do not show a pod that landed elsewhere.
+                digest = runner.store.placements_digest()
+        clock.at("digest")
+        with TRACE.stage("jobs.finish.document"):
+            doc: dict = {
+                "phase": "Succeeded",
+                "done": res.succeeded,
+                "result": {
+                    "eventsApplied": res.events_applied,
+                    "podsScheduled": res.pods_scheduled,
+                    "unschedulableAttempts": res.unschedulable_attempts,
+                    "wallSeconds": round(res.wall_seconds, 3),
+                    "steps": len(res.steps),
+                },
+                "phases": dict(res.phase_seconds),
+                # The job's OWN latency quantiles (its private histograms).
+                "latency": job.trace_summary()["histograms"],
+            }
+            if res.lanes is not None:
+                doc["lanes"] = [
+                    [r.pods_scheduled, r.unschedulable_attempts] for r in res.lanes
+                ]
+            info = job._resume_info
+            if info is not None:
+                # eventsReplayed counts only THIS process's suffix — the
+                # restart-check evidence that an incremental resume
+                # did strictly less work than a from-scratch replay.
+                doc["resume"] = {
+                    "fromSegment": info["fromSegment"],
+                    "cursor": info["cursor"],
+                    "eventsReplayed": res.events_applied - info["carried_events"],
+                }
+            if job._snapshot_info is not None:
+                doc["snapshot"] = job._snapshot_info
+            if drv is not None:
+                doc["replay"] = drv.stats()  # includes the shared compile_cache
+                doc["replay"]["placements_digest"] = digest
+        clock.at("document")
         return doc
 
     # -- lookups & lifecycle --------------------------------------------

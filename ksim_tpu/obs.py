@@ -179,6 +179,9 @@ SPAN_NAMES: tuple[str, ...] = (
     "jobs.run",  # one tenant job end-to-end on a job-plane worker
     #              (ksim_tpu/jobs/manager.py; recorded on the JOB's
     #              private plane via the worker's scoped override)
+    "jobs.result",  # GET /api/v1/jobs/<id>/result on the HTTP handler
+    #                 thread: the document serialised and written — the
+    #                 last thing a job's client waits for (global plane)
     "jobs.submit",  # POST /api/v1/jobs on the HTTP handler thread:
     #                 body read + parse, spec validation (15,000
     #                 operations are 15,000 Operation objects) and the
@@ -274,6 +277,30 @@ SPAN_NAMES: tuple[str, ...] = (
     "jobs.run.snapshot",  # stage of jobs.run: the spec's
     #                       initialSnapshot loaded into the job's store
     #                       (SnapshotService.load), before the runner
+    "jobs.run.build",  # stage of jobs.run: SchedulerService(...) and
+    #                    ScenarioRunner(...), up to runner.run
+    "jobs.finish.digest",  # stage after jobs.run, on the job's plane:
+    #                        store.placements_digest() over every pod
+    "jobs.finish.document",  # stage: the result document (the job's
+    #                          latency summary, drv.stats(), the blocks)
+    "jobs.finish.release",  # stage: job.let_go(), the job's graph freed
+    #                         (the scheduled collection that follows is
+    #                         the ring span service.gc)
+    "replay.lower.tensors.state",  # stage of replay.lower.tensors: the
+    #                                initial dynamic state (live / bound /
+    #                                backoff rows: one pass over the
+    #                                store's nodes and pods)
+    "replay.lower.tensors.interpod",  # stage: the inter-pod per-node
+    #                                   accumulators of the bound pods
+    #                                   and their check against the
+    #                                   featurizer's carry
+    "replay.lower.tensors.ranks",  # stage: the per-step event index
+    #                                tensors, the slot simulation, rank
+    #                                rows and live-node views a step
+    #                                (around replay.lower.walk_order)
+    "replay.lower.tensors.statics",  # stage: queue width, statics,
+    #                                  consts, nominations, the victim-
+    #                                  search tables, state0, the log
 )
 
 #: Instant event names.
@@ -595,6 +622,11 @@ class _NoopSpan:
     def lap(self, name: str, **args) -> None:
         pass
 
+    def watch(self) -> None:
+        pass
+
+    children_ns = 0
+
 
 _NOOP = _NoopSpan()
 
@@ -649,6 +681,7 @@ class _Span:
 
     __slots__ = (
         "_plane", "name", "args", "_t0", "_jax_ctx", "_lap", "_observe", "_timer",
+        "_watch", "children_ns",
     )
 
     def __init__(self, plane: "TracePlane", name: str, args: dict) -> None:
@@ -660,6 +693,8 @@ class _Span:
         self._lap = None  # (name, t0, args, jax_ctx) of the open lap
         self._observe = None
         self._timer = None
+        self._watch = None
+        self.children_ns = 0  # what ``watch`` summed, set at exit
 
     def __enter__(self):
         plane = self._plane
@@ -677,6 +712,19 @@ class _Span:
         values the caller only learns inside the span, e.g. the ACTUAL
         lowered step count of a window that hit a vocabulary miss."""
         self.args.update(args)
+
+    def watch(self) -> None:
+        """Ask the plane to sum what this span's DIRECT children on this
+        thread take from here on — child spans, laps and stages alike,
+        the gc hook's spans too — into ``children_ns`` (read it after the
+        exit): the span's extent less that is the time no name covers.
+        The ring cannot say it (a stage is not in it).  One span a plane
+        at a time, called inside the ``with``; every other close pays
+        one attribute read for it."""
+        plane = self._plane
+        self._watch = plane._watch = [
+            threading.get_ident(), getattr(plane._tls, "depth", 0), 0,
+        ]
 
     def lap(self, name: str, **args) -> None:
         """Open the next SEQUENTIAL child phase of this span, closing
@@ -722,6 +770,9 @@ class _Span:
             _jax_annotation_exit(self._jax_ctx)
         if self._observe is not None:
             self._observe(self._timer, (t1 - self._t0) / 1e9)
+        if self._watch is not None:
+            self.children_ns = self._watch[2]
+            plane._watch = self._watch = None
         tl.depth = depth - 1
         if exc_type is not None:
             self.args["error"] = exc_type.__name__
@@ -817,6 +868,10 @@ class TracePlane:
         # here (atomic, lock-free) and every locked section drains first.
         self._deferred: deque = deque()
         self._stage_end = _StageEnd(self)
+        # ``[thread id, children's depth, summed ns]`` while a span of
+        # this plane watches its children (``_Span.watch``), else None.
+        # Only the watching thread writes the sum.
+        self._watch: "list | None" = None
 
     # -- configuration ---------------------------------------------------
 
@@ -1030,6 +1085,8 @@ class TracePlane:
             name, t0, _depth, ctx = stages.pop()
             if ctx is not None:
                 _jax_annotation_exit(ctx)
+            if self._watch is not None:
+                self._watch_child(threading.get_ident(), _depth, now - t0)
             with self._lock:
                 self._drain_deferred()
                 self._time_span(name, t0, now)
@@ -1068,6 +1125,8 @@ class TracePlane:
         self, name: str, t0: int, t1: int, depth: int, args: dict
     ) -> None:
         tid = threading.get_ident()
+        if self._watch is not None:
+            self._watch_child(tid, depth, t1 - t0)
         if self._tags:
             args = {**self._tags, **args}
         sink = self._sink
@@ -1080,6 +1139,20 @@ class TracePlane:
                 sink(rec)
             except Exception:  # a broken sink must not break the plane
                 pass
+
+    def _watch_child(self, tid: int, depth: int, ns: int) -> None:
+        """A span, lap or stage of ``depth`` closed on thread ``tid``:
+        add it to the watching span's sum if it is that span's direct
+        child.  A span that closes INSIDE an open stage of the same
+        level (``replay.reconcile.evict`` in ``.write``) is the stage's
+        time already."""
+        w = self._watch
+        if w is None or w[1] != depth or w[0] != tid:
+            return
+        stages = getattr(self._tls, "stages", None)
+        if stages and stages[-1][2] == depth:
+            return
+        w[2] += ns
 
     def _time_span(self, name: str, t0: int, t1: int) -> None:  # ksimlint: lock-held(_lock)
         """The timing layer alone: one observation in ``name``'s
@@ -1131,9 +1204,10 @@ class TracePlane:
         """Record a span WITHOUT taking the lock (see ``_deferred``);
         it reaches histograms and ring at the next locked section."""
         t = threading.current_thread()
-        self._deferred.append(
-            (name, t0, t1, t.ident, t.name, getattr(self._tls, "depth", 0), args)
-        )
+        depth = getattr(self._tls, "depth", 0)
+        if self._watch is not None:
+            self._watch_child(t.ident, depth, t1 - t0)
+        self._deferred.append((name, t0, t1, t.ident, t.name, depth, args))
 
     # -- evidence --------------------------------------------------------
 

@@ -709,16 +709,24 @@ class SchedulerService:
                             run[0], nominees, nodes, featurizer, factory,
                             namespaces, volume_kw, prof, sched_name,
                         )
+                cpu0 = time.thread_time()
                 with TRACE.phase("service.bind", self.metrics, "bind") as ph:
-                    render_s, store_s, done, written, formatted = self._bind_results(
+                    (
+                        render_s, store_s, hooks_s, done, written, formatted,
+                    ) = self._bind_results(
                         run, feats, plugins, res, placements, prof=prof,
                         nominees=nominees,
                     )
                     ph.set(render_s=round(render_s, 6), store_s=round(store_s, 6))
+                # This thread's own CPU time across the bind: against the
+                # ``bind`` timer it says whether a slow bind was slow code
+                # or a thread that did not run.
+                self.metrics.observe("bind_cpu", time.thread_time() - cpu0)
                 # Per-pod work is summed with two clock reads a pod and
                 # recorded once per pass: never a span per pod.
                 self.metrics.observe("render", render_s)
                 self.metrics.observe("bind_store", store_s)
+                self.metrics.observe("bind_hooks", hooks_s)
                 self.metrics.inc("render_values", written)
                 self.metrics.inc("render_values_formatted", formatted)
                 remaining = remaining[done:]
@@ -1197,25 +1205,29 @@ class SchedulerService:
 
     def _bind_results(
         self, queue, feats, plugins, res, placements, prof=None, nominees=None
-    ) -> tuple[float, float, int, int, int]:
+    ) -> tuple[float, float, float, int, int, int]:
         """Decode, render and write back the pods of one engine run, up
         to and including the first whose preemption found victims (their
         going and its nomination change what every later pod sees: the
         pass evaluates the rest anew); returns the seconds summed over
-        the pods inside ``render_pod_results`` and inside
-        ``store.rewrap`` (the rest of the ``bind`` timer is the host hook
-        chains and the loop itself), how many pods were written, and from
+        the pods inside ``render_pod_results``, inside ``store.rewrap``
+        and inside the host hook chains around them (PostFilter, Reserve,
+        Permit and its settling, PreBind, Bind, PostBind, the victims'
+        evictions: the stretches between the other two's readings, so
+        the rest of the ``bind`` timer is the loop itself), how many
+        pods were written, and from
         the run's ``RenderCtx`` the score values written and the integers
         formatted afresh for them.
         ``nominees``: the nominations that stand as the run begins
         (``_live_nominees``; none changes before the run ends), for the
         dry runs of its preemptors."""
         clock = time.perf_counter
-        render_s = store_s = 0.0
+        render_s = store_s = hooks_s = 0.0
         render_ctx = RenderCtx(feats, plugins) if self._record == "full" else None
         done = 0
         for j, pod in enumerate(queue):
             done = j + 1
+            t_pod = clock()
             sel = int(res.selected[j])
             node_name = feats.nodes.names[sel] if sel >= 0 else None
             nominated, victims, postfilter = None, [], None
@@ -1265,6 +1277,7 @@ class SchedulerService:
                 if not bind_ok:
                     self._run_unreserve(plugins, pod, node_name)
             t0 = clock()
+            hooks_s += t0 - t_pod
             anno = (
                 render_pod_results(
                     feats,
@@ -1283,13 +1296,15 @@ class SchedulerService:
                 if self._record == "full"
                 else {}
             )
-            render_s += clock() - t0
+            t_pod = clock()
+            render_s += t_pod - t0
             node_name_settle = None if reserve_failed else node_name
             node_name, parked = self._settle_permit(
                 pod, node_name_settle, permit_verdict, wait_deadlines, anno,
                 placements, plugins=plugins, prof=prof,
             )
             if parked:
+                hooks_s += clock() - t_pod
                 continue
             if not bind_ok:
                 # A Reserve/PreBind/Bind failure fails the cycle: the pod
@@ -1328,6 +1343,7 @@ class SchedulerService:
                 return new
 
             t0 = clock()
+            hooks_s += t0 - t_pod
             try:
                 updated = self._store.rewrap(
                     "pods", name_of(pod), namespace_of(pod), rebuild
@@ -1342,7 +1358,8 @@ class SchedulerService:
                     namespace_of(pod), name_of(pod),
                 )
                 continue
-            store_s += clock() - t0
+            t_pod = clock()
+            store_s += t_pod - t0
             with self._own_rvs_lock:
                 self._own_rvs.add(updated["metadata"]["resourceVersion"])
             if node_name is not None:
@@ -1355,11 +1372,13 @@ class SchedulerService:
             placements[f"{namespace_of(pod)}/{name_of(pod)}"] = node_name
             if nominated and node_name is None:
                 self._clear_lower_nominations(nominated, pod)
+                hooks_s += clock() - t_pod
                 break
+            hooks_s += clock() - t_pod
         if render_ctx is None:
-            return render_s, store_s, done, 0, 0
+            return render_s, store_s, hooks_s, done, 0, 0
         return (
-            render_s, store_s, done,
+            render_s, store_s, hooks_s, done,
             render_ctx.values_written, render_ctx.values_formatted,
         )
 

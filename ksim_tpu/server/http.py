@@ -505,14 +505,15 @@ class _Handler(BaseHTTPRequestHandler):
         (``jobs.submit.read`` / ``.parse`` / ``.build`` / ``.enqueue``)
         show in a traced slice.  That plane is off in an untraced
         server, so the same boundaries are read here on a
-        ``SubmitClock`` the job keeps: its result's ``submit`` block
-        (the benchmark's ``submit_s_per_job``).  A refused submission
+        ``JobClock`` the job keeps: its result's ``submit`` block
+        (the benchmark's ``submit_s_per_job``) and the start of its
+        ``account``.  A refused submission
         has no job and leaves the span alone."""
         from ksim_tpu.jobs import (
             JobLimitExceeded,
             JobQueueFull,
+            JobClock,
             JobThrottled,
-            SubmitClock,
         )
         from ksim_tpu.scenario.spec import ScenarioSpecError
 
@@ -520,7 +521,7 @@ class _Handler(BaseHTTPRequestHandler):
         # collection while the body is read and parsed is one its
         # client waits on.
         runtime0 = runtime_totals()
-        clock = SubmitClock()
+        clock = JobClock()
         try:
             TRACE.stage("jobs.submit.read")
             raw = self._read_body()
@@ -547,7 +548,7 @@ class _Handler(BaseHTTPRequestHandler):
                 doc,
                 tenant=self.headers.get("X-Ksim-Tenant"),
                 runtime0=runtime0,
-                submit_clock=clock,
+                clock=clock,
             )
         except ScenarioSpecError as e:
             self._json(400, {"message": str(e)})
@@ -608,18 +609,22 @@ class _Handler(BaseHTTPRequestHandler):
         if sub == "":
             self._json(200, job.status())
         elif sub == "result":
-            state, result, error = job.result_view()
-            if state == "succeeded":
-                self._json(200, {"id": job.id, "state": state, **(result or {})})
-            elif state in ("failed", "cancelled", "interrupted"):
-                self._json(
-                    200,
-                    {"id": job.id, "state": state, "phase": "Failed", "message": error},
-                )
-            else:
-                self._json(
-                    409, {"message": f"job {job_id} is {state}; result not ready"}
-                )
+            # The last thing a job's client waits for: the document
+            # serialised and written (global plane, like jobs.submit).
+            with TRACE.span("jobs.result", job=job_id):
+                state, result, error = job.result_view()
+                if state == "succeeded":
+                    self._json(200, {"id": job.id, "state": state, **(result or {})})
+                elif state in ("failed", "cancelled", "interrupted"):
+                    self._json(
+                        200,
+                        {"id": job.id, "state": state, "phase": "Failed",
+                         "message": error},
+                    )
+                else:
+                    self._json(
+                        409, {"message": f"job {job_id} is {state}; result not ready"}
+                    )
         elif sub == "trace":
             # The JOB's private ring — the isolation story made visible:
             # only this tenant's spans/events, every record job-tagged.

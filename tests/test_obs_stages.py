@@ -31,8 +31,17 @@ PROGRAM = "replay.lower.featurize.program"
 SUBMIT_STAGES = tuple(
     f"jobs.submit.{s}" for s in ("read", "parse", "build", "enqueue")
 )
+# PR 51: the job's start and end, and the seams of the lowering's last lap.
+JOB_STAGES = (
+    "jobs.run.build", "jobs.finish.digest", "jobs.finish.document",
+    "jobs.finish.release",
+)
+TENSOR_SEAMS = tuple(
+    f"replay.lower.tensors.{s}" for s in ("state", "interpod", "ranks", "statics")
+)
 STAGE_NAMES = (
     RECONCILE_STAGES + (EFFECTS,) + FEATURIZER_STAGES + (PROGRAM,) + SUBMIT_STAGES
+    + JOB_STAGES + TENSOR_SEAMS
 )
 
 
@@ -151,7 +160,7 @@ def test_plane_off_is_the_noop_singleton(name):
 
 def test_every_stage_name_is_registered():
     assert set(STAGE_NAMES) <= set(obs.SPAN_NAMES)
-    assert len(set(STAGE_NAMES)) == 16
+    assert len(set(STAGE_NAMES)) == 24
 
 
 def test_a_stage_follows_the_threads_scoped_plane(fresh):
@@ -235,6 +244,31 @@ def test_job_result_carries_every_stage_below_its_parent(served_job):
         assert result["latency"][name]["count"] == lowerings, name
 
 
+def test_job_result_carries_the_jobs_own_stages_and_the_tensor_seams(served_job):
+    """What had closed when the document took its latency summary is in
+    it: the build, the digest, every seam once a lowering, tiling the
+    lap.  (``jobs.finish.document`` and ``.release`` close after it:
+    the ``account`` carries their seconds.)"""
+    result, _trace = served_job
+    latency, phases = result["latency"], result["phases"]
+    for name in ("jobs.run.build", "jobs.finish.digest"):
+        assert latency[name]["count"] == 1, name
+        assert name not in phases  # outside runner.run, like jobs.run.snapshot
+    lowerings = latency["replay.lower.tensors"]["count"]
+    for name in TENSOR_SEAMS:
+        # The walk-order stage cuts the ranks seam in two on a sampling
+        # service only; this job does not sample.
+        assert latency[name]["count"] == lowerings, name
+    eps = 1e-5
+    seams = sum(phases[n] for n in TENSOR_SEAMS)
+    assert seams <= phases["replay.lower.tensors"] + eps
+    assert seams >= 0.9 * phases["replay.lower.tensors"]
+    account = result["account"]
+    assert account["build_s"] == pytest.approx(
+        latency["jobs.run.build"]["mean_seconds"], abs=1e-3
+    )
+
+
 def test_job_result_carries_the_submit_block(served_job):
     result, _trace = served_job
     submit = result["submit"]
@@ -280,7 +314,10 @@ def test_job_ring_holds_no_stage_and_the_parents_keep_their_self_time(served_job
     def inside(e, p):
         return e is not p and e[3] == p[3] and p[0] <= e[0] and e[1] <= p[1]
 
-    for parent in ("replay.lower.featurize", "replay.reconcile"):
+    for parent in (
+        "replay.lower.featurize", "replay.reconcile", "replay.lower",
+        "replay.lower.tensors", "jobs.run",
+    ):
         want = 0
         for p in (e for e in ring if e[2] == parent):
             nested = [e for e in ring if inside(e, p)]
@@ -291,17 +328,24 @@ def test_job_ring_holds_no_stage_and_the_parents_keep_their_self_time(served_job
 
 
 def test_submit_clock_needs_all_five_readings():
-    from ksim_tpu.jobs import SubmitClock
+    from ksim_tpu.jobs import JobClock
 
-    clock = SubmitClock()
+    clock = JobClock()
     for _ in range(3):
         clock.mark()
     clock.close()
     doc = clock.seconds()
     assert list(doc) == ["read_s", "parse_s", "build_s", "enqueue_s", "total_s"]
-    short = SubmitClock()
+    short = JobClock()
     short.close()
     assert short.seconds() is None
+    # Four and a close that never comes is a handler that died: None,
+    # after the wait (not exercised here); more than five is no submit.
+    long = JobClock()
+    for _ in range(4):
+        long.mark()
+    long.close()
+    assert long.seconds() is None
 
 
 def test_a_job_no_handler_submitted_has_no_submit_block():

@@ -103,6 +103,7 @@ def _child_jobs(events: int, nodes: int, out_path: str) -> None:
                 "error": err,
                 "counts": counts,
                 "device_round_trips": replay.get("device_round_trips", 0),
+                "account": (result or {}).get("account"),
                 "ring": [
                     {"name": r["name"], "ph": r["ph"], "args": r["args"]}
                     for r in j.trace.ring_records()
@@ -471,6 +472,24 @@ def main() -> None:
                     _fail(f"job {jrec['id']}'s ring has no {span} span")
             if not names4.get("runner.step") and not names4.get("replay.reconcile"):
                 _fail(f"job {jrec['id']}'s ring has no step/reconcile spans")
+            # The job accounts for its own wall (docs/jobs.md "account"):
+            # the sequential parts and what none of them names ARE the
+            # wall, to the millisecond, with two jobs sharing the process.
+            account = jrec["account"] or {}
+            parts = (
+                "submit_s", "queue_s", "journal_s", "run_s", "digest_s",
+                "document_s", "release_s", "collect_s", "unnamed_s",
+            )
+            if any(account.get(k) is None or account[k] < 0 for k in parts):
+                _fail(f"job {jrec['id']}'s account lacks a part: {account}")
+            closed = sum(account[k] for k in parts)
+            if abs(closed - account["wall_s"]) > 1e-3:
+                _fail(
+                    f"job {jrec['id']}'s account does not close: parts "
+                    f"{closed:.6f} s against wall_s {account['wall_s']:.6f} s"
+                )
+            if not 0 <= account["run_self_s"] <= account["run_s"]:
+                _fail(f"job {jrec['id']}'s run_self_s is off: {account}")
         if counts_seen[0] != counts_seen[1]:
             _fail(f"concurrent jobs diverged: {counts_seen}")
         print(
