@@ -21,8 +21,10 @@ is a data file found by its name (README.md), and so are the generator kinds
 and the plain references of jobs; this file names none of them.  A run that
 cannot succeed — a generator kind or reference no file provides, a job that
 leaves the device path where the configuration guarantees every step on it, a
-warm-up document without a key the guarantees name — ends at once, non-zero,
-with the reason and no result line.
+warm-up document without a key the guarantees name, a traced request that ends
+before its slice opened (the slice lies ``trace.offset_share`` of the previous
+request's wall into the traced one: ``Slice``) — ends at once, non-zero, with
+the reason and no result line.
 ``--rehearsal`` runs the same code at the sizes the data files give under
 ``"rehearsal"`` with the server pinned to the CPU, and prints counts only.
 """
@@ -109,22 +111,67 @@ def reference_of(config: dict):
 # -- the traced slice ---------------------------------------------------------
 
 
+class BadTraffic(ValueError):
+    """A traffic file the harness refuses before a server starts."""
+
+
+class SliceMissed(client.CannotSucceed):
+    """The traced request ended before its slice was to open."""
+
+
+def trace_spec(traffic: dict) -> dict:
+    """The traffic file's ``trace`` block, held to its keys.  Where the
+    slice opens is ``offset_share`` of the wall of the last request the run
+    completed before the traced one (README.md): never a number of seconds,
+    which a faster program walks out from under."""
+    spec = dict(traffic.get("trace") or {})
+    name = traffic.get("name")
+    unknown = sorted(set(spec) - {"request", "offset_share", "max_s", "why"})
+    if unknown:
+        raise BadTraffic(
+            f"traffic {name!r}: trace holds {', '.join(unknown)}; its keys are request, "
+            "offset_share (a share of the previous request's wall, not seconds), max_s and why")
+    share, request = spec.get("offset_share", 0.0), spec.get("request", 0)
+    if isinstance(share, bool) or not isinstance(share, (int, float)) or not 0.0 <= share < 1.0:
+        raise BadTraffic(f"traffic {name!r}: trace.offset_share {share!r} is not in [0, 1)")
+    warmups = min(traffic.get("warmup_min", 2), traffic.get("warmup_max", 4))
+    if share > 0 and request == 0 and warmups < 1:
+        raise BadTraffic(
+            f"traffic {name!r}: trace.offset_share {share} is a share of the wall of the last "
+            "request completed before the traced one, and with request 0 and no warm-up "
+            "(warmup_min / warmup_max under 1) the run completes none before it")
+    return spec
+
+
 class Slice:
     """Profiler on for one slice of the window: SIGUSR1 / SIGUSR2 to the
     server child (``server_child.py``), from a timer, so that the traced
-    request is driven like any other."""
+    request is driven like any other.  The timer runs ``offset_share`` of
+    the previous request's wall, read in the same run."""
+
+    Timer = threading.Timer
 
     def __init__(self, server, spec: dict, out_dir: str) -> None:
-        self.server, self.spec, self.dir = server, spec, out_dir
-        self.state = "idle"  # idle -> armed -> on -> off
+        self.server, self.dir = server, out_dir
+        self.state = "idle"  # idle -> armed -> on -> off, or armed -> missed
+        self.request, self.max_s = spec.get("request", 0), spec.get("max_s", 8.0)
+        self.share = float(spec.get("offset_share", 0.0))
+        self.of_wall_s = self.open_at_s = self.request_wall_s = self._began = None
         self._lock = threading.Lock()
         self._timer = None
 
-    def request_starts(self, index: int) -> None:
-        if self.state == "idle" and index == self.spec.get("request", 0):
-            self.state = "armed"
-            self._timer = threading.Timer(self.spec.get("offset_s", 0.0), self._start)
-            self._timer.start()
+    def request_starts(self, index: int, previous_wall_s: "float | None") -> None:
+        if self.state != "idle" or index != self.request:
+            return
+        if self.share > 0 and not previous_wall_s:
+            raise client.CannotSucceed(
+                f"trace.offset_share {self.share}: no request was completed before request {index}")
+        self.of_wall_s = previous_wall_s
+        self.open_at_s = self.share * (previous_wall_s or 0.0)
+        self.state = "armed"
+        self._began = time.monotonic()
+        self._timer = self.Timer(self.open_at_s, self._start)
+        self._timer.start()
 
     def _start(self) -> None:
         with self._lock:
@@ -141,23 +188,43 @@ class Slice:
             time.sleep(0.05)
         with self._lock:
             if self.state == "on":
-                self._timer = threading.Timer(self.spec.get("max_s", 8.0), self.stop)
+                log(f"slice: tracing from {time.monotonic() - self._began:.3f} s into request "
+                    f"{self.request} (planned: {self.open_at_s:.3f} s = offset_share "
+                    f"{self.share} of {self.of_wall_s or 0.0:.3f} s), {self.max_s} s at most")
+                self._timer = self.Timer(self.max_s, self.stop)
                 self._timer.start()
 
     def stop(self) -> None:
         with self._lock:
-            if self.state == "on":
-                self.server.proc.send_signal(signal.SIGUSR2)
             if self._timer is not None:
                 self._timer.cancel()
-            self.state = "off"
+            if self.state == "on":
+                self.server.proc.send_signal(signal.SIGUSR2)
+                self.state = "off"
+            elif self.state == "armed":
+                self.state = "missed"
 
-    def request_ends(self) -> None:
+    def request_ends(self, wall_s: float) -> None:
         if self.state in ("armed", "on"):
+            self.request_wall_s = wall_s
             self.stop()
 
+    def placement(self) -> str:
+        """Where the slice was to open, in the one line a failed traced run
+        ends on: the share, the wall it was taken of, the second that makes,
+        and the traced request's own wall."""
+        if self.open_at_s is None:
+            return f"request {self.request}, the traced one, never started"
+        said = lambda v: "not known" if v is None else f"{v:.3f} s"
+        return (f"offset_share {self.share} of the previous request's wall {said(self.of_wall_s)} "
+                f"put the slice's start {self.open_at_s:.3f} s into the traced request, "
+                f"which took {said(self.request_wall_s)}")
+
     def collect(self, deadline: float) -> "str | None":
-        """Wait for the child to finish writing, return the trace file."""
+        """Wait for the child to finish writing, return the trace file.  A
+        slice that was never opened and closed leaves none to wait for."""
+        if self.state != "off":
+            return None
         while not os.path.exists(os.path.join(self.dir, "DONE")):
             if time.monotonic() >= deadline or self.server.proc.poll() is not None:
                 return None
@@ -234,6 +301,7 @@ def load_cell(bench: dict, name: str, rehearsal: bool) -> dict:
         config = overlay(config, config.get("rehearsal"))
         traffic = overlay(traffic, traffic.get("rehearsal"))
         locks = cell_doc.get("rehearsal_locks") or {}
+    traffic["trace"] = trace_spec(traffic)
     is_job = config["request"] == "job"
     return {"cell": cell, "config": config, "traffic": traffic, "locks": locks,
             "is_job": is_job, "guarantees": config["guarantees"],
@@ -275,6 +343,7 @@ class Driver:
         self.rng = random.Random(seed)
         self.reservoir: list = []  # a seeded sample of the window's raw answers
         self.started = 0
+        self.last_wall_s = None  # of the last request that succeeded, warm-ups included
 
     def request(self) -> dict:
         deadline = time.monotonic() + REQUEST_CAP_S
@@ -292,6 +361,8 @@ class Driver:
             log(f"request failed: {e}")
             return {"wall_s": 0.0, "failed": True}
         rec["units"] = self.inputs["units"]
+        if not rec["failed"]:
+            self.last_wall_s = rec["wall_s"]
         return rec
 
     def between(self) -> None:
@@ -302,10 +373,12 @@ class Driver:
         index = self.started
         self.started += 1
         if self.slice is not None:
-            self.slice.request_starts(index)
+            self.slice.request_starts(index, self.last_wall_s)
         rec = self.request()
         if self.slice is not None:
-            self.slice.request_ends()
+            self.slice.request_ends(rec["wall_s"])
+            if self.slice.state == "missed":
+                raise SliceMissed(self.slice.placement())
             if self.c["is_job"] and not rec.get("failed"):
                 rec["spans"] = self.server.job_spans(rec["id"])
         raw = rec.pop("raw", None)
@@ -496,7 +569,7 @@ def run(args) -> int:
     try:
         c = load_cell(bench, args.workload, args.rehearsal)
         inputs = build_inputs(c["config"], c["traffic"], args.seed)
-    except byname.Unknown as e:
+    except (byname.Unknown, BadTraffic) as e:
         log(f"FAILED before the server starts: {e}")
         return 2
     cell = c["cell"]
@@ -504,7 +577,7 @@ def run(args) -> int:
         f"{inputs['units']} units per request")
     server, work = start_server(c, bool(args.trace))
     profile_dir = os.path.join(work, "profile")
-    slice_ = Slice(server, c["traffic"].get("trace") or {}, profile_dir) if args.trace else None
+    slice_ = Slice(server, c["traffic"]["trace"], profile_dir) if args.trace else None
 
     def on_term(signum, frame):
         raise client.BenchFailure(f"signal {signum}")
@@ -516,10 +589,14 @@ def run(args) -> int:
     except (client.BenchFailure, OSError, ValueError, KeyError) as e:
         if slice_ is not None:
             slice_.stop()
-        log(f"FAILED: {type(e).__name__}: {e}")
-        log("server output, last 40 lines:\n" + server.log_tail())
+        missed = isinstance(e, SliceMissed)  # the placement's fault, not the server's
+        if not missed:
+            log(f"FAILED: {type(e).__name__}: {e}")
+            log("server output, last 40 lines:\n" + server.log_tail())
         server.stop()
         shutil.rmtree(work, ignore_errors=True)
+        if missed:
+            log(f"FAILED: the traced request ended before its slice opened: {e}")
         return 1
     warm, win, setup_s = got["warm"], got["win"], got["setup_s"]
     log("window closed" + (f"; trace file {'in hand' if trace_file else 'missing'}" if slice_ else ""))
@@ -592,8 +669,8 @@ def run(args) -> int:
             "metrics": metrics, "device": dev}
     if args.trace:
         if not trace or trace["busy_s"] <= 0:
-            log("FAILED: the traced slice holds no device operation")
             log_compared(compared)
+            log(f"FAILED: the traced slice holds no device operation: {slice_.placement()}")
             return 1
         dev["busy_s"], dev["window_s"] = trace["busy_s"], trace["window_s"]
         line["breakdown"] = {"device_ops": trace["device_ops"], "idle_gaps": trace["idle_gaps"]}

@@ -83,16 +83,18 @@ def test_paths_are_the_documented_ones():
     assert spec("featurize_node_tables_built")["path"] == ["replay", "featurize_node_builds"]
 
 
-def test_benchmark_json_lists_them_last_and_in_the_job_cells():
+def test_benchmark_json_lists_them_in_one_block_and_in_the_job_cells():
     with open(os.path.join(ROOT, "BENCHMARK.json"), encoding="utf-8") as f:
         per_layer = json.load(f)["per_layer"]
-    assert len(per_layer) == 62
-    added = per_layer[45:]
+    # PR 38's seventeen closed the list at 62; later PRs append after them.
+    assert len(per_layer) >= 62
+    added = per_layer[45:62]
     assert sorted(m["name"] for m in added) == NAMES
     for m in added:
         assert m["moves"] == "events_per_s" and m["better"] == "lower"
+        # The cells of PR 38; a later PR's cell is appended behind them.
         cells = ["sperf-5k-preempt_basic"] if m["name"] == "reconcile_evict_s_per_job" else JOB_CELLS
-        assert m["workloads"] == cells, m["name"]
+        assert m["workloads"][:len(cells)] == cells, m["name"]
         counted = m["name"] == "featurize_node_tables_built"
         assert (m["unit"], m["source"]) == (("count", "program_counter") if counted
                                             else ("s", "program_span"))
